@@ -8,9 +8,11 @@ Four pillars, mirroring the reference (``apex/__init__.py:1-23``):
   4. ``apex_tpu.mlp`` / ``normalization`` / ``fp16_utils`` — fused layers and
      legacy manual mixed-precision utilities
 
-Unlike the reference, every component has a pure-XLA fallback: nothing is a
-hard error in the absence of the Pallas fast path (cf. the reference's
-"no Python fallback" note, ``apex/__init__.py:10-16``).
+Unlike the reference (its "no Python fallback" note,
+``apex/__init__.py:10-16``), every Pallas kernel has a pure-XLA twin.  The
+twin is CHOSEN — by an argument (``impl=``, ``use_pallas=``, ``attn_impl=``,
+``backward=``) or by the platform (kernels interpret off-TPU) — never
+substituted when a kernel fails: a kernel that does not compile is an error.
 """
 
 from . import amp

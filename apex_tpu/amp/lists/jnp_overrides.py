@@ -4,7 +4,7 @@ TPU re-design of ``apex/amp/lists/torch_overrides.py:7-136`` and
 ``functional_overrides.py:18-91``: names here are attributes of ``jax.numpy``,
 ``jax.lax`` or ``jax.nn`` instead of torch namespaces.
 
-Categories (same taxonomy as the reference):
+Categories (the reference's own):
   - LOW_PREC_FUNCS: MXU-friendly ops run in fp16/bf16 (FP16_FUNCS/BFLOAT16_FUNCS)
   - FP32_FUNCS:     numerically sensitive ops forced to fp32
   - CASTS:          binary ops promoted to the widest input type

@@ -240,7 +240,8 @@ def vmem_estimate(bq, bk, D, esz, bias_per_q, bwd=False) -> int:
 
 
 from ...utils.pallas import (interpret_mode as _interpret,
-                             compiler_params as _compiler_params)
+                             compiler_params as _compiler_params,
+                             out_vma as _out_vma, sds as _sds)
 
 
 def _dropout_keep(seed, bh, row0, col0, shape, rate):
@@ -403,6 +404,9 @@ def _flash_fwd(q, k, v, bias, causal, dropout_rate, seed, heads,
     bk = min(bk, Sk)
     grid = (BH, (Sq + bq - 1) // bq, (Sk + bk - 1) // bk)
     seed_arr = jnp.reshape(jnp.asarray(seed, jnp.int32), (1,))
+    # outputs vary over every mesh axis an input varies over: under
+    # shard_map(check_vma=True) an untyped out_shape is an error
+    vma = _out_vma(q, k, v, bias)
 
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, bq=bq, bk=bk, causal=causal,
@@ -424,8 +428,8 @@ def _flash_fwd(q, k, v, bias, causal, dropout_rate, seed, heads,
             pl.BlockSpec((1, bq, 1), lambda bh, qi, ki: (bh, qi, 0),
                          memory_space=pltpu.VMEM),
         ],
-        out_shape=[jax.ShapeDtypeStruct((BH, Sq, D), q.dtype),
-                   jax.ShapeDtypeStruct((BH, Sq, 1), jnp.float32)],
+        out_shape=[_sds((BH, Sq, D), q.dtype, vma),
+                   _sds((BH, Sq, 1), jnp.float32, vma)],
         scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, D), jnp.float32)],
@@ -437,6 +441,7 @@ def _flash_fwd(q, k, v, bias, causal, dropout_rate, seed, heads,
         compiler_params=_compiler_params(
             ("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
+        name="apex_flash_fwd",
     )(seed_arr, q, k, v, bias)
     return out[:, :orig_sq], lse[:, :orig_sq]
 
@@ -651,11 +656,13 @@ def _flash_bwd_dq(q, k, v, bias, causal, dropout_rate, seed, heads, lse,
         in_specs=dq_in,
         out_specs=pl.BlockSpec((1, bq, D), lambda bh, qi, ki: (bh, qi, 0),
                                memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((BH, Sq, D), q.dtype),
+        out_shape=_sds((BH, Sq, D), q.dtype,
+                       _out_vma(q, k, v, bias, do, lse, delta)),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         compiler_params=_compiler_params(
             ("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
+        name="apex_flash_bwd_dq",
     )(seed_arr, q, k, v, bias, do, lse, delta)
     return dq[:, :orig_sq]
 
@@ -697,6 +704,8 @@ def _flash_bwd_dkv(q, k, v, bias, causal, dropout_rate, seed, heads, lse,
     lse, delta = _pad_lse_delta(lse, delta, Sq)
     seed_arr = jnp.reshape(jnp.asarray(seed, jnp.int32), (1,))
 
+    vma = _out_vma(q, k, v, bias, do, lse, delta)
+
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, bq=bq, bk=bk, causal=causal,
                           dropout_rate=dropout_rate, heads=heads),
@@ -708,13 +717,14 @@ def _flash_bwd_dkv(q, k, v, bias, causal, dropout_rate, seed, heads, lse,
             pl.BlockSpec((1, bk, D), lambda bh, ki, qi: (bh, ki, 0),
                          memory_space=pltpu.VMEM),
         ],
-        out_shape=[jax.ShapeDtypeStruct((BH, Sk, D), k.dtype),
-                   jax.ShapeDtypeStruct((BH, Sk, D), v.dtype)],
+        out_shape=[_sds((BH, Sk, D), k.dtype, vma),
+                   _sds((BH, Sk, D), v.dtype, vma)],
         scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
                         pltpu.VMEM((bk, D), jnp.float32)],
         compiler_params=_compiler_params(
             ("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
+        name="apex_flash_bwd_dkv",
     )(seed_arr, q, k, v, bias, do, lse, delta)
     return dk[:, :orig_sk], dv[:, :orig_sk]
 
@@ -733,6 +743,7 @@ def _flash_bwd_fused(q, k, v, bias, causal, dropout_rate, seed, heads, lse,
     lse, delta = _pad_lse_delta(lse, delta, Sq)
     seed_arr = jnp.reshape(jnp.asarray(seed, jnp.int32), (1,))
     nk = (Sk + bk - 1) // bk
+    vma = _out_vma(q, k, v, bias, do, lse, delta)
 
     dqp, dk, dv = pl.pallas_call(
         functools.partial(_bwd_fused_kernel, bq=bq, bk=bk, causal=causal,
@@ -747,14 +758,15 @@ def _flash_bwd_fused(q, k, v, bias, causal, dropout_rate, seed, heads, lse,
             pl.BlockSpec((1, bk, D), lambda bh, ki, qi: (bh, ki, 0),
                          memory_space=pltpu.VMEM),
         ],
-        out_shape=[jax.ShapeDtypeStruct((BH, nk, Sq, D), jnp.float32),
-                   jax.ShapeDtypeStruct((BH, Sk, D), k.dtype),
-                   jax.ShapeDtypeStruct((BH, Sk, D), v.dtype)],
+        out_shape=[_sds((BH, nk, Sq, D), jnp.float32, vma),
+                   _sds((BH, Sk, D), k.dtype, vma),
+                   _sds((BH, Sk, D), v.dtype, vma)],
         scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
                         pltpu.VMEM((bk, D), jnp.float32)],
         compiler_params=_compiler_params(
             ("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
+        name="apex_flash_bwd_fused",
     )(seed_arr, q, k, v, bias, do, lse, delta)
     dq = jnp.sum(dqp, axis=1).astype(q.dtype)
     return dq[:, :orig_sq], dk[:, :orig_sk], dv[:, :orig_sk]

@@ -32,7 +32,8 @@ NEG_INF = -1e30
 
 
 from ...utils.pallas import (interpret_mode as _interpret,
-                             compiler_params as _compiler_params)
+                             compiler_params as _compiler_params,
+                             out_vma as _out_vma, sds as _sds)
 
 
 # --------------------------------------------------------------------------
@@ -115,8 +116,9 @@ def _xent_fwd_pallas(logits, labels, smoothing, bn=256, bh=512):
             pl.BlockSpec((bn, 1), lambda i, j: (i, 0),
                          memory_space=pltpu.VMEM),
         ],
-        out_shape=[jax.ShapeDtypeStruct((n, 1), jnp.float32),
-                   jax.ShapeDtypeStruct((n, 1), jnp.float32)],
+        # typed with the inputs' varying mesh axes: under
+        # shard_map(check_vma=True) an untyped out_shape is an error
+        out_shape=[_sds((n, 1), jnp.float32, _out_vma(lab, logits))] * 2,
         scratch_shapes=[pltpu.VMEM((bn, 1), jnp.float32)] * 4,
         # rows (i) are independent; the vocab walk (j) accumulates into
         # scratch sequentially.  Same declaration the measured-fast
@@ -124,6 +126,7 @@ def _xent_fwd_pallas(logits, labels, smoothing, bn=256, bh=512):
         compiler_params=_compiler_params(
             ("parallel", "arbitrary")),
         interpret=_interpret(),
+        name="apex_xentropy_fwd",
     )(lab, logits)
     return loss[:, 0], lse[:, 0]
 

@@ -36,8 +36,8 @@ leave the audit trail.  The shape:
 
 Writer-validates (the goodput-ledger mold): :func:`control_violations`
 runs before every :func:`write`, and the same auditor is what
-``tools/control_chaos.py`` and the watcher's ``control_chaos`` stage
-re-run on the artifact — one schema, two enforcement points.
+``tools/control_chaos.py`` re-runs on the artifact — one schema, two
+enforcement points.
 
 Like ``telemetry/goodput.py`` this module imports no jax at module
 scope and must import standalone: the tooling layer file-loads it to

@@ -15,70 +15,44 @@ from __future__ import annotations
 
 import ctypes
 import dataclasses
-import hashlib
-import os
-import subprocess
-import tempfile
+import warnings
 from typing import Optional, Tuple
 
 import numpy as np
 
-_SRC = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.dirname(os.path.abspath(__file__)))), "csrc", "prefetch.cpp")
+from ..utils import native
 
 _lib = None
 _lib_tried = False
 
 
-def _build_dirs():
-    yield os.path.join(os.path.dirname(_SRC), "_build")
-    yield os.path.join(tempfile.gettempdir(), "apex_tpu_build")
-
-
 def _load() -> Optional[ctypes.CDLL]:
     global _lib, _lib_tried
-    if _lib is not None or _lib_tried:
+    if _lib_tried:
         return _lib
     _lib_tried = True
-    if not os.path.exists(_SRC):
-        return None
     try:
-        with open(_SRC, "rb") as f:
-            tag = hashlib.sha256(f.read()).hexdigest()[:16]
-    except OSError:
+        so, _ = native.build("prefetch.cpp", "libapex_tpu_prefetch")
+    except native.NativeBuildError as err:
+        warnings.warn(f"data.loader: native prefetch engine unavailable, "
+                      f"using the python ring instead ({err})",
+                      RuntimeWarning)
         return None
-    for d in _build_dirs():
-        so = os.path.join(d, f"libapex_tpu_prefetch_{tag}.so")
-        if not os.path.exists(so):
-            try:
-                os.makedirs(d, exist_ok=True)
-                tmp = so + f".tmp{os.getpid()}"
-                subprocess.run(
-                    ["g++", "-O3", "-shared", "-fPIC", "-std=c++17",
-                     "-pthread", "-o", tmp, _SRC],
-                    check=True, capture_output=True, timeout=120)
-                os.replace(tmp, so)
-            except Exception:
-                continue
-        try:
-            lib = ctypes.CDLL(so)
-        except OSError:
-            continue
-        lib.pf_create.restype = ctypes.c_void_p
-        lib.pf_create.argtypes = [
-            ctypes.c_char_p, ctypes.c_void_p, ctypes.c_int64,
-            ctypes.c_int64, ctypes.c_int64, ctypes.c_int32, ctypes.c_int32,
-            ctypes.c_int32, ctypes.c_uint64]
-        lib.pf_acquire.restype = ctypes.c_int32
-        lib.pf_acquire.argtypes = [
-            ctypes.c_void_p, ctypes.POINTER(ctypes.c_void_p),
-            ctypes.POINTER(ctypes.c_void_p),
-            ctypes.POINTER(ctypes.c_int64)]
-        lib.pf_release.argtypes = [ctypes.c_void_p, ctypes.c_int32]
-        lib.pf_destroy.argtypes = [ctypes.c_void_p]
-        _lib = lib
-        return _lib
-    return None
+    lib = ctypes.CDLL(so)
+    lib.pf_create.restype = ctypes.c_void_p
+    lib.pf_create.argtypes = [
+        ctypes.c_char_p, ctypes.c_void_p, ctypes.c_int64,
+        ctypes.c_int64, ctypes.c_int64, ctypes.c_int32, ctypes.c_int32,
+        ctypes.c_int32, ctypes.c_uint64]
+    lib.pf_acquire.restype = ctypes.c_int32
+    lib.pf_acquire.argtypes = [
+        ctypes.c_void_p, ctypes.POINTER(ctypes.c_void_p),
+        ctypes.POINTER(ctypes.c_void_p),
+        ctypes.POINTER(ctypes.c_int64)]
+    lib.pf_release.argtypes = [ctypes.c_void_p, ctypes.c_int32]
+    lib.pf_destroy.argtypes = [ctypes.c_void_p]
+    _lib = lib
+    return _lib
 
 
 def native_available() -> bool:

@@ -58,9 +58,11 @@ def _block_rows(total: int) -> int:
     return max(br, 1)
 
 
-def _grid_call(kernel, flats, out_dtypes, *, scalars=None, block_rows=None):
-    """Run ``kernel`` over 1-D flat buffers chunked as (block_rows, LANE)
-    with ``parallel`` grid semantics (PERF_NOTES §2).
+def _grid_call(name, kernel, flats, out_dtypes, *, scalars=None,
+               block_rows=None):
+    """Run ``kernel`` (named ``name`` in lowered text and traces) over
+    1-D flat buffers chunked as (block_rows, LANE) with ``parallel`` grid
+    semantics (PERF_NOTES §2).
 
     flats: list of (total,) arrays (equal length).  scalars: optional (1, S)
     f32 array placed in SMEM.
@@ -99,6 +101,7 @@ def _grid_call(kernel, flats, out_dtypes, *, scalars=None, block_rows=None):
         compiler_params=_compiler_params(
             ("parallel",)),
         interpret=_interpret(),
+        name=name,
     )(*ins)
     if not isinstance(outs, (list, tuple)):
         outs = (outs,)
@@ -125,7 +128,8 @@ def multi_tensor_scale(flat_in, scale, out_dtype=None):
         y = x_ref[:].astype(jnp.float32) * s_ref[0, 0]
         o_ref[:] = y.astype(o_ref.dtype)
 
-    (out,) = _grid_call(kernel, [flat_in], [out_dtype], scalars=scalars)
+    (out,) = _grid_call("apex_mt_scale", kernel, [flat_in], [out_dtype],
+                        scalars=scalars)
     return out, _overflow_flag(out)
 
 
@@ -143,8 +147,8 @@ def multi_tensor_axpby(flat_x, flat_y, a, b, out_dtype=None):
              + y_ref[:].astype(jnp.float32) * s_ref[0, 1])
         o_ref[:] = r.astype(o_ref.dtype)
 
-    (out,) = _grid_call(kernel, [flat_x, flat_y], [out_dtype],
-                        scalars=scalars)
+    (out,) = _grid_call("apex_mt_axpby", kernel, [flat_x, flat_y],
+                        [out_dtype], scalars=scalars)
     return out, _overflow_flag(out)
 
 
@@ -186,6 +190,7 @@ def multi_tensor_l2norm(flat_in):
         compiler_params=_compiler_params(
             ("arbitrary",)),
         interpret=_interpret(),
+        name="apex_l2norm",
     )(flat_in.reshape(rows, LANE))
     return jnp.sqrt(sumsq[0, 0])
 
@@ -225,7 +230,8 @@ def fused_adam_flat(flat_g, flat_p, flat_m, flat_v, scalars, *,
         if maybe_model:
             maybe_model[0][:] = p_new.astype(maybe_model[0].dtype)
 
-    return _grid_call(kernel, [flat_g, flat_p, flat_m, flat_v], out_dtypes,
+    return _grid_call("apex_adam_flat", kernel,
+                      [flat_g, flat_p, flat_m, flat_v], out_dtypes,
                       scalars=scalars)  # [p, m, v] (+ model copy)
 
 
@@ -260,7 +266,8 @@ def fused_lamb_stage1_flat(flat_g, flat_p, flat_m, flat_v, scalars, *,
         mo_ref[:] = m
         vo_ref[:] = v
 
-    return _grid_call(kernel, [flat_g, flat_p, flat_m, flat_v],
+    return _grid_call("apex_lamb_stage1_flat", kernel,
+                      [flat_g, flat_p, flat_m, flat_v],
                       [jnp.float32, jnp.float32, jnp.float32],
                       scalars=scalars)  # [update, m, v]
 

@@ -26,7 +26,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..utils.pallas import (interpret_mode as _interpret,
-                            compiler_params as _compiler_params)
+                            compiler_params as _compiler_params,
+                            out_vma as _out_vma, sds as _sds)
 
 
 def _kernel(activation, has_bias, x_ref, w_ref, *refs):
@@ -99,11 +100,14 @@ def fused_dense_act(x, w, b=None, activation="relu", *, block_m=256,
         out_specs=pl.BlockSpec((block_m, block_n),
                                lambda mi, ni, ki: (mi, ni),
                                memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((Mp, Np), x.dtype),
+        # typed with the inputs' varying mesh axes: under
+        # shard_map(check_vma=True) an untyped out_shape is an error
+        out_shape=_sds((Mp, Np), x.dtype, _out_vma(*ins)),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
         compiler_params=_compiler_params(
             ("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
+        name="apex_dense_act",
     )(*ins)
     return out[:M, :N]
 

@@ -26,7 +26,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..utils.pallas import (interpret_mode as _interpret,
-                            compiler_params as _compiler_params)
+                            compiler_params as _compiler_params,
+                            out_vma as _out_vma, sds as _sds)
 
 # per-block VMEM budget for the x block (fp32); leaves headroom for out +
 # double buffering within ~16 MB VMEM
@@ -124,17 +125,22 @@ def ln_fwd_pallas(x2d, weight, bias, eps):
         ins += [weight.reshape(1, h), bias.reshape(1, h)]
         in_specs += [_param_spec(h), _param_spec(h)]
 
+    vma = _out_vma(*ins)
+
     out, mean, invvar = pl.pallas_call(
         functools.partial(_fwd_kernel, eps, affine),
         grid=(grid,),
         in_specs=in_specs,
         out_specs=[_full_spec(br, h), _row_spec(br), _row_spec(br)],
-        out_shape=[jax.ShapeDtypeStruct((rows, h), x2d.dtype),
-                   jax.ShapeDtypeStruct((rows, 1), jnp.float32),
-                   jax.ShapeDtypeStruct((rows, 1), jnp.float32)],
+        # typed with the inputs' varying mesh axes: under
+        # shard_map(check_vma=True) an untyped out_shape is an error
+        out_shape=[_sds((rows, h), x2d.dtype, vma),
+                   _sds((rows, 1), jnp.float32, vma),
+                   _sds((rows, 1), jnp.float32, vma)],
         compiler_params=_compiler_params(
             ("parallel",)),
         interpret=_interpret(),
+        name="apex_layer_norm_fwd",
     )(*ins)
     return out[:n], mean[:n], invvar[:n]
 
@@ -170,10 +176,11 @@ def ln_bwd_pallas(g2d, x2d, mean, invvar, weight, eps):
         grid=(grid,),
         in_specs=in_specs,
         out_specs=_full_spec(br, h),
-        out_shape=jax.ShapeDtypeStruct((rows, h), x2d.dtype),
+        out_shape=_sds((rows, h), x2d.dtype, _out_vma(*ins)),
         compiler_params=_compiler_params(
             ("parallel",)),
         interpret=_interpret(),
+        name="apex_layer_norm_bwd",
     )(*ins)
     return dx[:n]
 

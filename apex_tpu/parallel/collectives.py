@@ -369,16 +369,13 @@ def adasum_merge(stacked):
 
 
 def _gather(x, axis_name, *, tiled: bool = False):
-    """all_gather with a leading world axis, typed *invariant* where the
-    jax supports it (every device provably holds the same stack — the
-    replication fact check_vma needs, same pattern as the ZeRO param
-    allgather).  ``tiled=True`` concatenates along axis 0 instead of
-    stacking (the flat-buffer allgather shape)."""
-    try:
-        from jax._src.lax.parallel import all_gather_invariant
-        return all_gather_invariant(x, axis_name, axis=0, tiled=tiled)
-    except ImportError:        # pragma: no cover - older jax
-        return jax.lax.all_gather(x, axis_name, axis=0, tiled=tiled)
+    """all_gather with a leading world axis, typed *invariant* (every
+    device provably holds the same stack — the replication fact
+    check_vma needs, same pattern as the ZeRO param allgather).
+    ``tiled=True`` concatenates along axis 0 instead of stacking (the
+    flat-buffer allgather shape)."""
+    from jax._src.lax.parallel import all_gather_invariant
+    return all_gather_invariant(x, axis_name, axis=0, tiled=tiled)
 
 
 # ---------------------------------------------------------------------------

@@ -70,19 +70,17 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from .mesh import DATA_AXIS, current_mesh, axis_is_bound, lax_axis_size
+from .mesh import DATA_AXIS, current_mesh, axis_is_bound
+from ..utils.pallas import presummed
 
 
 def _leaf_paths(grads, need_paths: bool):
-    """Flatten with key paths when available (per-bucket callable
-    routing); path strings are empty on jaxes without the API."""
+    """Flatten with key-path strings (per-bucket callable routing);
+    empty strings when the caller routes nothing by path."""
     if need_paths:
-        fw = getattr(jax.tree_util, "tree_flatten_with_path", None)
-        if fw is not None:
-            pl, treedef = fw(grads)
-            keystr = getattr(jax.tree_util, "keystr", lambda kp: str(kp))
-            return ([l for _, l in pl], [keystr(kp) for kp, _ in pl],
-                    treedef)
+        pl, treedef = jax.tree_util.tree_flatten_with_path(grads)
+        return ([l for _, l in pl],
+                [jax.tree_util.keystr(kp) for kp, _ in pl], treedef)
     leaves, treedef = jax.tree_util.tree_flatten(grads)
     return leaves, [""] * len(leaves), treedef
 
@@ -126,7 +124,7 @@ def allreduce_tree(grads, *, axis_name: str = DATA_AXIS,
     from . import collectives as _coll
     if not axis_is_bound(axis_name):
         return grads if residuals is None else (grads, residuals)
-    world = lax_axis_size(axis_name)
+    world = jax.lax.axis_size(axis_name)
     # telemetry collective meter (docs/telemetry.md): payload bytes and
     # leaf count are static facts of the traced reduction — counted ONLY
     # for leaves that actually psum (vma-pre-summed leaves emit no
@@ -170,8 +168,6 @@ def allreduce_tree(grads, *, axis_name: str = DATA_AXIS,
     res_leaves = (jax.tree_util.tree_leaves(residuals)
                   if residuals is not None else [None] * len(leaves))
 
-    from ..utils.pallas import _vma_of
-
     def reduce_leaf(g, r, spec):
         orig_dtype = g.dtype
         # upcast BEFORE the vma branch: a pre-summed low-precision leaf
@@ -179,9 +175,7 @@ def allreduce_tree(grads, *, axis_name: str = DATA_AXIS,
         # pre-scheme code did
         if always_fp32 and orig_dtype != jnp.float32:
             g = g.astype(jnp.float32)
-        vma = _vma_of(g)
-        already_summed = vma is not None and axis_name not in vma
-        if already_summed:
+        if presummed(g, axis_name):
             # the cotangent psum ran; only the (pre*post) scaling remains
             scale = pre * post
             if scale != 1.0:

@@ -32,7 +32,7 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
-from .mesh import axis_is_bound, lax_axis_size
+from .mesh import axis_is_bound
 
 EXPERT_AXIS = "expert"
 
@@ -78,7 +78,7 @@ def moe_ffn(x, router_w, w_in, w_out, *, axis_name: Optional[str] = EXPERT_AXIS,
     T, D = x.shape
     e_local = w_in.shape[0]
     bound = axis_name is not None and axis_is_bound(axis_name)
-    n = lax_axis_size(axis_name) if bound else 1
+    n = jax.lax.axis_size(axis_name) if bound else 1
     e_total = e_local * n
     capacity = max(int(capacity_factor * T / e_total), 1)
 

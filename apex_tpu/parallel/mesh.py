@@ -151,17 +151,9 @@ def axis_is_bound(axis_name) -> bool:
     """
     names = (axis_name if isinstance(axis_name, (tuple, list))
              else (axis_name,))
-    try:
-        from jax._src.core import get_axis_env
-        env = get_axis_env()
-        return all(env.axis_exists(n) for n in names)
-    except ImportError:  # pragma: no cover - older/newer jax layout
-        try:
-            for n in names:
-                jax.lax.axis_index(n)
-            return True
-        except NameError:
-            return False
+    from jax._src.core import get_axis_env
+    env = get_axis_env()
+    return all(env.axis_exists(n) for n in names)
 
 
 def bound_axes(*names) -> tuple:
@@ -173,39 +165,7 @@ def axis_size(axis_name: str, mesh: Optional[Mesh] = None) -> int:
     mesh = mesh or current_mesh()
     if mesh is None:
         return 1
-    return dict(mesh.shape_tuple if hasattr(mesh, "shape_tuple") else
-                mesh.shape.items()).get(axis_name, 1)
-
-
-def lax_axis_size(axis_name: str) -> int:
-    """``jax.lax.axis_size`` across jax versions — the accessor only exists
-    in newer releases.  Inside a shard_map/pmap body, returns the bound
-    axis's size; the ``psum(1, axis)`` fallback is the classic idiom (a
-    unit constant summed over the axis folds to the static size at trace
-    time)."""
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-def shard_map(f, **kwargs):
-    """``shard_map`` across jax versions: newer jax moved it out of
-    ``jax.experimental`` and renamed the replication-check kwarg
-    ``check_rep`` -> ``check_vma``.  Accepts either spelling and
-    translates to whatever the installed jax understands, so callers (and
-    the tests) can be written against the current API without pinning."""
-    import inspect
-    try:
-        from jax import shard_map as _sm
-    except ImportError:                    # pre-rename jax
-        from jax.experimental.shard_map import shard_map as _sm
-    params = inspect.signature(_sm).parameters
-    for theirs, ours in (("check_rep", "check_vma"),
-                         ("check_vma", "check_rep")):
-        if ours in kwargs and ours not in params and theirs in params:
-            kwargs[theirs] = kwargs.pop(ours)
-    return _sm(f, **kwargs)
+    return dict(mesh.shape).get(axis_name, 1)
 
 
 def num_slices(devices: Optional[Sequence] = None) -> int:

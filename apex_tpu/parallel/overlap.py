@@ -65,8 +65,8 @@ Success is self-measuring: the per-bucket collectives meter through the
 same ``record_collective`` counters (logical bytes sum exactly to the
 deferred path's), and the A/B that proves loss parity is the same one
 in which the timeline's ``exposed_comm_fraction`` and the ledger's
-``badput.exposed_comm_ms`` must drop (``bench.py --overlap``,
-``tpu_watch.sh`` stage 2g).  See docs/parallel.md "Async overlap
+``badput.exposed_comm_ms`` must drop (``bench.py --overlap``).  See
+docs/parallel.md "Async overlap
 execution".
 """
 from __future__ import annotations
@@ -82,7 +82,8 @@ from typing import Callable, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from .mesh import DATA_AXIS, axis_is_bound, lax_axis_size
+from .mesh import DATA_AXIS, axis_is_bound
+from ..utils.pallas import presummed
 from ..multi_tensor_apply.flattener import LANE
 
 __all__ = ["MODES", "ENV_KNOB", "TUNING_KEY", "DEFAULT_MESSAGE_SIZE",
@@ -290,7 +291,7 @@ def bucketed_allreduce(grads, *, axis_name: str = DATA_AXIS,
             "and use the deferred allreduce_tree")
     if not axis_is_bound(axis_name):
         return grads if residuals is None else (grads, residuals)
-    world = lax_axis_size(axis_name)
+    world = jax.lax.axis_size(axis_name)
 
     from ..telemetry import events as _tel_events
     metering = _tel_events.metering()
@@ -312,8 +313,6 @@ def bucketed_allreduce(grads, *, axis_name: str = DATA_AXIS,
     out = [None] * n
     out_res = list(res_leaves)
 
-    from ..utils.pallas import _vma_of
-
     # pass 1: vma classification (trace-static, so the bucket layout
     # stays deterministic) — pre-summed leaves scale in place and never
     # bucket/meter, exactly as in allreduce_tree
@@ -323,8 +322,7 @@ def bucketed_allreduce(grads, *, axis_name: str = DATA_AXIS,
     for i, g in enumerate(leaves):
         if always_fp32 and g.dtype != jnp.float32:
             g = g.astype(jnp.float32)
-        vma = _vma_of(g)
-        if vma is not None and axis_name not in vma:
+        if presummed(g, axis_name):
             scale = pre * post
             if scale != 1.0:
                 g = g * scale
@@ -475,7 +473,7 @@ def chunked_reduce_scatter(flat_g, axis_name: str, spec=None, *,
     Returns ``(g_shard, new_residual, n_chunks)``.
     """
     from . import collectives as _coll
-    world = lax_axis_size(axis_name)
+    world = jax.lax.axis_size(axis_name)
     per = flat_g.shape[0] // world
     if spec is None or spec.scheme == "fp32":
         align = LANE
@@ -542,7 +540,7 @@ def segmented_allgather(shard, axis_name: str, spec=None, *,
     n_segments)``.
     """
     from . import collectives as _coll
-    world = lax_axis_size(axis_name)
+    world = jax.lax.axis_size(axis_name)
     s = int(shard.shape[0])
     if spec is not None and spec.scheme == "int8_blockscale":
         align = math.lcm(LANE, spec.block)

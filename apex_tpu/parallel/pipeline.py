@@ -24,8 +24,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
-from .mesh import lax_axis_size
-from ..utils.pallas import _to_varying
+from ..utils.pallas import to_varying
 
 PIPE_AXIS = "pipe"
 
@@ -40,7 +39,7 @@ def pipeline_apply(stage_fn: Callable, stage_params, x, *,
     h) -> h`` must preserve the activation shape (classic pipeline
     contract).  Returns (M, B, ...) outputs, REPLICATED on every device.
     """
-    S = lax_axis_size(axis_name)
+    S = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     M = x.shape[0]
     ticks = M + S - 1
@@ -48,8 +47,8 @@ def pipeline_apply(stage_fn: Callable, stage_params, x, *,
 
     # per-device buffers (varying over the pipe axis) — fresh zeros are
     # replicated under the vma type system, so lift for a stable loop carry
-    h0 = _to_varying(jnp.zeros_like(x[0]), (axis_name,))
-    outs0 = _to_varying(jnp.zeros_like(x), (axis_name,))
+    h0 = to_varying(jnp.zeros_like(x[0]), (axis_name,))
+    outs0 = to_varying(jnp.zeros_like(x), (axis_name,))
 
     def tick(t, carry):
         recv, outs = carry

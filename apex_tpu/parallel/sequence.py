@@ -28,8 +28,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from .mesh import SEQ_AXIS, lax_axis_size
-from ..utils.pallas import _to_varying
+from .mesh import SEQ_AXIS
+from ..utils.pallas import to_varying
 
 _NEG = -0.7 * float(jnp.finfo(jnp.float32).max)
 
@@ -93,7 +93,7 @@ def ring_attention(q, k, v, *, axis_name: str = SEQ_AXIS, causal: bool = False,
     contiguous sequence block (device i holds positions
     [i*S_local, (i+1)*S_local)).  Returns (B, H, S_local, D).
     """
-    n = lax_axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     B, H, Sq, D = q.shape
     Sk = k.shape[2]
@@ -101,13 +101,17 @@ def ring_attention(q, k, v, *, axis_name: str = SEQ_AXIS, causal: bool = False,
         scale = 1.0 / (D ** 0.5)
     q = q * jnp.asarray(scale, q.dtype)
 
-    # the running stats are per-device values (varying over the ring axis);
-    # fresh zeros are replicated under the vma type system — lift them so
-    # the fori_loop carry is type-stable
-    m0 = _to_varying(jnp.full((B, H, Sq), _NEG * 0.5, jnp.float32),
-                     (axis_name,))
-    l0 = _to_varying(jnp.zeros((B, H, Sq), jnp.float32), (axis_name,))
-    a0 = _to_varying(jnp.zeros((B, H, Sq, D), jnp.float32), (axis_name,))
+    # the running stats are per-device values: fresh constants are
+    # replicated under the vma type system, while the loop body makes them
+    # vary over everything q/k/v vary over (the ring axis, plus e.g. the
+    # data axis of a dp x sp mesh) — lift them so the fori_loop carry is
+    # type-stable
+    carry_axes = (jax.typeof(q).vma | jax.typeof(k).vma | jax.typeof(v).vma
+                  | {axis_name})
+    m0 = to_varying(jnp.full((B, H, Sq), _NEG * 0.5, jnp.float32),
+                    carry_axes)
+    l0 = to_varying(jnp.zeros((B, H, Sq), jnp.float32), carry_axes)
+    a0 = to_varying(jnp.zeros((B, H, Sq, D), jnp.float32), carry_axes)
     perm = [(j, (j + 1) % n) for j in range(n)]
     q_off = idx * Sq
 
@@ -137,7 +141,7 @@ def ulysses_attention(q, k, v, *, axis_name: str = SEQ_AXIS,
     attention per local head group (``attn_fn`` override hooks in e.g. the
     Pallas flash kernel), and converts back.  Requires H % axis_size == 0.
     """
-    n = lax_axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     B, H, S_local, D = q.shape
     if H % n:
         raise SequenceShardingError(

@@ -302,7 +302,7 @@ def build_plan_step(cfg, mesh, plan, *, global_batch: int, lr: float = 1e-2,
     from .plan import build_flagship_step
     # async overlap execution rides the dp engine: resolve the ambient
     # mode here (env APEX_TPU_OVERLAP / tuning ddp_overlap — what
-    # Plan.apply or the watcher A/B sets) and surface it both to the
+    # Plan.apply or an A/B run sets) and surface it both to the
     # DDP harness and in the engine info, so the A/B artifact records
     # which execution actually ran
     from . import overlap as _ov
@@ -410,9 +410,9 @@ def _build_sp_step(cfg, mesh, plan, global_batch, lr, meter):
     from jax.sharding import PartitionSpec as P
     from ..models import transformer_init, transformer_loss
     from ..optimizers import FusedAdam
-    from ..utils.pallas import has_vma, _to_varying
+    from ..utils.pallas import to_varying
     from .distributed import DistributedDataParallel
-    from .mesh import shard_map
+    from jax import shard_map
     from .sequence import (ring_attention, ulysses_attention, validate_sp)
 
     n_dp = int(mesh.shape[DATA_AXIS])
@@ -428,7 +428,6 @@ def _build_sp_step(cfg, mesh, plan, global_batch, lr, meter):
     opt = FusedAdam(lr=lr, impl="fused")
     ddp = DistributedDataParallel(axis_name=DATA_AXIS)
     su = ddp.weight_update(opt)
-    vma_kw = {} if has_vma() else {"check_vma": False}
     pspec = jax.tree_util.tree_map(lambda _: P(), params0)
 
     if strategy == "ulysses":
@@ -443,7 +442,7 @@ def _build_sp_step(cfg, mesh, plan, global_batch, lr, meter):
     def grads_of(params, tokens):
         off = jax.lax.axis_index(SEQ_AXIS) * s_local
         pv = jax.tree_util.tree_map(
-            lambda p: _to_varying(p, (DATA_AXIS, SEQ_AXIS)), params)
+            lambda p: to_varying(p, (DATA_AXIS, SEQ_AXIS)), params)
         loss, grads = jax.value_and_grad(lambda p: transformer_loss(
             p, {"tokens": tokens, "targets": tokens}, cfg,
             attn_override=attn, pos_offset=off))(pv)
@@ -483,7 +482,7 @@ def _build_sp_step(cfg, mesh, plan, global_batch, lr, meter):
     step_sm = jax.jit(shard_map(
         body, mesh=mesh,
         in_specs=(pspec, sspec, P(DATA_AXIS, SEQ_AXIS)),
-        out_specs=(pspec, sspec, P()), **vma_kw))
+        out_specs=(pspec, sspec, P())))
     state0 = opt.init(params0) if su is None else init_s(params0)
 
     info = {"family": plan.family, "engine": f"shard_map.sp.{strategy}",
@@ -513,6 +512,20 @@ def _build_sp_step(cfg, mesh, plan, global_batch, lr, meter):
     return (params0, state0), step, info
 
 
+def _typed_replicated(x, axis_name):
+    """``x`` typed as replicated over ``axis_name``.  The pp and ep
+    engines keep ONE flat optimizer state per shard — shared (dense)
+    weights followed by that shard's stage/expert slice — so a shared
+    weight read back out of it is typed varying over the axis although
+    every shard computed the same value.  jax has no collective-free
+    assertion of replication; ``pmax`` is exact on equal values and
+    yields the type a replicated out_spec needs.  It costs one
+    all-reduce of the shared weights per step — gone when the engines
+    keep shared and sharded state apart (ROADMAP D2)."""
+    import jax
+    return jax.lax.pmax(x, axis_name)
+
+
 def _build_pp_step(cfg, mesh, plan, global_batch, lr, meter):
     """The pipeline-parallel engine: shard_map over (data, pipe), the
     flagship's stacked layer axis partitioned into one stage slice per
@@ -531,9 +544,9 @@ def _build_pp_step(cfg, mesh, plan, global_batch, lr, meter):
     from ..models.transformer import _layer
     from ..normalization.fused_layer_norm import fused_layer_norm_affine
     from ..optimizers import FusedAdam
-    from ..utils.pallas import _to_varying
+    from ..utils.pallas import to_varying
     from .distributed import DistributedDataParallel
-    from .mesh import shard_map
+    from jax import shard_map
     from .pipeline import PIPE_AXIS, pipeline_apply, unstack_local
 
     n_dp = int(mesh.shape[DATA_AXIS])
@@ -623,7 +636,7 @@ def _build_pp_step(cfg, mesh, plan, global_batch, lr, meter):
 
     def grads_of(params, tokens):
         pv = jax.tree_util.tree_map(
-            lambda p: _to_varying(p, (DATA_AXIS, PIPE_AXIS)), params)
+            lambda p: to_varying(p, (DATA_AXIS, PIPE_AXIS)), params)
         loss, grads = jax.value_and_grad(
             lambda p: local_loss(p, tokens))(pv)
         # dense grads are stage-masked partials (embed injection on
@@ -643,20 +656,21 @@ def _build_pp_step(cfg, mesh, plan, global_batch, lr, meter):
         fl = opt.flattener_for(params)
         flat = fl.flatten(grads)
         ok = jnp.all(jnp.isfinite(flat)).astype(jnp.float32)
+        ok = jax.lax.pmin(ok, PIPE_AXIS)       # skip on all stages or none
         new_state = opt.step_flat(state, flat)
         new_state = jax.tree_util.tree_map(
             lambda nw, old: jnp.where(ok > 0, nw, old), new_state, state)
-        return fl.unflatten(new_state.master, like=params), new_state, loss
+        new_params = fl.unflatten(new_state.master, like=params)
+        for k in ("embed", "head"):
+            new_params[k] = jax.tree_util.tree_map(
+                lambda x: _typed_replicated(x, PIPE_AXIS), new_params[k])
+        return new_params, new_state, loss
 
-    # check off: check_rep cannot infer the fori_loop carry's
-    # replication through pipeline_apply's ppermute (the same posture
-    # as tests/L0/test_pipeline_parallel.py, prescribed by its error)
     init_s = jax.jit(shard_map(lambda p: opt.init(p), mesh=mesh,
-                               in_specs=(pspec,), out_specs=sspec,
-                               check_vma=False))
+                               in_specs=(pspec,), out_specs=sspec))
     step_sm = jax.jit(shard_map(
         body, mesh=mesh, in_specs=(pspec, sspec, P(DATA_AXIS)),
-        out_specs=(pspec, sspec, P()), check_vma=False))
+        out_specs=(pspec, sspec, P())))
     state0 = init_s(params0)
 
     ticks = m_micro + n_pp - 1
@@ -743,10 +757,10 @@ def _build_ep_step(cfg, mesh, plan, global_batch, lr, meter):
     from ..models.moe_transformer import (moe_transformer_init,
                                           moe_transformer_loss)
     from ..optimizers import FusedAdam
-    from ..utils.pallas import has_vma, _to_varying
+    from ..utils.pallas import to_varying
     from .distributed import DistributedDataParallel
     from .expert import EXPERT_AXIS
-    from .mesh import shard_map
+    from jax import shard_map
 
     n_dp = int(mesh.shape[DATA_AXIS])
     n_ep = int(mesh.shape.get(EXPERT_AXIS, 1))
@@ -766,7 +780,6 @@ def _build_ep_step(cfg, mesh, plan, global_batch, lr, meter):
                                    n_expert_shards=1)
     opt = FusedAdam(lr=lr, impl="fused")
     ddp = DistributedDataParallel(axis_name=DATA_AXIS)
-    vma_kw = {} if has_vma() else {"check_vma": False}
     pspec = jax.tree_util.tree_map_with_path(
         lambda path, _: (P(EXPERT_AXIS) if n_ep > 1
                          and _is_expert_leaf(path) else P()), params0)
@@ -788,7 +801,7 @@ def _build_ep_step(cfg, mesh, plan, global_batch, lr, meter):
 
     def grads_of(params, tokens):
         pv = jax.tree_util.tree_map(
-            lambda p: _to_varying(p, grad_axes), params)
+            lambda p: to_varying(p, grad_axes), params)
         loss, grads = jax.value_and_grad(lambda p: moe_transformer_loss(
             p, {"tokens": tokens, "targets": tokens}, cfg_moe,
             expert_axis=expert_axis))(pv)
@@ -811,17 +824,24 @@ def _build_ep_step(cfg, mesh, plan, global_batch, lr, meter):
         fl = opt.flattener_for(params)
         flat = fl.flatten(grads)
         ok = jnp.all(jnp.isfinite(flat)).astype(jnp.float32)
+        if n_ep > 1:
+            ok = jax.lax.pmin(ok, EXPERT_AXIS)     # skip on all shards or none
         new_state = opt.step_flat(state, flat)
         new_state = jax.tree_util.tree_map(
             lambda nw, old: jnp.where(ok > 0, nw, old), new_state, state)
-        return fl.unflatten(new_state.master, like=params), new_state, loss
+        new_params = fl.unflatten(new_state.master, like=params)
+        if n_ep > 1:
+            new_params = jax.tree_util.tree_map_with_path(
+                lambda path, x: (x if _is_expert_leaf(path)
+                                 else _typed_replicated(x, EXPERT_AXIS)),
+                new_params)
+        return new_params, new_state, loss
 
     init_s = jax.jit(shard_map(lambda p: opt.init(p), mesh=mesh,
-                               in_specs=(pspec,), out_specs=sspec,
-                               **vma_kw))
+                               in_specs=(pspec,), out_specs=sspec))
     step_sm = jax.jit(shard_map(
         body, mesh=mesh, in_specs=(pspec, sspec, tok_spec),
-        out_specs=(pspec, sspec, P()), **vma_kw))
+        out_specs=(pspec, sspec, P())))
     state0 = init_s(params0)
 
     info = {"family": plan.family, "engine": "shard_map.ep",
@@ -859,8 +879,8 @@ def _build_zero_step(cfg, mesh, plan, global_batch, lr, meter):
     from jax.sharding import PartitionSpec as P
     from ..contrib.optimizers import DistributedFusedAdam
     from ..models import transformer_init, transformer_loss
-    from ..utils.pallas import has_vma, _to_varying
-    from .mesh import shard_map
+    from ..utils.pallas import to_varying
+    from jax import shard_map
 
     n_dp = int(mesh.shape[DATA_AXIS])
     if global_batch % n_dp:
@@ -873,14 +893,13 @@ def _build_zero_step(cfg, mesh, plan, global_batch, lr, meter):
     opt = DistributedFusedAdam(lr=lr, shard_axis=DATA_AXIS, impl="xla")
     pspec = jax.tree_util.tree_map(lambda _: P(), params0)
     sspec = opt.state_pspecs()
-    vma_kw = {} if has_vma() else {"check_vma": False}
 
     init_s = jax.jit(shard_map(lambda p: opt.init(p), mesh=mesh,
                                in_specs=(pspec,), out_specs=sspec))
 
     def body(params, state, tokens):
         pv = jax.tree_util.tree_map(
-            lambda p: _to_varying(p, (DATA_AXIS,)), params)
+            lambda p: to_varying(p, (DATA_AXIS,)), params)
         loss, grads = jax.value_and_grad(lambda p: transformer_loss(
             p, {"tokens": tokens, "targets": tokens}, cfg))(pv)
         new_params, new_state = opt.step(state, grads, params)
@@ -888,7 +907,7 @@ def _build_zero_step(cfg, mesh, plan, global_batch, lr, meter):
 
     step_sm = jax.jit(shard_map(
         body, mesh=mesh, in_specs=(pspec, sspec, P(DATA_AXIS)),
-        out_specs=(pspec, sspec, P()), **vma_kw))
+        out_specs=(pspec, sspec, P())))
     state0 = init_s(params0)
 
     info = {"family": plan.family, "engine": "shard_map.zero", "dp": n_dp}

@@ -64,7 +64,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from .mesh import DATA_AXIS, lax_axis_size
+from .mesh import DATA_AXIS
+from ..utils.pallas import presummed
 from ..multi_tensor_apply.flattener import TreeFlattener, LANE
 
 __all__ = ["MODES", "ENV_KNOB", "TUNING_KEY", "AG_TUNING_KEY",
@@ -307,7 +308,7 @@ class ShardedUpdate:
         built once per device and each device keeps only its contiguous
         1/N slice of every flat-length field (scalars and per-tensor
         vectors — NovoGrad's ``v`` — stay replicated)."""
-        n = lax_axis_size(self.axis_name)
+        n = jax.lax.axis_size(self.axis_name)
         fl = self._fl(params, n)
         state = self._slice_state(self.optimizer.init(params), fl, n)
         self._gauge_state(state, n)
@@ -341,7 +342,7 @@ class ShardedUpdate:
         reduce-scatter — full flat, fp32, per-device.  MUST run inside
         shard_map/pmap with ``axis_name`` bound; carry it through
         ``step(..., residual=...)`` so TrainGuard snapshots it."""
-        n = lax_axis_size(self.axis_name)
+        n = jax.lax.axis_size(self.axis_name)
         return jnp.zeros((self._fl(params, n).total,), jnp.float32)
 
     # -- the step ------------------------------------------------------------
@@ -359,7 +360,7 @@ class ShardedUpdate:
         mode = _ov.resolve_mode(self.overlap)
         msize = (self.message_size if self.message_size is not None
                  else _ov.DEFAULT_MESSAGE_SIZE)
-        n = lax_axis_size(self.axis_name)
+        n = jax.lax.axis_size(self.axis_name)
         fl = self._fl(params, n)
         flat_g = fl.flatten(grads)
 
@@ -395,9 +396,7 @@ class ShardedUpdate:
         # psum-summed by the cotangent rule — scattering them again
         # would double-sum, so a pre-summed flat buffer just slices
         # (no collective runs, and none is metered).
-        from ..utils.pallas import _vma_of
-        vma = _vma_of(flat_g)
-        already_summed = vma is not None and self.axis_name not in vma
+        already_summed = presummed(flat_g, self.axis_name)
         per = fl.total // n
         if already_summed:
             idx = jax.lax.axis_index(self.axis_name)

@@ -9,7 +9,7 @@ select-revert, atomic ``checkpoint.save``) become one operational layer
     checked boundaries and written by a background thread (the step loop
     never blocks on disk);
   * **preemption safety** — SIGTERM/SIGINT (real, or injected via a
-    ``preempt`` fault) become snapshot-then-clean-exit, so a tunnel flap
+    ``preempt`` fault) become snapshot-then-clean-exit, so a preemption
     mid-run costs the steps since the last boundary, not the run;
   * **auto-resume** — a new ``run()`` over the same checkpoint dir picks
     up at the manifest's newest verified checkpoint (corrupt files are

@@ -2,7 +2,7 @@
 instrumented-transformer demo) into the per-op FLOPs/bytes table and the
 step-metrics summary; ``python -m apex_tpu.telemetry trace <file>``
 renders the span-timeline summary from a Chrome-trace file (a
-``Tracer.write`` export, a ``tpu_watch.sh`` stage timeline, or a
+``Tracer.write`` export, a streaming never-closed event array, or a
 jax-profiler run dir); ``python -m apex_tpu.telemetry mem [artifact]``
 renders the per-class peak-HBM attribution table (the flagship
 transformer step, a bench artifact's MFU/peak-HBM fields, or a

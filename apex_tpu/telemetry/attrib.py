@@ -142,13 +142,17 @@ def _first_shape_dims(type_str: str) -> Optional[List[int]]:
     return [int(d) for d in dims.split(",")] if dims else []
 
 
-def _operand_types(rest: str) -> List[str]:
+_OPERAND_REF_RE = re.compile(r"%([\w.\-]+)")
+
+
+def _operand_types(rest: str, types: Dict[str, str]) -> List[str]:
     """Operand type strings from the text following the opening paren of
-    ``opcode(...)`` — every ``dtype[dims]`` before the attribute section
-    belongs to an operand reference."""
-    # operands end at the first top-level "), " — cheap approximation:
-    # shapes inside attributes (to_apply etc.) appear after "), " so
-    # cutting at the close paren that balances the open is enough
+    ``opcode(...)``.  The printer writes operands either with their
+    types inline (``f32[8,32]{1,0} %a``) or as bare references
+    (``%a``); a bare reference resolves through ``types``, the
+    enclosing computation's ``var -> type`` table."""
+    # operands end at the close paren that balances the open; shapes
+    # inside attributes (to_apply etc.) come after it
     depth = 1
     for i, ch in enumerate(rest):
         if ch == "(":
@@ -158,12 +162,15 @@ def _operand_types(rest: str) -> List[str]:
             if depth == 0:
                 rest = rest[:i]
                 break
-    return [m.group(0) for m in _SHAPE_RE.finditer(rest)]
+    inline = [m.group(0) for m in _SHAPE_RE.finditer(rest)]
+    if inline:
+        return inline
+    return [types[m.group(1)] for m in _OPERAND_REF_RE.finditer(rest)
+            if m.group(1) in types]
 
 
-def _dot_flops(out_elems: int, rest: str) -> Optional[float]:
+def _dot_flops(out_elems: int, rest: str, ops: List[str]) -> Optional[float]:
     """2 * out_elems * prod(lhs contracting dim sizes)."""
-    ops = _operand_types(rest)
     m = _CONTRACT_RE.search(rest)
     if not ops or m is None:
         return None
@@ -179,10 +186,9 @@ def _dot_flops(out_elems: int, rest: str) -> Optional[float]:
     return 2.0 * out_elems * k
 
 
-def _conv_flops(out_elems: int, rest: str) -> Optional[float]:
+def _conv_flops(out_elems: int, rest: str, ops: List[str]) -> Optional[float]:
     """2 * out_elems * (kernel elems / output feature count): the MAC
     count each output element costs, independent of layout labels."""
-    ops = _operand_types(rest)
     if len(ops) < 2:
         return None
     k_dims = _first_shape_dims(ops[1])
@@ -202,14 +208,15 @@ def _conv_flops(out_elems: int, rest: str) -> Optional[float]:
     return 2.0 * out_elems * (kernel_elems / max(out_feat, 1))
 
 
-def _instr_flops(opcode: str, out_elems: int, rest: str,
-                 fused_flops: Dict[str, tuple]) -> tuple:
-    """(flops, transcendentals) for one instruction."""
+def _instr_flops(ins: dict, fused_flops: Dict[str, tuple]) -> tuple:
+    """(flops, transcendentals) for one parsed instruction record."""
+    opcode, out_elems = ins["opcode"], ins["out_elems"]
+    rest, ops = ins["rest"], ins["operand_types"]
     if opcode == "dot":
-        f = _dot_flops(out_elems, rest)
+        f = _dot_flops(out_elems, rest, ops)
         return (f if f is not None else 2.0 * out_elems, 0.0)
     if opcode == "convolution":
-        f = _conv_flops(out_elems, rest)
+        f = _conv_flops(out_elems, rest, ops)
         return (f if f is not None else 2.0 * out_elems, 0.0)
     if opcode == "fusion":
         m = _CALLS_RE.search(rest)
@@ -217,7 +224,6 @@ def _instr_flops(opcode: str, out_elems: int, rest: str,
             return fused_flops[m.group(1)]
         return (float(out_elems), 0.0)
     if opcode in ("reduce", "reduce-window"):
-        ops = _operand_types(rest)
         if ops:
             e, _ = _type_info(ops[0])
             return (float(e), 0.0)
@@ -246,6 +252,7 @@ def parse_hlo(text: str) -> List[dict]:
     ``out_bytes``.
     """
     computations: Dict[str, List[dict]] = {}
+    types: Dict[str, Dict[str, str]] = {}     # computation -> var -> type
     comp_order: List[str] = []
     entry: Optional[str] = None
     current: Optional[str] = None
@@ -256,6 +263,7 @@ def parse_hlo(text: str) -> List[dict]:
         if cm and line.rstrip().endswith("{"):
             current = cm.group("name")
             computations[current] = []
+            types[current] = {}
             comp_order.append(current)
             if line.lstrip().startswith("ENTRY"):
                 entry = current
@@ -270,13 +278,16 @@ def parse_hlo(text: str) -> List[dict]:
         opcode = im.group("opcode")
         out_elems, out_bytes = _type_info(im.group("type"))
         rest = im.group("rest")
-        op_bytes = sum(_type_info(t)[1] for t in _operand_types(rest))
+        operand_types = _operand_types(rest, types[current])
+        types[current][im.group("var")] = im.group("type")
+        op_bytes = sum(_type_info(t)[1] for t in operand_types)
         nm = _OPNAME_RE.search(rest)
         computations[current].append({
             "op": im.group("var"), "opcode": opcode,
             "jax_op": (nm.group(1).split("/")[-1] if nm else ""),
             "out_elems": out_elems, "out_bytes": out_bytes,
-            "operand_bytes": op_bytes, "rest": rest,
+            "operand_bytes": op_bytes, "operand_types": operand_types,
+            "rest": rest,
         })
     if entry is None and comp_order:
         entry = comp_order[-1]   # HLO text always ends with ENTRY
@@ -292,8 +303,7 @@ def parse_hlo(text: str) -> List[dict]:
         for ins in instrs:
             if ins["opcode"] in _SKIP:
                 continue
-            f, t = _instr_flops(ins["opcode"], ins["out_elems"],
-                                ins["rest"], fused_flops)
+            f, t = _instr_flops(ins, fused_flops)
             fl += f
             tr += t
         fused_flops[name] = (fl, tr)
@@ -303,8 +313,7 @@ def parse_hlo(text: str) -> List[dict]:
     for ins in computations.get(entry, ()):
         if ins["opcode"] in _SKIP:
             continue
-        f, t = _instr_flops(ins["opcode"], ins["out_elems"], ins["rest"],
-                            fused_flops)
+        f, t = _instr_flops(ins, fused_flops)
         cls = op_class(ins["opcode"])
         if ins["opcode"] == "fusion":
             m = _CALLS_RE.search(ins["rest"])
@@ -359,15 +368,6 @@ def collectives_table(rows) -> dict:
     }
 
 
-def _compiled_text(compiled) -> str:
-    try:
-        return compiled.as_text()
-    except Exception:
-        # older jax: go through the runtime executable's HLO modules
-        return "\n".join(m.to_string() for m in
-                         compiled.runtime_executable().hlo_modules())
-
-
 def op_table(fn: Callable, *args, static_argnums=(), donate_argnums=(),
              peak_flops: Optional[float] = None,
              peak_bw: Optional[float] = None, **kwargs) -> dict:
@@ -385,17 +385,15 @@ def op_table(fn: Callable, *args, static_argnums=(), donate_argnums=(),
     jitted = jax.jit(fn, static_argnums=static_argnums,
                      donate_argnums=donate_argnums)
     compiled = jitted.lower(*args, **kwargs).compile()
-    rows = parse_hlo(_compiled_text(compiled))
+    rows = parse_hlo(compiled.as_text())
 
     try:
         cost = compiled.cost_analysis()
     except Exception:   # pragma: no cover - backend without cost model
         cost = None
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else None
 
     platform = jax.devices()[0].platform
-    ceil = resolve_ceilings(platform)
+    ceil = resolve_ceilings(jax.devices()[0])
     pf = peak_flops or ceil["peak_flops"]
     pb = peak_bw or ceil["peak_bw"]
 

@@ -192,8 +192,8 @@ _STEP_SPANS = frozenset(("train.step", "guard.health_check"))
 _COUNTED_EVENTS = ("rollback", "resumed", "preempted", "fault_injected",
                    "elastic.reshard", "elastic.replan")
 
-#: the canonical artifact filename the guard writes and the CLI /
-#: watcher stage look for in a run directory
+#: the canonical artifact filename the guard writes and the CLI looks
+#: for in a run directory
 ARTIFACT_NAME = "GOODPUT.json"
 
 

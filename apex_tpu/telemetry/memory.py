@@ -382,7 +382,7 @@ def memory_table(fn, *args, static_argnums=(), donate_argnums=(),
     jitted = jax.jit(fn, static_argnums=static_argnums,
                      donate_argnums=donate_argnums)
     compiled = jitted.lower(*args, **kwargs).compile()
-    text = _attrib._compiled_text(compiled)
+    text = compiled.as_text()
     table = hlo_liveness(text)
     try:
         table["stats"] = _stats_dict(compiled.memory_analysis())
@@ -520,9 +520,9 @@ def device_memory_stats(device=None) -> Optional[dict]:
 
 
 def device_memory_json() -> str:
-    """The counter-track args for ``tpu_watch.sh``'s streaming stage
-    timeline: a one-line JSON object of the allocator counters, or the
-    empty string when unsupported (the watcher then appends nothing)."""
+    """The counter-track args for a streaming timeline: a one-line JSON
+    object of the allocator counters, or the empty string when
+    unsupported (the writer then appends nothing)."""
     stats = device_memory_stats()
     if not stats:
         return ""

@@ -1,7 +1,7 @@
 """Render a telemetry JSONL run into the step-metrics summary.
 
 ``python -m apex_tpu.telemetry run.jsonl`` prints the summary the bench
-harnesses and ``tpu_watch.sh`` consume: step-time stats, items/sec,
+harnesses consume: step-time stats, items/sec,
 overflow events + final loss scale, collective bytes/calls, and loader
 queue depth/wait.  With no path it runs the built-in demo: the flagship
 transformer train step is instrumented on the ambient backend (CPU in

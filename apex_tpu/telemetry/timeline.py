@@ -35,8 +35,8 @@ exposed.  This module closes the measurement half of that loop:
     unrelated zeros);
   * :func:`cli` — ``python -m apex_tpu.telemetry timeline
     <trace|profiler-dir>``: the per-step decomposition table and the
-    per-device skew section (``--json`` for the machine-readable form
-    the ``tpu_watch.sh`` timeline stage captures).
+    per-device skew section (``--json`` for the machine-readable
+    form).
 
 The measured ``exposed_comm_fraction`` is what ``bench.py``'s opt-in
 one-step profiled capture embeds in its artifact and
@@ -116,6 +116,9 @@ def _base_opcode(name: str) -> Optional[str]:
     if not m:
         return None
     base = m.group(1)
+    # the CPU backend runs an unfused op as a one-op "wrapped_<opcode>"
+    # computation and names its span after that
+    base = base.removeprefix("wrapped_")
     # async collectives lower to start/done pairs on real devices; both
     # halves classify as their base collective
     for suffix in ("-start", "-done"):
@@ -587,8 +590,7 @@ def cli(argv=None) -> int:
                     help="write the merged chrome timeline here "
                          "(requires --host)")
     ap.add_argument("--json", action="store_true",
-                    help="print the decomposition as one JSON document "
-                         "(the tpu_watch.sh artifact form)")
+                    help="print the decomposition as one JSON document")
     ap.add_argument("--z", type=float, default=STRAGGLER_Z,
                     help="straggler z-score threshold")
     ap.add_argument("--top", type=int, default=24, help="step rows shown")

@@ -780,8 +780,8 @@ def note_step(step: int, seconds: float, registry=None) -> None:
 def load_chrome(path: str) -> List[dict]:
     """Load chrome-trace events from ``path``: a :meth:`Tracer.write`
     file, a jax-profiler run dir, or a *streaming* JSON-array file
-    (``tpu_watch.sh`` appends events without ever closing the array —
-    the Trace Event Format explicitly allows it).  Returns the
+    (a writer may append events without ever closing the array — the
+    Trace Event Format explicitly allows it).  Returns the
     ``pyprof.parse`` event shape (complete spans only)."""
     if os.path.isdir(path):
         from ..pyprof import parse as _parse
@@ -794,7 +794,7 @@ def load_chrome(path: str) -> List[dict]:
     except ValueError:
         # streaming array (one record per appended line, never closed):
         # recover line by line, DROPPING an unparseable tail — a writer
-        # killed mid-append (disk full, watcher host died) must lose
+        # killed mid-append (disk full, host died) must lose
         # only its torn last record, never the hundreds of finished
         # spans before it
         data = []
@@ -877,7 +877,7 @@ def cli(argv=None) -> int:
         prog="python -m apex_tpu.telemetry trace",
         description="Render a span summary (per-name count/total/p50/p99 "
                     "self-time) from a chrome-trace file, a Tracer.write "
-                    "export, a tpu_watch.sh stage timeline, or a "
+                    "export, a streaming never-closed event array, or a "
                     "jax-profiler run dir.")
     ap.add_argument("trace", help="trace file (.json / .json.gz) or "
                                   "profiler log dir")

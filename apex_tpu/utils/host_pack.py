@@ -1,9 +1,9 @@
 """ctypes bindings for the native host packing engine (csrc/host_pack.cpp)
 — the ``apex_C.flatten/unflatten`` runtime analog.
 
-Compiled on first use with the ambient ``g++`` (cached next to the package
-or in the user cache dir); degrades to a numpy implementation when no
-toolchain is available, so the Python API is always live:
+Compiled on first use by :mod:`apex_tpu.utils.native` (``g++`` into
+``csrc/_build/``).  Where that fails the same API runs on numpy, after a
+warning that says why:
 
     from apex_tpu.utils import host_pack
     flat = host_pack.pack(arrays, offsets, total)      # one buffer
@@ -12,68 +12,42 @@ toolchain is available, so the Python API is always live:
 from __future__ import annotations
 
 import ctypes
-import hashlib
-import os
-import subprocess
-import tempfile
+import warnings
 from typing import List, Optional, Sequence
 
 import numpy as np
 
-_SRC = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.dirname(os.path.abspath(__file__)))), "csrc", "host_pack.cpp")
+from . import native
 
 _lib = None
 _lib_tried = False
 
 
-def _build_dirs():
-    yield os.path.join(os.path.dirname(_SRC), "_build")
-    yield os.path.join(tempfile.gettempdir(), "apex_tpu_build")
-
-
 def _load() -> Optional[ctypes.CDLL]:
     global _lib, _lib_tried
-    if _lib is not None or _lib_tried:
+    if _lib_tried:
         return _lib
     _lib_tried = True
-    if not os.path.exists(_SRC):
-        return None
     try:
-        with open(_SRC, "rb") as f:
-            tag = hashlib.sha256(f.read()).hexdigest()[:16]
-    except OSError:
+        so, _ = native.build("host_pack.cpp", "libapex_tpu_host")
+    except native.NativeBuildError as err:
+        warnings.warn(f"host_pack: native library unavailable, packing "
+                      f"with numpy instead ({err})", RuntimeWarning)
         return None
-    for d in _build_dirs():
-        so = os.path.join(d, f"libapex_tpu_host_{tag}.so")
-        if not os.path.exists(so):
-            try:
-                os.makedirs(d, exist_ok=True)
-                tmp = so + f".tmp{os.getpid()}"
-                subprocess.run(
-                    ["g++", "-O3", "-shared", "-fPIC", "-std=c++17",
-                     "-pthread", "-o", tmp, _SRC],
-                    check=True, capture_output=True, timeout=120)
-                os.replace(tmp, so)
-            except Exception:
-                continue
-        try:
-            lib = ctypes.CDLL(so)
-            lib.apex_tpu_pack.argtypes = [
-                ctypes.POINTER(ctypes.c_void_p),
-                ctypes.POINTER(ctypes.c_int64),
-                ctypes.POINTER(ctypes.c_int64), ctypes.c_int64,
-                ctypes.c_void_p, ctypes.c_int64]
-            lib.apex_tpu_unpack.argtypes = [
-                ctypes.c_void_p, ctypes.POINTER(ctypes.c_int64),
-                ctypes.POINTER(ctypes.c_int64), ctypes.c_int64,
-                ctypes.POINTER(ctypes.c_void_p), ctypes.c_int64]
-            if lib.apex_tpu_host_pack_abi() == 1:
-                _lib = lib
-                return _lib
-        except OSError:
-            continue
-    return None
+    lib = ctypes.CDLL(so)
+    lib.apex_tpu_pack.argtypes = [
+        ctypes.POINTER(ctypes.c_void_p),
+        ctypes.POINTER(ctypes.c_int64),
+        ctypes.POINTER(ctypes.c_int64), ctypes.c_int64,
+        ctypes.c_void_p, ctypes.c_int64]
+    lib.apex_tpu_unpack.argtypes = [
+        ctypes.c_void_p, ctypes.POINTER(ctypes.c_int64),
+        ctypes.POINTER(ctypes.c_int64), ctypes.c_int64,
+        ctypes.POINTER(ctypes.c_void_p), ctypes.c_int64]
+    if lib.apex_tpu_host_pack_abi() != 1:
+        raise RuntimeError(f"{so}: unexpected ABI version")
+    _lib = lib
+    return _lib
 
 
 def native_available() -> bool:

@@ -2,6 +2,7 @@
 from __future__ import annotations
 
 import jax
+from jax.experimental.pallas import tpu as pltpu
 
 
 def interpret_mode() -> bool:
@@ -10,54 +11,40 @@ def interpret_mode() -> bool:
 
 
 def compiler_params(dimension_semantics):
-    """TPU CompilerParams across jax versions: the class was named
-    ``TPUCompilerParams`` before jax 0.5-era releases renamed it to
-    ``CompilerParams`` — every kernel builds it through here so one jax
-    bump (or rollback) cannot break the whole Pallas surface again."""
-    from jax.experimental.pallas import tpu as pltpu
-    cls = (getattr(pltpu, "CompilerParams", None)
-           or getattr(pltpu, "TPUCompilerParams"))
-    return cls(dimension_semantics=tuple(dimension_semantics))
+    """Mosaic compiler params carrying the grid's dimension semantics."""
+    return pltpu.CompilerParams(
+        dimension_semantics=tuple(dimension_semantics))
 
 
-def has_vma() -> bool:
-    """True when this jax tracks varying-manual-axes (vma) typing
-    (``jax.lax.pvary``/``pcast`` exist).  The 0.4-era ``check_rep``
-    cannot infer replication of autodiff-psummed / allgathered outputs
-    under ``shard_map`` — callers (tests included) disable the check on
-    those jaxes and rely on vma typing elsewhere."""
-    return hasattr(jax.lax, "pvary") or hasattr(jax.lax, "pcast")
+def to_varying(a, axes):
+    """Lift ``a`` to vary over the manual mesh ``axes`` (inside
+    ``shard_map``).  ``pcast`` rejects axes the value already varies
+    over, so only the missing ones are cast — callers name the axes they
+    need, whatever the value's current type."""
+    missing = tuple(ax for ax in axes if ax not in jax.typeof(a).vma)
+    return jax.lax.pcast(a, missing, to="varying") if missing else a
 
 
-def _vma_of(a):
-    try:
-        return jax.typeof(a).vma
-    except AttributeError:  # pragma: no cover - jax without vma typing
-        return None
+def presummed(g, axis_name) -> bool:
+    """True when the gradient ``g`` is already summed over ``axis_name``.
 
-
-def _to_varying(a, axes):
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is not None:
-        return pcast(a, axes, to="varying")
-    pvary = getattr(jax.lax, "pvary", None)
-    if pvary is not None:  # pragma: no cover - jax with only legacy pvary
-        return pvary(a, axes)
-    return a  # jax without vma typing: replication isn't tracked at all
+    Under ``shard_map``'s varying-axes typing, a cotangent of a
+    replicated input arrives psum-SUMMED and typed unvarying; reducing it
+    again would double-count.  ``shard_map(check_vma=False)`` types
+    NOTHING as varying and sums nothing, so an empty type proves
+    anything only when typing is on — which ``axis_index``, varying by
+    construction, reveals."""
+    tracked = axis_name in jax.typeof(jax.lax.axis_index(axis_name)).vma
+    return tracked and axis_name not in jax.typeof(g).vma
 
 
 def out_vma(*arrays):
     """Varying-mesh-axes set for pallas_call out_shapes: the union of the
     inputs' vma (under shard_map(check_vma=True) outputs inherit what the
-    inputs vary over; elsewhere this is just frozenset()).  Returns None on
-    jax versions without vma-typed avals so ShapeDtypeStruct gets its
-    default."""
+    inputs vary over; elsewhere this is just frozenset())."""
     union = frozenset()
     for a in arrays:
-        v = _vma_of(a)
-        if v is None:
-            return None
-        union = union | v
+        union = union | jax.typeof(a).vma
     return union
 
 
@@ -67,17 +54,9 @@ def align_vma(arrays):
     types, and mixed vma (a varying grad next to a replicated scalar) is a
     type error there.  Returns (arrays, union_vma)."""
     union = out_vma(*arrays)
-    if not union:
-        return list(arrays), union
-    out = []
-    for a in arrays:
-        missing = tuple(union - _vma_of(a))
-        out.append(_to_varying(a, missing) if missing else a)
-    return out, union
+    return [to_varying(a, union) for a in arrays], union
 
 
 def sds(shape, dtype, vma):
-    """ShapeDtypeStruct with vma when supported (vma=None -> plain)."""
-    if vma is None:
-        return jax.ShapeDtypeStruct(shape, dtype)
+    """ShapeDtypeStruct typed with the given varying-mesh-axes set."""
     return jax.ShapeDtypeStruct(shape, dtype, vma=vma)

@@ -1,0 +1,784 @@
+#!/usr/bin/env python
+"""chip_smoke.py — the quickest proof that the system still starts on the chip.
+
+Runs, in ONE process, the library's main path through the entry points a
+user would call, at the full width of the models the repo supports:
+
+  native      the csrc helpers build from the committed sources
+  kernels     every pallas_call compiled by Mosaic at a production shape
+              and compared with its XLA twin
+  serve       InferenceEngine + ContinuousBatcher at BERT-large width
+  resnet50    examples/imagenet/main_amp.py, b128, amp O2 + FusedAdam
+  bert_large  examples/bert/pretrain.py, 24L b8 x s512, flash + remat,
+              amp O5 + FusedLAMB(impl="fused"); the compiled step is
+              shown to contain the Pallas kernels
+  multichip   (when jax finds >= 4 devices) the trainers --distributed /
+              --zero / --sync-bn and one step of every plan family on a
+              4-device mesh, each device holding its share
+
+    python chip_smoke.py                # on a TPU; anything else exits 2
+    python chip_smoke.py --rehearse     # the same phases, tiny, on the CPU
+    python chip_smoke.py --only kernels,serve
+
+Weights are random from a seed; nothing is read from the network.  Per
+phase it prints wall / compile / run seconds, the device allocator's
+high-water mark and the compile cache's hits — facts about this run,
+not benchmark metrics.  A phase that raises prints its traceback, the
+other phases still run, and the exit code is 1.  The last line of stdout
+is one JSON object: ``{"ok": true, "device": {"platform": ..., "kind":
+..., "count": ...}}``; the full report goes to
+``chiprun_out/chip_smoke.json``.
+"""
+from __future__ import annotations
+
+import argparse
+import gc
+import importlib.util
+import json
+import os
+import sys
+import time
+import traceback
+
+REPO = os.path.dirname(os.path.abspath(__file__))
+sys.path.insert(0, REPO)
+
+
+def say(msg: str) -> None:
+    print(f"[chip_smoke] {msg}", flush=True)
+
+
+def load_example(rel_path: str):
+    """The shipped example at ``rel_path`` as a module (its ``main(argv)``
+    is the entry point a user runs)."""
+    name = "chip_smoke_" + os.path.basename(rel_path)[:-3]
+    spec = importlib.util.spec_from_file_location(
+        name, os.path.join(REPO, rel_path))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+# ---------------------------------------------------------------------------
+# what jax spent compiling, and whether the persistent cache answered
+# ---------------------------------------------------------------------------
+
+class CompileMeter:
+    """Sums jax's own compile-time and compile-cache events."""
+
+    _COMPILE = ("/jax/core/compile/jaxpr_to_mlir_module_duration",
+                "/jax/core/compile/backend_compile_duration")
+
+    def __init__(self):
+        import jax.monitoring
+        self.compile_s = 0.0
+        self.hits = 0
+        self.misses = 0
+        jax.monitoring.register_event_duration_secs_listener(self._on_time)
+        jax.monitoring.register_event_listener(self._on_event)
+
+    def _on_time(self, name, secs, **_):
+        if name in self._COMPILE:
+            self.compile_s += secs
+
+    def _on_event(self, name, **_):
+        if name == "/jax/compilation_cache/cache_hits":
+            self.hits += 1
+        elif name == "/jax/compilation_cache/cache_misses":
+            self.misses += 1
+
+    def snapshot(self):
+        return self.compile_s, self.hits, self.misses
+
+
+def memory_fact(devices) -> dict:
+    """Allocator counters of each device, where the backend reports them
+    (the CPU backend does not)."""
+    stats = [d.memory_stats() for d in devices]
+    if not all(stats):
+        return {"bytes_in_use": None, "peak_bytes_in_use": None}
+    return {"bytes_in_use": [int(s["bytes_in_use"]) for s in stats],
+            "peak_bytes_in_use": [int(s["peak_bytes_in_use"]) for s in stats]}
+
+
+def attempt(label: str, fn) -> dict:
+    """Run one phase / leg / check: its facts with ``ok: True``, or — after
+    printing the traceback — ``ok: False`` and the error; seconds either
+    way.  A failure never stops the ones after it."""
+    t0 = time.monotonic()
+    try:
+        rec = {"ok": True, **fn()}
+    except Exception:
+        say(f"{label} FAILED:")
+        traceback.print_exc(file=sys.stdout)
+        rec = {"ok": False, "error": traceback.format_exc()[-1500:]}
+    rec["s"] = round(time.monotonic() - t0, 2)
+    gc.collect()
+    return rec
+
+
+# ---------------------------------------------------------------------------
+# small numeric helpers
+# ---------------------------------------------------------------------------
+
+def rel_err(got, want) -> float:
+    """max |got - want| over max |want|, over every leaf, in float32."""
+    import jax
+    import jax.numpy as jnp
+    worst = 0.0
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        g = jnp.asarray(g, jnp.float32)
+        w = jnp.asarray(w, jnp.float32)
+        if g.shape != w.shape:
+            raise AssertionError(f"shape {g.shape} != reference {w.shape}")
+        if not bool(jnp.all(jnp.isfinite(g))):
+            return float("inf")
+        denom = float(jnp.max(jnp.abs(w))) or 1.0
+        worst = max(worst, float(jnp.max(jnp.abs(g - w))) / denom)
+    return worst
+
+
+def pallas_kernel_names(traced) -> set:
+    """Names of every ``pallas_call`` in a traced step (``jit(f).trace``),
+    walking into scan / remat / custom-vjp / pjit sub-jaxprs."""
+    found = set()
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found.add(str(eqn.params["name"]))
+            for val in eqn.params.values():
+                for sub in (val if isinstance(val, (tuple, list)) else (val,)):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        walk(sub)
+
+    walk(traced.jaxpr.jaxpr)
+    return found
+
+
+def check_trainer_report(report: dict, steps: int) -> dict:
+    """The facts a trainer run must show: every printed loss finite, the
+    loss falling (last third of the run below the first third, which a
+    noisy small batch cannot fake), and every step applied (none skipped
+    by the loss scaler)."""
+    import math
+    losses = [float(x) for x in report["losses"]]
+    if len(losses) != steps:
+        raise AssertionError(f"{len(losses)} losses printed for {steps} steps")
+    if not all(math.isfinite(x) for x in losses):
+        raise AssertionError(f"non-finite loss: {losses}")
+    third = max(1, steps // 3)
+    if not sum(losses[-third:]) < sum(losses[:third]):
+        raise AssertionError(f"loss did not fall: {losses}")
+    if report["optimizer_steps"] != steps:
+        raise AssertionError(
+            f"{report['optimizer_steps']} optimizer steps applied of "
+            f"{steps}: the loss scaler skipped some")
+    return {"losses": [round(x, 4) for x in losses],
+            "optimizer_steps": report["optimizer_steps"]}
+
+
+def check_holds_share(tree, devices, what: str, balance=False) -> None:
+    """Every array of ``tree`` lives on all of ``devices`` (replicated or
+    sharded) — nothing left behind on device 0.  ``balance`` (for states
+    of gigabytes, where stray kilobytes cannot tip it): where the
+    allocator reports, no device holds less than half of what the
+    fullest holds."""
+    import jax
+    for path, x in jax.tree_util.tree_leaves_with_path(tree):
+        if hasattr(x, "sharding") and x.sharding.device_set != set(devices):
+            raise AssertionError(
+                f"{what}{jax.tree_util.keystr(path)} is on "
+                f"{len(x.sharding.device_set)} device(s), not "
+                f"{len(devices)}")
+    in_use = balance and memory_fact(devices)["bytes_in_use"]
+    if in_use and min(in_use) < 0.5 * max(in_use):
+        raise AssertionError(f"{what}: bytes in use per device {in_use}")
+
+
+# ---------------------------------------------------------------------------
+# phases.  Each takes the run context and returns a dict of facts; it
+# raises on any failure.
+# ---------------------------------------------------------------------------
+
+def phase_native(ctx) -> dict:
+    """Both csrc libraries build from the committed sources (or are found
+    already built) in csrc/_build/."""
+    from apex_tpu.utils import native
+    out = {}
+    for src, lib in (("host_pack.cpp", "libapex_tpu_host"),
+                     ("prefetch.cpp", "libapex_tpu_prefetch")):
+        path, built_now = native.build(src, lib)
+        out[src] = {"built_now": built_now,
+                    "lib": os.path.relpath(path, REPO)}
+        say(f"  {src}: {'built' if built_now else 'found'} {out[src]['lib']}")
+    from apex_tpu.data import native_available as loader_native
+    from apex_tpu.utils.host_pack import native_available as pack_native
+    if not (loader_native() and pack_native()):
+        raise AssertionError("a native library built but did not load")
+    return out
+
+
+def _kernel_checks(full: bool):
+    """(name, tolerance, thunk -> rel_err) for every pallas_call in the
+    repo.  ``full``: production shapes (BERT-large: B8 H16 D64, vocab
+    30592, d 1024, d_ff 4096; flash also at S=2048 so the default blocks
+    are reached un-clamped); else tiny interpret-mode shapes."""
+    import jax
+    import jax.numpy as jnp
+    from apex_tpu.contrib.multihead_attn import flash as F
+    from apex_tpu.contrib.xentropy import softmax_xentropy_loss
+    from apex_tpu.multi_tensor_apply import kernels as K
+    from apex_tpu.normalization import fused_layer_norm_affine
+    from apex_tpu.ops.fused_mlp import dense_act
+
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    key = jax.random.PRNGKey(0)
+
+    def rnd(i, shape, dtype=f32, scale=1.0):
+        return (scale * jax.random.normal(jax.random.fold_in(key, i), shape,
+                                          f32)).astype(dtype)
+
+    checks = []
+
+    # -- flash attention: forward, and the three backwards forced apart --
+    def flash_inputs(S, BH):
+        D = 64
+        q, k, v, do = (rnd(i, (BH, S, D), bf16, 0.5) for i in range(4))
+        return q, k, v, do, jnp.zeros((1, 1, S), f32)
+
+    def flash_fwd(S, BH, causal, rate=0.0):
+        q, k, v, _, bias = flash_inputs(S, BH)
+        got = jax.jit(lambda a, b, c: F.flash_attention(
+            a, b, c, bias, 7, causal, rate, 1))(q, k, v)
+        want = jax.jit(lambda a, b, c: F._xla_reference(
+            a, b, c, bias, causal, rate, 7, 1))(q, k, v)
+        return rel_err(got, want)
+
+    def flash_bwd(S, BH, causal, impl, rate=0.0):
+        q, k, v, do, bias = flash_inputs(S, BH)
+
+        def ref(a, b, c):      # autodiff of the plain-XLA attention
+            _, vjp = jax.vjp(lambda a_, b_, c_: F._xla_reference(
+                a_, b_, c_, bias, causal, rate, 7, 1), a, b, c)
+            return vjp(do)
+
+        if impl == "xla":      # the public switch
+            def run(a, b, c):
+                _, vjp = jax.vjp(lambda a_, b_, c_: F.flash_attention(
+                    a_, b_, c_, bias, 7, causal, rate, 1, "xla"), a, b, c)
+                return vjp(do)
+        else:                  # split (dq + dkv) / fused, forced
+            def run(a, b, c):
+                out, lse = F._flash_fwd(a, b, c, bias, causal, rate, 7, 1)
+                return F._flash_bwd(a, b, c, bias, causal, rate, 7, 1, out,
+                                    lse, do, fuse=(impl == "fused"))
+        return rel_err(jax.jit(run)(q, k, v), jax.jit(ref)(q, k, v))
+
+    for S, BH in (((512, 128), (2048, 32)) if full else ((128, 2),)):
+        for causal in (True, False):
+            tag = f"S{S}{'c' if causal else ''}"
+            checks.append((f"flash_fwd[{tag}]", 3e-2,
+                           lambda S=S, BH=BH, c=causal: flash_fwd(S, BH, c)))
+            for impl in ("split", "fused", "xla"):
+                checks.append((
+                    f"flash_bwd_{impl}[{tag}]", 5e-2,
+                    lambda S=S, BH=BH, c=causal, i=impl: flash_bwd(
+                        S, BH, c, i)))
+    S, BH = (512, 128) if full else (128, 2)
+    # dropout: the in-kernel uint32 hash must rebuild the XLA mask bit for bit
+    checks.append((f"flash_fwd_dropout[S{S}c]", 3e-2,
+                   lambda: flash_fwd(S, BH, True, 0.1)))
+    checks.append((f"flash_bwd_fused_dropout[S{S}c]", 5e-2,
+                   lambda: flash_bwd(S, BH, True, "fused", 0.1)))
+
+    # -- xentropy, forward and backward (ragged last vocabulary block) ----
+    N, V = (4096, 30592) if full else (64, 1000)
+
+    def xent(dtype):
+        logits = rnd(10, (N, V), dtype, 2.0)
+        labels = jax.random.randint(jax.random.fold_in(key, 11), (N,), 0, V)
+
+        def run(impl):
+            return jax.jit(jax.value_and_grad(
+                lambda lg: softmax_xentropy_loss(
+                    lg, labels, 0.1, 0, False, impl).sum()))(logits)
+        return rel_err(run("pallas"), run("xla"))
+
+    checks.append((f"xentropy[{N}x{V},f32]", 1e-4, lambda: xent(f32)))
+    checks.append((f"xentropy[{N}x{V},bf16]", 2e-2, lambda: xent(bf16)))
+
+    # -- layer norm, forward and backward ----------------------------------
+    R, H = (4096, 1024) if full else (64, 256)
+
+    def layer_norm(dtype):
+        x = rnd(20, (R, H), dtype)
+        w, b = 1.0 + rnd(21, (H,), f32, 0.1), rnd(22, (H,), f32, 0.1)
+
+        def run(use_pallas):
+            return jax.jit(jax.value_and_grad(
+                lambda x_, w_, b_: fused_layer_norm_affine(
+                    x_, w_, b_, (H,), use_pallas=use_pallas
+                ).astype(f32).sum(), argnums=(0, 1, 2)))(x, w, b)
+        return rel_err(run(True), run(False))
+
+    checks.append((f"layer_norm[{R}x{H},f32]", 1e-4, lambda: layer_norm(f32)))
+    checks.append((f"layer_norm[{R}x{H},bf16]", 2e-2,
+                   lambda: layer_norm(bf16)))
+
+    # -- fused GEMM + bias + activation --------------------------------------
+    M, Kd, Nd = (4096, 1024, 4096) if full else (64, 128, 256)
+
+    def mlp(dtype):
+        x, w = rnd(30, (M, Kd), dtype, 0.1), rnd(31, (Kd, Nd), dtype, 0.1)
+        b = rnd(32, (Nd,), dtype, 0.1)
+        got = jax.jit(lambda a, c, d: dense_act(a, c, d, "relu"))(x, w, b)
+        want = jax.jit(lambda a, c, d: jnp.maximum(jnp.dot(
+            a, c, precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=f32) + d.astype(f32), 0.0))(x, w, b)
+        return rel_err(got, want)
+
+    checks.append((f"dense_act[{M}x{Kd}x{Nd},f32]", 2e-2, lambda: mlp(f32)))
+    checks.append((f"dense_act[{M}x{Kd}x{Nd},bf16]", 2e-2, lambda: mlp(bf16)))
+
+    # -- the flat multi-tensor kernels ---------------------------------------
+    total = 32 * 1024 * 1024 if full else 8192
+
+    def multi_tensor():
+        x, y = rnd(40, (total,)), rnd(41, (total,))
+        scaled, _ = jax.jit(lambda a: K.multi_tensor_scale(a, 0.5))(x)
+        axpby, _ = jax.jit(
+            lambda a, b: K.multi_tensor_axpby(a, b, 2.0, -0.5))(x, y)
+        norm = jax.jit(K.multi_tensor_l2norm)(x)
+        return max(rel_err(scaled, x * 0.5),
+                   rel_err(axpby, 2.0 * x - 0.5 * y),
+                   rel_err(norm, jnp.sqrt(jnp.sum(x * x))))
+
+    def moments():
+        g, p = rnd(50, (total,), f32, 0.01), rnd(51, (total,))
+        m, v = rnd(52, (total,), f32, 0.01), jnp.abs(rnd(53, (total,))) * 1e-4
+        return g, p, m, v
+
+    def adam_flat():
+        g, p, m, v = moments()
+        lr, b1, b2, eps, wd, rc1, rc2, inv = (1e-3, 0.9, 0.999, 1e-8, 0.01,
+                                              1.2, 1.1, 0.5)
+        scalars = jnp.asarray([[lr, b1, b2, eps, wd, rc1, rc2, inv]], f32)
+        got = jax.jit(lambda *a: K.fused_adam_flat(
+            *a, model_dtype=bf16))(g, p, m, v, scalars)
+
+        def twin(g, p, m, v):
+            g = g * inv
+            m2 = b1 * m + (1.0 - b1) * g
+            v2 = b2 * v + (1.0 - b2) * g * g
+            p2 = p - lr * ((m2 * rc1) / (jnp.sqrt(v2 * rc2) + eps) + wd * p)
+            return [p2, m2, v2, p2.astype(bf16)]
+        want = jax.jit(twin)(g, p, m, v)
+        # the bf16 model copy may round a last-bit-different fp32 value the
+        # other way: one bf16 ulp (2^-8) there, fp32 agreement elsewhere
+        return max(rel_err(got[:3], want[:3]),
+                   rel_err(got[3], want[3]) * (1e-5 / 2 ** -8))
+
+    def lamb_stage1_flat():
+        g, p, m, v = moments()
+        b1, b2, eps, wd, rc1, rc2, clip, inv, b3 = (
+            0.9, 0.999, 1e-6, 0.01, 1.2, 1.1, 0.7, 0.5, 0.1)
+        scalars = jnp.asarray(
+            [[b1, b2, eps, wd, rc1, rc2, clip, inv, b3]], f32)
+        got = jax.jit(K.fused_lamb_stage1_flat)(g, p, m, v, scalars)
+
+        def twin(g, p, m, v):
+            g = g * inv * clip
+            m2 = b1 * m + b3 * g
+            v2 = b2 * v + (1.0 - b2) * g * g
+            return [(m2 * rc1) / (jnp.sqrt(v2 * rc2) + eps) + wd * p, m2, v2]
+        return rel_err(got, jax.jit(twin)(g, p, m, v))
+
+    checks.append((f"multi_tensor_scale_axpby_l2norm[{total}]", 1e-4,
+                   multi_tensor))
+    checks.append((f"fused_adam_flat[{total}]", 1e-5, adam_flat))
+    checks.append((f"fused_lamb_stage1_flat[{total}]", 1e-5,
+                   lamb_stage1_flat))
+    return checks
+
+
+def phase_kernels(ctx) -> dict:
+    """Every pallas_call against its XLA twin.  All checks run; the phase
+    fails when any raised or missed its tolerance."""
+    def checked(tol, thunk):
+        def run():
+            err = thunk()
+            if not err <= tol:
+                raise AssertionError(f"rel_err {err:.3e} > tol {tol:.0e}")
+            return {"rel_err": float(f"{err:.3e}"), "tol": tol}
+        return run
+
+    results = {}
+    for name, tol, thunk in _kernel_checks(ctx["full"]):
+        results[name] = attempt(f"kernel check {name}", checked(tol, thunk))
+        say(f"  {name}: {results[name]}")
+    bad = [n for n, r in results.items() if not r["ok"]]
+    if bad:
+        raise AssertionError(f"kernel checks failed: {bad}")
+    return {"checks": results}
+
+
+def phase_serve(ctx) -> dict:
+    """A server that answers a few requests: BERT-large width, causal,
+    bf16 weights, requests of different prompt lengths submitted together;
+    then the engine's logits against the trainer's forward and against
+    its own one-shot prefill (the paged cache must be invisible)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from apex_tpu.models import (TransformerConfig, bert_large_config,
+                                 transformer_apply, transformer_init)
+    from apex_tpu.serve import (CacheConfig, ContinuousBatcher,
+                                InferenceEngine, PagePool, Request)
+
+    if ctx["full"]:
+        cfg = bert_large_config(causal=True, xent_impl="xla")
+        cache = CacheConfig(page_size=16, num_pages=160, max_ctx=512)
+        width, prompt_lens, new_tokens = 8, (5, 37, 128, 300), 8
+    else:
+        cfg = TransformerConfig(vocab_size=128, max_len=32, num_layers=2,
+                                d_model=32, num_heads=2, d_ff=64,
+                                causal=True, xent_impl="xla")
+        cache = CacheConfig(page_size=8, num_pages=24, max_ctx=32)
+        width, prompt_lens, new_tokens = 2, (3, 5, 9, 14), 3
+    params = jax.jit(lambda: transformer_init(jax.random.PRNGKey(0), cfg))()
+    eng = InferenceEngine(params, cfg, cache=cache, olevel="bf16",
+                          decode_width=width)
+
+    rng = np.random.RandomState(0)
+    requests = [Request(rid=f"r{n}", max_new_tokens=new_tokens,
+                        prompt=[int(t) for t in
+                                rng.randint(1, cfg.vocab_size, size=n)])
+                for n in prompt_lens]
+    batcher = ContinuousBatcher(eng)
+    for r in requests:
+        batcher.submit(r)
+    batcher.run(max_steps=16 * new_tokens)
+    served = {}
+    for r in requests:
+        res = batcher.results[r.rid]
+        toks = [int(t) for t in res.tokens]
+        if res.status != "done" or len(toks) != new_tokens or not all(
+                0 <= t < cfg.vocab_size for t in toks):
+            raise AssertionError(f"request {r.rid}: status {res.status}, "
+                                 f"tokens {toks}")
+        served[r.rid] = {"prompt_len": len(r.prompt), "tokens": toks}
+
+    # numerics, on the shortest request: prefill's last-row logits vs the
+    # trainer forward (two separately compiled programs, bf16), then each
+    # paged decode step vs the engine's one-shot prefill of the sequence
+    prompt = requests[0].prompt
+    S, PPR = cache.max_ctx, cache.pages_per_request
+    pool = PagePool(cache)
+
+    def padded(seq):
+        toks = np.zeros(S, np.int32)
+        toks[:len(seq)] = seq
+        return toks
+
+    def table_of(pages):
+        table = np.zeros(PPR, np.int32)
+        table[:len(pages)] = pages
+        return table
+
+    pages = pool.alloc(cache.pages_for(len(prompt)))
+    first, logits = eng.prefill(padded(prompt), len(prompt),
+                                table_of(pages), 0)
+    bf16_params = jax.tree_util.tree_map(
+        lambda x: x.astype(jnp.bfloat16), params)
+    trainer = jax.jit(lambda p, t: transformer_apply(
+        p, t, eng.cfg))(bf16_params, jnp.asarray(padded(prompt))[None])
+    err_trainer = rel_err(logits, trainer[0, len(prompt) - 1])
+
+    scratch = pool.alloc(PPR)      # the oracle's own pages
+    seq, err_paged = list(prompt) + [int(first)], 0.0
+    for _ in range(3):
+        pos = len(seq) - 1
+        need = cache.pages_for(pos + 1)
+        if need > len(pages):
+            pages = pages + pool.alloc(need - len(pages))
+        toks_w, positions = np.zeros(width, np.int32), np.zeros(width, np.int32)
+        toks_w[0], positions[0] = seq[-1], pos
+        tables = np.zeros((width, PPR), np.int32)
+        tables[0] = table_of(pages)
+        zeros = np.zeros(width, np.int32)
+        nxt, dec_logits = eng.decode_step(
+            toks_w, positions, tables, zeros, np.zeros(width, np.float32),
+            zeros)
+        _, oracle = eng.prefill(padded(seq), pos + 1, table_of(scratch), 0)
+        err_paged = max(err_paged, rel_err(dec_logits[0], oracle))
+        seq.append(int(np.asarray(nxt)[0]))
+    tol = 3e-2                     # bf16 through every layer
+    if not (err_trainer <= tol and err_paged <= tol):
+        raise AssertionError(
+            f"logits off: vs trainer forward {err_trainer:.3e}, paged "
+            f"decode vs one-shot {err_paged:.3e} (tol {tol})")
+    return {"layers": cfg.num_layers, "d_model": cfg.d_model,
+            "max_ctx": cache.max_ctx, "decode_width": width,
+            "weights_compression": eng.compression_ratio, "served": served,
+            "logits_rel_err_vs_trainer": float(f"{err_trainer:.3e}"),
+            "logits_rel_err_paged_vs_oneshot": float(f"{err_paged:.3e}"),
+            "tol": tol}
+
+
+def _resnet_argv(ctx, steps, extra=()):
+    size = (["--arch", "resnet50", "--batch-size", "128"] if ctx["full"]
+            else ["--arch", "resnet18", "--batch-size", "8"])
+    return size + ["--opt-level", "O2", "--steps", str(steps),
+                   "--print-freq", "1", *extra]
+
+
+def phase_resnet50(ctx) -> dict:
+    """A trainer that takes a few steps: ResNet-50 b128, amp O2 +
+    FusedAdam + batch norm, on learnable synthetic images."""
+    steps = 12
+    report = {}
+    load_example("examples/imagenet/main_amp.py").main(
+        _resnet_argv(ctx, steps), report=report)
+    return check_trainer_report(report, steps)
+
+
+def _bert_argv(ctx, steps, batch, extra=()):
+    # the tiny model needs a large step to move its loss within a few
+    # steps; at full width the example's default learning rate does
+    size = (["--bert-large", "--seq-len", "512"] if ctx["full"] else
+            ["--layers", "2", "--d-model", "64", "--heads", "2", "--vocab",
+             "512", "--seq-len", "128", "--lr", "5e-2"])
+    return size + ["--batch-size", str(batch), "--attn", "fast", "--remat",
+                   "--steps", str(steps), "--print-freq", "1", *extra]
+
+
+def _flash_bwd_present(names: set) -> bool:
+    return ("apex_flash_bwd_fused" in names
+            or {"apex_flash_bwd_dq", "apex_flash_bwd_dkv"} <= names)
+
+
+def phase_bert_large(ctx) -> dict:
+    """The required leg: BERT-large 24L b8 x s512, flash attention +
+    remat, amp O5, FusedLAMB on the flat engine with the global-norm clip
+    — and the compiled step shown to contain the Pallas kernels."""
+    import re
+    steps = 9
+    report = {}
+    load_example("examples/bert/pretrain.py").main(
+        _bert_argv(ctx, steps, batch=8 if ctx["full"] else 2), report=report)
+    facts = check_trainer_report(report, steps)
+
+    traced = report["step"].trace(report["state"], report["batch"])
+    names = pallas_kernel_names(traced)
+    required = {"apex_flash_fwd", "apex_l2norm"}
+    if ctx["on_tpu"]:
+        required.add("apex_xentropy_fwd")     # "auto" is XLA off the chip
+    missing = sorted(required - names)
+    if missing or not _flash_bwd_present(names):
+        raise AssertionError(
+            f"traced step lacks Pallas kernels {missing or 'flash bwd'}; "
+            f"found {sorted(names)}")
+    facts["pallas_calls_traced"] = sorted(names)
+    if ctx["on_tpu"]:
+        # and in what Mosaic was handed: one tpu_custom_call per kernel
+        text = traced.lower().as_text()
+        lowered = set(re.findall(r'kernel_name = "([^"]+)"', text))
+        if not (required <= lowered and _flash_bwd_present(lowered)):
+            raise AssertionError(
+                f"lowered step has tpu_custom_calls {sorted(lowered)}, "
+                f"expected {sorted(required)} and a flash backward")
+        facts["tpu_custom_calls_lowered"] = sorted(lowered)
+    return facts
+
+
+def _plan_families():
+    from apex_tpu.parallel import plan as pm
+    return [("dp2xtp2", pm.Plan(dp=2, tp=2)),
+            ("dp2xsp2_ring", pm.Plan(dp=2, sp=2, sp_strategy="ring")),
+            ("dp2xsp2_ulysses", pm.Plan(dp=2, sp=2, sp_strategy="ulysses")),
+            ("dp2xpp2", pm.Plan(dp=2, pp_stages=2, pp_microbatches=2)),
+            ("dp2xep2", pm.Plan(dp=2, ep=2)),
+            ("dp4_zero1", pm.Plan(dp=4, update_sharding="zero1")),
+            ("dp4_zero", pm.Plan(dp=4, zero=True))]
+
+
+def phase_multichip(ctx) -> dict:
+    """The same trainers data-parallel over every device, and one step of
+    each plan family on a 4-device mesh; each device holds its share."""
+    import jax
+    import jax.numpy as jnp
+    from apex_tpu.models import TransformerConfig
+    from apex_tpu.parallel import spmd
+
+    devices = jax.devices()
+    n = len(devices)
+    out = {}
+
+    def leg(name, fn):
+        out[name] = attempt(f"multichip leg {name}", fn)
+        out[name]["memory"] = memory_fact(devices)
+        say(f"  {name}: {out[name]}")
+
+    steps = 9
+    bert_batch = (8 if ctx["full"] else 1) * n
+
+    def bert(extra):
+        def run():
+            report = {}
+            load_example("examples/bert/pretrain.py").main(
+                _bert_argv(ctx, steps, bert_batch, extra), report=report)
+            facts = check_trainer_report(report, steps)
+            state = report["state"]
+            check_holds_share(getattr(state, "carry", state), devices,
+                              "state", balance=ctx["full"])
+            return facts
+        return run
+
+    def resnet():
+        report = {}
+        argv = _resnet_argv(ctx, steps, ("--distributed", "--sync-bn"))
+        if not ctx["full"]:
+            argv[argv.index("--batch-size") + 1] = str(2 * n)
+        load_example("examples/imagenet/main_amp.py").main(
+            argv, report=report)
+        facts = check_trainer_report(report, steps)
+        check_holds_share(report["state"], devices, "state",
+                          balance=ctx["full"])
+        return facts
+
+    leg("bert_large_distributed", bert(("--distributed",)))
+    leg("bert_large_zero", bert(("--zero",)))
+    leg("resnet50_distributed_syncbn", resnet)
+
+    # two layers so a 2-stage pipeline divides evenly; the loss kernel
+    # stays "auto" so that on the chip the engines' shard_maps carry the
+    # Pallas xentropy, as a production config would
+    cfg = TransformerConfig(vocab_size=64, max_len=16, num_layers=2,
+                            d_model=32, num_heads=2, d_ff=64)
+    tokens = jnp.zeros((4, cfg.max_len), jnp.int32)
+
+    def family(plan):
+        def run():
+            with plan.apply(devices[: plan.chips]) as mesh:
+                carry, step, info = spmd.build_plan_step(
+                    cfg, mesh, plan, global_batch=4, meter=False)
+                carry, loss = step(carry, tokens)
+                if not bool(jnp.isfinite(loss)):
+                    raise AssertionError(f"loss {loss}")
+                check_holds_share(carry, devices[: plan.chips], "carry")
+            return {"engine": info.get("engine"), "loss": float(loss)}
+        return run
+
+    for name, plan in _plan_families():
+        leg(f"family_{name}", family(plan))
+    bad = [name for name, rec in out.items() if not rec["ok"]]
+    if bad:
+        raise AssertionError(f"multichip legs failed: {bad}")
+    return {"devices": n, "legs": out}
+
+
+PHASES = {
+    "native": phase_native,
+    "kernels": phase_kernels,
+    "serve": phase_serve,
+    "resnet50": phase_resnet50,
+    "bert_large": phase_bert_large,
+    "multichip": phase_multichip,
+}
+MULTICHIP_DEVICES = 4
+
+
+def run_phases(ctx, names) -> dict:
+    import jax
+    meter = ctx["meter"]
+    devices = jax.devices()
+    report = {}
+    for name in names:
+        if name == "multichip" and len(devices) < MULTICHIP_DEVICES:
+            say(f"phase multichip: skipped — jax found {len(devices)} "
+                f"device(s), the leg needs {MULTICHIP_DEVICES}")
+            report[name] = {"ok": True, "skipped":
+                            f"{len(devices)} device(s) < {MULTICHIP_DEVICES}"}
+            continue
+        say(f"phase {name} ...")
+        c0, h0, m0 = meter.snapshot()
+        rec = attempt(f"phase {name}", lambda: PHASES[name](ctx))
+        c1, h1, m1 = meter.snapshot()
+        wall = rec.pop("s")
+        rec.update(wall_s=wall, compile_s=round(c1 - c0, 2),
+                   run_s=round(wall - (c1 - c0), 2),
+                   compile_cache={"hits": h1 - h0, "misses": m1 - m0},
+                   memory=memory_fact(devices))
+        report[name] = rec
+        say(f"phase {name}: {'ok' if rec['ok'] else 'FAILED'}  "
+            f"wall {rec['wall_s']} s = compile {rec['compile_s']} s + "
+            f"run {rec['run_s']} s; cache {rec['compile_cache']}; "
+            f"peak bytes in use (process high-water) "
+            f"{rec['memory']['peak_bytes_in_use']}")
+    return report
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--rehearse", action="store_true",
+                    help="run the same phases at tiny shapes on the CPU "
+                         "(kernels interpreted); never chosen for you")
+    ap.add_argument("--only", default=None,
+                    help=f"comma-separated phases of {list(PHASES)}")
+    args = ap.parse_args(argv)
+    names = list(PHASES) if not args.only else args.only.split(",")
+    unknown = [n for n in names if n not in PHASES]
+    if unknown:
+        ap.error(f"unknown phases {unknown}; known: {list(PHASES)}")
+
+    import jax
+    from apex_tpu.utils import platform as plat
+    if args.rehearse:
+        plat.force_cpu(MULTICHIP_DEVICES)
+    dev = jax.devices()[0]
+    device = {"platform": dev.platform, "kind": dev.device_kind,
+              "count": len(jax.devices())}
+    say(f"jax {jax.__version__}  platform {device['platform']}  "
+        f"device_kind {device['kind']}  devices {device['count']}")
+    if args.rehearse:
+        say("REHEARSAL: tiny shapes, platform: cpu — proves the script, "
+            "not the chip")
+    elif not plat.found_tpu("chip_smoke.py"):
+        print("nothing was run (--rehearse runs the phases tiny on the "
+              "CPU)", file=sys.stderr)
+        return 2
+
+    meter = CompileMeter()
+    cache_dir = plat.enable_compile_cache()
+    say(f"compile cache: {cache_dir}")
+    ctx = {"full": not args.rehearse, "on_tpu": dev.platform == "tpu",
+           "meter": meter}
+    t0 = time.monotonic()
+    report = run_phases(ctx, names)
+    failed = [n for n, r in report.items() if not r["ok"]]
+    _, hits, misses = meter.snapshot()
+    summary = {"ok": not failed, "device": device}
+    if args.rehearse:
+        summary["rehearsal"] = True
+    if failed:
+        summary["failed"] = failed
+    full_report = {**summary, "jax": jax.__version__,
+                   "wall_s": round(time.monotonic() - t0, 1),
+                   "compile_cache": {"dir": cache_dir, "hits": hits,
+                                     "misses": misses},
+                   "phases": report}
+    out_dir = os.path.join(REPO, "chiprun_out")
+    os.makedirs(out_dir, exist_ok=True)
+    with open(os.path.join(out_dir, "chip_smoke.json"), "w") as f:
+        json.dump(full_report, f, indent=1)
+    say(f"done in {full_report['wall_s']} s; compile cache hits {hits}, "
+        f"misses {misses}; failed phases: {failed or 'none'}")
+    print(json.dumps(summary))
+    return 1 if failed else 0
+
+
+if __name__ == "__main__":
+    raise SystemExit(main())

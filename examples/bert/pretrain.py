@@ -6,7 +6,8 @@ this harness makes config 4 runnable end-to-end: transformer encoder + amp
 O5 (bf16 + fp32 masters on the flat engine) + FusedLAMB with global-norm
 clipping, on synthetic MLM batches.  Distributed options:
 
-  --distributed    shard the batch over all devices (DP via pjit)
+  --distributed    shard the batch over all devices (DP: shard_map +
+                   DistributedDataParallel gradient averaging)
   --zero           ZeRO sharded optimizer states (DistributedFusedLAMB
                    inside shard_map: psum_scatter grads -> sharded update
                    -> bf16 all_gather)
@@ -104,8 +105,27 @@ def parse_args(argv=None):
     return p.parse_args(argv)
 
 
+_SYN_POOL = 64           # distinct token ids the synthetic corpus uses
+
+
+@functools.lru_cache(maxsize=4)
+def _syn_pool(vocab):
+    """The corpus's token ids: fixed seed, spread over the vocabulary."""
+    return np.random.RandomState(1234).choice(
+        np.arange(1, vocab), size=min(_SYN_POOL, vocab - 1), replace=False)
+
+
 def synthetic_mlm(rng, batch, seq, vocab):
-    tokens = rng.randint(0, vocab, size=(batch, seq)).astype(np.int32)
+    """Synthetic MLM batch over a fixed pool of ``_SYN_POOL`` token ids
+    spread across the vocabulary (pool seed independent of the batch
+    seed).  The pool makes the corpus LEARNABLE — its unigram prior takes
+    the loss from ln(vocab) towards ln(pool) — which is what a numerics
+    proof on hardware checks; tokens uniform over the whole vocabulary
+    bound the loss at ln(vocab) from the first step and prove nothing
+    (the same posture as the imagenet example's class prototypes)."""
+    pool = _syn_pool(vocab)
+    tokens = pool[rng.randint(0, len(pool), size=(batch, seq))].astype(
+        np.int32)
     targets = tokens.copy()
     mask = rng.rand(batch, seq) < 0.15
     tokens[mask] = 0                      # [MASK]
@@ -148,8 +168,21 @@ def sharded_mlm_loader(args, steps):
                          num_steps=steps, transform=tf)
 
 
+def _device_batch(np_batch, sharding):
+    return {k: jax.device_put(v, sharding) for k, v in np_batch.items()}
+
+
 def run_standard(args, cfg, mesh):
-    """amp O5 + FusedLAMB (flat fused engine) under pjit sharding."""
+    """amp O5 + FusedLAMB (flat fused engine), data-parallel over the
+    mesh's ``data`` axis: each device runs the step on its shard of the
+    batch inside ``shard_map`` and the gradients are averaged by
+    ``DistributedDataParallel``.  (Not GSPMD auto-partitioning: the
+    Pallas kernels — flash attention, xentropy, the l2norm of the clip —
+    have no partitioning rule, so on a TPU "Mosaic kernels cannot be
+    automatically partitioned"; under ``shard_map`` each device simply
+    runs them on its shard.)"""
+    from jax import shard_map
+    from apex_tpu.parallel import DistributedDataParallel
     moe = isinstance(cfg, MoETransformerConfig)
     init_fn = moe_transformer_init if moe else transformer_init
     loss_impl = moe_transformer_loss if moe else transformer_loss
@@ -160,6 +193,10 @@ def run_standard(args, cfg, mesh):
                     state_dtype=jnp.bfloat16 if args.state_dtype else None)
     state = amp.initialize(params, opt, opt_level=args.opt_level,
                            verbosity=0)
+    # replicate over the mesh up front: left on the default device, the
+    # state would be re-laid-out by the first step and the step traced
+    # and compiled a second time for the new input shardings
+    state = jax.device_put(state, NamedSharding(mesh, P()))
     sharding = NamedSharding(mesh, P("data"))
 
     # donate the amp state: the flat fused engine writes fresh master/m/v
@@ -168,29 +205,33 @@ def run_standard(args, cfg, mesh):
     # the un-donated transient would be an extra ~4 GB of flat fp32
     # state.  Safe: amp.initialize never aliases buffers between the
     # model and master trees for this param family.
+    ddp = DistributedDataParallel(axis_name="data")
+
     @functools.partial(jax.jit, donate_argnums=0)
+    @functools.partial(
+        shard_map, mesh=mesh, in_specs=(P(), P("data")),
+        out_specs=(P(), P()),
+        check_vma=False)            # interpret-mode pallas limitation
     def train_step(state, batch):
         def loss_fn(p):
             loss = loss_impl(p, batch, cfg)
             return amp.scale_loss(loss, state), loss
         g, loss = jax.grad(loss_fn, has_aux=True)(state.model_params)
-        return amp.amp_step(state, g), loss
+        g = ddp.allreduce_grads(g)
+        return amp.amp_step(state, g), jax.lax.pmean(loss, "data")
 
     def step(state, np_batch):
-        batch = {k: jax.device_put(v, sharding) for k, v in np_batch.items()}
-        return train_step(state, batch)
+        return train_step(state, _device_batch(np_batch, sharding))
 
+    step.trace = lambda state, np_batch: train_step.trace(
+        state, _device_batch(np_batch, sharding))
+    step.optimizer_steps = lambda state: int(state.opt_state.count)
     return state, step
 
 
 def run_zero(args, cfg, mesh):
     """ZeRO: DistributedFusedLAMB inside shard_map (sharded opt state)."""
-    try:
-        from jax import shard_map
-        vma_kw = {"check_vma": False}   # interpret-mode pallas limitation
-    except ImportError:  # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map
-        vma_kw = {"check_rep": False}
+    from jax import shard_map
     from apex_tpu.contrib.optimizers import DistributedFusedLAMB
 
     params = jax.jit(
@@ -208,7 +249,7 @@ def run_zero(args, cfg, mesh):
         return opt.init(p)
 
     opt_state = jax.jit(init_fn)(params)
-    n_dev = mesh.devices.size
+    sharding = NamedSharding(mesh, P("data"))
 
     # donate the (params, sharded opt state) carry: the stage-1 kernels
     # write fresh buffers (PERF_NOTES §2), so in-place HBM reuse happens
@@ -221,7 +262,8 @@ def run_zero(args, cfg, mesh):
             shard_map, mesh=mesh,
             in_specs=(rep, sspec,
                       jax.tree_util.tree_map(lambda _: P("data"), batch)),
-            out_specs=(rep, sspec, P()), **vma_kw)
+            out_specs=(rep, sspec, P()),
+            check_vma=False)        # interpret-mode pallas limitation
         def inner(p, s, local_batch):
             local = {k: v for k, v in local_batch.items()}
             loss, g = jax.value_and_grad(
@@ -232,8 +274,7 @@ def run_zero(args, cfg, mesh):
         new_p, new_s, loss = inner(params, opt_state, batch)
         return (new_p, new_s), loss
 
-    sharding = NamedSharding(mesh, P("data"))
-    carry = (params, opt_state)
+    carry = (jax.device_put(params, NamedSharding(mesh, P())), opt_state)
 
     class _State:            # match run_standard's (state, step) shape
         pass
@@ -242,10 +283,13 @@ def run_zero(args, cfg, mesh):
     holder.carry = carry
 
     def step(holder_state, np_batch):
-        batch = {k: jax.device_put(v, sharding) for k, v in np_batch.items()}
-        holder.carry, loss = train_step(holder.carry, batch)
+        holder.carry, loss = train_step(
+            holder.carry, _device_batch(np_batch, sharding))
         return holder, loss
 
+    step.trace = lambda holder_state, np_batch: train_step.trace(
+        holder.carry, _device_batch(np_batch, sharding))
+    step.optimizer_steps = lambda holder_state: int(holder.carry[1].count)
     return holder, step
 
 
@@ -299,7 +343,13 @@ def run_plan(args, cfg):
     return losses.val
 
 
-def main(argv=None):
+def main(argv=None, report=None):
+    """Train; returns the last printed loss.  ``report``, a dict the
+    caller owns, is filled (standard and ``--zero`` paths) with what a
+    check of the run needs: the printed ``losses``, the number of
+    ``optimizer_steps`` actually applied (a step the scaler skipped does
+    not count), and ``state`` / ``step`` / ``batch`` so the compiled
+    step can be inspected through ``step.trace(state, batch)``."""
     args = parse_args(argv)
     if args.moe and (args.bert_large or args.zero):
         raise SystemExit("--moe combines with the standard path only")
@@ -390,6 +440,7 @@ def main(argv=None):
     with use_mesh(mesh):
         state, step = (run_zero if args.zero else run_standard)(args, cfg,
                                                                 mesh)
+        history = []
         for i in range(args.steps):
             if data_it is not None:
                 batch = next(data_it)      # prefetched shard-addressed
@@ -401,12 +452,19 @@ def main(argv=None):
             state, loss = step(state, batch)
             if (i + 1) % args.print_freq == 0 or i == args.steps - 1:
                 losses.update(float(loss))
+                history.append(losses.val)
                 rate = tput.tick(args.print_freq * args.batch_size)
                 print(f"step {i + 1:4d}  {losses}  "
                       f"{rate:.1f} sequences/sec", flush=True)
+        if report is not None:
+            report.update(losses=history, state=state, step=step,
+                          batch=batch,
+                          optimizer_steps=step.optimizer_steps(state))
     print(f"=> done: final loss {losses.val:.4f}")
     return losses.val
 
 
 if __name__ == "__main__":
+    from apex_tpu.utils.platform import enable_compile_cache
+    enable_compile_cache()
     main()

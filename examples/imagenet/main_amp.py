@@ -185,7 +185,12 @@ def native_batches(args, batch, steps):
     """Batches via the native prefetch engine (apex_tpu.data): C++ worker
     threads assemble batches in a ring while the step runs; yields numpy so
     the training loop's sharded device_put stays in charge of placement."""
-    from apex_tpu.data import ArraySource, NativeLoader, SyntheticSource
+    from apex_tpu.data import (ArraySource, NativeLoader, SyntheticSource,
+                               native_available)
+    if not native_available():
+        raise RuntimeError(
+            "--loader native: the native prefetch engine could not be "
+            "built (its warning says why); use --loader python")
     if args.data:
         img = os.path.join(args.data, "images.npy")
         lab = os.path.join(args.data, "labels.npy")
@@ -266,7 +271,12 @@ def validate(args, cfg, state, bn_state, mesh, batch_sharding):
     return m1.avg
 
 
-def main(argv=None):
+def main(argv=None, report=None):
+    """Train; returns the average speed print.  ``report``, a dict the
+    caller owns, is filled (plain loop only) with what a check of the run
+    needs: the printed ``losses``, the number of ``optimizer_steps``
+    actually applied (a step the scaler skipped does not count), and the
+    final ``state``."""
     args = parse_args(argv)
     if args.deterministic:
         np.random.seed(args.seed)
@@ -315,6 +325,11 @@ def main(argv=None):
         print(f"=> resumed from {args.resume} at step {start_step}")
 
     batch_sharding = NamedSharding(mesh, P("data"))
+    # replicate over the mesh up front: left on the default device, the
+    # state would be re-laid-out by the first step and the step traced
+    # and compiled a second time for the new input shardings
+    state, bn_state = jax.device_put((state, bn_state),
+                                     NamedSharding(mesh, P()))
 
     @jax.jit
     def train_step(state, bn_state, images, labels):
@@ -393,8 +408,8 @@ def main(argv=None):
         if args.validate and rep.status == "completed":
             validate(args, cfg, state, bn_state, mesh, batch_sharding)
         if rep.status != "completed":
-            # the watcher (tpu_watch.sh guard leg) keys its DONE marker
-            # on a zero exit: an interrupted run must read as retryable
+            # a caller keys "done" on a zero exit: an interrupted run
+            # must read as retryable
             raise SystemExit(3)
         return None
 
@@ -409,6 +424,7 @@ def main(argv=None):
         batches = synthetic_batches(args.batch_size, args.seed, total_steps)
 
     losses, top1, speed = AverageMeter(), AverageMeter(), AverageMeter()
+    history = []
     prof = None
     if args.prof:
         from apex_tpu import pyprof
@@ -433,6 +449,7 @@ def main(argv=None):
                 dt = time.perf_counter() - t0
                 ips = window * args.batch_size / dt
                 losses.update(loss, window)
+                history.append(loss)
                 top1.update(float(acc), window)
                 if step - start_step + 1 > args.print_freq:  # skip compile
                     speed.update(ips)
@@ -442,6 +459,10 @@ def main(argv=None):
                       f"Prec@1 {top1.val:.3f}", flush=True)
                 t0 = time.perf_counter()
                 window = 0
+
+    if report is not None:
+        report.update(losses=history, state=state,
+                      optimizer_steps=int(state.opt_state.count))
 
     if args.validate:
         validate(args, cfg, state, bn_state, mesh, batch_sharding)
@@ -457,4 +478,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from apex_tpu.utils.platform import enable_compile_cache
+    enable_compile_cache()
     main()

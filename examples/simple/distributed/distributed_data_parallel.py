@@ -112,4 +112,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from apex_tpu.utils.platform import enable_compile_cache
+    enable_compile_cache()
     main()

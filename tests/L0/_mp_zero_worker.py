@@ -26,10 +26,7 @@ import jax                        # noqa: E402
 import jax.numpy as jnp           # noqa: E402
 from jax.sharding import Mesh, PartitionSpec as P  # noqa: E402
 
-try:
-    from jax import shard_map
-except ImportError:               # older jax layout
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from apex_tpu.contrib.optimizers import DistributedFusedLAMB  # noqa: E402
 

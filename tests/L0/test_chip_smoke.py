@@ -1,0 +1,155 @@
+"""chip_smoke.py — the on-chip smoke — exercised where there is no chip:
+the rehearsal mode runs every phase tiny on the CPU (kernels interpreted),
+so the script, its exit codes and its one-JSON-line contract are tier-1
+tested; without the flag the script must refuse the CPU before doing any
+work."""
+import importlib.util
+import json
+import os
+import subprocess
+import sys
+
+import jax
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+SMOKE = os.path.join(ROOT, "chip_smoke.py")
+ENV = {**os.environ, "JAX_PLATFORMS": "cpu"}
+
+
+def _load_smoke():
+    spec = importlib.util.spec_from_file_location("chip_smoke", SMOKE)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.fixture
+def compile_cache_config():
+    """chip_smoke / the bench mains turn the persistent compile cache on
+    for their process; put this process's settings back afterwards."""
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    saved = {k: getattr(jax.config, k) for k in keys}
+    yield
+    for k, v in saved.items():
+        jax.config.update(k, v)
+
+
+def test_rehearsal_runs_every_phase_on_cpu():
+    """``--rehearse``: every phase at tiny shapes on the CPU, exit 0, and
+    the last stdout line is the JSON summary labelled platform cpu."""
+    r = subprocess.run([sys.executable, SMOKE, "--rehearse"],
+                       capture_output=True, text=True, timeout=600,
+                       cwd=ROOT, env=ENV)
+    assert r.returncode == 0, (r.stdout[-3000:], r.stderr[-1500:])
+    summary = json.loads(r.stdout.strip().splitlines()[-1])
+    assert summary["ok"] is True and summary["rehearsal"] is True
+    assert summary["device"]["platform"] == "cpu"
+    assert summary["device"]["count"] >= 4
+    with open(os.path.join(ROOT, "chiprun_out", "chip_smoke.json")) as f:
+        report = json.load(f)
+    smoke = _load_smoke()
+    assert list(report["phases"]) == list(smoke.PHASES)
+    for name, rec in report["phases"].items():
+        assert rec["ok"] and "skipped" not in rec, (name, rec)
+        assert rec["compile_s"] >= 0 and rec["wall_s"] >= rec["compile_s"]
+    # the BERT step was shown to hold the flash + l2norm kernels
+    traced = report["phases"]["bert_large"]["pallas_calls_traced"]
+    assert {"apex_flash_fwd", "apex_l2norm"} <= set(traced)
+    # every plan family took its step on the 4-device mesh
+    legs = report["phases"]["multichip"]["legs"]
+    assert sum(k.startswith("family_") for k in legs) == 7
+    assert all(leg["ok"] for leg in legs.values())
+
+
+def test_without_the_flag_the_cpu_is_refused():
+    """No TPU and no ``--rehearse``: non-zero exit before any work, the
+    platform named, and no result line."""
+    r = subprocess.run([sys.executable, SMOKE], capture_output=True,
+                       text=True, timeout=120, cwd=ROOT, env=ENV)
+    assert r.returncode == 2
+    assert "'cpu'" in r.stderr and "TPU" in r.stderr
+    assert not any(ln.startswith("{") for ln in r.stdout.splitlines())
+    assert "phase" not in r.stdout
+
+
+def test_a_failing_phase_fails_the_run(monkeypatch, capsys,
+                                       compile_cache_config):
+    """A phase that raises prints its traceback, the remaining phases
+    still run, and the exit code and summary say failed."""
+    smoke = _load_smoke()
+
+    def boom(ctx):
+        raise RuntimeError("Mosaic lowering exploded")
+    monkeypatch.setitem(smoke.PHASES, "kernels", boom)
+    rc = smoke.main(["--rehearse", "--only", "kernels,native"])
+    out = capsys.readouterr().out
+    assert rc == 1
+    assert "Traceback" in out and "Mosaic lowering exploded" in out
+    assert "phase native: ok" in out              # later phases still ran
+    summary = json.loads(out.strip().splitlines()[-1])
+    assert summary["ok"] is False and summary["failed"] == ["kernels"]
+
+    with pytest.raises(SystemExit):               # a typo'd phase name
+        smoke.main(["--rehearse", "--only", "kernals"])
+
+
+def test_compile_cache_is_placed_from_outside_or_in_the_checkout(
+        monkeypatch, compile_cache_config):
+    from apex_tpu.utils import platform as plat
+    # unset: the fixed in-checkout directory, set in code
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    assert plat.enable_compile_cache() == os.path.join(ROOT, ".jax_cache")
+    assert jax.config.jax_compilation_cache_dir == os.path.join(
+        ROOT, ".jax_cache")
+    # set: the variable's directory is reported and no code sets one
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+    updated = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda key, value: updated.append(key))
+    assert plat.enable_compile_cache() == "/some/dir"
+    assert "jax_compilation_cache_dir" not in updated
+    # ... because jax reads the variable itself
+    r = subprocess.run(
+        [sys.executable, "-c",
+         "import jax; print(jax.config.jax_compilation_cache_dir)"],
+        capture_output=True, text=True, timeout=120,
+        env={**ENV, "JAX_COMPILATION_CACHE_DIR": "/some/dir"})
+    assert r.stdout.strip() == "/some/dir", r.stderr[-1500:]
+
+
+def test_force_cpu_after_another_backend_is_an_error(monkeypatch):
+    """A process keeps the backend it initialised: asking for more CPU
+    devices than the live backend has cannot be granted, and says so."""
+    from apex_tpu.utils import platform as plat
+    assert plat.backends_initialized()
+    plat.force_cpu(jax.device_count())            # already satisfied
+    with pytest.raises(RuntimeError, match="before the first jax"):
+        plat.force_cpu(jax.device_count() + 1)
+
+
+@pytest.mark.parametrize("script", ["bench.py", "bench_kernels.py"])
+def test_bench_mains_refuse_to_run_off_the_chip(script, capsys,
+                                                compile_cache_config):
+    """No CPU stand-in: off the chip the bench mains exit non-zero and
+    print no metric line."""
+    spec = importlib.util.spec_from_file_location(
+        script[:-3] + "_main_test", os.path.join(ROOT, script))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    assert mod.main([]) == 2
+    captured = capsys.readouterr()
+    assert "'cpu'" in captured.err and "refusing" in captured.err
+    assert "{" not in captured.out
+
+
+def test_errored_legs_are_what_makes_the_bench_exit_code_nonzero():
+    from apex_tpu.utils.bench_legs import errored
+    detail = {"rn50": {"images_per_sec": 1.0},
+              "bert_e2e": {"error": "XlaRuntimeError(...)"},
+              "n_params": 3}
+    assert errored(detail) == ["bert_e2e"]
+    assert errored({"rn50": {"batch": 1}}) == []

@@ -486,8 +486,8 @@ def _build_zero1_harness(world):
     from apex_tpu.optimizers import FusedAdam
     from apex_tpu.parallel import create_mesh
     from apex_tpu.parallel import weight_update as wu
-    from apex_tpu.parallel.mesh import shard_map
-    from apex_tpu.utils.pallas import has_vma, _to_varying
+    from jax import shard_map
+    from apex_tpu.utils.pallas import to_varying
 
     mesh = create_mesh({"data": world}, jax.devices()[:world])
     cfg = TransformerConfig(vocab_size=64, max_len=20, num_layers=1,
@@ -497,13 +497,12 @@ def _build_zero1_harness(world):
     su = wu.ShardedUpdate(FusedAdam(lr=1e-2, impl="fused"),
                           axis_name="data",
                           collective_scheme="int8_blockscale:min_bytes=0")
-    vma_kw = {} if has_vma() else {"check_vma": False}
     pspec = jax.tree_util.tree_map(lambda _: P(), params0)
     sspec = su.state_pspecs(params0, world)
 
     def grads_of(params, tokens):
         pv = jax.tree_util.tree_map(
-            lambda p: _to_varying(p, ("data",)), params)
+            lambda p: to_varying(p, ("data",)), params)
         return jax.value_and_grad(lambda p: transformer_loss(
             p, {"tokens": tokens, "targets": tokens}, cfg))(pv)
 
@@ -520,7 +519,7 @@ def _build_zero1_harness(world):
     jstep = jax.jit(shard_map(
         body, mesh=mesh,
         in_specs=(pspec, sspec, P("data"), P("data")),
-        out_specs=(pspec, sspec, P("data"), P()), **vma_kw))
+        out_specs=(pspec, sspec, P("data"), P())))
     state0, res0 = jax.jit(init_s)(params0)
 
     def step_fn(state, batch):

@@ -8,10 +8,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-try:
-    from jax import shard_map
-except ImportError:  # older jax layout
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from apex_tpu import amp
@@ -96,7 +93,7 @@ def test_amp_o2_shard_map_explicit_psum(mesh):
         pred = pred * p["bn_scale"]
         return jnp.mean((pred - y) ** 2) * scale
 
-    from apex_tpu.utils.pallas import _to_varying
+    from apex_tpu.utils.pallas import to_varying
 
     @jax.jit
     def dist_step(state, X, Y):
@@ -113,7 +110,7 @@ def test_amp_o2_shard_map_explicit_psum(mesh):
             # the explicit DDP allreduce, lift params to per-device
             # (varying) copies first, so grads are local like torch's
             p = jax.tree_util.tree_map(
-                lambda t: _to_varying(t, ("data",)), p)
+                lambda t: to_varying(t, ("data",)), p)
             g = jax.grad(local_loss)(p, x[0], y[0], state.loss_scale)
             return allreduce_tree(g, axis_name="data")   # average=True
         grads = grads_fn(state.model_params, X, Y)
@@ -167,7 +164,7 @@ def test_allreduce_tree_handles_presummed_grads(mesh):
     double reduction) — the mechanical guard for the cotangent-psum
     footgun."""
     from apex_tpu.parallel import allreduce_tree
-    from apex_tpu.utils.pallas import _to_varying
+    from apex_tpu.utils.pallas import to_varying
 
     X = jax.random.normal(jax.random.PRNGKey(7), (N_DEV, 4, 16))
     w = 0.2 * jax.random.normal(jax.random.PRNGKey(8), (16, 8))
@@ -181,7 +178,7 @@ def test_allreduce_tree_handles_presummed_grads(mesh):
                            in_specs=(P(), P("data")), out_specs=P())
         def f(w, x):
             if lift:
-                w = _to_varying(w, ("data",))
+                w = to_varying(w, ("data",))
             g = jax.grad(loss)(w, x[0])
             return allreduce_tree(g, axis_name="data")
         return f(w, X)

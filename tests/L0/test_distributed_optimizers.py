@@ -14,7 +14,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from apex_tpu.parallel.mesh import shard_map   # check_vma/check_rep compat
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from apex_tpu.contrib.optimizers import (DistributedFusedAdam,
@@ -66,12 +66,7 @@ def run_sharded(opt, params, n_dev=8, iters=ITERS, mesh=None, specs=None,
     specs = specs if specs is not None else P(*(mesh.axis_names))
     gspec = jax.tree_util.tree_map(lambda _: specs, params)
     sspec = opt.state_pspecs()
-    # the replication-typing validation additionally needs a jax with vma
-    # typing: the 0.4-era check_rep cannot infer the allgathered outputs
-    # replicated and rejects the step wholesale
-    from apex_tpu.utils.pallas import has_vma
-    vma_kw = ({"check_vma": False}
-              if opt.impl == "fused" or not has_vma() else {})
+    vma_kw = {"check_vma": False} if opt.impl == "fused" else {}
 
     @functools.partial(
         shard_map, mesh=mesh,

@@ -47,13 +47,13 @@ from apex_tpu.optimizers import FusedAdam
 from apex_tpu.parallel import collectives, create_mesh
 from apex_tpu.parallel import plan as plan_mod
 from apex_tpu.parallel import weight_update as wu
-from apex_tpu.parallel.mesh import shard_map
+from jax import shard_map
 from apex_tpu.resilience import (CheckpointManager, GuardConfig,
                                  ManifestCompatWarning, TrainGuard,
                                  WorldSizeMismatchError, faults, guard)
 from apex_tpu.telemetry import MemorySink, Registry, events
 from apex_tpu.telemetry.report import format_summary, summarize
-from apex_tpu.utils.pallas import has_vma, _to_varying
+from apex_tpu.utils.pallas import to_varying
 
 N_DEV = 8
 GLOBAL_BATCH = 8
@@ -320,13 +320,12 @@ def _build_harness(world):
     su = wu.ShardedUpdate(FusedAdam(lr=1e-2, impl="fused"),
                           axis_name="data",
                           collective_scheme="int8_blockscale:min_bytes=0")
-    vma_kw = {} if has_vma() else {"check_vma": False}
     pspec = jax.tree_util.tree_map(lambda _: P(), params0)
     sspec = su.state_pspecs(params0, world)
 
     def grads_of(params, tokens):
         pv = jax.tree_util.tree_map(
-            lambda p: _to_varying(p, ("data",)), params)
+            lambda p: to_varying(p, ("data",)), params)
         return jax.value_and_grad(lambda p: transformer_loss(
             p, {"tokens": tokens, "targets": tokens}, cfg))(pv)
 
@@ -343,7 +342,7 @@ def _build_harness(world):
     jstep = jax.jit(shard_map(
         body, mesh=mesh,
         in_specs=(pspec, sspec, P("data"), P("data")),
-        out_specs=(pspec, sspec, P("data"), P()), **vma_kw))
+        out_specs=(pspec, sspec, P("data"), P())))
     state0, res0 = jax.jit(init_s)(params0)
 
     def step_fn(state, batch):

@@ -49,7 +49,7 @@ from apex_tpu.optimizers import FusedAdam
 from apex_tpu.parallel import create_mesh
 from apex_tpu.parallel import plan as plan_mod
 from apex_tpu.parallel import weight_update as wu
-from apex_tpu.parallel.mesh import shard_map
+from jax import shard_map
 from apex_tpu.resilience import CheckpointManager, GuardConfig, \
     TrainGuard, faults
 from apex_tpu.resilience.guard import _AsyncWriter
@@ -58,7 +58,7 @@ from apex_tpu.telemetry import events as events_mod
 from apex_tpu.telemetry import trace as trace_mod
 from apex_tpu.telemetry.report import format_summary, load_records, \
     summarize
-from apex_tpu.utils.pallas import has_vma, _to_varying
+from apex_tpu.utils.pallas import to_varying
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -614,7 +614,6 @@ def _build_zero1(world):
     params0 = transformer_init(jax.random.PRNGKey(0), cfg)
     su = wu.ShardedUpdate(FusedAdam(lr=1e-2, impl="fused"),
                           axis_name="data")
-    vma_kw = {} if has_vma() else {"check_vma": False}
     pspec = jax.tree_util.tree_map(lambda _: P(), params0)
     sspec = su.state_pspecs(params0, world)
 
@@ -625,7 +624,7 @@ def _build_zero1(world):
 
     def body(params, state, tokens):
         pv = jax.tree_util.tree_map(
-            lambda p: _to_varying(p, ("data",)), params)
+            lambda p: to_varying(p, ("data",)), params)
         loss, grads = jax.value_and_grad(lambda p: transformer_loss(
             p, {"tokens": tokens, "targets": tokens}, cfg))(pv)
         params, state = su.step(state, grads, params)
@@ -633,7 +632,7 @@ def _build_zero1(world):
 
     jstep = jax.jit(shard_map(
         body, mesh=mesh, in_specs=(pspec, sspec, P("data")),
-        out_specs=(pspec, sspec, P()), **vma_kw))
+        out_specs=(pspec, sspec, P())))
     state0 = jax.jit(init_s)(params0)
 
     def step_fn(state, batch):

@@ -55,7 +55,7 @@ def test_resnet_syncbn_matches_large_batch(tiny_rn):
     """SyncBN over a shard_map'd batch == plain BN on the full batch — the
     two_gpu_unit_test.py oracle, on a CPU device mesh."""
     from jax.sharding import Mesh, PartitionSpec as P
-    from apex_tpu.parallel.mesh import shard_map
+    from jax import shard_map
 
     cfg, params, state = tiny_rn
     n_dev = min(4, len(jax.devices()))
@@ -162,8 +162,8 @@ def test_transformer_mask_polarity_nonzero_is_pad():
 
 
 @pytest.mark.slow   # ~15s: the flash-vs-default numerics oracle at
-# model scale; the kernel-level oracles (test_multihead_attn, tpu_smoke
-# --tiny) keep the surface in tier-1 (ISSUE 12 budget reclaim)
+# model scale; the kernel-level oracles (test_multihead_attn, chip_smoke
+# --rehearse) keep the surface in tier-1 (ISSUE 12 budget reclaim)
 def test_transformer_fast_attention_matches_default():
     """attn_impl='fast' (contrib flash kernel) must match the jnp oracle
     path in forward AND gradients — the analog of the reference examples

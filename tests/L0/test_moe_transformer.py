@@ -7,7 +7,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from apex_tpu.parallel.mesh import shard_map   # check_vma/check_rep compat
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from apex_tpu.models import (MoETransformerConfig, moe_transformer_init,
@@ -93,7 +93,7 @@ def test_expert_sharded_matches_single_device():
     except TypeError:  # older jax
         smap = functools.partial(shard_map, mesh=mesh,
                                  in_specs=(pspec, P()),
-                                 out_specs=(P(), P()), check_rep=False)
+                                 out_specs=(P(), P()), check_vma=False)
 
     @jax.jit
     @smap
@@ -165,7 +165,7 @@ def test_expert_sharded_remat_grads():
         except TypeError:  # older jax
             smap = functools.partial(shard_map, mesh=mesh,
                                      in_specs=(pspec, P()), out_specs=P(),
-                                     check_rep=False)
+                                     check_vma=False)
 
         @jax.jit
         def g(params):

@@ -574,3 +574,36 @@ def test_flash_block_clamp():
     finally:
         os.environ.clear()
         os.environ.update(old)
+
+
+def _worst_vmem_ratio(budget_bytes):
+    """Worst used/budget ratio of the per-grid-step VMEM estimate over
+    the block sizes every flash kernel variant resolves to at the
+    production regime (D=64, seq 512): every kernel variant x stream
+    dtype x bias layout the ``_clamp_blocks`` chain serves."""
+    from apex_tpu.contrib.multihead_attn import flash as F
+    worst = 0.0
+    for bwd in (False, "dq", "dkv", "fused", True):
+        for esz in (2, 4):                    # bf16 / f32 streams
+            for bias_per_q in (False, True):
+                bq, bk = F._clamp_blocks(None, None, 64, esz, bias_per_q,
+                                         bwd=bwd, sq=512, sk=512)
+                est = F.vmem_estimate(bq, bk, 64, esz, bias_per_q, bwd)
+                worst = max(worst, est / budget_bytes)
+    return worst
+
+
+def test_clamped_blocks_model_under_the_vmem_budget(monkeypatch):
+    """The block sizes ``_clamp_blocks`` resolves must model under the
+    budget it enforces, and the estimator must still point the right way
+    (a config the clamp would never emit models OVER budget — the check
+    is not a tautology).  Pure estimator arithmetic: what Mosaic really
+    accepts is chip_smoke.py's kernels phase."""
+    from apex_tpu.contrib.multihead_attn import flash as F
+    budget = F._VMEM_BUDGET_MB * 2 ** 20
+    assert 0.0 < _worst_vmem_ratio(budget) <= 1.0
+    assert F.vmem_estimate(4096, 8192, 64, 4, True, "fused") > budget
+    # a shrunk budget the floors cannot meet is breached: the model and
+    # the clamp read the same budget
+    monkeypatch.setenv("APEX_TPU_FLASH_VMEM_MB", "0.05")
+    assert _worst_vmem_ratio(0.05 * 2 ** 20) > 1.0

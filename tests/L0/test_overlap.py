@@ -48,10 +48,10 @@ from apex_tpu.parallel import (DistributedDataParallel, collectives,
                                create_mesh, overlap)
 from apex_tpu.parallel import weight_update as wu
 from apex_tpu.parallel.distributed import allreduce_tree
-from apex_tpu.parallel.mesh import shard_map
+from jax import shard_map
 from apex_tpu.optimizers import FusedAdam
 from apex_tpu.telemetry import MemorySink, Registry, events
-from apex_tpu.utils.pallas import has_vma, _to_varying
+from apex_tpu.utils.pallas import to_varying
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -222,7 +222,6 @@ def _run_reduce(mesh, fn):
         lambda x: jnp.stack([x * (1.0 + 0.1 * d) for d in range(N_DEV)]),
         g)
     spec = jax.tree_util.tree_map(lambda _: P("data"), g)
-    vma_kw = {} if has_vma() else {"check_vma": False}
 
     def body(gd):
         gd = jax.tree_util.tree_map(lambda x: x[0], gd)
@@ -230,7 +229,7 @@ def _run_reduce(mesh, fn):
         return jax.tree_util.tree_map(lambda x: x[None], out)
 
     return jax.jit(shard_map(body, mesh=mesh, in_specs=(spec,),
-                             out_specs=spec, **vma_kw))(stacked)
+                             out_specs=spec))(stacked)
 
 
 @pytest.mark.parametrize("kw", [
@@ -297,7 +296,6 @@ def test_bucketed_int8_ef_tolerance_and_residual_layout(mesh):
         rstacked = jax.tree_util.tree_map(
             lambda x: jnp.stack([x] * N_DEV), res0)
         spec = jax.tree_util.tree_map(lambda _: P("data"), g0)
-        vma_kw = {} if has_vma() else {"check_vma": False}
 
         def body(gd, rd):
             gd = jax.tree_util.tree_map(lambda x: x[0], gd)
@@ -307,8 +305,7 @@ def test_bucketed_int8_ef_tolerance_and_residual_layout(mesh):
                     jax.tree_util.tree_map(lambda x: x[None], new_res))
 
         return jax.jit(shard_map(body, mesh=mesh, in_specs=(spec, spec),
-                                 out_specs=(spec, spec),
-                                 **vma_kw))(stacked, rstacked)
+                                 out_specs=(spec, spec)))(stacked, rstacked)
 
     spec8 = "int8_blockscale:block=32,min_bytes=0"
     ref, ref_res = run(lambda g, r: allreduce_tree(
@@ -410,12 +407,11 @@ def _bucketed_train_fns(mesh):
         params0)
     pspec = jax.tree_util.tree_map(lambda _: P(), params0)
     rspec = jax.tree_util.tree_map(lambda _: P("data"), params0)
-    vma_kw = {} if has_vma() else {"check_vma": False}
 
     def body(params, res, tokens):
         res = jax.tree_util.tree_map(lambda r: r[0], res)
         pv = jax.tree_util.tree_map(
-            lambda p: _to_varying(p, ("data",)), params)
+            lambda p: to_varying(p, ("data",)), params)
         loss, grads = jax.value_and_grad(lambda p: transformer_loss(
             p, {"tokens": tokens, "targets": tokens}, cfg))(pv)
         grads, res = ddp.allreduce_grads(grads, residuals=res)
@@ -427,7 +423,7 @@ def _bucketed_train_fns(mesh):
 
     step = jax.jit(shard_map(
         body, mesh=mesh, in_specs=(pspec, rspec, P("data")),
-        out_specs=(pspec, rspec, P()), **vma_kw))
+        out_specs=(pspec, rspec, P())))
     return (params0, res0), step
 
 
@@ -490,7 +486,6 @@ def _flat_grads(i):
 
 
 def _zero1_steps(mesh, su, params):
-    vma_kw = {} if has_vma() else {"check_vma": False}
     pspec = jax.tree_util.tree_map(lambda _: P(), params)
     gspec = jax.tree_util.tree_map(lambda _: P("data"), params)
     sspec = su.state_pspecs(params, N_DEV)
@@ -502,7 +497,7 @@ def _zero1_steps(mesh, su, params):
 
     @functools.partial(shard_map, mesh=mesh,
                        in_specs=(sspec, gspec, pspec),
-                       out_specs=(pspec, sspec), **vma_kw)
+                       out_specs=(pspec, sspec))
     def step_s(state, g, p):
         g = jax.tree_util.tree_map(lambda x: x[0], g)
         return su.step(state, g, p)
@@ -603,11 +598,11 @@ def test_predict_consumes_per_scheme_fraction(profile_file):
         optimizer_bytes=3 << 22, activations_bytes=8192, batch_bytes=1024,
         temps_bytes=512, output_bytes=64, args_bytes=16,
         constants_bytes=8, peak_hbm_bytes=3e7, layers=2,
-        act_layer_bytes=4096, seq=64, heads=4, platform="tpu")
+        act_layer_bytes=4096, seq=64, heads=4, platform="tpu_v5e")
     p8 = pm.predict(prof, pm.Plan(dp=N_DEV,
                                   collective_scheme="int8_blockscale"),
-                    platform="tpu")
-    p32 = pm.predict(prof, pm.Plan(dp=N_DEV), platform="tpu")
+                    platform="tpu_v5e")
+    p32 = pm.predict(prof, pm.Plan(dp=N_DEV), platform="tpu_v5e")
     assert p8.breakdown["dp_comm_ms"] > 0
     assert p8.breakdown["dp_comm_exposed_ms"] == 0.0
     assert p32.breakdown["dp_comm_exposed_ms"] == pytest.approx(
@@ -641,8 +636,8 @@ def test_exposed_comm_drop_fixture_and_audit(tmp_path):
     ``exposed_comm_fraction`` STRICTLY below the deferred one; embedded
     in the same artifact that proves parity, the
     ``overlap_exec_violations`` audit accepts it — and flags the
-    regressed capture.  (The real on-chip drop is tpu_watch.sh stage
-    2g's job; this pins the measurement + audit contract.)"""
+    regressed capture.  (The real on-chip drop is a chip run's job;
+    this pins the measurement + audit contract.)"""
     from apex_tpu.telemetry import timeline as tl
     # deferred: 50ms of all-reduce entirely AFTER compute (all exposed)
     _write_capture(str(tmp_path / "off"), [

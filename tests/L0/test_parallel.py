@@ -16,7 +16,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from apex_tpu.parallel.mesh import shard_map  # jax-version compat
+from jax import shard_map
 
 from apex_tpu import parallel
 from apex_tpu.parallel import (
@@ -158,27 +158,16 @@ def test_syncbn_backward_matches_oracle(mesh):
     w = rng.rand(C).astype(np.float32) + 0.5
     b = rng.randn(C).astype(np.float32)
 
-    # the 0.4-era check_rep cannot infer the autodiff-psummed gw/gb
-    # replicated (a jax with vma typing can); disable the check there
-    from apex_tpu.utils.pallas import has_vma
-    has_vma = has_vma()
-
     @functools.partial(
         shard_map, mesh=mesh, in_specs=(P("data"), P(), P()),
-        out_specs=(P("data"), P(), P()),
-        **({} if has_vma else {"check_vma": False}))
+        out_specs=(P("data"), P(), P()))
     def dist_grads(xs, wt, bs):
         def f(xs, wt, bs):
             out, _, _ = sync_batch_norm(xs, wt, bs, axis_name="data")
             return jnp.sum(out ** 2)
-        # with the replication check on, shard_map autodiff psums
-        # cotangents of replicated inputs itself; with it off (the old-jax
-        # path above) they come back device-local and need the psum here
-        gx, gw, gb = jax.grad(f, argnums=(0, 1, 2))(xs, wt, bs)
-        if not has_vma:
-            gw = jax.lax.psum(gw, "data")
-            gb = jax.lax.psum(gb, "data")
-        return gx, gw, gb
+        # with the replication check on, shard_map autodiff psums the
+        # cotangents of replicated inputs itself
+        return jax.grad(f, argnums=(0, 1, 2))(xs, wt, bs)
 
     gx, gw, gb = dist_grads(jnp.asarray(x), jnp.asarray(w), jnp.asarray(b))
 

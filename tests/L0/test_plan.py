@@ -168,7 +168,7 @@ def test_profile_step_surfaces_compiled_collectives():
     """The profile carries the compiled program's real collective
     payloads (the attrib sub-table) for comm-model calibration."""
     from jax.sharding import PartitionSpec as P
-    from apex_tpu.parallel.mesh import shard_map
+    from jax import shard_map
     mesh = create_mesh({"data": N_DEV})
     sm = shard_map(lambda x: jax.lax.psum(x, "data"), mesh=mesh,
                    in_specs=(P("data"),), out_specs=P("data"))
@@ -287,7 +287,7 @@ def test_int8_wins_on_tpu_wire_loses_on_cpu(flagship):
                        platform=platform)
         return p.breakdown["dp_comm_ms"]
 
-    assert dp_comm("tpu", "int8_blockscale") < dp_comm("tpu", "fp32")
+    assert dp_comm("tpu_v5e", "int8_blockscale") < dp_comm("tpu_v5e", "fp32")
     assert dp_comm("cpu", "int8_blockscale") > dp_comm("cpu", "fp32")
 
 
@@ -519,8 +519,8 @@ def _load_apply():
 # the 25% plan_violations bar (back-to-back runs of identical configs
 # spread 10-40%) — the leg's mechanics (coverage-row selection, audit,
 # decide() -> from_tuning round-trip) stay tier-1 through the synthetic
-# planner tests above, and the real leg runs as watcher stage 2d
-# (PLAN_AB_r5.json) where the TPU backend gives stable measurements
+# planner tests above; the real leg belongs on the TPU backend, which
+# gives stable measurements
 def test_bench_plan_acceptance_loop(profile_file, monkeypatch):
     """ACCEPTANCE: ``bench_plan`` on the CPU mesh — >= 12 candidates,
     the predicted-fastest plan's measured step time within 25% of its

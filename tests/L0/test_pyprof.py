@@ -204,9 +204,9 @@ def test_parse_real_capture(tmp_path):
 
 
 def test_resolve_ceilings_generations_and_env(monkeypatch):
-    """ISSUE 10 satellite: per-TPU-generation ceilings rows plus the
-    documented APEX_TPU_CEILINGS override, so planner/roofline
-    predictions aren't pinned to the single generic "tpu" row."""
+    """Per-TPU-generation ceilings rows chosen by ``device_kind`` plus
+    the documented APEX_TPU_CEILINGS override; a device the table does
+    not carry is an error, never another chip's numbers."""
     monkeypatch.delenv(prof.ENV_CEILINGS, raising=False)
     # every row carries the full silicon key set (the planner reads all
     # of them); num_slices is topology, override-only — a row carrying
@@ -214,26 +214,35 @@ def test_resolve_ceilings_generations_and_env(monkeypatch):
     for name, row in prof.HW_CEILINGS.items():
         assert set(row) == set(prof.CEILING_KEYS) - {"num_slices"}, name
     monkeypatch.setenv(prof.ENV_CEILINGS, "num_slices=2")
-    assert prof.resolve_ceilings("tpu")["num_slices"] == 2
+    assert prof.resolve_ceilings("tpu_v5e")["num_slices"] == 2
     monkeypatch.delenv(prof.ENV_CEILINGS)
-    # the generic tpu row stays the v5e chip the r5 runs measured on
-    assert prof.HW_CEILINGS["tpu"] == prof.HW_CEILINGS["tpu_v5e"]
-    assert prof.resolve_ceilings("tpu") == prof.HW_CEILINGS["tpu"]
-    # unknown platform falls back to the cpu row (attrib posture)
-    assert prof.resolve_ceilings("quantum") == prof.HW_CEILINGS["cpu"]
+    # a TPU resolves through its device_kind, as jax reports it
+    assert prof.ceilings_row("TPU v5 lite") == "tpu_v5e"
+    assert prof.resolve_ceilings("TPU v5 lite") == \
+        prof.HW_CEILINGS["tpu_v5e"]
+    assert prof.ceilings_row("TPU v4") == "tpu_v4"
+    # the live device resolves too (the CPU mesh the tests run on)
+    assert prof.ceilings_row() == "cpu"
+    assert prof.resolve_ceilings(jax.devices()[0]) == \
+        prof.HW_CEILINGS["cpu"]
+    # no generic "tpu" row, and an unknown device is an error — not the
+    # cpu row, not v5e's numbers under another chip's name
+    for unknown in ("tpu", "quantum", "TPU v9000"):
+        with pytest.raises(ValueError, match="no hardware ceilings"):
+            prof.resolve_ceilings(unknown)
     # named-row override (shorthand resolves to the tpu_* row)
     monkeypatch.setenv(prof.ENV_CEILINGS, "v5p")
-    assert prof.resolve_ceilings("tpu")["peak_flops"] == \
+    assert prof.resolve_ceilings("tpu_v5e")["peak_flops"] == \
         prof.HW_CEILINGS["tpu_v5p"]["peak_flops"]
     # row + key override, applied left to right
     monkeypatch.setenv(prof.ENV_CEILINGS, "v4,ici_bw=5e10")
-    c = prof.resolve_ceilings("tpu")
+    c = prof.resolve_ceilings("tpu_v5e")
     assert c["peak_bw"] == prof.HW_CEILINGS["tpu_v4"]["peak_bw"]
     assert c["ici_bw"] == 5e10
     # a typo'd key or row fails loudly, never silently
     monkeypatch.setenv(prof.ENV_CEILINGS, "peak_floops=1e12")
     with pytest.raises(ValueError, match="unknown ceiling"):
-        prof.resolve_ceilings("tpu")
+        prof.resolve_ceilings("tpu_v5e")
     monkeypatch.setenv(prof.ENV_CEILINGS, "v9000")
     with pytest.raises(ValueError, match="unknown ceilings row"):
-        prof.resolve_ceilings("tpu")
+        prof.resolve_ceilings("tpu_v5e")

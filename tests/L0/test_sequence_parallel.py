@@ -8,7 +8,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from apex_tpu.parallel.mesh import shard_map   # check_vma/check_rep compat
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from apex_tpu.parallel.sequence import ring_attention, ulysses_attention

@@ -261,7 +261,7 @@ def test_collective_meter_records_bytes_and_calls():
     from jax.sharding import PartitionSpec as P
     from apex_tpu.parallel import create_mesh
     from apex_tpu.parallel.distributed import allreduce_tree
-    from apex_tpu.parallel.mesh import shard_map
+    from jax import shard_map
     mesh = create_mesh({"data": 8})
     reg = Registry(sink=MemorySink(), flush_interval=0, rank0_only=False)
     events.set_default(reg)
@@ -289,7 +289,7 @@ def test_collective_meter_skips_already_summed_leaves():
     from jax.sharding import PartitionSpec as P
     from apex_tpu.parallel import create_mesh
     from apex_tpu.parallel.distributed import allreduce_tree
-    from apex_tpu.parallel.mesh import shard_map
+    from jax import shard_map
     mesh = create_mesh({"data": 8})
     reg = Registry(sink=MemorySink(), flush_interval=0, rank0_only=False)
     events.set_default(reg)
@@ -318,7 +318,7 @@ def test_collective_meter_free_when_no_registry():
     from jax.sharding import PartitionSpec as P
     from apex_tpu.parallel import create_mesh
     from apex_tpu.parallel.distributed import allreduce_tree
-    from apex_tpu.parallel.mesh import shard_map
+    from jax import shard_map
     mesh = create_mesh({"data": 8})
     assert events.get_default() is None
 
@@ -587,7 +587,8 @@ def test_attrib_collectives_subtable_logical_bytes():
     exchanges.  Under shard_map the shapes are per-partition — the
     per-device payload the alpha-beta model predicts."""
     from jax.sharding import PartitionSpec as P
-    from apex_tpu.parallel.mesh import create_mesh, shard_map
+    from jax import shard_map
+    from apex_tpu.parallel.mesh import create_mesh
     from apex_tpu.telemetry import attrib
 
     n_dev = len(jax.devices())

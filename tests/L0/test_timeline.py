@@ -334,7 +334,7 @@ def test_summarize_profiler_dir_fixture(tmp_path):
 def test_cli_timeline_renders_table_and_json(tmp_path):
     """``python -m apex_tpu.telemetry timeline <profiler-dir>``: the
     per-step decomposition table + per-device skew section; ``--json``
-    emits the machine form the tpu_watch.sh stage captures."""
+    emits the machine form."""
     _write_profiler_dir(tmp_path, _fixture_trace_events())
     env = {**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": ROOT}
     r = subprocess.run(
@@ -415,7 +415,7 @@ def test_overlap_roundtrip_decide_to_plan(profile_file):
         activations_bytes=1 << 30, batch_bytes=64 << 20,
         temps_bytes=1 << 28, output_bytes=4096)
     p_full = planmod.predict(prof_model, planmod.Plan(dp=8),
-                             platform="tpu")
+                             platform="tpu_v5e")
     assert p_full.breakdown["overlap_fraction"] == 1.0
     assert p_full.breakdown["dp_comm_exposed_ms"] == \
         pytest.approx(p_full.breakdown["dp_comm_ms"])
@@ -423,7 +423,7 @@ def test_overlap_roundtrip_decide_to_plan(profile_file):
     profile_file.write_text(json.dumps(prof))
     tuning.reload()
     p_tuned = planmod.predict(prof_model, planmod.Plan(dp=8),
-                              platform="tpu")
+                              platform="tpu_v5e")
     assert p_tuned.breakdown["overlap_fraction"] == 0.25
     assert p_tuned.breakdown["dp_comm_exposed_ms"] == \
         pytest.approx(0.25 * p_tuned.breakdown["dp_comm_ms"])
@@ -434,7 +434,7 @@ def test_overlap_roundtrip_decide_to_plan(profile_file):
         pytest.approx(hidden, rel=1e-6)
     # explicit argument beats the tuning profile
     p_exp = planmod.predict(prof_model, planmod.Plan(dp=8),
-                            platform="tpu", overlap_fraction=0.5)
+                            platform="tpu_v5e", overlap_fraction=0.5)
     assert p_exp.breakdown["overlap_fraction"] == 0.5
 
 

@@ -408,8 +408,8 @@ def test_cli_trace_profiler_dir_fixture(tmp_path):
 
 
 def test_load_chrome_streaming_array(tmp_path):
-    """The tpu_watch.sh stage timeline is a NEVER-CLOSED JSON array
-    (crash-safe appends); the loader must read it anyway."""
+    """A streaming timeline is a NEVER-CLOSED JSON array (crash-safe
+    appends); the loader must read it anyway."""
     p = tmp_path / "watch.json"
     p.write_text('[\n'
                  '{"name":"watch.smoke","ph":"X","ts":0,"dur":5,'

@@ -47,11 +47,11 @@ from apex_tpu.optimizers import (FusedAdam, FusedAdagrad, FusedLAMB,
 from apex_tpu.parallel import (DistributedDataParallel, Reducer,
                                collectives, create_mesh)
 from apex_tpu.parallel import weight_update as wu
-from apex_tpu.parallel.mesh import shard_map
+from jax import shard_map
 from apex_tpu.resilience import faults
 from apex_tpu.telemetry import MemorySink, Registry, events
 from apex_tpu.telemetry import records_violations
-from apex_tpu.utils.pallas import has_vma, _to_varying
+from apex_tpu.utils.pallas import to_varying
 
 N_DEV = 8
 
@@ -159,7 +159,6 @@ def _make_steps(mesh, opt_unsharded, sharded_update, params):
     per-leaf allreduce, full replicated ``step_flat``, amp's
     skip-on-overflow select."""
     ddp = DistributedDataParallel(axis_name="data")
-    vma_kw = {} if has_vma() else {"check_vma": False}
     pspec = jax.tree_util.tree_map(lambda _: P(), params)
     gspec = jax.tree_util.tree_map(lambda _: P("data"), params)
     state_u = opt_unsharded.init(params)
@@ -167,7 +166,7 @@ def _make_steps(mesh, opt_unsharded, sharded_update, params):
 
     @functools.partial(shard_map, mesh=mesh,
                        in_specs=(uspec, gspec, pspec),
-                       out_specs=(pspec, uspec), **vma_kw)
+                       out_specs=(pspec, uspec))
     def step_u(state, g, p):
         g = jax.tree_util.tree_map(lambda x: x[0], g)
         g = ddp.allreduce_grads(g)
@@ -188,7 +187,7 @@ def _make_steps(mesh, opt_unsharded, sharded_update, params):
 
     @functools.partial(shard_map, mesh=mesh,
                        in_specs=(sspec, gspec, pspec),
-                       out_specs=(pspec, sspec), **vma_kw)
+                       out_specs=(pspec, sspec))
     def step_s(state, g, p):
         g = jax.tree_util.tree_map(lambda x: x[0], g)
         return sharded_update.step(state, g, p)
@@ -274,7 +273,6 @@ def test_gradient_predivide_factor_matches_unsharded(mesh):
     opt_u = FusedAdam(lr=1e-2, impl="fused")
     su = ddp.weight_update(FusedAdam(lr=1e-2, impl="fused"))
     assert su.gradient_predivide_factor == 4.0
-    vma_kw = {} if has_vma() else {"check_vma": False}
     pspec = jax.tree_util.tree_map(lambda _: P(), params)
     gspec = jax.tree_util.tree_map(lambda _: P("data"), params)
     state_u = opt_u.init(params)
@@ -282,7 +280,7 @@ def test_gradient_predivide_factor_matches_unsharded(mesh):
 
     @functools.partial(shard_map, mesh=mesh,
                        in_specs=(uspec, gspec, pspec),
-                       out_specs=(pspec, uspec), **vma_kw)
+                       out_specs=(pspec, uspec))
     def step_u(state, g, p):
         g = jax.tree_util.tree_map(lambda x: x[0], g)
         g = ddp.allreduce_grads(g)        # carries the predivide knob
@@ -303,7 +301,7 @@ def test_gradient_predivide_factor_matches_unsharded(mesh):
 
     @functools.partial(shard_map, mesh=mesh,
                        in_specs=(sspec, gspec, pspec),
-                       out_specs=(pspec, sspec), **vma_kw)
+                       out_specs=(pspec, sspec))
     def step_s(state, g, p):
         g = jax.tree_util.tree_map(lambda x: x[0], g)
         return su.step(state, g, p)
@@ -358,7 +356,6 @@ def test_overflow_reverts_residual(mesh):
     su = wu.ShardedUpdate(FusedAdam(lr=1e-2, impl="fused"),
                           axis_name="data",
                           collective_scheme="int8_blockscale:min_bytes=0")
-    vma_kw = {} if has_vma() else {"check_vma": False}
     pspec = jax.tree_util.tree_map(lambda _: P(), params)
     gspec = jax.tree_util.tree_map(lambda _: P("data"), params)
     sspec = su.state_pspecs(params, N_DEV)
@@ -370,7 +367,7 @@ def test_overflow_reverts_residual(mesh):
 
     @functools.partial(shard_map, mesh=mesh,
                        in_specs=(sspec, gspec, pspec, P("data")),
-                       out_specs=(pspec, sspec, P("data")), **vma_kw)
+                       out_specs=(pspec, sspec, P("data")))
     def step_s(state, g, p, res):
         g = jax.tree_util.tree_map(lambda x: x[0], g)
         p2, s2, r2 = su.step(state, g, p, residual=res[0])
@@ -440,14 +437,18 @@ def _transformer_fns(mesh, *, sharded, rs_scheme=None, ag_scheme=None,
     pre-sums them)."""
     from apex_tpu.models import transformer_init, transformer_loss
     cfg = _tiny_cfg()
-    params0 = transformer_init(jax.random.PRNGKey(0), cfg)
+    # placed on the mesh up front: jit traces again when an argument's
+    # sharding changes, and a first step fed default-device arrays
+    # would be traced (and its trace-time meters counted) twice
+    params0 = jax.device_put(
+        transformer_init(jax.random.PRNGKey(0), cfg),
+        jax.sharding.NamedSharding(mesh, P()))
     opt = FusedAdam(lr=1e-2, impl="fused")
-    vma_kw = {} if has_vma() else {"check_vma": False}
     pspec = jax.tree_util.tree_map(lambda _: P(), params0)
 
     def grads_of(params, tokens):
         pv = jax.tree_util.tree_map(
-            lambda p: _to_varying(p, ("data",)), params)
+            lambda p: to_varying(p, ("data",)), params)
         return jax.value_and_grad(lambda p: transformer_loss(
             p, {"tokens": tokens, "targets": tokens}, cfg))(pv)
 
@@ -471,7 +472,7 @@ def _transformer_fns(mesh, *, sharded, rs_scheme=None, ag_scheme=None,
 
         step = jax.jit(shard_map(
             body, mesh=mesh, in_specs=(pspec, uspec, P("data")),
-            out_specs=(pspec, uspec, P()), **vma_kw))
+            out_specs=(pspec, uspec, P())))
         return (params0, state0), step
 
     su = wu.ShardedUpdate(opt, axis_name="data",
@@ -493,7 +494,7 @@ def _transformer_fns(mesh, *, sharded, rs_scheme=None, ag_scheme=None,
         step = jax.jit(shard_map(
             body, mesh=mesh,
             in_specs=(pspec, sspec, P("data"), P("data")),
-            out_specs=(pspec, sspec, P("data"), P()), **vma_kw))
+            out_specs=(pspec, sspec, P("data"), P())))
         state0, res0 = jax.jit(init_s)(params0)
         return (params0, state0, res0), step
 
@@ -509,7 +510,7 @@ def _transformer_fns(mesh, *, sharded, rs_scheme=None, ag_scheme=None,
 
     step = jax.jit(shard_map(
         body, mesh=mesh, in_specs=(pspec, sspec, P("data")),
-        out_specs=(pspec, sspec, P()), **vma_kw))
+        out_specs=(pspec, sspec, P())))
     return (params0, jax.jit(init_s)(params0)), step
 
 

@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from apex_tpu.parallel.mesh import shard_map   # check_vma/check_rep compat
+from jax import shard_map
 
 from apex_tpu import amp
 from apex_tpu.optimizers import FusedSGD
@@ -53,17 +53,11 @@ def run_config_dp(opt_level, loss_scale=None, steps=STEPS):
     mesh = Mesh(np.array(jax.devices()[:N_DEV]), ("data",))
     rep = jax.tree_util.tree_map(lambda _: P(), (state, bn_state))
 
-    # the replicated-out_specs typing is only inferable on a jax with vma
-    # typing; the 0.4-era check_rep rejects the psum'd updates wholesale
-    from apex_tpu.utils.pallas import has_vma
-    has_vma = has_vma()
-
     @jax.jit
     @functools.partial(
         shard_map, mesh=mesh,
         in_specs=(rep[0], rep[1], P("data"), P("data")),
-        out_specs=(rep[0], rep[1], P()),
-        **({} if has_vma else {"check_vma": False}))
+        out_specs=(rep[0], rep[1], P()))
     def step(state, bn_state, xl, yl):
         def loss_fn(p):
             logits, ns = _dp_apply(p, bn_state, xl, compute_dtype)

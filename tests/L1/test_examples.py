@@ -61,7 +61,7 @@ def test_bert_example():
 
 @pytest.mark.slow   # ~17s: the base test_bert_example keeps the
 # entry point in tier-1; the flash-kernel numerics this variant adds
-# are covered by tpu_smoke --tiny and the multihead_attn suite
+# are covered by chip_smoke --rehearse and the multihead_attn suite
 # (ISSUE 12 budget reclaim)
 def test_bert_example_fast_attention():
     """--attn fast trains through the contrib flash kernel (interpret

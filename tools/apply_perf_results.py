@@ -89,8 +89,8 @@ The headline flat-engine winner and vs_baseline are recorded in the
 table (informational — the optimizer ``impl`` is a user-facing state
 layout choice, not auto-flipped).
 
-Run automatically by tpu_watch.sh after both benches complete; safe to
-re-run by hand.  Refuses to write from non-TPU artifacts.
+Run by hand after both benches complete; safe to re-run.  Refuses to
+write from non-TPU artifacts.
 """
 from __future__ import annotations
 

@@ -23,12 +23,10 @@ drift to failing.  Schema-invalid goodput ledgers fail regardless of
 backend: a ledger whose classes don't partition the wall is broken
 accounting, not noise.
 
-One JSON document on stdout with ``--json`` (the ``tpu_watch.sh``
-``watch.goodput`` stage's atomic artifact); the human table otherwise.
+One JSON document on stdout with ``--json``; the human table otherwise.
 Exit 0 = no drift, 1 = drift / invalid ledger, 2 = nothing to ingest.
 
-No jax import, ever — this tool runs in CI and in the watcher's probe
-loop; the goodput schema is file-loaded exactly like
+No jax import, ever — this tool runs in CI; the goodput schema is file-loaded exactly like
 ``apply_perf_results`` loads the telemetry schema.
 """
 from __future__ import annotations

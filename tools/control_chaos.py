@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """The run-controller chaos acceptance as a one-shot artifact (ISSUE 19).
 
-Run by ``tpu_watch.sh`` stage 3c: train the flagship-shaped transformer
+Trains the flagship-shaped transformer
 N-way under TrainGuard with a ``straggler@K:F`` fault armed and an
 ``apex_tpu.control.RunController`` riding the health-check window.  The
 leave-one-out z-score must name the slowed device persistently, the
@@ -19,8 +19,9 @@ Prints exactly ONE JSON line on stdout::
      "quarantined_device": "d0", "bitwise": true, "elapsed_s": 41.0}
 
 exit 0 iff the acceptance holds.  CPU runs the same logic on the forced
-8-device host platform; the tool exists to capture the SAME proof on
-real silicon through the watcher.
+8-device host platform (``XLA_FLAGS=
+--xla_force_host_platform_device_count=8``); on a multi-chip host the same
+command runs on the chips — one process, the backend jax brings up.
 """
 from __future__ import annotations
 
@@ -42,12 +43,11 @@ def _build(world, cfg, su, global_batch):
     from jax.sharding import PartitionSpec as P
     from apex_tpu.models import transformer_init, transformer_loss
     from apex_tpu.parallel import create_mesh
-    from apex_tpu.parallel.mesh import shard_map
-    from apex_tpu.utils.pallas import has_vma, _to_varying
+    from jax import shard_map
+    from apex_tpu.utils.pallas import to_varying
 
     mesh = create_mesh({"data": world}, jax.devices()[:world])
     params0 = transformer_init(jax.random.PRNGKey(0), cfg)
-    vma_kw = {} if has_vma() else {"check_vma": False}
     pspec = jax.tree_util.tree_map(lambda _: P(), params0)
     sspec = su.state_pspecs(params0, world)
 
@@ -58,7 +58,7 @@ def _build(world, cfg, su, global_batch):
 
     def body(params, state, tokens):
         pv = jax.tree_util.tree_map(
-            lambda p: _to_varying(p, ("data",)), params)
+            lambda p: to_varying(p, ("data",)), params)
         loss, grads = jax.value_and_grad(lambda p: transformer_loss(
             p, {"tokens": tokens, "targets": tokens}, cfg))(pv)
         params, state = su.step(state, grads, params)
@@ -66,7 +66,7 @@ def _build(world, cfg, su, global_batch):
 
     jstep = jax.jit(shard_map(
         body, mesh=mesh, in_specs=(pspec, sspec, P("data")),
-        out_specs=(pspec, sspec, P()), **vma_kw))
+        out_specs=(pspec, sspec, P())))
     state0 = jax.jit(init_s)(params0)
 
     def step_fn(state, batch):

@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """The elastic kill-N-resume-M proof as a one-shot artifact (ISSUE 11).
 
-Run by ``tpu_watch.sh`` stage 3b: train the flagship-shaped transformer
+Trains the flagship-shaped transformer
 N-way under TrainGuard with zero1 update-sharding + int8 error-feedback
 residuals, kill it mid-epoch with an injected ``resize@K:M`` fault,
 resume M-way through ``apex_tpu.elastic`` (manifest world-size detect →
@@ -40,18 +40,17 @@ def _build(world, cfg, su, global_batch):
     from jax.sharding import PartitionSpec as P
     from apex_tpu.models import transformer_init, transformer_loss
     from apex_tpu.parallel import create_mesh
-    from apex_tpu.parallel.mesh import shard_map
-    from apex_tpu.utils.pallas import has_vma, _to_varying
+    from jax import shard_map
+    from apex_tpu.utils.pallas import to_varying
 
     mesh = create_mesh({"data": world}, jax.devices()[:world])
     params0 = transformer_init(jax.random.PRNGKey(0), cfg)
-    vma_kw = {} if has_vma() else {"check_vma": False}
     pspec = jax.tree_util.tree_map(lambda _: P(), params0)
     sspec = su.state_pspecs(params0, world)
 
     def grads_of(params, tokens):
         pv = jax.tree_util.tree_map(
-            lambda p: _to_varying(p, ("data",)), params)
+            lambda p: to_varying(p, ("data",)), params)
         return jax.value_and_grad(lambda p: transformer_loss(
             p, {"tokens": tokens, "targets": tokens}, cfg))(pv)
 
@@ -68,7 +67,7 @@ def _build(world, cfg, su, global_batch):
     jstep = jax.jit(shard_map(
         body, mesh=mesh,
         in_specs=(pspec, sspec, P("data"), P("data")),
-        out_specs=(pspec, sspec, P("data"), P()), **vma_kw))
+        out_specs=(pspec, sspec, P("data"), P())))
     state0, res0 = jax.jit(init_s)(params0)
 
     def step_fn(state, batch):
