@@ -27,6 +27,7 @@ import jax.numpy as jnp
 from . import amp as _amp
 from . import scaler as _scaler
 from .properties import Properties, opt_levels
+from ..pyprof import annotate, annotate_function
 from ..utils import pytree as _pt
 
 
@@ -255,6 +256,7 @@ def amp_step(amp_state: AmpState, grads, *, loss_id: int = 0, lr=None):
     return amp_step_multi(amp_state, [(grads, loss_id)], lr=lr)
 
 
+@annotate_function(name="apex.amp_step")
 def amp_step_multi(amp_state: AmpState, grads_and_ids, *, lr=None):
     """Multi-loss pipeline: several backward passes, each scaled by its own
     loss_id scaler, accumulated into ONE optimizer step (the reference's
@@ -269,17 +271,18 @@ def amp_step_multi(amp_state: AmpState, grads_and_ids, *, lr=None):
     if amp_state.optimizer is None:
         raise RuntimeError("amp_step_multi requires an optimizer passed to "
                            "initialize()")
-    total32 = None
-    finites = {}
-    for grads, loss_id in grads_and_ids:
-        g32, finite = _scaler.unscale(amp_state.scalers[loss_id], grads)
-        finites[loss_id] = (finites[loss_id] & finite
-                            if loss_id in finites else finite)
-        total32 = g32 if total32 is None else jax.tree_util.tree_map(
-            jnp.add, total32, g32)
-    all_finite = None
-    for f in finites.values():
-        all_finite = f if all_finite is None else (all_finite & f)
+    with annotate("apex.unscale"):
+        total32 = None
+        finites = {}
+        for grads, loss_id in grads_and_ids:
+            g32, finite = _scaler.unscale(amp_state.scalers[loss_id], grads)
+            finites[loss_id] = (finites[loss_id] & finite
+                                if loss_id in finites else finite)
+            total32 = g32 if total32 is None else jax.tree_util.tree_map(
+                jnp.add, total32, g32)
+        all_finite = None
+        for f in finites.values():
+            all_finite = f if all_finite is None else (all_finite & f)
 
     scalers = tuple(
         _scaler.update(s, finites[i]) if i in finites else s
@@ -290,26 +293,32 @@ def amp_step_multi(amp_state: AmpState, grads_and_ids, *, lr=None):
         # one fused unflatten-with-cast produces the model copy
         opt = amp_state.optimizer
         fl = _master_flattener(amp_state)
-        new_opt_state = opt.step_flat(amp_state.opt_state,
-                                      fl.flatten(total32), lr=lr)
-        new_opt_state = _scaler.apply_if_finite(all_finite, new_opt_state,
-                                                amp_state.opt_state)
-        model_params = fl.unflatten(new_opt_state.master,
-                                    like=amp_state.model_params)
+        with annotate("apex.opt_update"):
+            new_opt_state = opt.step_flat(amp_state.opt_state,
+                                          fl.flatten(total32), lr=lr)
+            new_opt_state = _scaler.apply_if_finite(
+                all_finite, new_opt_state, amp_state.opt_state)
+        with annotate("apex.model_copy"):
+            model_params = fl.unflatten(new_opt_state.master,
+                                        like=amp_state.model_params)
         return amp_state._replace(model_params=model_params,
                                   scalers=scalers,
                                   opt_state=new_opt_state)
 
     masters = (amp_state.master_params if amp_state.master_params is not None
                else amp_state.model_params)
-    new_masters, new_opt_state = amp_state.optimizer.step(
-        amp_state.opt_state, total32, masters, lr=lr)
-    new_masters = _scaler.apply_if_finite(all_finite, new_masters, masters)
-    new_opt_state = _scaler.apply_if_finite(all_finite, new_opt_state,
-                                            amp_state.opt_state)
+    with annotate("apex.opt_update"):
+        new_masters, new_opt_state = amp_state.optimizer.step(
+            amp_state.opt_state, total32, masters, lr=lr)
+        new_masters = _scaler.apply_if_finite(all_finite, new_masters,
+                                              masters)
+        new_opt_state = _scaler.apply_if_finite(all_finite, new_opt_state,
+                                                amp_state.opt_state)
 
     if amp_state.master_params is not None:
-        model_params = _pt.master_to_model(new_masters, amp_state.model_params)
+        with annotate("apex.model_copy"):
+            model_params = _pt.master_to_model(new_masters,
+                                               amp_state.model_params)
         return amp_state._replace(model_params=model_params,
                                   master_params=new_masters,
                                   scalers=scalers, opt_state=new_opt_state)

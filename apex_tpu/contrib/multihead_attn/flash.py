@@ -45,6 +45,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ...pyprof import annotate
+
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 1024
 # The recompute-backward kernels default to the 128-block regime that
@@ -911,7 +913,8 @@ def flash_attention(q, k, v, bias, seed=0, causal=False, dropout_rate=0.0,
     ``attention_core`` for a *learned* additive bias.
     """
     _check_backward(backward)
-    out, _ = _flash_fwd(q, k, v, bias, causal, dropout_rate, seed, heads)
+    with annotate("apex.flash"):
+        out, _ = _flash_fwd(q, k, v, bias, causal, dropout_rate, seed, heads)
     return out
 
 
@@ -928,19 +931,22 @@ def _check_backward(backward):
 
 def _vjp_fwd(q, k, v, bias, seed, causal, dropout_rate, heads, backward):
     _check_backward(backward)
-    out, lse = _flash_fwd(q, k, v, bias, causal, dropout_rate, seed, heads)
+    with annotate("apex.flash"):
+        out, lse = _flash_fwd(q, k, v, bias, causal, dropout_rate, seed,
+                              heads)
     return out, (q, k, v, bias, seed, out, lse)
 
 
 def _vjp_bwd(causal, dropout_rate, heads, backward, res, do):
     q, k, v, bias, seed, out, lse = res
     impl = _resolve_backward(backward)
-    if impl == "xla":
-        dq, dk, dv = _xla_bwd(q, k, v, bias, causal, dropout_rate, seed,
-                              heads, out, lse, do)
-    else:
-        dq, dk, dv = _flash_bwd(q, k, v, bias, causal, dropout_rate, seed,
-                                heads, out, lse, do)
+    with annotate("apex.flash"):
+        if impl == "xla":
+            dq, dk, dv = _xla_bwd(q, k, v, bias, causal, dropout_rate, seed,
+                                  heads, out, lse, do)
+        else:
+            dq, dk, dv = _flash_bwd(q, k, v, bias, causal, dropout_rate,
+                                    seed, heads, out, lse, do)
     return dq, dk, dv, None, None
 
 

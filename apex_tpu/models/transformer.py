@@ -28,6 +28,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..normalization.fused_layer_norm import fused_layer_norm_affine
+from ..pyprof import annotate
 
 
 @dataclasses.dataclass(frozen=True)
@@ -228,19 +229,23 @@ def _layer(x, lp, cfg: TransformerConfig, mask, dropout_rng,
     """Pre-LN transformer block (the contrib norm-add layout,
     ``apex/contrib/multihead_attn/self_multihead_attn.py`` norm-add variant)."""
     dt = x.dtype
-    h = fused_layer_norm_affine(x, lp["ln1_g"].astype(dt), lp["ln1_b"].astype(dt),
-                                (cfg.d_model,))
-    r1 = None
-    if dropout_rng is not None:
-        dropout_rng, r1 = jax.random.split(dropout_rng)
-    x = x + _attention(h, lp["wqkv"], lp["bqkv"], lp["wo"], lp["bo"], cfg,
-                       mask, r1, attn_override)
-    h = fused_layer_norm_affine(x, lp["ln2_g"].astype(dt), lp["ln2_b"].astype(dt),
-                                (cfg.d_model,))
-    h = jnp.einsum("bsd,df->bsf", h, lp["w1"].astype(dt)) + lp["b1"].astype(dt)
-    h = jax.nn.gelu(h)
-    h = jnp.einsum("bsf,fd->bsd", h, lp["w2"].astype(dt)) + lp["b2"].astype(dt)
-    return x + h
+    with annotate("apex.attn"):
+        h = fused_layer_norm_affine(x, lp["ln1_g"].astype(dt),
+                                    lp["ln1_b"].astype(dt), (cfg.d_model,))
+        r1 = None
+        if dropout_rng is not None:
+            dropout_rng, r1 = jax.random.split(dropout_rng)
+        x = x + _attention(h, lp["wqkv"], lp["bqkv"], lp["wo"], lp["bo"],
+                           cfg, mask, r1, attn_override)
+    with annotate("apex.mlp"):
+        h = fused_layer_norm_affine(x, lp["ln2_g"].astype(dt),
+                                    lp["ln2_b"].astype(dt), (cfg.d_model,))
+        h = jnp.einsum("bsd,df->bsf", h, lp["w1"].astype(dt)) \
+            + lp["b1"].astype(dt)
+        h = jax.nn.gelu(h)
+        h = jnp.einsum("bsf,fd->bsd", h, lp["w2"].astype(dt)) \
+            + lp["b2"].astype(dt)
+        return x + h
 
 
 def transformer_apply(params, tokens, cfg: TransformerConfig, *,
@@ -261,14 +266,15 @@ def transformer_apply(params, tokens, cfg: TransformerConfig, *,
             f"attn_impl must be 'default' or 'fast', got {cfg.attn_impl!r}")
     emb = params["embed"]
     dt = cfg.dtype
-    if pos_offset is None:
-        pos = emb["pos"][: tokens.shape[1]]
-    else:
-        pos = jax.lax.dynamic_slice_in_dim(emb["pos"], pos_offset,
-                                           tokens.shape[1])
-    x = emb["tok"][tokens].astype(dt) + pos[None].astype(dt)
-    x = fused_layer_norm_affine(x, emb["ln_g"].astype(dt),
-                                emb["ln_b"].astype(dt), (cfg.d_model,))
+    with annotate("apex.embed"):
+        if pos_offset is None:
+            pos = emb["pos"][: tokens.shape[1]]
+        else:
+            pos = jax.lax.dynamic_slice_in_dim(emb["pos"], pos_offset,
+                                               tokens.shape[1])
+        x = emb["tok"][tokens].astype(dt) + pos[None].astype(dt)
+        x = fused_layer_norm_affine(x, emb["ln_g"].astype(dt),
+                                    emb["ln_b"].astype(dt), (cfg.d_model,))
 
     n_layers = params["layers"]["wqkv"].shape[0]
     if dropout_rng is not None:
@@ -303,10 +309,11 @@ def transformer_apply(params, tokens, cfg: TransformerConfig, *,
     x, _ = jax.lax.scan(body, x, xs, unroll=int(cfg.scan_unroll))
 
     hd = params["head"]
-    x = fused_layer_norm_affine(x, hd["ln_g"].astype(dt), hd["ln_b"].astype(dt),
-                                (cfg.d_model,))
-    w_out = (emb["tok"].T if cfg.tie_embeddings else hd["out"]).astype(dt)
-    return jnp.einsum("bsd,dv->bsv", x, w_out)
+    with annotate("apex.head"):
+        x = fused_layer_norm_affine(x, hd["ln_g"].astype(dt),
+                                    hd["ln_b"].astype(dt), (cfg.d_model,))
+        w_out = (emb["tok"].T if cfg.tie_embeddings else hd["out"]).astype(dt)
+        return jnp.einsum("bsd,dv->bsv", x, w_out)
 
 
 def transformer_loss(params, batch, cfg: TransformerConfig, *,
@@ -323,13 +330,15 @@ def transformer_loss(params, batch, cfg: TransformerConfig, *,
                                attn_override=attn_override,
                                pos_offset=pos_offset)
     B, S, V = logits.shape
-    # padding_idx=-1: padding is expressed through ``weights``, and vocab id 0
-    # is a legitimate target here (unlike the reference's seq2seq pad=0)
-    nll = softmax_xentropy_loss(logits.reshape(B * S, V),
-                                batch["targets"].reshape(B * S),
-                                smoothing, -1, False,
-                                cfg.xent_impl).reshape(B, S)
-    w = batch.get("weights")
-    if w is None:
-        return nll.mean()
-    return (nll * w).sum() / jnp.maximum(w.sum(), 1.0)
+    with annotate("apex.loss"):
+        # padding_idx=-1: padding is expressed through ``weights``, and vocab
+        # id 0 is a legitimate target here (unlike the reference's seq2seq
+        # pad=0)
+        nll = softmax_xentropy_loss(logits.reshape(B * S, V),
+                                    batch["targets"].reshape(B * S),
+                                    smoothing, -1, False,
+                                    cfg.xent_impl).reshape(B, S)
+        w = batch.get("weights")
+        if w is None:
+            return nll.mean()
+        return (nll * w).sum() / jnp.maximum(w.sum(), 1.0)

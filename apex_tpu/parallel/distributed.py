@@ -71,6 +71,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .mesh import DATA_AXIS, current_mesh, axis_is_bound
+from ..pyprof import annotate
 from ..utils.pallas import presummed
 
 
@@ -369,22 +370,19 @@ class DistributedDataParallel:
                 "needs the full grad set; callable routing is per-leaf) — "
                 "falling back to the deferred allreduce")
             mode = "off"
-        if mode == "bucketed":
-            return _ov.bucketed_allreduce(
-                grads, axis_name=self.axis_name,
-                average=self.gradient_average,
-                predivide_factor=self.gradient_predivide_factor,
-                always_fp32=self.allreduce_always_fp32,
-                scheme=self.collective_scheme, residuals=residuals,
-                min_compress_bytes=self.collective_min_bytes,
-                message_size=self.message_size)
-        return allreduce_tree(
-            grads, axis_name=self.axis_name,
-            average=self.gradient_average,
+        kwargs = dict(
+            axis_name=self.axis_name, average=self.gradient_average,
             predivide_factor=self.gradient_predivide_factor,
             always_fp32=self.allreduce_always_fp32,
             scheme=self.collective_scheme, residuals=residuals,
             min_compress_bytes=self.collective_min_bytes)
+        # everything the reduction puts on the device: flatten, casts, pre-
+        # and post-scaling, the collectives
+        with annotate("apex.ddp_allreduce"):
+            if mode == "bucketed":
+                return _ov.bucketed_allreduce(
+                    grads, message_size=self.message_size, **kwargs)
+            return allreduce_tree(grads, **kwargs)
 
     def init_residuals(self, grads):
         """Zero error-feedback residual pytree to carry in step state

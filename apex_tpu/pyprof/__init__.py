@@ -21,12 +21,58 @@ start/stop/trace helpers the examples call with ``--prof``.
 
 ``annotate`` works both inside jit (becomes a ``jax.named_scope`` on the
 lowered HLO) and outside (becomes a ``TraceAnnotation`` wall-time range).
+
+The library names the blocks of a training step itself, from a fixed
+vocabulary (:data:`SCOPES`): a device trace then says which block an
+instruction belongs to (``op_name="jit(train_step)/.../apex.attn/
+apex.flash/..."``; backward and recompute show as ``transpose(jvp(apex.attn))``
+and ``rematted_computation`` in the same path).  See ``docs/pyprof.md``.
 """
 from __future__ import annotations
 
 import contextlib
+import os
+import re
 
 import jax
+
+#: The ``apex.*`` scopes the library enters, outermost first where they nest.
+#: ``annotate`` refuses any other name that starts with ``apex.``: a reader
+#: of a trace (``benchmarks/scopes.py``) can rely on exactly these.
+SCOPES = (
+    "apex.embed",          # models.transformer: token + position lookup, norm
+    "apex.attn",           # _layer: ln1, QKV, head transposes, core, out proj
+    "apex.flash",          # contrib.multihead_attn.flash, inside apex.attn
+    "apex.mlp",            # _layer: ln2, the two matmuls, GELU, residual
+    "apex.head",           # final norm and the vocabulary projection
+    "apex.loss",           # transformer_loss: logits reshape -> weighted mean
+    "apex.amp_step",       # amp.frontend.amp_step_multi, whole body
+    "apex.unscale",        # inside it: unscale to f32 + the finite reduction
+    "apex.opt_update",     # inside it: optimizer.step / step_flat + skip select
+    "apex.model_copy",     # inside it: master -> model-precision copy
+    "apex.ddp_allreduce",  # parallel.DistributedDataParallel.allreduce_grads
+)
+
+# jax strips debug info - where a named scope lives - before it hashes the
+# persistent compile cache's key (cache_key.py:_canonicalize_ir).  On jax 0.9.0
+# (ISSUE 24) f compiled bare, then under named_scope("apex.attn") against one
+# cache directory, was a HIT whose text read op_name="jit(f)/dot_general": a
+# profile of a cached step shows the names it was first compiled with.  So the
+# key holds the metadata, and file names are written relative to the checkout
+# (the parent of the apex_tpu package), else every checkout path would be a
+# cold compile.  At import, not on the first ``annotate``: a seeded init, a
+# reference check and the step then get the same kind of key in every run.
+# A value the user has set stays, where it can be told from the default: jax
+# keeps no record of who set a flag, so an explicit False is honoured from the
+# environment (JAX_COMPILATION_CACHE_INCLUDE_METADATA_IN_KEY=0) or from a
+# jax.config.update made after this import.
+_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+if "JAX_COMPILATION_CACHE_INCLUDE_METADATA_IN_KEY" not in os.environ:
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+if jax.config.jax_hlo_source_file_canonicalization_regex is None:
+    jax.config.update("jax_hlo_source_file_canonicalization_regex",
+                      re.escape(_ROOT + os.sep))
 
 
 class _State:
@@ -61,16 +107,15 @@ def annotate(name: str, **attrs):
     Inside a jit trace this contributes a ``jax.named_scope`` (op-name
     prefix in the HLO/XPlane); outside it opens a host ``TraceAnnotation``
     wall-clock range.  ``attrs`` are appended to the name (the reference
-    encodes args into the NVTX message, nvmarker.py:46-108)."""
+    encodes args into the NVTX message, nvmarker.py:46-108).  A name that
+    starts with ``apex.`` must be one of :data:`SCOPES`."""
+    if name.startswith("apex.") and name not in SCOPES:
+        raise ValueError(f"{name!r} is not in apex_tpu.pyprof.SCOPES "
+                         f"{SCOPES}: the apex.* vocabulary is fixed")
     if attrs:
         name = name + "|" + ",".join(f"{k}={v}" for k, v in attrs.items())
-    with jax.named_scope(name):
-        try:
-            anno = jax.profiler.TraceAnnotation(name)
-        except Exception:           # pragma: no cover - API drift safety
-            anno = contextlib.nullcontext()
-        with anno:
-            yield
+    with jax.named_scope(name), jax.profiler.TraceAnnotation(name):
+        yield
 
 
 def annotate_function(fn=None, *, name: str | None = None):

@@ -1,0 +1,236 @@
+"""The step names its blocks from inside (``apex_tpu.pyprof.SCOPES``), and
+the persistent compile cache cannot hand a profile an executable that was
+compiled before the names existed.
+
+The compiled text of a tiny BERT step (remat, flash attention, amp O5,
+FusedLAMB on the flat engine — the benchmark's path) is what a device trace
+shows: every instruction's ``op_name`` is the path ``benchmarks/scopes.py``
+reads.  The step is lowered and compiled once per module.
+"""
+import functools
+import json
+import os
+import re
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import Mesh, PartitionSpec as P
+
+from apex_tpu import amp, pyprof
+from apex_tpu.models import (TransformerConfig, transformer_init,
+                             transformer_loss)
+from apex_tpu.optimizers import FusedLAMB
+from apex_tpu.parallel import DistributedDataParallel
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+CFG = TransformerConfig(vocab_size=256, max_len=128, num_layers=2,
+                        d_model=64, num_heads=2, d_ff=128,
+                        dtype=jnp.bfloat16, remat=True, attn_impl="fast")
+# "%name = <type, maybe a tuple> opcode(operands), ..., metadata={op_name=..."
+_INSTRUCTION = re.compile(
+    r' = .*? ([a-z][\w-]*)\(.*metadata=\{[^}]*op_name="([^"]*)"')
+
+
+def _state_and_batch(batch):
+    params = transformer_init(jax.random.PRNGKey(0), CFG)
+    opt = FusedLAMB(lr=1e-3, weight_decay=0.01, max_grad_norm=1.0,
+                    impl="fused")
+    state = amp.initialize(params, opt, opt_level="O5", verbosity=0)
+    tokens = jnp.zeros((batch, 128), jnp.int32)
+    return state, {"tokens": tokens, "targets": tokens,
+                   "weights": jnp.ones((batch, 128), jnp.float32)}
+
+
+def _step(state, batch, ddp=None):
+    def loss_fn(p):
+        loss = transformer_loss(p, batch, CFG)
+        return amp.scale_loss(loss, state), loss
+    g, loss = jax.grad(loss_fn, has_aux=True)(state.model_params)
+    if ddp is not None:
+        g = ddp.allreduce_grads(g)
+    return amp.amp_step(state, g), loss
+
+
+def _op_names(fn, *args):
+    """``[(opcode, op_name)]`` of the compiled program's instructions."""
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    return [m.groups() for m in map(_INSTRUCTION.search, text.splitlines())
+            if m]
+
+
+@pytest.fixture(scope="module")
+def step_ops():
+    return _op_names(_step, *_state_and_batch(2))
+
+
+@pytest.fixture(scope="module")
+def ddp_step_ops():
+    mesh = Mesh(jax.devices()[:2], ("data",))
+    step = jax.shard_map(
+        functools.partial(_step, ddp=DistributedDataParallel("data")),
+        mesh=mesh, in_specs=(P(), P("data")), out_specs=(P(), P()),
+        check_vma=False)
+    return _op_names(step, *_state_and_batch(4))
+
+
+def test_scopes_are_a_fixed_vocabulary():
+    assert len(set(pyprof.SCOPES)) == len(pyprof.SCOPES)
+    for name in pyprof.SCOPES:
+        assert re.fullmatch(r"apex\.[a-z_]+", name), name
+
+
+def test_every_matmul_belongs_to_a_block(step_ops):
+    matmuls = [path for opcode, path in step_ops
+               if opcode in ("dot", "convolution")]
+    assert len(matmuls) >= 10
+    for path in matmuls:
+        assert any(name in path for name in pyprof.SCOPES), path
+
+
+@pytest.mark.parametrize("name", pyprof.SCOPES)
+def test_scope_occurs_in_the_step(name, step_ops, ddp_step_ops):
+    one_chip = {path for _, path in step_ops if name in path}
+    if name == "apex.ddp_allreduce":
+        # only a step that reduces has it, and the collective lies under it
+        assert not one_chip
+        reduced = [(op, path) for op, path in ddp_step_ops if name in path]
+        assert any(op.startswith("all-reduce") for op, _ in reduced)
+    else:
+        assert one_chip, name
+        assert any(name in path for _, path in ddp_step_ops)
+
+
+@pytest.mark.parametrize("block", ["apex.attn", "apex.mlp"])
+@pytest.mark.parametrize("mark", ["rematted_computation", "transpose("])
+def test_backward_and_recompute_are_written_into_the_path(mark, block,
+                                                          step_ops):
+    """jax names them itself: no scope of ours for a phase."""
+    paths = [path for _, path in step_ops if block in path]
+    assert any(mark in path for path in paths)
+    assert any("transpose(" not in path for path in paths)     # the forward
+
+
+def test_flash_nests_inside_attention(step_ops):
+    inside = [path for _, path in step_ops if "apex.flash" in path]
+    assert inside
+    for path in inside:
+        assert path.index("apex.attn") < path.index("apex.flash"), path
+
+
+def test_update_blocks_nest_inside_amp_step(step_ops):
+    for inner in ("apex.unscale", "apex.opt_update", "apex.model_copy"):
+        for path in (p for _, p in step_ops if inner in p):
+            assert "apex.amp_step/" + inner in path, path
+
+
+@pytest.mark.parametrize("name", ["apex.nonesuch", "apex.", "apex.attention",
+                                  "apex.attn.core"])
+def test_annotate_refuses_an_apex_name_outside_the_vocabulary(name):
+    with pytest.raises(ValueError, match="SCOPES"):
+        with pyprof.annotate(name):
+            pass
+
+
+@pytest.mark.parametrize("name", ["fwd", "apexish", "my.apex.attn"])
+def test_annotate_passes_other_names(name):
+    ops = _op_names(lambda x: _annotated(name, x), jnp.ones((4, 4)))
+    assert any(name + "|layer=3/" in path for _, path in ops)
+
+
+def _annotated(name, x):
+    with pyprof.annotate(name, layer=3):
+        return jnp.dot(x, x)
+
+
+# ---------------------------------------------------------------------------
+# the compile cache: a scope must be a new entry, a checkout path must not
+# ---------------------------------------------------------------------------
+
+_PROGRAM = '''
+import contextlib, json, os, re, sys
+import jax, jax.numpy as jnp
+from apex_tpu import pyprof
+
+cache_dir, scope = sys.argv[1], sys.argv[2] == "1"
+jax.config.update("jax_compilation_cache_dir", cache_dir)
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+
+
+def f(x, w):
+    with pyprof.annotate("apex.attn") if scope else contextlib.nullcontext():
+        return jnp.dot(x, w)
+
+
+x = jnp.ones((8, 8))
+text = jax.jit(f).lower(x, x).compile().as_text()
+print(json.dumps({
+    "package": os.path.dirname(os.path.dirname(pyprof.__file__)),
+    "entries": [e for e in os.listdir(cache_dir) if e.startswith("jit_f-")],
+    "op_names": re.findall('op_name="([^"]*)"', text)}))
+'''
+
+
+def _checkout(tmp_path, name):
+    """A stand-in for a checkout in another directory: the package (a link)
+    and a user's file beside it."""
+    root = tmp_path / name
+    root.mkdir()
+    (root / "apex_tpu").symlink_to(os.path.join(ROOT, "apex_tpu"))
+    (root / "program.py").write_text(_PROGRAM)
+    return root
+
+
+def _run(root, cache_dir, scope, **env):
+    env = {**{k: v for k, v in os.environ.items() if k != "PYTHONPATH"},
+           "JAX_PLATFORMS": "cpu", **env}
+    proc = subprocess.run(
+        [sys.executable, "program.py", str(cache_dir), str(int(scope))],
+        cwd=root, env=env, capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    out = json.loads(proc.stdout.splitlines()[-1])
+    assert out["package"] == str(root / "apex_tpu")     # the link, not ROOT
+    return out
+
+
+@pytest.fixture(scope="module")
+def cache_runs(tmp_path_factory):
+    tmp = tmp_path_factory.mktemp("scopes_cache")
+    a, b = _checkout(tmp, "a"), _checkout(tmp, "b")
+    cache = tmp / "cache"
+    cache.mkdir()
+    return {"bare": _run(a, cache, False), "scoped": _run(a, cache, True),
+            "moved": _run(b, cache, True)}
+
+
+def test_a_scope_is_a_new_cache_entry_and_shows_in_the_text(cache_runs):
+    assert cache_runs["bare"]["op_names"][-1] == "jit(f)/dot_general"
+    assert len(cache_runs["bare"]["entries"]) == 1
+    assert len(cache_runs["scoped"]["entries"]) == 2
+    assert cache_runs["scoped"]["op_names"][-1] == \
+        "jit(f)/apex.attn/dot_general"
+
+
+def test_another_checkout_path_is_the_same_cache_entry(cache_runs):
+    assert sorted(cache_runs["moved"]["entries"]) == \
+        sorted(cache_runs["scoped"]["entries"])
+    assert cache_runs["moved"]["op_names"] == cache_runs["scoped"]["op_names"]
+
+
+def test_without_metadata_in_the_key_the_cache_hands_out_stale_names(
+        tmp_path):
+    """The trap itself, and that a user's own setting stays: with the key
+    as jax builds it by default, the scoped function is a HIT on the bare
+    one's entry and its text has no scope."""
+    root = _checkout(tmp_path, "c")
+    cache = tmp_path / "cache"
+    cache.mkdir()
+    off = {"JAX_COMPILATION_CACHE_INCLUDE_METADATA_IN_KEY": "0"}
+    _run(root, cache, False, **off)
+    stale = _run(root, cache, True, **off)
+    assert len(stale["entries"]) == 1
+    assert stale["op_names"][-1] == "jit(f)/dot_general"
