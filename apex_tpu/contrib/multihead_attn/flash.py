@@ -18,17 +18,22 @@ Semantics parity with the CUDA kernels:
     full (1|B, Sq, Sk) score masks; ``causal`` covers the time-mask path.
 
 forward  : out, lse   (lse = log-sum-exp per query row, the saved residual)
-backward : recompute-based (flash bwd).  Two strategies, selected by
-    ``_resolve_fuse``:
-      - split: one kernel for dq (grid over q blocks), one for dk/dv (grid
-        over k blocks) — each with its OWN tunable block sizes (their VMEM
-        footprints differ; see ``vmem_estimate``);
-      - fused: one kernel on the dkv grid recomputes P and the dropout mask
-        ONCE and feeds all three accumulations; dq is emitted as per-k-block
-        partials (BH, nk, Sq, D) summed outside the kernel (the splash-
-        attention fused-backward layout).  The partial buffer is
-        O(Sk/bk * Sq) per batch-head — quadratic in sequence — so fusion is
-        only used "where the grid allows" (under a byte cap, overridable).
+backward : recompute-based (flash bwd).  One algorithm whose tile is a
+    function of the shape the call has (``_whole_key_blocks``): P and the
+    dropout mask are recomputed ONCE per grid step and feed dq, dk and dv.
+      - whole_key: where the keys of a head fit in VMEM one step covers all
+        of them (nk = 1: S 512 at D 64 runs ONE 512x512 tile a head), so
+        the step's ``ds @ k`` IS that q block's dq and the kernel writes it
+        itself, in ``q.dtype``;
+      - partials: where they do not (long sequences) the grid is
+        (BH, nk, nq) over 128x128 tiles and dq leaves the kernel as
+        per-k-block f32 partials (BH, nk, Sq, D) summed by XLA (the splash-
+        attention fused-backward layout) — O(Sk/bk * Sq) per batch-head, so
+        only under a byte cap (``_resolve_fuse``);
+      - split: above the cap (or forced), one kernel for dq (grid over q
+        blocks) and one for dk/dv (grid over k blocks), each with its OWN
+        tunable block sizes (their VMEM footprints differ; see
+        ``vmem_estimate``).
     The whole Pallas backward can also be swapped for the XLA math path via
     ``backward="pallas"|"xla"|"auto"`` on :func:`flash_attention` — ``auto``
     consults the measured tuning profile (``flash_bwd_impl``) so a recorded
@@ -46,19 +51,24 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ...pyprof import annotate
+from ...telemetry import events as _tel_events
 
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 1024
-# The recompute-backward kernels default to the 128-block regime that
-# jax's own pallas flash kernel picks at BERT-class shapes
-# (BlockSizes.get_default: 128 across the dkv/dq blocks).  The only
-# on-chip measurement of fwd-sized bwd blocks (512x1024, r5 first
-# capture) ran 17x slower than the XLA pair; until the
-# flash_bwd_autotune sweep lands a measured winner (tuning profile keys
-# flash_bwd_block_q/k override these), the public prior is the best
-# evidence available.
+# The recompute-backward tile where one step cannot hold a head's keys
+# (long sequences), for the split dq / dkv kernels, and for callers that
+# give ``_clamp_blocks`` no shape: the 128-block regime of jax's own pallas
+# flash kernel.  Where the keys fit, the fused backward's built-in is
+# ``_whole_key_blocks`` instead: a 128x128 grid at S 512 ran 22.1 ms a call
+# at b112 (6.9 % of its roofline; ledger, PR 24) — per-step overhead, not
+# work — where one 512x512 tile a head runs 4.4 ms (PERF.md section 6, PR 26).
 DEFAULT_BWD_BLOCK_Q = 128
 DEFAULT_BWD_BLOCK_K = 128
+# q rows of a whole-key tile: as many as fit the budget, between these.
+# Under 128 rows a step's five products no longer fill the MXU's rows and
+# today's 128x128 paths take over.
+_WHOLE_KEY_MAX_BQ = 512
+_WHOLE_KEY_MIN_BQ = 128
 NEG_INF = -1e30
 
 # Fused-backward dq-partials buffer cap (HBM bytes): the fused kernel emits
@@ -119,14 +129,48 @@ def _resolve_backward(backward: str) -> str:
 _VMEM_BUDGET_MB = 8.0
 
 
-def _clamp_blocks(bq, bk, D, esz, bias_per_q, bwd=False, sq=None, sk=None):
+def _vmem_budget() -> float:
+    import os
+    return float(os.environ.get("APEX_TPU_FLASH_VMEM_MB",
+                                _VMEM_BUDGET_MB)) * 2 ** 20
+
+
+def _whole_key_blocks(sq, sk, D, esz, bias_per_q, causal):
+    """The fused backward's built-in tile, from the shape the call has:
+    ``(bq, bk)`` with ``bk`` covering ALL keys of a head (nk = 1, so dq is
+    finished inside the kernel) and as many q rows as the VMEM budget
+    holds, or None where the keys do not fit beside ``_WHOLE_KEY_MIN_BQ``
+    rows (long sequences: the 128x128 paths run there).
+
+    ``causal`` is an input of the rule although it changes no answer today:
+    at nk = 1 nothing of a causal tile is skipped, and on the v5e at
+    S 512 / BH 512 the whole-key tile still took 1.24 ms a call against
+    5.90 ms + 0.71 ms of partials' sum for the 128x128 grid with its
+    skipping (256x512: 1.42 ms; PERF.md section 6, PR 26)."""
+    del causal
+    bk = max(128, -(-sk // 128) * 128)
+    bq = min(_WHOLE_KEY_MAX_BQ, max(8, -(-sq // 8) * 8))
+    floor = min(bq, _WHOLE_KEY_MIN_BQ)
+    budget = _vmem_budget()
+    while vmem_estimate(bq, bk, D, esz, bias_per_q, "fused") > budget:
+        if bq // 2 < floor:
+            return None
+        bq = max(8, (bq // 2 // 8) * 8)
+    return bq, bk
+
+
+def _clamp_blocks(bq, bk, D, esz, bias_per_q, bwd=False, sq=None, sk=None,
+                  causal=False):
     """Shrink (bq, bk) until the kernel's per-step VMEM estimate fits the
     budget.  ``bq``/``bk`` None means "default, overridable by env", and
     only those are budget-clamped; explicit values (an autotune sweep, a
     user who measured) are taken as-is so what runs is what was asked for —
     a config that genuinely exceeds VMEM then fails loudly at compile.
     ``sq``/``sk`` (the actual sequence lengths) cap the blocks BEFORE
-    estimating, so short sequences aren't shrunk below what fits anyway.
+    estimating, so short sequences aren't shrunk below what fits anyway;
+    for ``bwd="fused"`` they (with ``causal``) also decide the built-in
+    end of the chain — :func:`_whole_key_blocks` where the keys fit, the
+    128x128 constants where they do not or where no shape is given.
     ``bwd`` selects the footprint model AND the env/profile chain:
     ``False`` (forward), ``"dq"`` / ``"dkv"`` / ``"fused"`` (the three
     backward kernels — per-kernel keys, falling back to the shared bwd
@@ -138,12 +182,12 @@ def _clamp_blocks(bq, bk, D, esz, bias_per_q, bwd=False, sq=None, sk=None):
     # measures them separately — fwd blocks that stream k/v differ from
     # bwd blocks that also stream do and accumulate dk/dv), so bwd
     # consults ONLY the bwd env pin / tuning key / built-in chain.  The
-    # fwd winner deliberately does not leak into bwd: the one on-chip
-    # measurement of fwd-sized bwd blocks ran 17x slow, and a partial
-    # autotune window may write the fwd profile key without the bwd one.
+    # fwd winner deliberately does not leak into bwd: a partial autotune
+    # window may write the fwd profile key without the bwd one.
     # Per-kernel chain (bwd="dq"|"dkv"|"fused"; fused rides the dkv keys,
     # it runs on the dkv grid): argument > per-kernel env pin > shared bwd
-    # env pin > per-kernel profile > shared bwd profile > 128x128 built-in.
+    # env pin > per-kernel profile > shared bwd profile > built-in (the
+    # shape's whole-key tile for "fused", else 128x128).
     chains_q, chains_k = [], []
     if bwd in ("dq", "dkv", "fused"):
         kern = "DKV" if bwd in ("dkv", "fused") else "DQ"
@@ -168,7 +212,7 @@ def _clamp_blocks(bq, bk, D, esz, bias_per_q, bwd=False, sq=None, sk=None):
     # precedence (per path): argument > env pin > profile > built-in.
     from ...utils import tuning
 
-    def _pick(chain, default):
+    def _pick(chain):
         for env, _ in chain:
             if env in os.environ:
                 return int(os.environ[env]), True
@@ -176,24 +220,28 @@ def _clamp_blocks(bq, bk, D, esz, bias_per_q, bwd=False, sq=None, sk=None):
             v = tuning.get_on_tpu(tune, None)
             if v is not None:
                 return int(v), False
-        return default, False
+        return None, False
 
     bq_pinned = bq is not None
     bk_pinned = bk is not None
     if bq is None:
-        bq, bq_pinned = _pick(chains_q,
-                              DEFAULT_BWD_BLOCK_Q if bwd
-                              else DEFAULT_BLOCK_Q)
+        bq, bq_pinned = _pick(chains_q)
     if bk is None:
-        bk, bk_pinned = _pick(chains_k,
-                              DEFAULT_BWD_BLOCK_K if bwd
-                              else DEFAULT_BLOCK_K)
+        bk, bk_pinned = _pick(chains_k)
+    if (bq is None and bk is None and bwd == "fused"
+            and sq is not None and sk is not None):
+        whole = _whole_key_blocks(sq, sk, D, esz, bias_per_q, causal)
+        if whole is not None:
+            return whole
+    if bq is None:
+        bq = DEFAULT_BWD_BLOCK_Q if bwd else DEFAULT_BLOCK_Q
+    if bk is None:
+        bk = DEFAULT_BWD_BLOCK_K if bwd else DEFAULT_BLOCK_K
     if sq is not None:
         bq = min(bq, max(8, -(-sq // 8) * 8))
     if sk is not None:
         bk = min(bk, max(128, -(-sk // 128) * 128))
-    budget = float(os.environ.get("APEX_TPU_FLASH_VMEM_MB",
-                                  _VMEM_BUDGET_MB)) * 2 ** 20
+    budget = _vmem_budget()
 
     while (vmem_estimate(bq, bk, D, esz, bias_per_q, bwd) > budget
            and not bk_pinned and bk > 128):
@@ -210,10 +258,17 @@ def vmem_estimate(bq, bk, D, esz, bias_per_q, bwd=False) -> int:
     ``bwd``: ``False`` forward; ``"dq"`` / ``"dkv"`` / ``"fused"`` model the
     individual backward kernels (the dq kernel streams one (bq, D) output +
     one f32 accumulator; the dkv kernel streams dk+dv outputs + two (bk, D)
-    f32 accumulators; fused adds the f32 dq-partial output block on top of
-    dkv) — their footprints genuinely differ, which is why their block
+    f32 accumulators; fused adds the dq output block on top of dkv, counted
+    as the f32 partial — the whole-key kernel's ``q.dtype`` block is
+    smaller) — their footprints genuinely differ, which is why their block
     sizes tune independently.  ``True`` keeps the legacy combined model (a
     superset of dq+dkv, used by the shared-chain callers).
+
+    Every backward model counts what dominates a large tile: the (bq, bk)
+    f32 intermediates of the recompute (``s``/``p``, ``dp``, ``ds``) and
+    the stream-dtype copies of ``p`` and ``ds`` the MXU takes — 4 MiB at
+    512 x 512 in bf16 beside ~1.5 MiB of streams — and the (bq, 1) f32
+    ``lse`` / ``delta`` columns at the 128 lanes a VMEM block pads them to.
 
     Module-level so ``bench_kernels.py``'s ``flash_vmem_probe`` leg can
     validate the model against real Mosaic compiles (round-4 verdict
@@ -221,9 +276,11 @@ def vmem_estimate(bq, bk, D, esz, bias_per_q, bwd=False) -> int:
     qkv_io = (bq * D + 2 * bk * D + bq * D) * esz   # q, k, v, out|dq
     bias = (bq if bias_per_q else 1) * bk * 4
     scratch = bq * (2 + D) * 4 + bq * 4
+    tile = bq * bk * (3 * 4 + 2 * esz)              # s|p, dp, ds + MXU copies
+    columns = 2 * bq * 128 * 4                      # lse, delta (lane-padded)
     if bwd in ("dq", "dkv", "fused"):
         # streams common to every backward kernel: q, k, v, do, lse, delta
-        io = (2 * bq * D + 2 * bk * D) * esz + 2 * bq * 4
+        io = (2 * bq * D + 2 * bk * D) * esz + columns
         if bwd == "dq":
             io += bq * D * esz                      # dq output
             scratch = bq * D * 4                    # dq accumulator
@@ -231,13 +288,14 @@ def vmem_estimate(bq, bk, D, esz, bias_per_q, bwd=False) -> int:
             io += 2 * bk * D * esz                  # dk + dv outputs
             scratch = 2 * bk * D * 4                # dk/dv accumulators
             if bwd == "fused":
-                io += bq * D * 4                    # f32 dq-partial output
-        return 2 * (io + bias) + scratch
+                io += bq * D * 4                    # dq (partial) output
+        return 2 * (io + bias) + scratch + tile
     total = 2 * (qkv_io + bias) + scratch           # x2: double buffer
     if bwd:
-        extra_io = bq * D * esz + 2 * bq * 4        # do, lse, delta
+        extra_io = bq * D * esz + columns           # do, lse, delta
         extra_io += 2 * bk * D * esz                # dk + dv outputs
         total += 2 * extra_io + 2 * bk * D * 4      # + dkv accumulators
+        total += tile
     return total
 
 
@@ -546,19 +604,22 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
 
 
 def _bwd_fused_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref,
-                      lse_ref, delta_ref, dqp_ref, dk_ref, dv_ref, dk_acc,
+                      lse_ref, delta_ref, dq_ref, dk_ref, dv_ref, dk_acc,
                       dv_acc, *, bq, bk, causal, dropout_rate, heads):
     """One recompute feeds all three gradients: P (and the dropout mask) is
     rebuilt ONCE per (k-block, q-block) step; dk/dv accumulate in scratch
-    over the q sweep; the step's dq contribution is emitted as an f32
-    partial, summed over k blocks outside the kernel (each (ki, qi) partial
-    block is visited exactly once, so there is no output-revisit hazard —
-    the splash-attention fused-backward layout).  Versus the split kernels
-    this halves the P recompute and the do@v^T matmul and regenerates the
-    dropout mask once instead of twice, at the cost of the (BH, nk, Sq, D)
-    partial buffer ``_resolve_fuse`` budgets."""
+    over the q sweep; the step's ``ds @ k`` leaves through ``dq_ref``, whose
+    block the caller shapes by nk.  With one k block a head (whole_key) it
+    is the (bq, D) block of dq itself, written in ``q.dtype``.  With more
+    it is this step's f32 partial (``dq_ref`` is then (BH, nk, Sq, D) under
+    ``_resolve_fuse``'s byte cap), summed over k blocks outside the kernel
+    (the splash-attention fused-backward layout).  Either way each block is
+    visited exactly once, so there is no output-revisit hazard.  Versus the
+    split kernels this halves the P recompute and the do@v^T matmul and
+    regenerates the dropout mask once instead of twice."""
     bh, ki, qi = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     nq = pl.num_programs(2)
+    dq_blk = (0,) * (len(dq_ref.shape) - 2)     # (bh,) or (bh, ki) of the block
 
     @pl.when(qi == 0)
     def _():
@@ -594,16 +655,16 @@ def _bwd_fused_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref,
                                          (((0,), (0,)), ((), ())),
                                          preferred_element_type=jnp.float32)
         k = k_ref[0]
-        dqp_ref[0, 0] = jax.lax.dot_general(
+        dq_ref[dq_blk] = jax.lax.dot_general(
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            preferred_element_type=jnp.float32).astype(dq_ref.dtype)
 
     if causal:
         @pl.when(jnp.logical_not(run))
         def _():
-            # a causal-skipped step still owns its dq-partial block (each
-            # is visited exactly once): it must be defined
-            dqp_ref[0, 0] = jnp.zeros_like(dqp_ref[0, 0])
+            # a causal-skipped step still owns its dq block (each is
+            # visited exactly once): it must be defined
+            dq_ref[dq_blk] = jnp.zeros(dq_ref.shape[-2:], dq_ref.dtype)
 
     @pl.when(qi == nq - 1)
     def _():
@@ -734,8 +795,9 @@ def _flash_bwd_dkv(q, k, v, bias, causal, dropout_rate, seed, heads, lse,
 def _flash_bwd_fused(q, k, v, bias, causal, dropout_rate, seed, heads, lse,
                      delta, do, bq, bk):
     """All three gradients from one kernel on the dkv grid (blocks arrive
-    pre-clamped through the ``fused`` chain).  dq comes back as per-k-block
-    f32 partials summed here — a cheap XLA reduction."""
+    pre-clamped through the ``fused`` chain).  With nk = 1 the kernel's dq
+    output IS dq, (BH, Sq, D) in ``q.dtype``; with nk > 1 dq comes back as
+    per-k-block f32 partials summed here by XLA."""
     q, k, v, bias, do, orig_sq, orig_sk = _pad_inputs(q, k, v, bias, do,
                                                       bq=bq, bk=bk)
     BH, Sq, D = q.shape
@@ -746,21 +808,29 @@ def _flash_bwd_fused(q, k, v, bias, causal, dropout_rate, seed, heads, lse,
     seed_arr = jnp.reshape(jnp.asarray(seed, jnp.int32), (1,))
     nk = (Sk + bk - 1) // bk
     vma = _out_vma(q, k, v, bias, do, lse, delta)
+    if nk == 1:
+        dq_spec = pl.BlockSpec((1, bq, D), lambda bh, ki, qi: (bh, qi, 0),
+                               memory_space=pltpu.VMEM)
+        dq_shape = _sds((BH, Sq, D), q.dtype, vma)
+    else:
+        dq_spec = pl.BlockSpec((1, 1, bq, D),
+                               lambda bh, ki, qi: (bh, ki, qi, 0),
+                               memory_space=pltpu.VMEM)
+        dq_shape = _sds((BH, nk, Sq, D), jnp.float32, vma)
 
-    dqp, dk, dv = pl.pallas_call(
+    dq, dk, dv = pl.pallas_call(
         functools.partial(_bwd_fused_kernel, bq=bq, bk=bk, causal=causal,
                           dropout_rate=dropout_rate, heads=heads),
         grid=(BH, nk, (Sq + bq - 1) // bq),
         in_specs=_dkv_in_specs(bias, heads, bq, bk, D),
         out_specs=[
-            pl.BlockSpec((1, 1, bq, D), lambda bh, ki, qi: (bh, ki, qi, 0),
-                         memory_space=pltpu.VMEM),
+            dq_spec,
             pl.BlockSpec((1, bk, D), lambda bh, ki, qi: (bh, ki, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, bk, D), lambda bh, ki, qi: (bh, ki, 0),
                          memory_space=pltpu.VMEM),
         ],
-        out_shape=[_sds((BH, nk, Sq, D), jnp.float32, vma),
+        out_shape=[dq_shape,
                    _sds((BH, Sk, D), k.dtype, vma),
                    _sds((BH, Sk, D), v.dtype, vma)],
         scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
@@ -770,15 +840,15 @@ def _flash_bwd_fused(q, k, v, bias, causal, dropout_rate, seed, heads, lse,
         interpret=_interpret(),
         name="apex_flash_bwd_fused",
     )(seed_arr, q, k, v, bias, do, lse, delta)
-    dq = jnp.sum(dqp, axis=1).astype(q.dtype)
+    if nk > 1:
+        dq = jnp.sum(dq, axis=1).astype(q.dtype)
     return dq[:, :orig_sq], dk[:, :orig_sk], dv[:, :orig_sk]
 
 
-def _resolve_fuse(fuse, BH, Sq, Sk, D, bk):
-    """Fused-vs-split strategy.  Explicit argument > APEX_TPU_FLASH_BWD_FUSE
-    env (0/1) > tuning profile ``flash_bwd_fuse`` (TPU only) > built-in
-    heuristic: fuse while the dq-partials buffer stays under the byte cap
-    (it grows as Sq*Sk/bk — "where the grid allows")."""
+def _forced_fuse(fuse):
+    """A fused-vs-split choice somebody MADE, else None: explicit argument
+    > APEX_TPU_FLASH_BWD_FUSE env (0/1) > tuning profile
+    ``flash_bwd_fuse`` (TPU only)."""
     import os
     if fuse is not None:
         return bool(fuse)
@@ -789,8 +859,18 @@ def _resolve_fuse(fuse, BH, Sq, Sk, D, bk):
         return env.lower() not in ("0", "off", "false", "no", "")
     from ...utils import tuning
     prof = tuning.get_on_tpu("flash_bwd_fuse", None)
-    if prof is not None:
-        return bool(prof)
+    return None if prof is None else bool(prof)
+
+
+def _resolve_fuse(fuse, BH, Sq, Sk, D, bk):
+    """Fused-vs-split strategy where dq leaves the kernel as partials
+    (nk > 1).  :func:`_forced_fuse` (argument > env > profile) > built-in
+    heuristic: fuse while the dq-partials buffer stays under the byte cap
+    (it grows as Sq*Sk/bk — "where the grid allows")."""
+    import os
+    forced = _forced_fuse(fuse)
+    if forced is not None:
+        return forced
     cap = float(os.environ.get("APEX_TPU_FLASH_BWD_FUSE_MB",
                                _FUSE_BUFFER_CAP_MB)) * 2 ** 20
     nk = -(-Sk // bk)
@@ -802,25 +882,39 @@ def _flash_bwd(q, k, v, bias, causal, dropout_rate, seed, heads, out, lse,
                fuse=None):
     """Recompute-backward dispatcher: (dq, dk, dv).
 
+    Two observables choose among the three paths, no knob: does one tile
+    hold a head's keys (nk = 1 -> ``whole_key``: the fused kernel writes
+    dq itself, there is no partials buffer and no cap to consult), and,
+    where it does not, does the f32 partials buffer stay under
+    :func:`_resolve_fuse`'s cap (``partials``, else ``split``).
+
     ``bq``/``bk`` pin BOTH kernels (the legacy shared knob the autotune
     sweeps use); ``dq_blocks``/``dkv_blocks`` (each an optional (bq, bk)
     tuple) pin the kernels separately — their VMEM footprints differ, so
     their optima do too.  ``fuse`` forces the fused/split strategy
-    (None = :func:`_resolve_fuse` auto)."""
+    (None = auto)."""
     # delta_i = rowsum(dO * O): tiny elementwise+reduce, XLA fuses it —
     # computed ONCE here and streamed to whichever backward kernels run
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1, keepdims=True)                   # (BH, Sq, 1)
     D, esz = q.shape[-1], q.dtype.itemsize
+    BH, Sq, Sk = q.shape[0], q.shape[1], k.shape[1]
     per_q = bias.shape[1] != 1
     dq_bq, dq_bk = dq_blocks if dq_blocks is not None else (bq, bk)
     kv_bq, kv_bk = dkv_blocks if dkv_blocks is not None else (bq, bk)
     f_bq, f_bk = _clamp_blocks(kv_bq, kv_bk, D, esz, per_q, bwd="fused",
-                               sq=q.shape[1], sk=k.shape[1])
-    fuse = _resolve_fuse(fuse, q.shape[0], q.shape[1], k.shape[1], D, f_bk)
+                               sq=Sq, sk=Sk, causal=causal)
+    nk = -(-Sk // f_bk)
+    if nk == 1:
+        fuse = _forced_fuse(fuse) is not False
+    else:
+        fuse = _resolve_fuse(fuse, BH, Sq, Sk, D, f_bk)
     if fuse:
+        _tel_events.record_flash_bwd(
+            "whole_key" if nk == 1 else "partials", f_bq, f_bk, nk)
         return _flash_bwd_fused(q, k, v, bias, causal, dropout_rate, seed,
                                 heads, lse, delta, do, f_bq, f_bk)
+    _tel_events.record_flash_bwd("split", f_bq, f_bk, nk)
     dq = _flash_bwd_dq(q, k, v, bias, causal, dropout_rate, seed, heads,
                        lse, delta, do, bq=dq_bq, bk=dq_bk)
     dk, dv = _flash_bwd_dkv(q, k, v, bias, causal, dropout_rate, seed,
@@ -942,6 +1036,7 @@ def _vjp_bwd(causal, dropout_rate, heads, backward, res, do):
     impl = _resolve_backward(backward)
     with annotate("apex.flash"):
         if impl == "xla":
+            _tel_events.record_flash_bwd("xla")
             dq, dk, dv = _xla_bwd(q, k, v, bias, causal, dropout_rate, seed,
                                   heads, out, lse, do)
         else:

@@ -172,6 +172,31 @@ def record_collective(axis_name: str, nbytes: int, n_leaves: int,
               wire_bytes=wire, **extra)
 
 
+FLASH_BWD_PATHS = ("whole_key", "partials", "split", "xla")
+
+
+def record_flash_bwd(path: str, bq: Optional[int] = None,
+                     bk: Optional[int] = None,
+                     nk: Optional[int] = None) -> None:
+    """Which flash-attention backward a traced program holds
+    (``contrib.multihead_attn.flash``): one call per traced backward —
+    trace time, like :func:`record_collective` under jit — with the
+    ``path`` taken (:data:`FLASH_BWD_PATHS`) and, for the Pallas paths,
+    the fused chain's tile ``(bq, bk)`` and the number of k blocks a head
+    ``nk`` the choice was made from (nk = 1 is ``whole_key``).  Counters
+    ``flash.bwd_calls.<path>`` and one ``flash.bwd`` event."""
+    if not active():
+        return
+    if path not in FLASH_BWD_PATHS:
+        raise ValueError(f"path must be one of {FLASH_BWD_PATHS}, "
+                         f"got {path!r}")
+    reg = _default
+    reg.counter(f"flash.bwd_calls.{path}").add(1)
+    tile = {} if bq is None else {"bq": int(bq), "bk": int(bk),
+                                  "nk": int(nk)}
+    reg.event("flash.bwd", path=path, **tile)
+
+
 def record_loader(depth: Optional[int], wait_seconds: float) -> None:
     """Loader meter: consumer wait per batch, ring/queue depth after the
     dequeue (None when the native ring can't report it)."""

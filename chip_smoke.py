@@ -243,7 +243,9 @@ def _kernel_checks(full: bool):
 
     checks = []
 
-    # -- flash attention: forward, and the three backwards forced apart --
+    # -- flash attention: forward; the backward as the shape's rule tiles
+    #    it (whole_key: one k block a head, dq finished in the kernel) and
+    #    the three backwards forced apart --
     def flash_inputs(S, BH):
         D = 64
         q, k, v, do = (rnd(i, (BH, S, D), bf16, 0.5) for i in range(4))
@@ -270,11 +272,23 @@ def _kernel_checks(full: bool):
                 _, vjp = jax.vjp(lambda a_, b_, c_: F.flash_attention(
                     a_, b_, c_, bias, 7, causal, rate, 1, "xla"), a, b, c)
                 return vjp(do)
-        else:                  # split (dq + dkv) / fused, forced
+        else:
+            # whole_key: nothing forced, the tile the rule gives this shape
+            # (S 2048 must still compile, whichever path that is); split
+            # (dq + dkv) forced; fused forced onto the 128x128 grid whose dq
+            # leaves as f32 partials (nk > 1 wherever S > 128)
+            forced = {"whole_key": {}, "split": {"fuse": False},
+                      "fused": {"fuse": True, "bq": 128, "bk": 128}}[impl]
+
             def run(a, b, c):
                 out, lse = F._flash_fwd(a, b, c, bias, causal, rate, 7, 1)
                 return F._flash_bwd(a, b, c, bias, causal, rate, 7, 1, out,
-                                    lse, do, fuse=(impl == "fused"))
+                                    lse, do, **forced)
+        if impl == "whole_key" and S <= 512:
+            tile = F._clamp_blocks(None, None, 64, 2, False, bwd="fused",
+                                   sq=S, sk=S, causal=causal)
+            if tile[1] < S:
+                raise AssertionError(f"S {S}: the rule gave {tile}, nk > 1")
         return rel_err(jax.jit(run)(q, k, v), jax.jit(ref)(q, k, v))
 
     for S, BH in (((512, 128), (2048, 32)) if full else ((128, 2),)):
@@ -282,7 +296,7 @@ def _kernel_checks(full: bool):
             tag = f"S{S}{'c' if causal else ''}"
             checks.append((f"flash_fwd[{tag}]", 3e-2,
                            lambda S=S, BH=BH, c=causal: flash_fwd(S, BH, c)))
-            for impl in ("split", "fused", "xla"):
+            for impl in ("whole_key", "split", "fused", "xla"):
                 checks.append((
                     f"flash_bwd_{impl}[{tag}]", 5e-2,
                     lambda S=S, BH=BH, c=causal, i=impl: flash_bwd(
@@ -291,8 +305,9 @@ def _kernel_checks(full: bool):
     # dropout: the in-kernel uint32 hash must rebuild the XLA mask bit for bit
     checks.append((f"flash_fwd_dropout[S{S}c]", 3e-2,
                    lambda: flash_fwd(S, BH, True, 0.1)))
-    checks.append((f"flash_bwd_fused_dropout[S{S}c]", 5e-2,
-                   lambda: flash_bwd(S, BH, True, "fused", 0.1)))
+    for impl in ("whole_key", "fused"):
+        checks.append((f"flash_bwd_{impl}_dropout[S{S}c]", 5e-2,
+                       lambda i=impl: flash_bwd(S, BH, True, i, 0.1)))
 
     # -- xentropy, forward and backward (ragged last vocabulary block) ----
     N, V = (4096, 30592) if full else (64, 1000)

@@ -607,3 +607,276 @@ def test_clamped_blocks_model_under_the_vmem_budget(monkeypatch):
     # the clamp read the same budget
     monkeypatch.setenv("APEX_TPU_FLASH_VMEM_MB", "0.05")
     assert _worst_vmem_ratio(0.05 * 2 ** 20) > 1.0
+
+
+# ---------------------------------------------------------------------------
+# whole-key backward: one tile holds a head's keys, dq finished in the kernel
+# ---------------------------------------------------------------------------
+
+_FLASH_BWD_ENV = ("APEX_TPU_FLASH_BWD_BLOCK_Q", "APEX_TPU_FLASH_BWD_BLOCK_K",
+                  "APEX_TPU_FLASH_BWD_DQ_BLOCK_Q",
+                  "APEX_TPU_FLASH_BWD_DQ_BLOCK_K",
+                  "APEX_TPU_FLASH_BWD_DKV_BLOCK_Q",
+                  "APEX_TPU_FLASH_BWD_DKV_BLOCK_K",
+                  "APEX_TPU_FLASH_BWD_FUSE", "APEX_TPU_FLASH_BWD_FUSE_MB",
+                  "APEX_TPU_FLASH_BWD_IMPL", "APEX_TPU_FLASH_VMEM_MB")
+
+
+@pytest.fixture
+def flash_env(monkeypatch):
+    """The whole-key tests read the BUILT-IN end of the chain: no ambient
+    pin may stand in front of it."""
+    for var in _FLASH_BWD_ENV:
+        monkeypatch.delenv(var, raising=False)
+    return monkeypatch
+
+
+@pytest.fixture
+def flash_counts():
+    """A default registry for the ``flash.bwd_calls.*`` counters; the
+    previous default comes back afterwards."""
+    from apex_tpu.telemetry import MemorySink, Registry, events
+    reg = Registry(sink=MemorySink(), flush_interval=0, rank0_only=False)
+    prev = events.set_default(reg)
+    yield reg
+    events.set_default(prev)
+
+
+def _whole_key_case(case, S):
+    """(sq, sk, causal, rate, bias layout, dtype) of one parity case."""
+    sq, sk, causal, rate, layout, dtype = S, S, False, 0.0, "none", jnp.float32
+    if case == "causal":
+        causal = True
+    elif case == "dropout":
+        rate = 0.1
+    elif case == "padding":
+        layout = "padding"
+    elif case == "per_query":
+        layout = "full"
+    elif case == "ragged":              # multiples of neither 8 nor 128
+        sq, sk = S - 12, S - 28
+    elif case == "sq_ne_sk":
+        sq = S // 2
+    elif case == "bf16":
+        dtype = jnp.bfloat16
+    return sq, sk, causal, rate, layout, dtype
+
+
+@pytest.mark.parametrize("case", ["plain", "causal", "dropout", "padding",
+                                  "per_query", "ragged", "sq_ne_sk", "bf16"])
+@pytest.mark.parametrize("S", [128, 512])
+def test_flash_bwd_whole_key_matches_split_and_xla(flash_env, flash_counts,
+                                                   S, case):
+    """Where one tile holds a head's keys the fused kernel writes dq itself
+    (path ``whole_key``); its gradients are those of the split dq / dkv
+    kernels and of the XLA twin, for every input the rule sees."""
+    from apex_tpu.contrib.multihead_attn import flash as F
+    sq, sk, causal, rate, layout, dtype = _whole_key_case(case, S)
+    b, h, d = 2, (2 if S == 128 else 1), 64   # interpret mode: keep BH small
+    ks = jax.random.split(jax.random.PRNGKey(26), 4)
+    q, do = (0.5 * jax.random.normal(kk, (b * h, sq, d), jnp.float32)
+             for kk in ks[:2])
+    k, v = (0.5 * jax.random.normal(kk, (b * h, sk, d), jnp.float32)
+            for kk in ks[2:])
+    q, k, v, do = (x.astype(dtype) for x in (q, k, v, do))
+    bias = _bias_layouts(b, sq, sk)[layout]
+
+    @jax.jit
+    def grads(seed):                    # the seed is traced, as in training
+        out, lse = F._flash_fwd(q, k, v, bias, causal, rate, seed, h)
+        args = (q, k, v, bias, causal, rate, seed, h, out, lse, do)
+        return (F._flash_bwd(*args), F._flash_bwd(*args, fuse=False),
+                F._xla_bwd(*args))
+
+    whole, split, xla = grads(jnp.int32(7))
+    counts = flash_counts.read()
+    assert counts["flash.bwd_calls.whole_key"] == 1
+    assert counts["flash.bwd_calls.split"] == 1
+    assert whole[0].shape == q.shape and whole[0].dtype == q.dtype
+    bf16 = dtype == jnp.bfloat16
+    for name, w, s_, x in zip(("dq", "dk", "dv"), whole, split, xla):
+        w, s_, x = (np.asarray(t, np.float32) for t in (w, s_, x))
+        np.testing.assert_allclose(w, s_, atol=3e-2 if bf16 else 2e-5,
+                                   rtol=2e-2 if bf16 else 1e-5, err_msg=name)
+        np.testing.assert_allclose(w, x, atol=5e-2 if bf16 else 5e-3,
+                                   rtol=2e-2 if bf16 else 1e-3, err_msg=name)
+
+
+@pytest.mark.parametrize("sq,sk,D,esz,per_q,causal,want", [
+    (128, 128, 64, 2, False, False, (128, 128)),      # bert_large.s128
+    (512, 512, 64, 2, False, False, (512, 512)),      # bert_large.s512
+    (512, 512, 64, 2, False, True, (512, 512)),
+    (512, 512, 64, 4, False, False, (256, 512)),      # f32 streams: nq = 2
+    (512, 512, 64, 2, True, False, (256, 512)),       # per-query bias block
+    (256, 512, 64, 2, False, False, (256, 512)),      # encdec: Sq != Sk
+    (100, 84, 64, 2, False, False, (104, 128)),       # ragged: rounded up
+    (1024, 1024, 64, 2, False, False, (256, 1024)),
+    (2048, 2048, 64, 2, False, False, (128, 2048)),
+    (4096, 4096, 64, 2, False, False, None),          # keys do not fit
+    (512, 512, 256, 2, False, False, (256, 512)),
+    (4096, 8192, 128, 4, True, True, None),
+])
+def test_flash_bwd_tile_rule(flash_env, sq, sk, D, esz, per_q, causal, want):
+    """The built-in end of the fused chain, as a table: one k block a head
+    wherever the keys fit the VMEM budget, today's 128 x 128 where they do
+    not; every tile the rule gives models under the budget."""
+    from apex_tpu.contrib.multihead_attn import flash as F
+    got = F._whole_key_blocks(sq, sk, D, esz, per_q, causal)
+    assert got == want
+    bq, bk = F._clamp_blocks(None, None, D, esz, per_q, bwd="fused",
+                             sq=sq, sk=sk, causal=causal)
+    budget = F._VMEM_BUDGET_MB * 2 ** 20
+    assert F.vmem_estimate(bq, bk, D, esz, per_q, "fused") <= budget
+    if want is None:
+        assert (bq, bk) == (F.DEFAULT_BWD_BLOCK_Q, F.DEFAULT_BWD_BLOCK_K)
+        assert -(-sk // bk) > 1
+    else:
+        assert (bq, bk) == want and -(-sk // bk) == 1
+    # the split kernels, and a caller that gives no shape, keep the constants
+    for bwd in ("dq", "dkv", True):
+        assert F._clamp_blocks(None, None, D, esz, per_q, bwd=bwd,
+                               sq=4096, sk=4096) == (128, 128)
+    assert F._clamp_blocks(None, None, D, esz, per_q,
+                           bwd="fused") == (128, 128)
+
+
+def test_flash_bwd_tile_rule_vmem_estimate_counts_the_tile():
+    """What dominates a large tile is in the model: the (bq, bk) f32
+    intermediates and their MXU copies (4 MiB of 512 x 512 in bf16)."""
+    from apex_tpu.contrib.multihead_attn import flash as F
+    big = F.vmem_estimate(512, 512, 64, 2, False, "fused")
+    assert 5 * 2 ** 20 < big < 8 * 2 ** 20
+    tile = 512 * 512 * (3 * 4 + 2 * 2)
+    for bwd in ("dq", "dkv", "fused", True):
+        grown = (F.vmem_estimate(512, 512, 64, 2, False, bwd)
+                 - F.vmem_estimate(512, 256, 64, 2, False, bwd))
+        assert grown > tile // 2, bwd
+
+
+@pytest.mark.parametrize("source", ["argument", "env", "env_dkv", "profile",
+                                    "profile_dkv", "budget"])
+def test_flash_bwd_tile_rule_is_the_last_link(flash_env, source):
+    """Explicit blocks, env pins and profile keys still win over the rule,
+    in today's order; a moved VMEM budget moves the rule's answer."""
+    from apex_tpu.contrib.multihead_attn import flash as F
+    from apex_tpu.utils import tuning
+    shape = dict(D=64, esz=2, bias_per_q=False, bwd="fused", sq=512, sk=512)
+    assert F._clamp_blocks(None, None, **shape) == (512, 512)
+    if source == "argument":
+        assert F._clamp_blocks(128, 256, **shape) == (128, 256)
+        # one pinned side: the other falls to the constant, not to the rule
+        assert F._clamp_blocks(256, None, **shape) == (256, 128)
+    elif source == "env":
+        flash_env.setenv("APEX_TPU_FLASH_BWD_BLOCK_Q", "128")
+        flash_env.setenv("APEX_TPU_FLASH_BWD_BLOCK_K", "128")
+        assert F._clamp_blocks(None, None, **shape) == (128, 128)
+    elif source == "env_dkv":
+        flash_env.setenv("APEX_TPU_FLASH_BWD_BLOCK_Q", "128")
+        flash_env.setenv("APEX_TPU_FLASH_BWD_BLOCK_K", "128")
+        flash_env.setenv("APEX_TPU_FLASH_BWD_DKV_BLOCK_Q", "256")
+        flash_env.setenv("APEX_TPU_FLASH_BWD_DKV_BLOCK_K", "256")
+        assert F._clamp_blocks(None, None, **shape) == (256, 256)
+    elif source in ("profile", "profile_dkv"):
+        prof = {"flash_bwd_block_q": 128, "flash_bwd_block_k": 256}
+        if source == "profile_dkv":
+            prof.update(flash_bwd_dkv_block_q=256, flash_bwd_dkv_block_k=128)
+        flash_env.setattr(tuning, "get_on_tpu",
+                          lambda key, default=None: prof.get(key, default))
+        want = (256, 128) if source == "profile_dkv" else (128, 256)
+        assert F._clamp_blocks(None, None, **shape) == want
+        flash_env.setenv("APEX_TPU_FLASH_BWD_BLOCK_Q", "64")
+        flash_env.setenv("APEX_TPU_FLASH_BWD_BLOCK_K", "512")
+        assert F._clamp_blocks(None, None, **shape) == (64, 512)
+    else:
+        flash_env.setenv("APEX_TPU_FLASH_VMEM_MB", "4")
+        assert F._clamp_blocks(None, None, **shape) == (256, 512)
+        flash_env.setenv("APEX_TPU_FLASH_VMEM_MB", "1")
+        assert F._whole_key_blocks(512, 512, 64, 2, False, False) is None
+
+
+def _walk_eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _walk_eqns(sub)
+
+
+def test_flash_grad_jaxpr_has_no_dq_partials(flash_env):
+    """grad(flash_attention) at S 512: the fused backward's dq output is
+    (BH, Sq, D) in q.dtype and no f32 (BH, nk, Sq, D) array exists for XLA
+    to sum — where the parent's 128 x 128 grid made one of nk = 4."""
+    BH, S, D = 4, 512, 64
+    q = jnp.zeros((BH, S, D), jnp.bfloat16)
+    bias = jnp.zeros((1, 1, S), jnp.float32)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda q, k, v: flash_attention(q, k, v, bias, 0, False, 0.0, 2,
+                                        "pallas").astype(jnp.float32).sum(),
+        argnums=(0, 1, 2)))(q, q, q)
+    eqns = list(_walk_eqns(jaxpr.jaxpr))
+    fused = [e for e in eqns if e.primitive.name == "pallas_call"
+             and e.params["name"] == "apex_flash_bwd_fused"]
+    assert len(fused) == 1
+    dq = fused[0].outvars[0].aval
+    assert dq.shape == (BH, S, D) and dq.dtype == jnp.bfloat16
+    for e in eqns:
+        for var in e.outvars:
+            aval = var.aval
+            partial = (getattr(aval, "ndim", 0) == 4
+                       and aval.dtype == jnp.float32
+                       and aval.shape[0] == BH and aval.shape[2:] == (S, D))
+            assert not partial, (e.primitive.name, aval)
+    # pinned back to 128 x 128 the partials are there: the check can see
+    flash_env.setenv("APEX_TPU_FLASH_BWD_BLOCK_Q", "128")
+    flash_env.setenv("APEX_TPU_FLASH_BWD_BLOCK_K", "128")
+    pinned = jax.make_jaxpr(jax.grad(
+        lambda q: flash_attention(q, q, q, bias, 0, False, 0.0, 2,
+                                  "pallas").astype(jnp.float32).sum()))(q)
+    shapes = [v.aval.shape for e in _walk_eqns(pinned.jaxpr)
+              for v in e.outvars if hasattr(v.aval, "shape")]
+    assert (BH, 4, S, D) in shapes
+
+
+@pytest.mark.parametrize("path", ["whole_key", "partials", "split", "xla"])
+def test_flash_bwd_calls_counter_names_the_path(flash_env, flash_counts,
+                                                path):
+    """``flash.bwd_calls.<path>`` counts one per traced backward under a
+    default registry; the event carries the tile the choice was made from."""
+    from apex_tpu.telemetry import events
+    h, s, d = 2, 256, 16
+    q = jax.random.normal(jax.random.PRNGKey(0), (h, s, d))
+    bias = jnp.zeros((1, 1, s), jnp.float32)
+    if path == "partials":              # nk = 2: dq leaves as partials
+        flash_env.setenv("APEX_TPU_FLASH_BWD_BLOCK_Q", "128")
+        flash_env.setenv("APEX_TPU_FLASH_BWD_BLOCK_K", "128")
+    elif path == "split":
+        flash_env.setenv("APEX_TPU_FLASH_BWD_FUSE", "0")
+    backward = "xla" if path == "xla" else "pallas"
+    jax.grad(lambda q: flash_attention(q, q, q, bias, 0, False, 0.0, h,
+                                       backward).sum())(q)
+    with pytest.raises(ValueError):
+        events.record_flash_bwd("cuda")
+    counts = {k: v for k, v in flash_counts.read().items()
+              if k.startswith("flash.bwd_calls.")}
+    assert counts == {f"flash.bwd_calls.{path}": 1}
+    ev = [r for r in flash_counts.flush() if r.get("name") == "flash.bwd"]
+    assert len(ev) == 1 and ev[0]["fields"]["path"] == path
+    if path == "xla":
+        assert "nk" not in ev[0]["fields"]
+    else:
+        assert ev[0]["fields"]["nk"] == (2 if path == "partials" else 1)
+
+
+def test_flash_bwd_calls_counter_is_a_noop_without_a_registry(flash_env):
+    from apex_tpu.telemetry import events
+    prev = events.set_default(None)
+    try:
+        assert not events.active()
+        events.record_flash_bwd("whole_key", 8, 128, 1)
+        events.record_flash_bwd("cuda")         # not even validated: free
+        q = jax.random.normal(jax.random.PRNGKey(0), (2, 64, 16))
+        bias = jnp.zeros((1, 1, 64), jnp.float32)
+        g = jax.grad(lambda q: flash_attention(q, q, q, bias, 0, False, 0.0,
+                                               2, "pallas").sum())(q)
+        assert np.all(np.isfinite(np.asarray(g)))
+    finally:
+        events.set_default(prev)
