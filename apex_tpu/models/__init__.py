@@ -16,6 +16,8 @@ from .dcgan import (DCGANConfig, dcgan_init, generator_apply,
                     discriminator_apply)
 from .moe_transformer import (MoETransformerConfig, moe_transformer_init,
                               moe_transformer_apply, moe_transformer_loss)
+from .lfm2 import (Lfm2Config, lfm2_24b_a2b_config, lfm2_cut_layer_types,
+                   lfm2_init, lfm2_apply, lfm2_loss, lfm2_routing)
 
 __all__ = [
     "TransformerConfig", "transformer_init", "transformer_apply",
@@ -25,4 +27,6 @@ __all__ = [
     "DCGANConfig", "dcgan_init", "generator_apply", "discriminator_apply",
     "MoETransformerConfig", "moe_transformer_init", "moe_transformer_apply",
     "moe_transformer_loss",
+    "Lfm2Config", "lfm2_24b_a2b_config", "lfm2_cut_layer_types", "lfm2_init",
+    "lfm2_apply", "lfm2_loss", "lfm2_routing",
 ]

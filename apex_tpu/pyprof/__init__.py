@@ -51,6 +51,10 @@ SCOPES = (
     "apex.opt_update",     # inside it: optimizer.step / step_flat + skip select
     "apex.model_copy",     # inside it: master -> model-precision copy
     "apex.ddp_allreduce",  # parallel.DistributedDataParallel.allreduce_grads
+    "apex.conv",           # models.lfm2: norm, gated short convolution, residual
+    "apex.moe",            # models.lfm2: norm, parallel.expert.routed_experts
+    "apex.router",         # inside it: scores, top-k, weights (float32)
+    "apex.experts",        # inside it: the grouped products and their gate
 )
 
 # jax strips debug info - where a named scope lives - before it hashes the

@@ -34,6 +34,7 @@ code stays free when telemetry is off.
 """
 from __future__ import annotations
 
+import collections
 from typing import Optional
 
 from . import registry as _registry
@@ -195,6 +196,56 @@ def record_flash_bwd(path: str, bq: Optional[int] = None,
     tile = {} if bq is None else {"bq": int(bq), "bk": int(bk),
                                   "nk": int(nk)}
     reg.event("flash.bwd", path=path, **tile)
+
+
+def record_moe_layout(experts: int, held: int, top_k: int,
+                      buffer_rows: int) -> None:
+    """The routing layout a traced program holds
+    (``parallel.expert.routed_experts``): one call per traced expert layer
+    — trace time, like :func:`record_flash_bwd` — with the number of
+    experts routed over, how many of them are held here, the experts a
+    token takes and the rows of the dispatch buffer (tokens x top_k: a row
+    for every assignment, so none can be dropped).  Counter
+    ``moe.layers_traced`` and one ``moe.layout`` event."""
+    if not active():
+        return
+    reg = _default
+    reg.counter("moe.layers_traced").add(1)
+    reg.event("moe.layout", experts=int(experts), held=int(held),
+              top_k=int(top_k), buffer_rows=int(buffer_rows))
+
+
+#: the newest steps' ``rows`` as :func:`record_expert_rows` was given them
+#: (numpy (expert layers, held) int arrays), oldest first
+EXPERT_ROWS_KEPT = 64
+_expert_rows: "collections.deque" = collections.deque(maxlen=EXPERT_ROWS_KEPT)
+
+
+def record_expert_rows(rows, dropped) -> None:
+    """Step side of the routing meter: what one forward pass sent the held
+    experts.  ``rows`` (expert layers, held) are the assignments each held
+    expert of each layer was sent, ``dropped`` the held assignments that
+    found no row in the buffer (0: the buffer has a row for each).  A model
+    calls it through ``jax.debug.callback`` once a forward pass, and only
+    where :func:`active` was true when the step was traced.  Counters
+    ``moe.rows_held`` / ``moe.rows_dropped``, histogram
+    ``moe.load_max_over_mean`` (the fullest held expert over the mean, worst
+    layer), and the array itself in :func:`expert_rows`."""
+    if not active():
+        return
+    import numpy as np
+    rows = np.asarray(rows).reshape(-1, np.shape(rows)[-1])
+    _expert_rows.append(rows)
+    reg = _default
+    reg.counter("moe.rows_held").add(int(rows.sum()))
+    reg.counter("moe.rows_dropped").add(int(np.sum(dropped)))
+    reg.histogram("moe.load_max_over_mean").observe(float(
+        (rows.max(axis=1) / np.maximum(rows.mean(axis=1), 1e-9)).max()))
+
+
+def expert_rows() -> list:
+    """The last :data:`EXPERT_ROWS_KEPT` steps' rows, oldest first."""
+    return list(_expert_rows)
 
 
 def record_loader(depth: Optional[int], wait_seconds: float) -> None:

@@ -12,6 +12,11 @@ user would call, at the full width of the models the repo supports:
   bert_large  examples/bert/pretrain.py, 24L b8 x s512, flash + remat,
               amp O5 + FusedLAMB(impl="fused"); the compiled step is
               shown to contain the Pallas kernels
+  lfm2        the same example with --lfm2 1 1 8: LFM2-24B-A2B's widths,
+              one dense layer and one period, 8 of 64 experts, b8 x s4096
+              (the benchmark cell's shapes); the step holds the kernels and
+              the grouped products, and loss and gradients match the
+              XLA-attention twin on one sequence
   multichip   (when jax finds >= 4 devices) the trainers --distributed /
               --zero / --sync-bn and one step of every plan family on a
               4-device mesh, each device holding its share
@@ -609,6 +614,83 @@ def phase_bert_large(ctx) -> dict:
     return facts
 
 
+def phase_lfm2(ctx) -> dict:
+    """LFM2-24B-A2B's share of the benchmark cell (``--lfm2 1 1 8 --vocab
+    8192``, b8 x s4096, flash + remat, amp O5, FusedLAMB on the flat engine)
+    through ``parse_args`` -> ``run_standard``: trains, the step holds the
+    Pallas kernels and the grouped products, and on one sequence the loss
+    and its gradient agree with the XLA-attention twin.  The rehearsal
+    keeps the pattern and the path at width 64."""
+    import dataclasses
+    import re
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from apex_tpu.models import lfm2_loss
+    from apex_tpu.parallel import create_mesh, use_mesh
+    pretrain = load_example("examples/bert/pretrain.py")
+    batch, seq = (8, 4096) if ctx["full"] else (2, 128)
+    args = pretrain.parse_args([
+        "--lfm2", "1", "1", "8", "--vocab", "8192", "--seq-len", str(seq),
+        "--batch-size", str(batch), "--attn", "fast", "--remat",
+        *([] if ctx["full"] else ["--lr", "2e-2"])])
+    cfg = pretrain.lfm2_config(args)
+    if not ctx["full"]:
+        cfg = dataclasses.replace(
+            cfg, vocab_size=256, hidden_size=64, intermediate_size=160,
+            moe_intermediate_size=32, num_experts=16, num_attention_heads=8,
+            num_key_value_heads=2)
+    steps, rng = 9, np.random.RandomState(0)
+    report = {"losses": []}
+    mesh = create_mesh({"data": 1}, devices=jax.devices()[:1])
+    with use_mesh(mesh):
+        state, step = pretrain.run_standard(args, cfg, mesh)
+        for _ in range(steps):
+            tokens, targets, weights = pretrain.synthetic_next_token(
+                rng, batch, seq, cfg.vocab_size)
+            np_batch = {"tokens": tokens, "targets": targets,
+                        "weights": weights}
+            state, loss = step(state, np_batch)
+            report["losses"].append(float(loss))
+        report["optimizer_steps"] = step.optimizer_steps(state)
+        facts = check_trainer_report(report, steps)
+        traced = step.trace(state, np_batch)
+    names = pallas_kernel_names(traced)
+    required = {"apex_flash_fwd", "apex_l2norm"}
+    if ctx["on_tpu"]:
+        required.add("apex_xentropy_fwd")
+    if not required <= names or not _flash_bwd_present(names):
+        raise AssertionError(f"traced step has Pallas kernels {sorted(names)}"
+                             f", expected {sorted(required)} and a flash "
+                             "backward")
+    grouped = len(re.findall(r"ragged_dot", str(traced.jaxpr)))
+    if not grouped:
+        raise AssertionError("the traced step holds no ragged_dot")
+    facts.update(pallas_calls_traced=sorted(names), ragged_dots=grouped)
+
+    # the twin: the same parameters, XLA attention, one sequence (its scores
+    # are 2 GiB in float32 at S 4096, so the optimizer state goes first)
+    params = state.model_params
+    del state, traced
+    one = {k: jnp.asarray(v[:1]) for k, v in np_batch.items()}
+
+    def loss_and_norm(cfg):
+        loss, grads = jax.jit(jax.value_and_grad(
+            lambda p: lfm2_loss(p, one, cfg)))(params)
+        return float(loss), float(jnp.sqrt(sum(
+            jnp.sum(jnp.square(g.astype(jnp.float32)))
+            for g in jax.tree_util.tree_leaves(grads))))
+    fast = loss_and_norm(cfg)
+    twin = loss_and_norm(dataclasses.replace(cfg, attn_impl="default"))
+    errors = [abs(a - b) / abs(b) for a, b in zip(fast, twin)]
+    facts.update(loss_and_grad_norm=fast, xla_attention_twin=twin,
+                 rel_err=[float(f"{e:.3e}") for e in errors])
+    if not (errors[0] < 2e-3 and errors[1] < 2e-2):
+        raise AssertionError(f"flash step against its XLA-attention twin: "
+                             f"{fast} vs {twin}")
+    return facts
+
+
 def _plan_families():
     from apex_tpu.parallel import plan as pm
     return [("dp2xtp2", pm.Plan(dp=2, tp=2)),
@@ -701,6 +783,7 @@ PHASES = {
     "serve": phase_serve,
     "resnet50": phase_resnet50,
     "bert_large": phase_bert_large,
+    "lfm2": phase_lfm2,
     "multichip": phase_multichip,
 }
 MULTICHIP_DEVICES = 4

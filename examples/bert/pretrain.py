@@ -35,7 +35,9 @@ from apex_tpu import amp
 from apex_tpu.models import (TransformerConfig, bert_large_config,
                              transformer_init, transformer_loss,
                              MoETransformerConfig, moe_transformer_init,
-                             moe_transformer_loss)
+                             moe_transformer_loss, Lfm2Config,
+                             lfm2_24b_a2b_config, lfm2_cut_layer_types,
+                             lfm2_init, lfm2_loss)
 from apex_tpu.optimizers import FusedLAMB
 from apex_tpu.parallel import create_mesh, use_mesh
 from apex_tpu.utils.logging import AverageMeter, Throughput
@@ -62,6 +64,16 @@ def parse_args(argv=None):
     p.add_argument("--opt-level", default="O5")
     p.add_argument("--bert-large", action="store_true",
                    help="full bert-large config (TPU-sized)")
+    p.add_argument("--lfm2", type=int, nargs=3, default=None,
+                   metavar=("DENSE", "PERIODS", "HELD"),
+                   help="LFM2-24B-A2B at its published widths (causal LM on "
+                        "next-token batches), cut to a chip's share: DENSE "
+                        "leading dense layers (published 2), PERIODS whole "
+                        "periods of (attention, conv, conv, conv) after "
+                        "them (published 9 and a half), the first HELD of "
+                        "the 64 experts of each layer, --vocab rows of the "
+                        "65536 of the embedding; the router still scores "
+                        "all 64 (docs/lfm2.md)")
     p.add_argument("--distributed", action="store_true")
     p.add_argument("--zero", action="store_true",
                    help="ZeRO sharded optimizer (DistributedFusedLAMB)")
@@ -133,6 +145,49 @@ def synthetic_mlm(rng, batch, seq, vocab):
     return tokens, targets, weights
 
 
+_SYN_COMMON = (8, 0.9)   # an eighth of the ids is common: 90% of fresh draws
+_SYN_FOLLOW = 0.5        # share of positions that follow the rule
+
+
+@functools.lru_cache(maxsize=4)
+def _syn_rule(vocab):
+    """The next-token corpus's fixed rule (fixed seed, like the MLM pool's):
+    which ids are common, and every id's successor — a permutation that
+    keeps common ids common, so following it leaves the frequencies alone."""
+    ids = np.random.RandomState(1234).permutation(vocab).astype(np.int32)
+    common, rare = ids[: vocab // _SYN_COMMON[0]], ids[vocab // _SYN_COMMON[0]:]
+    successor = np.empty(vocab, np.int32)
+    successor[common] = np.roll(common, 1)
+    successor[rare] = np.roll(rare, 1)
+    return common, rare, successor
+
+
+def synthetic_next_token(rng, batch, seq, vocab):
+    """Synthetic causal-LM batch over the WHOLE vocabulary.  A position is a
+    fresh draw — a common id with probability 0.9, else a rare one, uniform
+    within each kind — or, with probability ``_SYN_FOLLOW``, the fixed
+    successor of the id before it.  LEARNABLE: the common ids' prior is a
+    nat and a half under ln(vocab), and half the targets are a function of
+    the token before.  No id carries more than 0.9 / (vocab / 8) of the
+    tokens, so what a batch does to the model — the load of a routed expert,
+    say — is an average over thousands of ids and does not hang on which few
+    a seed drew (the MLM corpus's 64-id pool would).  ``targets`` are the
+    tokens shifted by one; the last position has none and weighs 0."""
+    common, rare, successor = _syn_rule(vocab)
+    fresh = np.where(rng.rand(batch, seq) < _SYN_COMMON[1],
+                     common[rng.randint(0, len(common), size=(batch, seq))],
+                     rare[rng.randint(0, len(rare), size=(batch, seq))])
+    follow = rng.rand(batch, seq) < _SYN_FOLLOW
+    tokens = fresh.astype(np.int32)
+    for t in range(1, seq):
+        tokens[:, t] = np.where(follow[:, t], successor[tokens[:, t - 1]],
+                                tokens[:, t])
+    targets = np.roll(tokens, -1, axis=1)
+    weights = np.ones((batch, seq), np.float32)
+    weights[:, -1] = 0.0
+    return tokens, targets, weights
+
+
 def _mask_mlm(tokens, seed, step_idx):
     """MLM masking pure in ``(seed, step)`` — applied to REAL token
     shards so resume/rollback replay the exact masked batch for any
@@ -183,9 +238,10 @@ def run_standard(args, cfg, mesh):
     runs them on its shard.)"""
     from jax import shard_map
     from apex_tpu.parallel import DistributedDataParallel
-    moe = isinstance(cfg, MoETransformerConfig)
-    init_fn = moe_transformer_init if moe else transformer_init
-    loss_impl = moe_transformer_loss if moe else transformer_loss
+    init_fn, loss_impl = {
+        MoETransformerConfig: (moe_transformer_init, moe_transformer_loss),
+        Lfm2Config: (lfm2_init, lfm2_loss),
+    }.get(type(cfg), (transformer_init, transformer_loss))
     params = jax.jit(
         lambda: init_fn(jax.random.PRNGKey(args.seed), cfg))()
     opt = FusedLAMB(lr=args.lr, weight_decay=0.01, max_grad_norm=1.0,
@@ -343,6 +399,17 @@ def run_plan(args, cfg):
     return losses.val
 
 
+def lfm2_config(args):
+    """``--lfm2 DENSE PERIODS HELD``: the published widths, the cut's depth,
+    experts and ``--vocab``."""
+    dense, periods, held = args.lfm2
+    return lfm2_24b_a2b_config(
+        vocab_size=args.vocab, num_dense_layers=dense,
+        layer_types=lfm2_cut_layer_types(dense, periods),
+        experts_held=(0, held), dtype=jnp.bfloat16, remat=args.remat,
+        attn_impl=args.attn)
+
+
 def main(argv=None, report=None):
     """Train; returns the last printed loss.  ``report``, a dict the
     caller owns, is filled (standard and ``--zero`` paths) with what a
@@ -353,6 +420,10 @@ def main(argv=None, report=None):
     args = parse_args(argv)
     if args.moe and (args.bert_large or args.zero):
         raise SystemExit("--moe combines with the standard path only")
+    if args.lfm2 and (args.bert_large or args.moe or args.zero or args.plan
+                      or args.data):
+        raise SystemExit("--lfm2 is a model preset of the standard path on "
+                         "synthetic next-token batches")
     if args.plan and (args.moe or args.zero or args.distributed
                       or args.auto_resume):
         raise SystemExit("--plan owns the parallelism decision — it does "
@@ -361,6 +432,8 @@ def main(argv=None, report=None):
     if args.bert_large:
         cfg = bert_large_config(dtype=jnp.bfloat16, remat=args.remat,
                                 attn_impl=args.attn)
+    elif args.lfm2:
+        cfg = lfm2_config(args)
     elif args.moe:
         cfg = MoETransformerConfig(
             vocab_size=args.vocab, max_len=args.seq_len,
@@ -381,11 +454,14 @@ def main(argv=None, report=None):
         raise ValueError(f"batch {args.batch_size} must divide {n_dev}")
     mesh = create_mesh({"data": n_dev}, devices=jax.devices()[:n_dev])
     print(f"=> {n_dev} device(s), {'ZeRO' if args.zero else 'standard'} "
-          f"optimizer, layers={cfg.num_layers} d={cfg.d_model} "
+          f"optimizer, layers="
+          f"{cfg.num_hidden_layers if args.lfm2 else cfg.num_layers} d="
+          f"{cfg.hidden_size if args.lfm2 else cfg.d_model} "
           f"seq={args.seq_len}")
 
     rng = np.random.RandomState(args.seed)
     losses, tput = AverageMeter("mlm_loss"), Throughput()
+    synthetic = synthetic_next_token if args.lfm2 else synthetic_mlm
 
     if args.auto_resume:
         if args.zero:
@@ -406,7 +482,7 @@ def main(argv=None, report=None):
                 # path below cannot be re-entered mid-stream)
                 rs = np.random.RandomState(
                     (args.seed * 1000003 + step_idx) % (2 ** 31 - 1))
-                tokens, targets, weights = synthetic_mlm(
+                tokens, targets, weights = synthetic(
                     rs, args.batch_size, args.seq_len, cfg.vocab_size)
                 return {"tokens": tokens, "targets": targets,
                         "weights": weights}
@@ -445,7 +521,7 @@ def main(argv=None, report=None):
             if data_it is not None:
                 batch = next(data_it)      # prefetched shard-addressed
             else:
-                tokens, targets, weights = synthetic_mlm(
+                tokens, targets, weights = synthetic(
                     rng, args.batch_size, args.seq_len, cfg.vocab_size)
                 batch = {"tokens": tokens, "targets": targets,
                          "weights": weights}

@@ -1,0 +1,335 @@
+"""LFM2-MoE (``apex_tpu.models.lfm2`` over ``parallel.expert.routed_experts``)
+against its plain float32 reference (``benchmarks/reference/lfm2_24b_a2b.py``)
+on seeded random weights at a small size: d 64, 2 key/value and 8 query heads
+of 8, 16 experts top-4 of which 4 are held, expert width 32, one dense layer
+and one period.  The expert bias is random and NON-ZERO everywhere, so that
+"chooses but does not weigh" is part of every comparison.
+"""
+import dataclasses
+import functools
+import importlib.util
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, PartitionSpec as P
+
+from apex_tpu import telemetry
+from apex_tpu.models import (Lfm2Config, lfm2_24b_a2b_config,
+                             lfm2_cut_layer_types, lfm2_init, lfm2_loss,
+                             lfm2_routing)
+from apex_tpu.models import lfm2 as lfm2_module
+from apex_tpu.parallel import create_mesh, use_mesh
+from apex_tpu.parallel.expert import route_top_k, routed_experts
+from apex_tpu.telemetry import events
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+def _load(rel_path, name):
+    spec = importlib.util.spec_from_file_location(
+        name, os.path.join(ROOT, rel_path))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+reference = _load("benchmarks/reference/lfm2_24b_a2b.py", "lfm2_reference")
+
+CFG = Lfm2Config(vocab_size=256, hidden_size=64, intermediate_size=160,
+                 moe_intermediate_size=32, num_experts=16,
+                 num_experts_per_tok=4, num_dense_layers=1,
+                 layer_types=lfm2_cut_layer_types(1, 1),
+                 num_attention_heads=8, num_key_value_heads=2,
+                 experts_held=(4, 4), xent_impl="xla")
+SEQ = 37        # no multiple of any flash block
+
+
+def _model(cfg):
+    """The configuration as the reference reads it: a plain dict."""
+    return {f.name: getattr(cfg, f.name) for f in dataclasses.fields(cfg)}
+
+
+def _params(cfg, seed=0):
+    params = lfm2_init(jax.random.PRNGKey(seed), cfg)
+    key = jax.random.PRNGKey(seed + 100)
+    for layer in params["layers"]:
+        if "expert_bias" in layer:
+            key, k = jax.random.split(key)
+            layer["expert_bias"] = 0.3 * jax.random.normal(
+                k, layer["expert_bias"].shape)
+    return params
+
+
+def _batch(cfg, batch=2, seq=SEQ, seed=0):
+    tokens = np.random.RandomState(seed).randint(
+        0, cfg.vocab_size, size=(batch, seq)).astype(np.int32)
+    weights = np.ones((batch, seq), np.float32)
+    weights[:, -1] = 0.0
+    return {"tokens": jnp.asarray(tokens),
+            "targets": jnp.asarray(np.roll(tokens, -1, axis=1)),
+            "weights": jnp.asarray(weights)}
+
+
+@pytest.fixture(autouse=True)
+def _float32_products():
+    with jax.default_matmul_precision("highest"):
+        yield
+
+
+@pytest.mark.parametrize("attn", ["default", "fast"])
+def test_loss_and_every_gradient_leaf_match_the_reference(attn):
+    cfg = dataclasses.replace(CFG, attn_impl=attn)
+    params, batch = _params(cfg), _batch(cfg)
+    loss, grads = jax.value_and_grad(lfm2_loss)(params, batch, cfg)
+    want, want_grads = jax.value_and_grad(reference.loss)(
+        params, batch, _model(cfg))
+    assert float(loss) == pytest.approx(float(want), rel=1e-5)
+    got = dict(jax.tree_util.tree_leaves_with_path(grads))
+    for path, leaf in jax.tree_util.tree_leaves_with_path(want_grads):
+        name = jax.tree_util.keystr(path)
+        if "expert_bias" in name:
+            # a buffer: no gradient reaches it, on either side
+            assert not np.any(got[path]) and not np.any(leaf), name
+            continue
+        assert np.any(leaf), name
+        np.testing.assert_allclose(
+            got[path], leaf, rtol=2e-3,
+            atol=2e-5 * float(jnp.max(jnp.abs(leaf))), err_msg=name)
+
+
+def test_remat_changes_nothing():
+    params, batch = _params(CFG), _batch(CFG)
+    plain = jax.grad(lfm2_loss)(params, batch, CFG)
+    remat = jax.grad(lfm2_loss)(params, batch,
+                                dataclasses.replace(CFG, remat=True))
+    for a, b in zip(jax.tree_util.tree_leaves(plain),
+                    jax.tree_util.tree_leaves(remat)):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-7)
+
+
+def _expert_layer(cfg, seed=3, tokens=50):
+    """One expert layer's parameters with ALL experts, and its input."""
+    whole = dataclasses.replace(cfg, experts_held=(0, cfg.num_experts))
+    layer = _params(whole, seed)["layers"][-1]
+    h = jax.random.normal(jax.random.PRNGKey(seed), (tokens, cfg.hidden_size))
+    return whole, layer, h
+
+
+def _share(layer, first, count):
+    return dict(layer, w13=layer["w13"][first:first + count],
+                w2=layer["w2"][first:first + count])
+
+
+def _routed(h, layer, first, **kw):
+    return routed_experts(h, layer["router"], layer["expert_bias"],
+                          layer["w13"], layer["w2"],
+                          top_k=CFG.num_experts_per_tok, first=first, **kw)
+
+
+def test_the_shares_sum_to_the_uncut_references_whole_layer():
+    whole, layer, h = _expert_layer(CFG)
+    want, _ = reference._expert_ffn(h, layer, _model(whole))
+    parts, rows = 0.0, 0
+    for first in range(0, 16, 4):
+        out, routing = _routed(h, _share(layer, first, 4), first,
+                               axis_name=None)
+        model = _model(dataclasses.replace(CFG, experts_held=(first, 4)))
+        np.testing.assert_allclose(
+            out, reference._expert_ffn(h, _share(layer, first, 4), model)[0],
+            rtol=1e-4, atol=1e-6)
+        parts, rows = parts + out, rows + int(routing["rows"].sum())
+    np.testing.assert_allclose(parts, want, rtol=1e-4, atol=1e-6)
+    assert rows == h.shape[0] * CFG.num_experts_per_tok
+
+
+def test_bound_to_an_axis_the_devices_hold_the_shares_and_sum_them():
+    whole, layer, h = _expert_layer(CFG)
+    want, _ = reference._expert_ffn(h, layer, _model(whole))
+    mesh = Mesh(np.array(jax.devices()[:4]), ("expert",))
+    shared = {k: P() for k in layer}
+    shared.update(w13=P("expert"), w2=P("expert"))
+
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=(P(), shared),
+                       out_specs=(P(), P("expert")), check_vma=False)
+    def layer_over_the_axis(h, layer):
+        out, routing = _routed(h, layer, 0, axis_name="expert")
+        return out, routing["rows"]
+
+    out, rows = layer_over_the_axis(h, layer)
+    np.testing.assert_allclose(out, want, rtol=1e-4, atol=1e-6)
+    assert int(rows.sum()) == h.shape[0] * CFG.num_experts_per_tok
+
+
+@pytest.mark.parametrize("favourite", [0, 3])
+def test_no_assignment_is_dropped_at_total_imbalance(favourite):
+    """Every token's four choices are the four held experts, so each of them
+    takes a row from EVERY token: T·4 rows held, none dropped, and the
+    result is the reference's.  The bias alone does it (the router's scores
+    are whatever they are), so it also shows the bias choosing."""
+    _, layer, h = _expert_layer(CFG, tokens=64)
+    first = 4
+    bias = jnp.full((16,), -5.0).at[first:first + 4].set(5.0)
+    bias = bias.at[first + favourite].add(1.0)
+    layer = dict(_share(layer, first, 4), expert_bias=bias)
+    out, routing = _routed(h, layer, first, axis_name=None)
+    assert routing["rows"].tolist() == [64, 64, 64, 64]
+    assert int(routing["dropped"]) == 0
+    assert sorted(np.unique(routing["ids"]).tolist()) == [4, 5, 6, 7]
+    model = _model(dataclasses.replace(CFG, experts_held=(first, 4)))
+    np.testing.assert_allclose(
+        out, reference._expert_ffn(h, layer, model)[0], rtol=1e-4, atol=1e-6)
+
+
+def test_the_bias_chooses_and_does_not_weigh():
+    _, layer, h = _expert_layer(CFG)
+    ids, weights = route_top_k(h, layer["router"], layer["expert_bias"], 4)
+    scores = jax.nn.sigmoid(h @ layer["router"])
+    picked = jnp.take_along_axis(scores, ids, axis=-1)
+    np.testing.assert_allclose(
+        weights, picked / (picked.sum(-1, keepdims=True) + 1e-6), rtol=1e-5)
+    # another bias, other experts; the same bias everywhere, the same experts
+    other, _ = route_top_k(h, layer["router"], -layer["expert_bias"], 4)
+    assert np.any(np.sort(ids, -1) != np.sort(other, -1))
+    same, w = route_top_k(h, layer["router"], layer["expert_bias"] + 7.0, 4)
+    assert np.array_equal(ids, same) and np.allclose(w, weights)
+    # and no gradient reaches it
+    g = jax.grad(lambda b: _routed(h, dict(layer, expert_bias=b), 0,
+                                   axis_name=None)[0].sum())(
+        layer["expert_bias"])
+    assert not np.any(g)
+
+
+def test_conv_mixer_is_causal_and_is_the_references():
+    layer = _params(CFG)["layers"][0]
+    u = jax.random.normal(jax.random.PRNGKey(1), (2, SEQ, 64))
+    out = lfm2_module._conv_mixer(u, layer, CFG)
+    assert CFG.conv_L_cache == 3
+    np.testing.assert_allclose(
+        out, reference._conv_mixer(u, layer, _model(CFG)), rtol=1e-5,
+        atol=1e-6)
+    t = 20
+    later = u.at[:, t + 1:].set(jax.random.normal(
+        jax.random.PRNGKey(2), (2, SEQ - t - 1, 64)))
+    changed = lfm2_module._conv_mixer(later, layer, CFG)
+    np.testing.assert_array_equal(out[:, :t + 1], changed[:, :t + 1])
+    assert np.any(out[:, t + 1:] != changed[:, t + 1:])
+
+
+@pytest.mark.parametrize("attn", ["default", "fast"])
+def test_grouped_query_attention_with_qk_norm_and_rope_is_the_references(attn):
+    cfg = dataclasses.replace(CFG, attn_impl=attn)
+    layer = dict(_params(cfg)["layers"][1])
+    # gains that are not 1, so the per-head norm's gain is in the comparison
+    layer["q_norm"] = 1.0 + 0.1 * jnp.arange(8.0)
+    layer["k_norm"] = 1.5 - 0.1 * jnp.arange(8.0)
+    u = jax.random.normal(jax.random.PRNGKey(1), (2, SEQ, 64))
+    np.testing.assert_allclose(
+        lfm2_module._attention_mixer(u, layer, cfg),
+        reference._attention_mixer(u, layer, _model(cfg)), rtol=2e-4,
+        atol=2e-5)
+
+
+def test_the_routing_meter_counts_once_a_forward_pass_under_remat():
+    cfg = dataclasses.replace(CFG, remat=True)
+    params, batch = _params(cfg), _batch(cfg)
+    registry = telemetry.Registry(enabled=True, memory=False)
+    previous = events.set_default(registry)
+    try:
+        before = len(events.expert_rows())
+        jax.block_until_ready(jax.jit(jax.grad(
+            lambda p: lfm2_loss(p, batch, cfg)))(params))
+        jax.effects_barrier()
+        assert len(events.expert_rows()) == before + 1
+        rows = events.expert_rows()[-1]
+    finally:
+        events.set_default(previous)
+    routing = lfm2_routing(params, batch["tokens"], cfg)
+    np.testing.assert_array_equal(rows, routing["rows"])
+    assert rows.shape == (4, 4) and not np.any(routing["dropped"])
+    layouts = [e["fields"] for e in registry._events
+               if e["name"] == "moe.layout"]
+    assert {"experts": 16, "held": 4, "top_k": 4,
+            "buffer_rows": 2 * SEQ * 4} in layouts
+    # off, the step holds no callback at all
+    text = jax.jit(lambda p: lfm2_loss(p, batch, cfg)).lower(params).as_text()
+    assert "callback" not in text
+
+
+def test_preset_is_the_published_configuration():
+    with open(os.path.join(ROOT, "benchmarks", "configs",
+                           "lfm2_24b_a2b.json")) as f:
+        doc = json.load(f)
+    cfg = lfm2_24b_a2b_config()
+    published = dict(doc, **doc["published"])
+    for key in ("hidden_size", "intermediate_size", "moe_intermediate_size",
+                "num_experts", "num_experts_per_tok", "num_dense_layers",
+                "num_attention_heads", "num_key_value_heads", "conv_L_cache",
+                "norm_eps", "norm_topk_prob", "use_expert_bias",
+                "routed_scaling_factor", "vocab_size", "num_hidden_layers"):
+        assert getattr(cfg, key) == published[key], key
+    assert cfg.rope_theta == doc["rope_parameters"]["rope_theta"]
+    assert cfg.head_dim == 64
+    assert cfg.layer_types[:6] == ("conv", "conv", "full_attention", "conv",
+                                   "conv", "conv")
+    assert cfg.layer_types[-2:] == ("full_attention", "conv")
+    assert cfg.layer_types.count("full_attention") == 10
+    # the cut the configuration runs is what the example's flag builds
+    pretrain = _load("examples/bert/pretrain.py", "pretrain_for_lfm2")
+    cut = pretrain.lfm2_config(pretrain.parse_args(doc["entry"]["argv"]))
+    for key, value in doc["model"].items():
+        got = getattr(cut, key)
+        assert (list(got) if isinstance(got, tuple) else got) == value, key
+
+
+def test_the_whole_step_trains_through_the_example():
+    """``parse_args`` -> ``run_standard`` under O5 with FusedLAMB on the flat
+    engine, the path the benchmark drives: finite, falling, no step skipped."""
+    pretrain = _load("examples/bert/pretrain.py", "pretrain_for_lfm2_step")
+    args = pretrain.parse_args(["--lfm2", "1", "1", "4", "--vocab", "256",
+                                "--seq-len", "64", "--batch-size", "8",
+                                "--attn", "fast", "--remat"])
+    assert args.opt_level == "O5"
+    cfg = dataclasses.replace(
+        CFG, experts_held=(0, 4), dtype=jnp.bfloat16, remat=args.remat,
+        attn_impl=args.attn, xent_impl="auto")
+    mesh = create_mesh({"data": 1}, devices=jax.devices()[:1])
+    rng = np.random.RandomState(0)
+    steps, losses = 24, []
+    with use_mesh(mesh):
+        state, step = pretrain.run_standard(args, cfg, mesh)
+        for _ in range(steps):
+            tokens, targets, weights = pretrain.synthetic_next_token(
+                rng, args.batch_size, args.seq_len, cfg.vocab_size)
+            state, loss = step(state, {"tokens": tokens, "targets": targets,
+                                       "weights": weights})
+            losses.append(float(loss))
+    assert np.all(np.isfinite(losses))
+    assert np.mean(losses[-6:]) < np.mean(losses[:6]) - 0.02, losses
+    assert step.optimizer_steps(state) == steps
+    # the bias stayed what it was: no gradient, and LAMB moves nothing at 0
+    for layer in state.model_params["layers"]:
+        if "expert_bias" in layer:
+            assert not np.any(layer["expert_bias"])
+
+
+def test_next_token_corpus_covers_the_vocabulary_and_follows_its_rule():
+    pretrain = _load("examples/bert/pretrain.py", "pretrain_for_lfm2_data")
+    tokens, targets, weights = pretrain.synthetic_next_token(
+        np.random.RandomState(0), 16, 512, 1024)
+    assert tokens.dtype == np.int32 and tokens.min() >= 0
+    assert len(np.unique(tokens)) > 600            # not a pool of 64
+    np.testing.assert_array_equal(targets[:, :-1], tokens[:, 1:])
+    assert not weights[:, -1].any() and weights[:, :-1].all()
+    common, rare, successor = pretrain._syn_rule(1024)
+    assert np.isin(common, tokens).all() and np.isin(rare, tokens).mean() > 0.4
+    followed = np.mean(successor[tokens[:, :-1]] == tokens[:, 1:])
+    assert 0.45 < followed < 0.56
+    assert 0.87 < np.isin(tokens, common).mean() < 0.93
+    # no id carries more than about its 0.9 / (vocab / 8) of the tokens
+    assert np.bincount(tokens.ravel(), minlength=1024).max() \
+        < 2 * 0.9 / 128 * tokens.size

@@ -20,8 +20,9 @@ import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
 from apex_tpu import amp, pyprof
-from apex_tpu.models import (TransformerConfig, transformer_init,
-                             transformer_loss)
+from apex_tpu.models import (Lfm2Config, TransformerConfig,
+                             lfm2_cut_layer_types, lfm2_init, lfm2_loss,
+                             transformer_init, transformer_loss)
 from apex_tpu.optimizers import FusedLAMB
 from apex_tpu.parallel import DistributedDataParallel
 
@@ -30,13 +31,23 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 CFG = TransformerConfig(vocab_size=256, max_len=128, num_layers=2,
                         d_model=64, num_heads=2, d_ff=128,
                         dtype=jnp.bfloat16, remat=True, attn_impl="fast")
+# the other model of the benchmark: one dense layer and one period of LFM2
+LFM2 = Lfm2Config(vocab_size=256, hidden_size=64, intermediate_size=160,
+                  moe_intermediate_size=32, num_experts=16,
+                  num_experts_per_tok=4, num_dense_layers=1,
+                  layer_types=lfm2_cut_layer_types(1, 1),
+                  num_attention_heads=8, num_key_value_heads=2,
+                  experts_held=(0, 4), dtype=jnp.bfloat16, remat=True,
+                  attn_impl="fast")
+#: the blocks only the LFM2 step enters
+LFM2_ONLY = ("apex.conv", "apex.moe", "apex.router", "apex.experts")
 # "%name = <type, maybe a tuple> opcode(operands), ..., metadata={op_name=..."
 _INSTRUCTION = re.compile(
     r' = .*? ([a-z][\w-]*)\(.*metadata=\{[^}]*op_name="([^"]*)"')
 
 
-def _state_and_batch(batch):
-    params = transformer_init(jax.random.PRNGKey(0), CFG)
+def _state_and_batch(batch, init=transformer_init, cfg=CFG):
+    params = init(jax.random.PRNGKey(0), cfg)
     opt = FusedLAMB(lr=1e-3, weight_decay=0.01, max_grad_norm=1.0,
                     impl="fused")
     state = amp.initialize(params, opt, opt_level="O5", verbosity=0)
@@ -45,9 +56,9 @@ def _state_and_batch(batch):
                    "weights": jnp.ones((batch, 128), jnp.float32)}
 
 
-def _step(state, batch, ddp=None):
+def _step(state, batch, ddp=None, loss_impl=transformer_loss, cfg=CFG):
     def loss_fn(p):
-        loss = transformer_loss(p, batch, CFG)
+        loss = loss_impl(p, batch, cfg)
         return amp.scale_loss(loss, state), loss
     g, loss = jax.grad(loss_fn, has_aux=True)(state.model_params)
     if ddp is not None:
@@ -77,24 +88,41 @@ def ddp_step_ops():
     return _op_names(step, *_state_and_batch(4))
 
 
+@pytest.fixture(scope="module")
+def lfm2_step_ops():
+    return _op_names(
+        functools.partial(_step, loss_impl=lfm2_loss, cfg=LFM2),
+        *_state_and_batch(2, lfm2_init, LFM2))
+
+
 def test_scopes_are_a_fixed_vocabulary():
     assert len(set(pyprof.SCOPES)) == len(pyprof.SCOPES)
     for name in pyprof.SCOPES:
         assert re.fullmatch(r"apex\.[a-z_]+", name), name
 
 
-def test_every_matmul_belongs_to_a_block(step_ops):
-    matmuls = [path for opcode, path in step_ops
-               if opcode in ("dot", "convolution")]
+@pytest.mark.parametrize("ops", ["step_ops", "lfm2_step_ops"])
+def test_every_matmul_belongs_to_a_block(ops, request):
+    matmuls = [path for opcode, path in request.getfixturevalue(ops)
+               if opcode in ("dot", "convolution", "ragged-dot")]
     assert len(matmuls) >= 10
     for path in matmuls:
         assert any(name in path for name in pyprof.SCOPES), path
 
 
 @pytest.mark.parametrize("name", pyprof.SCOPES)
-def test_scope_occurs_in_the_step(name, step_ops, ddp_step_ops):
+def test_scope_occurs_in_the_step(name, step_ops, ddp_step_ops,
+                                  lfm2_step_ops):
     one_chip = {path for _, path in step_ops if name in path}
-    if name == "apex.ddp_allreduce":
+    if name in LFM2_ONLY:
+        # the BERT step has no such block; the LFM2 step enters it forward,
+        # backward and in remat's second forward
+        assert not one_chip
+        paths = [path for _, path in lfm2_step_ops if name in path]
+        assert any("transpose(" in path for path in paths), name
+        assert any("rematted_computation" in path for path in paths), name
+        assert any("transpose(" not in path for path in paths), name
+    elif name == "apex.ddp_allreduce":
         # only a step that reduces has it, and the collective lies under it
         assert not one_chip
         reduced = [(op, path) for op, path in ddp_step_ops if name in path]
@@ -119,6 +147,23 @@ def test_flash_nests_inside_attention(step_ops):
     assert inside
     for path in inside:
         assert path.index("apex.attn") < path.index("apex.flash"), path
+
+
+@pytest.mark.parametrize("inner", ["apex.router", "apex.experts"])
+def test_router_and_experts_nest_inside_moe(inner, lfm2_step_ops):
+    inside = [path for _, path in lfm2_step_ops if inner in path]
+    assert inside
+    for path in inside:
+        assert path.index("apex.moe") < path.index(inner), path
+
+
+@pytest.mark.parametrize("name", ["apex.embed", "apex.attn", "apex.flash",
+                                  "apex.mlp", "apex.head", "apex.loss",
+                                  "apex.amp_step"])
+def test_lfm2_step_reuses_the_shared_blocks(name, lfm2_step_ops):
+    """The dense gated FFN is ``apex.mlp``; attention, embedding, head, loss
+    and the update are the blocks the BERT step has."""
+    assert any(name in path for _, path in lfm2_step_ops), name
 
 
 def test_update_blocks_nest_inside_amp_step(step_ops):
