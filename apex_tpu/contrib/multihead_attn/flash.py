@@ -18,18 +18,25 @@ Semantics parity with the CUDA kernels:
     full (1|B, Sq, Sk) score masks; ``causal`` covers the time-mask path.
 
 forward  : out, lse   (lse = log-sum-exp per query row, the saved residual)
-backward : recompute-based (flash bwd).  One algorithm whose tile is a
-    function of the shape the call has (``_whole_key_blocks``): P and the
-    dropout mask are recomputed ONCE per grid step and feed dq, dk and dv.
-      - whole_key: where the keys of a head fit in VMEM one step covers all
-        of them (nk = 1: S 512 at D 64 runs ONE 512x512 tile a head), so
-        the step's ``ds @ k`` IS that q block's dq and the kernel writes it
-        itself, in ``q.dtype``;
-      - partials: where they do not (long sequences) the grid is
-        (BH, nk, nq) over 128x128 tiles and dq leaves the kernel as
-        per-k-block f32 partials (BH, nk, Sq, D) summed by XLA (the splash-
-        attention fused-backward layout) — O(Sk/bk * Sq) per batch-head, so
-        only under a byte cap (``_resolve_fuse``);
+backward : recompute-based (flash bwd).  One algorithm whose tile and
+    residency are functions of the shape the call has (``_flash_bwd`` asks
+    in this order): P and the dropout mask are recomputed ONCE per tile
+    and feed dq, dk and dv.
+      - whole_key: where the keys of a head fit ONE tile
+        (``_whole_key_blocks``; nk = 1: S 512 at D 64 runs ONE 512x512
+        tile a head) the step's ``ds @ k`` IS that q block's dq and the
+        kernel writes it itself, in ``q.dtype``;
+      - resident: where they do not, but a head's K, V and its f32 dk / dv
+        accumulators fit VMEM (``_resident_blocks``; S 4096 at D 64), one
+        kernel keeps them there for the head's q sweep and walks the keys
+        in pieces: dq accumulates over the pieces and leaves once a q
+        tile, dk / dv once a head — nothing partial goes to HBM, and a
+        causal walk stops at the diagonal;
+      - partials: beyond that the grid is (BH, nk, nq) over 128x128 tiles
+        and dq leaves the kernel as per-k-block f32 partials
+        (BH, nk, Sq, D) summed by XLA (the splash-attention fused-backward
+        layout) — O(Sk/bk * Sq) per batch-head, so only under a byte cap
+        (``_resolve_fuse``);
       - split: above the cap (or forced), one kernel for dq (grid over q
         blocks) and one for dk/dv (grid over k blocks), each with its OWN
         tunable block sizes (their VMEM footprints differ; see
@@ -69,6 +76,17 @@ DEFAULT_BWD_BLOCK_K = 128
 # today's 128x128 paths take over.
 _WHOLE_KEY_MAX_BQ = 512
 _WHOLE_KEY_MIN_BQ = 128
+# Where a head's keys do not fit ONE tile they may still fit VMEM whole
+# (S 4096 x D 64 in bf16: K and V 512 KiB each): the resident kernel keeps
+# them there for the head and walks them in pieces of at most this many
+# keys, so a tile is again 512 x 512 and not a grid step each of 128 x 128.
+_RESIDENT_MAX_BK = 512
+# That kernel tells Mosaic what it needs (``vmem_limit_bytes``) instead of
+# living inside half the 16 MiB scoped default as the others do, so its
+# budget is this multiple of ``_vmem_budget()``: 32 MiB, a quarter of a v5e
+# core's VMEM.  The limit it states is the budget and a quarter more, for
+# what the model does not count (the dropout hash's tiles).
+_RESIDENT_VMEM_FACTOR = 4.0
 NEG_INF = -1e30
 
 # Fused-backward dq-partials buffer cap (HBM bytes): the fused kernel emits
@@ -159,24 +177,36 @@ def _whole_key_blocks(sq, sk, D, esz, bias_per_q, causal):
     return bq, bk
 
 
-def _clamp_blocks(bq, bk, D, esz, bias_per_q, bwd=False, sq=None, sk=None,
-                  causal=False):
-    """Shrink (bq, bk) until the kernel's per-step VMEM estimate fits the
-    budget.  ``bq``/``bk`` None means "default, overridable by env", and
-    only those are budget-clamped; explicit values (an autotune sweep, a
-    user who measured) are taken as-is so what runs is what was asked for —
-    a config that genuinely exceeds VMEM then fails loudly at compile.
-    ``sq``/``sk`` (the actual sequence lengths) cap the blocks BEFORE
-    estimating, so short sequences aren't shrunk below what fits anyway;
-    for ``bwd="fused"`` they (with ``causal``) also decide the built-in
-    end of the chain — :func:`_whole_key_blocks` where the keys fit, the
-    128x128 constants where they do not or where no shape is given.
-    ``bwd`` selects the footprint model AND the env/profile chain:
-    ``False`` (forward), ``"dq"`` / ``"dkv"`` / ``"fused"`` (the three
-    backward kernels — per-kernel keys, falling back to the shared bwd
-    keys), or ``True`` (legacy combined backward model, shared keys only).
-    Alignment floors: bk multiple of 128 (lane dim of the bias block), bq
-    multiple of 8 (sublane)."""
+def _resident_budget() -> float:
+    return _RESIDENT_VMEM_FACTOR * _vmem_budget()
+
+
+def _resident_blocks(sq, sk, D, esz, bias_per_q):
+    """The resident backward's tile, from the shape the call has: ``(bq,
+    bk)`` where a head's K, V and their f32 dk / dv accumulators fit VMEM
+    beside a tile of at least ``_WHOLE_KEY_MIN_BQ`` q rows by ``bk`` keys,
+    or None where they do not (very long sequences, or a per-query bias
+    whose (bq, Sk) block is too large: the 128x128 paths run there).  The
+    q rows shrink first, as in :func:`_whole_key_blocks`, then the piece."""
+    budget = _resident_budget()
+    top = min(_WHOLE_KEY_MAX_BQ, max(8, -(-sq // 8) * 8))
+    floor = min(top, _WHOLE_KEY_MIN_BQ)
+    bk = min(_RESIDENT_MAX_BK, max(128, -(-sk // 128) * 128))
+    while bk >= 128:
+        bq = top
+        while bq >= floor:
+            if vmem_estimate(bq, bk, D, esz, bias_per_q, "resident",
+                             sk=sk) <= budget:
+                return bq, bk
+            bq = (bq // 2 // 8) * 8
+        bk = (bk // 2 // 128) * 128
+    return None
+
+
+def _chosen_blocks(bq, bk, bwd):
+    """``(bq, bq_pinned, bk, bk_pinned)`` as somebody CHOSE them for the
+    kernel ``bwd`` names — argument > env pin > tuning profile — with None
+    where nobody did and the built-in end of the chain decides."""
     import os
     # the backward kernels have their own optimum (the r5 on-chip sweep
     # measures them separately — fwd blocks that stream k/v differ from
@@ -228,6 +258,28 @@ def _clamp_blocks(bq, bk, D, esz, bias_per_q, bwd=False, sq=None, sk=None,
         bq, bq_pinned = _pick(chains_q)
     if bk is None:
         bk, bk_pinned = _pick(chains_k)
+    return bq, bq_pinned, bk, bk_pinned
+
+
+def _clamp_blocks(bq, bk, D, esz, bias_per_q, bwd=False, sq=None, sk=None,
+                  causal=False):
+    """Shrink (bq, bk) until the kernel's per-step VMEM estimate fits the
+    budget.  ``bq``/``bk`` None means "default, overridable by env", and
+    only those are budget-clamped; explicit values (an autotune sweep, a
+    user who measured) are taken as-is so what runs is what was asked for —
+    a config that genuinely exceeds VMEM then fails loudly at compile.
+    ``sq``/``sk`` (the actual sequence lengths) cap the blocks BEFORE
+    estimating, so short sequences aren't shrunk below what fits anyway;
+    for ``bwd="fused"`` they (with ``causal``) also decide the built-in
+    end of the chain — :func:`_whole_key_blocks` where the keys fit, the
+    128x128 constants where they do not or where no shape is given.
+    ``bwd`` selects the footprint model AND the env/profile chain:
+    ``False`` (forward), ``"dq"`` / ``"dkv"`` / ``"fused"`` (the three
+    backward kernels — per-kernel keys, falling back to the shared bwd
+    keys), or ``True`` (legacy combined backward model, shared keys only).
+    Alignment floors: bk multiple of 128 (lane dim of the bias block), bq
+    multiple of 8 (sublane)."""
+    bq, bq_pinned, bk, bk_pinned = _chosen_blocks(bq, bk, bwd)
     if (bq is None and bk is None and bwd == "fused"
             and sq is not None and sk is not None):
         whole = _whole_key_blocks(sq, sk, D, esz, bias_per_q, causal)
@@ -252,7 +304,7 @@ def _clamp_blocks(bq, bk, D, esz, bias_per_q, bwd=False, sq=None, sk=None,
     return max(8, (bq // 8) * 8), max(128, (bk // 128) * 128)
 
 
-def vmem_estimate(bq, bk, D, esz, bias_per_q, bwd=False) -> int:
+def vmem_estimate(bq, bk, D, esz, bias_per_q, bwd=False, sk=None) -> int:
     """Per-grid-step VMEM footprint model (bytes) behind ``_clamp_blocks``.
 
     ``bwd``: ``False`` forward; ``"dq"`` / ``"dkv"`` / ``"fused"`` model the
@@ -262,7 +314,16 @@ def vmem_estimate(bq, bk, D, esz, bias_per_q, bwd=False) -> int:
     as the f32 partial — the whole-key kernel's ``q.dtype`` block is
     smaller) — their footprints genuinely differ, which is why their block
     sizes tune independently.  ``True`` keeps the legacy combined model (a
-    superset of dq+dkv, used by the shared-chain callers).
+    superset of dq+dkv, used by the shared-chain callers).  ``"resident"``
+    models the kernel that holds a head's ``sk`` keys (rounded up to whole
+    pieces of ``bk``) for all of its q tiles: K, V, the dk / dv output
+    blocks (each double-buffered like any block) and the f32 dk / dv
+    accumulators are counted at ``sk`` rows of whole 128-lane vregs (what
+    Mosaic allocates: at S 4096 x D 64 the three accumulators are 4.25 MiB
+    by its own report, and the kernel's whole need came to 16.2 MiB at
+    512 x 512 with dropout, 25.0 at S 8192 x 256 x 512, where this model
+    says 18.25 and 27.5; PERF.md section 6, PR 28), the bias block at
+    ``sk`` columns, the tile at (bq, bk).
 
     Every backward model counts what dominates a large tile: the (bq, bk)
     f32 intermediates of the recompute (``s``/``p``, ``dp``, ``ds``) and
@@ -278,6 +339,14 @@ def vmem_estimate(bq, bk, D, esz, bias_per_q, bwd=False) -> int:
     scratch = bq * (2 + D) * 4 + bq * 4
     tile = bq * bk * (3 * 4 + 2 * esz)              # s|p, dp, ds + MXU copies
     columns = 2 * bq * 128 * 4                      # lse, delta (lane-padded)
+    if bwd == "resident":
+        keys = -(-sk // bk) * bk
+        lanes = -(-D // 128) * 128      # a VMEM row of D 64 fills 128 lanes
+        # q, do, dq tiles; K, V and the dk, dv blocks of the whole head
+        io = (3 * bq + 4 * keys) * lanes * esz + columns
+        bias = max(8, bq if bias_per_q else 1) * keys * 4   # sublane-padded
+        scratch = (2 * keys + bq) * lanes * 4           # dk, dv, dq accumulators
+        return 2 * (io + bias) + scratch + tile
     if bwd in ("dq", "dkv", "fused"):
         # streams common to every backward kernel: q, k, v, do, lse, delta
         io = (2 * bq * D + 2 * bk * D) * esz + columns
@@ -672,6 +741,85 @@ def _bwd_fused_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref,
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
+def _bwd_resident_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref,
+                         lse_ref, delta_ref, dq_ref, dk_ref, dv_ref, dq_acc,
+                         dk_acc, dv_acc, *, bq, bk, causal, dropout_rate,
+                         heads):
+    """All three gradients of a head whose keys do not fit one tile but do
+    fit VMEM.  Grid (BH, nq): K, V, the dk / dv output blocks and their f32
+    accumulators stay for the head's whole q sweep; a step takes one q tile
+    and walks the keys in pieces of ``bk``.  P (and the dropout mask) is
+    rebuilt ONCE a (q tile, piece) and feeds dq, dk and dv, as in
+    :func:`_bwd_fused_kernel`; dq accumulates in f32 over the pieces and
+    leaves once a step in ``q.dtype``, dk / dv once a head.  Causal: the
+    walk stops at the diagonal (nothing above it is computed, and with the
+    keys resident there is nothing to fetch), and only the pieces the
+    diagonal crosses pay for the mask."""
+    bh, qi = pl.program_id(0), pl.program_id(1)
+    nq = pl.num_programs(1)
+    pieces = k_ref.shape[1] // bk
+    row0 = qi * bq
+
+    @pl.when(qi == 0)
+    def _():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    dq_acc[:] = jnp.zeros_like(dq_acc)
+
+    def piece(kc, masked):
+        col0 = pl.multiple_of(kc * bk, bk)
+        at = pl.ds(col0, bk)
+        q, do = q_ref[0], do_ref[0]                           # (bq, d)
+        k, v = k_ref[0, at, :], v_ref[0, at, :]               # (bk, d)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        s = s + bias_ref[0, :, at].astype(jnp.float32)
+        if masked:
+            rows = row0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+            cols = col0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(cols <= rows, s, NEG_INF)
+        p = jnp.exp(s - lse_ref[0])                           # (bq, bk)
+        if dropout_rate > 0.0:
+            keep = _dropout_keep(seed_ref[0], bh, row0, col0, p.shape,
+                                 dropout_rate) / (1.0 - dropout_rate)
+            pd = p * keep
+        else:
+            pd = p
+        dv_acc[at, :] += jax.lax.dot_general(
+            pd.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        if dropout_rate > 0.0:
+            dp = dp * keep
+        ds = (p * (dp - delta_ref[0])).astype(q.dtype)        # (bq, bk)
+        dk_acc[at, :] += jax.lax.dot_general(
+            ds, q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dq_acc[:] += jax.lax.dot_general(
+            ds, k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    def walk(lo, hi, masked):
+        jax.lax.fori_loop(lo, hi, lambda kc, _: piece(kc, masked), None)
+
+    if causal:
+        # pieces wholly under the diagonal, then those it crosses; those
+        # wholly above it (first key past the tile's last row) never run
+        under = jnp.minimum(pieces, (row0 + 1) // bk)
+        walk(0, under, False)
+        walk(under, jnp.minimum(pieces, (row0 + bq - 1) // bk + 1), True)
+    else:
+        walk(0, pieces, False)
+    dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
+
+    @pl.when(qi == nq - 1)
+    def _():
+        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+
 def _pad_lse_delta(lse, delta, Sq):
     if Sq != delta.shape[1]:
         delta = jnp.pad(delta, ((0, 0), (0, Sq - delta.shape[1]), (0, 0)))
@@ -845,6 +993,61 @@ def _flash_bwd_fused(q, k, v, bias, causal, dropout_rate, seed, heads, lse,
     return dq[:, :orig_sq], dk[:, :orig_sk], dv[:, :orig_sk]
 
 
+def _flash_bwd_resident(q, k, v, bias, causal, dropout_rate, seed, heads,
+                        lse, delta, do, bq, bk):
+    """All three gradients from ONE kernel where nk > 1
+    (:func:`_bwd_resident_kernel`; the tile comes from
+    :func:`_resident_blocks`): grid (BH, nq), the head's padded K / V and
+    the whole-head dk / dv blocks indexed by the head alone, so they are
+    fetched and written once a head.  Nothing partial goes to HBM."""
+    q, k, v, bias, do, orig_sq, orig_sk = _pad_inputs(q, k, v, bias, do,
+                                                      bq=bq, bk=bk)
+    BH, Sq, D = q.shape
+    Sk = k.shape[1]
+    bq = min(bq, Sq)
+    bk = min(bk, Sk)
+    lse, delta = _pad_lse_delta(lse, delta, Sq)
+    seed_arr = jnp.reshape(jnp.asarray(seed, jnp.int32), (1,))
+    vma = _out_vma(q, k, v, bias, do, lse, delta)
+    b_bcast, q_bcast = bias.shape[0] == 1, bias.shape[1] == 1
+
+    def tile(width):
+        return pl.BlockSpec((1, bq, width), lambda bh, qi: (bh, qi, 0),
+                            memory_space=pltpu.VMEM)
+
+    head = pl.BlockSpec((1, Sk, D), lambda bh, qi: (bh, 0, 0),
+                        memory_space=pltpu.VMEM)
+    limit = int(1.25 * _resident_budget())
+
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(_bwd_resident_kernel, bq=bq, bk=bk, causal=causal,
+                          dropout_rate=dropout_rate, heads=heads),
+        grid=(BH, Sq // bq),
+        in_specs=[
+            pl.BlockSpec(memory_space=pltpu.SMEM),           # seed
+            tile(D), head, head,                             # q, k, v
+            pl.BlockSpec((1, 1 if q_bcast else bq, Sk),
+                         lambda bh, qi: (0 if b_bcast else bh // heads,
+                                         0 if q_bcast else qi, 0),
+                         memory_space=pltpu.VMEM),
+            tile(D), tile(1), tile(1),                       # do, lse, delta
+        ],
+        out_specs=[tile(D), head, head],
+        out_shape=[_sds((BH, Sq, D), q.dtype, vma),
+                   _sds((BH, Sk, D), k.dtype, vma),
+                   _sds((BH, Sk, D), v.dtype, vma)],
+        scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32),
+                        pltpu.VMEM((Sk, D), jnp.float32),
+                        pltpu.VMEM((Sk, D), jnp.float32)],
+        # heads are independent; a head's q tiles accumulate dk / dv
+        compiler_params=_compiler_params(("parallel", "arbitrary"),
+                                         vmem_limit_bytes=limit),
+        interpret=_interpret(),
+        name="apex_flash_bwd_fused",
+    )(seed_arr, q, k, v, bias, do, lse, delta)
+    return dq[:, :orig_sq], dk[:, :orig_sk], dv[:, :orig_sk]
+
+
 def _forced_fuse(fuse):
     """A fused-vs-split choice somebody MADE, else None: explicit argument
     > APEX_TPU_FLASH_BWD_FUSE env (0/1) > tuning profile
@@ -882,11 +1085,16 @@ def _flash_bwd(q, k, v, bias, causal, dropout_rate, seed, heads, out, lse,
                fuse=None):
     """Recompute-backward dispatcher: (dq, dk, dv).
 
-    Two observables choose among the three paths, no knob: does one tile
-    hold a head's keys (nk = 1 -> ``whole_key``: the fused kernel writes
-    dq itself, there is no partials buffer and no cap to consult), and,
-    where it does not, does the f32 partials buffer stay under
-    :func:`_resolve_fuse`'s cap (``partials``, else ``split``).
+    Three questions of the shape choose among the four paths, in this
+    order and with no knob: does ONE tile hold a head's keys (nk = 1 ->
+    ``whole_key``: the fused kernel writes dq itself); where it does not,
+    do a head's K, V and dk / dv accumulators fit VMEM beside a tile
+    (:func:`_resident_blocks` -> ``resident``: one kernel walks the keys in
+    pieces, nothing partial leaves it); and only then does the f32
+    partials buffer of the 128x128 grid stay under :func:`_resolve_fuse`'s
+    cap (``partials``, else ``split``).  A tile or a strategy somebody
+    CHOSE (argument, env pin, profile key) keeps its meaning: it names the
+    128x128 grid's kernels, so ``resident`` is not asked.
 
     ``bq``/``bk`` pin BOTH kernels (the legacy shared knob the autotune
     sweeps use); ``dq_blocks``/``dkv_blocks`` (each an optional (bq, bk)
@@ -905,9 +1113,21 @@ def _flash_bwd(q, k, v, bias, causal, dropout_rate, seed, heads, out, lse,
     f_bq, f_bk = _clamp_blocks(kv_bq, kv_bk, D, esz, per_q, bwd="fused",
                                sq=Sq, sk=Sk, causal=causal)
     nk = -(-Sk // f_bk)
+    forced = _forced_fuse(fuse)
     if nk == 1:
-        fuse = _forced_fuse(fuse) is not False
+        fuse = forced is not False
     else:
+        c_bq, _, c_bk, _ = _chosen_blocks(kv_bq, kv_bk, "fused")
+        resident = None
+        if forced is None and c_bq is None and c_bk is None:
+            resident = _resident_blocks(Sq, Sk, D, esz, per_q)
+        if resident is not None:
+            r_bq, r_bk = resident
+            _tel_events.record_flash_bwd("resident", r_bq, r_bk,
+                                         -(-Sk // r_bk))
+            return _flash_bwd_resident(q, k, v, bias, causal, dropout_rate,
+                                       seed, heads, lse, delta, do, r_bq,
+                                       r_bk)
         fuse = _resolve_fuse(fuse, BH, Sq, Sk, D, f_bk)
     if fuse:
         _tel_events.record_flash_bwd(

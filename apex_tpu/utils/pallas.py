@@ -10,10 +10,13 @@ def interpret_mode() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def compiler_params(dimension_semantics):
-    """Mosaic compiler params carrying the grid's dimension semantics."""
+def compiler_params(dimension_semantics, vmem_limit_bytes=None):
+    """Mosaic compiler params carrying the grid's dimension semantics and,
+    for a kernel that needs more than the scoped default, the VMEM it may
+    use."""
     return pltpu.CompilerParams(
-        dimension_semantics=tuple(dimension_semantics))
+        dimension_semantics=tuple(dimension_semantics),
+        vmem_limit_bytes=vmem_limit_bytes)
 
 
 def to_varying(a, axes):

@@ -614,9 +614,59 @@ def bench_flash_vmem_probe(results, on_tpu):
                  f"est {rec['est_mb']}MB fits={rec['model_fits_16mb']} "
                  f"compiled={compiled}")
             gc.collect()
+    rows.update(_resident_vmem_rows())
     results["flash_vmem_probe"] = {
         "shape": f"S{S} D{D} esz2", "rows": rows,
         "all_agree": all(r["agrees"] for r in rows.values())}
+
+
+def _resident_vmem_rows():
+    """The resident backward's model (``vmem_estimate(..., "resident",
+    sk=)``) against Mosaic: that kernel states its need itself
+    (``vmem_limit_bytes`` = its budget and a quarter), so a row agrees
+    where the model says "fits the budget" and the kernel compiles under
+    the limit it states, or says "does not fit" where nothing is
+    promised.  Shapes only — nothing runs: the rule's own tiles at the
+    lengths it answers ``resident`` for (S 4096 is the LFM2 cell's), a
+    larger piece, and one a head too long."""
+    from apex_tpu.contrib.multihead_attn import flash as F
+    rows = {}
+    for S, D, dtype, per_q, tile in (
+            (4096, 64, jnp.bfloat16, False, None),
+            (4096, 64, jnp.bfloat16, False, (512, 1024)),
+            (4096, 64, jnp.float32, False, None),
+            (4096, 64, jnp.bfloat16, True, None),
+            (4096, 256, jnp.bfloat16, False, None),
+            (8192, 64, jnp.bfloat16, False, None),
+            (8192, 128, jnp.bfloat16, False, None),
+            (16384, 64, jnp.bfloat16, False, (256, 512))):
+        esz = jnp.dtype(dtype).itemsize
+        bq, bk = tile or F._resident_blocks(S, S, D, esz, per_q)
+        est = F.vmem_estimate(bq, bk, D, esz, per_q, "resident", sk=S)
+        BH = 64
+        x = jax.ShapeDtypeStruct((BH, S, D), dtype)
+        col = jax.ShapeDtypeStruct((BH, S, 1), jnp.float32)
+        bias = jax.ShapeDtypeStruct((1, S if per_q else 1, S), jnp.float32)
+        try:
+            jax.jit(lambda q, k, v, b, do, lse, delta: F._flash_bwd_resident(
+                q, k, v, b, True, 0.1, 0, 1, lse, delta, do, bq, bk)).lower(
+                    x, x, x, bias, x, col, col).compile()
+            compiled, err = True, None
+        except Exception as e:
+            compiled, err = False, repr(e)[:160]
+        fits = est <= F._resident_budget()
+        rec = {"est_mb": round(est / 2 ** 20, 2), "model_fits_budget": fits,
+               "budget_mb": F._resident_budget() / 2 ** 20,
+               "compiled": compiled, "agrees": compiled or not fits}
+        if err:
+            rec["error"] = err
+        name = (f"bwd_resident_S{S}_D{D}_{jnp.dtype(dtype).name}"
+                f"{'_perq' if per_q else ''}_{bq}x{bk}")
+        rows[name] = rec
+        _log(f"vmem_probe {name}: est {rec['est_mb']}MB fits={fits} "
+             f"compiled={compiled}")
+        gc.collect()
+    return rows
 
 
 def bench_xentropy(results, on_tpu):

@@ -249,7 +249,8 @@ def _kernel_checks(full: bool):
     checks = []
 
     # -- flash attention: forward; the backward as the shape's rule tiles
-    #    it (whole_key: one k block a head, dq finished in the kernel) and
+    #    it (whole_key: one k block a head, dq finished in the kernel;
+    #    resident at S 4096: the head's keys in VMEM, walked in pieces) and
     #    the three backwards forced apart --
     def flash_inputs(S, BH):
         D = 64
@@ -282,7 +283,8 @@ def _kernel_checks(full: bool):
             # (S 2048 must still compile, whichever path that is); split
             # (dq + dkv) forced; fused forced onto the 128x128 grid whose dq
             # leaves as f32 partials (nk > 1 wherever S > 128)
-            forced = {"whole_key": {}, "split": {"fuse": False},
+            forced = {"whole_key": {}, "resident": {},
+                      "split": {"fuse": False},
                       "fused": {"fuse": True, "bq": 128, "bk": 128}}[impl]
 
             def run(a, b, c):
@@ -294,6 +296,10 @@ def _kernel_checks(full: bool):
                                    sq=S, sk=S, causal=causal)
             if tile[1] < S:
                 raise AssertionError(f"S {S}: the rule gave {tile}, nk > 1")
+        if impl == "resident" and (
+                F._whole_key_blocks(S, S, 64, 2, False, causal) is not None
+                or F._resident_blocks(S, S, 64, 2, False) is None):
+            raise AssertionError(f"S {S}: the rule does not answer resident")
         return rel_err(jax.jit(run)(q, k, v), jax.jit(ref)(q, k, v))
 
     for S, BH in (((512, 128), (2048, 32)) if full else ((128, 2),)):
@@ -306,6 +312,18 @@ def _kernel_checks(full: bool):
                     f"flash_bwd_{impl}[{tag}]", 5e-2,
                     lambda S=S, BH=BH, c=causal, i=impl: flash_bwd(
                         S, BH, c, i)))
+    if full:
+        # the LFM2 cell's attention shape (BH small: the twin's scores are
+        # BH x 64 MiB): nothing forced, the resident kernel must be what
+        # the rule answers and what Mosaic compiles, with and without mask
+        checks.append(("flash_fwd[S4096c]", 3e-2,
+                       lambda: flash_fwd(4096, 4, True)))
+        checks.append(("flash_bwd_resident[S4096c]", 5e-2,
+                       lambda: flash_bwd(4096, 4, True, "resident")))
+        checks.append(("flash_bwd_resident[S4096]", 5e-2,
+                       lambda: flash_bwd(4096, 4, False, "resident")))
+        checks.append(("flash_bwd_resident_dropout[S4096c]", 5e-2,
+                       lambda: flash_bwd(4096, 4, True, "resident", 0.1)))
     S, BH = (512, 128) if full else (128, 2)
     # dropout: the in-kernel uint32 hash must rebuild the XLA mask bit for bit
     checks.append((f"flash_fwd_dropout[S{S}c]", 3e-2,
@@ -656,13 +674,15 @@ def phase_lfm2(ctx) -> dict:
         facts = check_trainer_report(report, steps)
         traced = step.trace(state, np_batch)
     names = pallas_kernel_names(traced)
-    required = {"apex_flash_fwd", "apex_l2norm"}
+    # ONE backward kernel at S 4096 (resident), not the dq / dkv pair
+    required = {"apex_flash_fwd", "apex_flash_bwd_fused", "apex_l2norm"}
     if ctx["on_tpu"]:
         required.add("apex_xentropy_fwd")
-    if not required <= names or not _flash_bwd_present(names):
+    if not required <= names or names & {"apex_flash_bwd_dq",
+                                         "apex_flash_bwd_dkv"}:
         raise AssertionError(f"traced step has Pallas kernels {sorted(names)}"
-                             f", expected {sorted(required)} and a flash "
-                             "backward")
+                             f", expected {sorted(required)} and no split "
+                             "flash backward")
     grouped = len(re.findall(r"ragged_dot", str(traced.jaxpr)))
     if not grouped:
         raise AssertionError("the traced step holds no ragged_dot")

@@ -1,6 +1,8 @@
 """Fast-vs-default parity tests for contrib.multihead_attn — mirrors
 ``apex/contrib/test/multihead_attn`` (fwd + bwd parity across mask variants,
 norm-add, encdec)."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -662,17 +664,13 @@ def _whole_key_case(case, S):
     return sq, sk, causal, rate, layout, dtype
 
 
-@pytest.mark.parametrize("case", ["plain", "causal", "dropout", "padding",
-                                  "per_query", "ragged", "sq_ne_sk", "bf16"])
-@pytest.mark.parametrize("S", [128, 512])
-def test_flash_bwd_whole_key_matches_split_and_xla(flash_env, flash_counts,
-                                                   S, case):
-    """Where one tile holds a head's keys the fused kernel writes dq itself
-    (path ``whole_key``); its gradients are those of the split dq / dkv
-    kernels and of the XLA twin, for every input the rule sees."""
+def _check_bwd_against_split_and_xla(flash_counts, path, b, h, sq, sk,
+                                     causal, rate, layout, dtype):
+    """The unforced backward at the shape takes ``path``, and its dq / dk /
+    dv are those of the split dq / dkv kernels and of the XLA twin.
+    Returns the path's ``flash.bwd`` event."""
     from apex_tpu.contrib.multihead_attn import flash as F
-    sq, sk, causal, rate, layout, dtype = _whole_key_case(case, S)
-    b, h, d = 2, (2 if S == 128 else 1), 64   # interpret mode: keep BH small
+    d = 64
     ks = jax.random.split(jax.random.PRNGKey(26), 4)
     q, do = (0.5 * jax.random.normal(kk, (b * h, sq, d), jnp.float32)
              for kk in ks[:2])
@@ -688,18 +686,36 @@ def test_flash_bwd_whole_key_matches_split_and_xla(flash_env, flash_counts,
         return (F._flash_bwd(*args), F._flash_bwd(*args, fuse=False),
                 F._xla_bwd(*args))
 
-    whole, split, xla = grads(jnp.int32(7))
+    got, split, xla = grads(jnp.int32(7))
     counts = flash_counts.read()
-    assert counts["flash.bwd_calls.whole_key"] == 1
+    assert counts[f"flash.bwd_calls.{path}"] == 1
     assert counts["flash.bwd_calls.split"] == 1
-    assert whole[0].shape == q.shape and whole[0].dtype == q.dtype
+    for g, x in zip(got, (q, k, v)):
+        assert g.shape == x.shape and g.dtype == x.dtype
     bf16 = dtype == jnp.bfloat16
-    for name, w, s_, x in zip(("dq", "dk", "dv"), whole, split, xla):
+    for name, w, s_, x in zip(("dq", "dk", "dv"), got, split, xla):
         w, s_, x = (np.asarray(t, np.float32) for t in (w, s_, x))
         np.testing.assert_allclose(w, s_, atol=3e-2 if bf16 else 2e-5,
                                    rtol=2e-2 if bf16 else 1e-5, err_msg=name)
         np.testing.assert_allclose(w, x, atol=5e-2 if bf16 else 5e-3,
                                    rtol=2e-2 if bf16 else 1e-3, err_msg=name)
+    return next(r["fields"] for r in flash_counts.flush()
+                if r.get("name") == "flash.bwd"
+                and r["fields"]["path"] == path)
+
+
+@pytest.mark.parametrize("case", ["plain", "causal", "dropout", "padding",
+                                  "per_query", "ragged", "sq_ne_sk", "bf16"])
+@pytest.mark.parametrize("S", [128, 512])
+def test_flash_bwd_whole_key_matches_split_and_xla(flash_env, flash_counts,
+                                                   S, case):
+    """Where one tile holds a head's keys the fused kernel writes dq itself
+    (path ``whole_key``); its gradients are those of the split dq / dkv
+    kernels and of the XLA twin, for every input the rule sees."""
+    b, h = 2, (2 if S == 128 else 1)    # interpret mode: keep BH small
+    ev = _check_bwd_against_split_and_xla(flash_counts, "whole_key", b, h,
+                                          *_whole_key_case(case, S))
+    assert ev["nk"] == 1
 
 
 @pytest.mark.parametrize("sq,sk,D,esz,per_q,causal,want", [
@@ -836,11 +852,13 @@ def test_flash_grad_jaxpr_has_no_dq_partials(flash_env):
     assert (BH, 4, S, D) in shapes
 
 
-@pytest.mark.parametrize("path", ["whole_key", "partials", "split", "xla"])
+@pytest.mark.parametrize("path", ["whole_key", "resident", "partials",
+                                  "split", "xla"])
 def test_flash_bwd_calls_counter_names_the_path(flash_env, flash_counts,
                                                 path):
     """``flash.bwd_calls.<path>`` counts one per traced backward under a
     default registry; the event carries the tile the choice was made from."""
+    from apex_tpu.contrib.multihead_attn import flash as F
     from apex_tpu.telemetry import events
     h, s, d = 2, 256, 16
     q = jax.random.normal(jax.random.PRNGKey(0), (h, s, d))
@@ -850,6 +868,9 @@ def test_flash_bwd_calls_counter_names_the_path(flash_env, flash_counts,
         flash_env.setenv("APEX_TPU_FLASH_BWD_BLOCK_K", "128")
     elif path == "split":
         flash_env.setenv("APEX_TPU_FLASH_BWD_FUSE", "0")
+    elif path == "resident":            # no tile holds the keys; VMEM does
+        flash_env.setenv("APEX_TPU_FLASH_VMEM_MB", "0.75")
+        flash_env.setattr(F, "_RESIDENT_MAX_BK", 128)
     backward = "xla" if path == "xla" else "pallas"
     jax.grad(lambda q: flash_attention(q, q, q, bias, 0, False, 0.0, h,
                                        backward).sum())(q)
@@ -863,7 +884,10 @@ def test_flash_bwd_calls_counter_names_the_path(flash_env, flash_counts,
     if path == "xla":
         assert "nk" not in ev[0]["fields"]
     else:
-        assert ev[0]["fields"]["nk"] == (2 if path == "partials" else 1)
+        pieces = {"partials": 2, "resident": 2}.get(path, 1)
+        assert ev[0]["fields"]["nk"] == pieces
+        if path == "resident":          # the tile the walk runs, not 128x128's
+            assert (ev[0]["fields"]["bq"], ev[0]["fields"]["bk"]) == (128, 128)
 
 
 def test_flash_bwd_calls_counter_is_a_noop_without_a_registry(flash_env):
@@ -880,3 +904,244 @@ def test_flash_bwd_calls_counter_is_a_noop_without_a_registry(flash_env):
         assert np.all(np.isfinite(np.asarray(g)))
     finally:
         events.set_default(prev)
+
+
+# ---------------------------------------------------------------------------
+# resident backward: no tile holds a head's keys, VMEM does — one kernel walks
+# them in pieces and finishes dq, dk and dv itself
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def small_vmem(flash_env):
+    """A budget under which S 256-512 at D 64 answers the dispatcher's
+    questions as S 4096 does under the real one: no whole-key tile, the
+    head resident, its keys walked in several pieces of 128."""
+    from apex_tpu.contrib.multihead_attn import flash as F
+    flash_env.setenv("APEX_TPU_FLASH_VMEM_MB", "1.5")
+    flash_env.setattr(F, "_RESIDENT_MAX_BK", 128)
+    return flash_env
+
+
+# case -> what differs from (sq 512, sk 512, not causal, no dropout, no
+# bias, float32)
+_RESIDENT_CASES = {
+    "plain": {},
+    "causal": dict(causal=True),
+    "dropout": dict(rate=0.1),
+    "causal_dropout": dict(causal=True, rate=0.1),
+    "padding": dict(layout="padding"),
+    "causal_padding": dict(causal=True, layout="padding"),
+    "per_query": dict(layout="full"),
+    "causal_per_query": dict(causal=True, layout="full"),
+    "ragged": dict(sq=500, sk=484),     # multiples of neither 8 nor 128
+    "causal_ragged": dict(sq=484, sk=500, causal=True),
+    "sq_lt_sk": dict(sq=256, causal=True),   # the last pieces never run
+    "sq_gt_sk": dict(sk=256, causal=True),   # the walk stops at the keys
+    "s256": dict(sq=256, sk=256),
+    "bf16": dict(dtype=jnp.bfloat16),
+    "causal_bf16": dict(causal=True, dtype=jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_RESIDENT_CASES))
+def test_flash_bwd_resident_matches_split_and_xla(small_vmem, flash_counts,
+                                                  case):
+    """Where no tile holds a head's keys but VMEM does, ONE kernel walks
+    them in pieces (path ``resident``); its gradients are those of the
+    split dq / dkv kernels and of the XLA twin, for every input the
+    dispatcher sends it."""
+    c = {**dict(sq=512, sk=512, causal=False, rate=0.0, layout="none",
+                dtype=jnp.float32), **_RESIDENT_CASES[case]}
+    ev = _check_bwd_against_split_and_xla(
+        flash_counts, "resident", 2, 1, c["sq"], c["sk"], c["causal"],
+        c["rate"], c["layout"], c["dtype"])
+    assert ev["bk"] == 128 and ev["nk"] == -(-c["sk"] // 128) >= 2
+
+
+def _traced_bwd_path(F, reg, BH, sq, sk, D, dtype, per_q=False, causal=True,
+                     **pins):
+    """The ``flash.bwd`` event of ONE traced (never run) ``_flash_bwd`` at
+    the shape: what the dispatcher answers, whatever the size."""
+    sds = jax.ShapeDtypeStruct
+    q, do, out = (sds((BH, sq, D), dtype),) * 3
+    k = v = sds((BH, sk, D), dtype)
+    bias = sds((1, sq if per_q else 1, sk), jnp.float32)
+    lse = sds((BH, sq, 1), jnp.float32)
+    jax.eval_shape(
+        lambda q, k, v, bias, out, lse, do: F._flash_bwd(
+            q, k, v, bias, causal, 0.0, 0, 1, out, lse, do, **pins),
+        q, k, v, bias, out, lse, do)
+    ev = [r["fields"] for r in reg.flush() if r.get("name") == "flash.bwd"]
+    assert len(ev) == 1
+    return ev[0]
+
+
+@pytest.mark.parametrize("BH,sq,sk,D,dtype,per_q,want", [
+    # the keys fit one tile: whole_key, tile and all, as before this path
+    (4, 128, 128, 64, "bfloat16", False, ("whole_key", 128, 128, 1)),
+    (4, 512, 512, 64, "bfloat16", False, ("whole_key", 512, 512, 1)),
+    (4, 512, 512, 64, "float32", False, ("whole_key", 256, 512, 1)),
+    (4, 2048, 2048, 64, "bfloat16", False, ("whole_key", 128, 2048, 1)),
+    # they do not, the head fits VMEM: lfm2_24b_a2b.ep8_s4096 is the first
+    (256, 4096, 4096, 64, "bfloat16", False, ("resident", 512, 512, 8)),
+    (4, 2048, 4096, 64, "bfloat16", False, ("resident", 512, 512, 8)),
+    (4, 4000, 4000, 64, "bfloat16", False, ("resident", 512, 512, 8)),
+    (4, 4096, 4096, 64, "float32", False, ("resident", 512, 512, 8)),
+    (4, 4096, 4096, 128, "bfloat16", False, ("resident", 512, 512, 8)),
+    (4, 8192, 8192, 64, "bfloat16", False, ("resident", 512, 512, 16)),
+    (4, 8192, 8192, 128, "bfloat16", False, ("resident", 512, 512, 16)),
+    (4, 4096, 4096, 64, "bfloat16", True, ("resident", 256, 512, 8)),
+    # too long for residency (or a per-query bias block too wide): the
+    # 128 x 128 grid, partials under the cap and the split pair above it
+    (1, 16384, 16384, 64, "bfloat16", False, ("partials", 128, 128, 128)),
+    (4, 16384, 16384, 64, "bfloat16", False, ("split", 128, 128, 128)),
+    (32, 8192, 8192, 64, "float32", False, ("split", 128, 128, 64)),
+    (32, 8192, 8192, 64, "bfloat16", True, ("split", 128, 128, 64)),
+])
+def test_flash_bwd_dispatch_rule(flash_env, flash_counts, BH, sq, sk, D,
+                                 dtype, per_q, want):
+    """The dispatcher's three questions as a table over shapes, under the
+    real budget: one tile a head where the keys fit it, the resident walk
+    where the head fits VMEM, the 128 x 128 grid's two paths beyond."""
+    from apex_tpu.contrib.multihead_attn import flash as F
+    dtype = jnp.dtype(dtype)
+    ev = _traced_bwd_path(F, flash_counts, BH, sq, sk, D, dtype, per_q)
+    assert (ev["path"], ev["bq"], ev["bk"], ev["nk"]) == want
+    path, bq, bk, _ = want
+    whole = F._whole_key_blocks(sq, sk, D, dtype.itemsize, per_q, True)
+    resident = F._resident_blocks(sq, sk, D, dtype.itemsize, per_q)
+    assert (whole is not None) == (path == "whole_key")
+    if path == "resident":
+        assert resident == (bq, bk)
+        assert F.vmem_estimate(bq, bk, D, dtype.itemsize, per_q, "resident",
+                               sk=sk) <= F._resident_budget()
+    elif path != "whole_key":
+        assert resident is None
+
+
+@pytest.mark.parametrize("pin", ["fuse_arg", "split_arg", "fuse_env",
+                                 "split_env", "blocks_arg", "blocks_env",
+                                 "dkv_blocks_arg", "profile"])
+def test_flash_bwd_pins_keep_their_meaning_at_s4096(flash_env, flash_counts,
+                                                    pin):
+    """A strategy or a tile somebody chose names the 128 x 128 grid's
+    kernels, as before: ``resident`` is asked only where nobody chose."""
+    from apex_tpu.contrib.multihead_attn import flash as F
+    from apex_tpu.utils import tuning
+    shape = (2, 4096, 4096, 64, jnp.bfloat16)
+    assert _traced_bwd_path(F, flash_counts, *shape)["path"] == "resident"
+    pins, want = {}, "partials"
+    if pin == "fuse_arg":
+        pins = dict(fuse=True)
+    elif pin == "split_arg":
+        pins, want = dict(fuse=False), "split"
+    elif pin == "fuse_env":
+        flash_env.setenv("APEX_TPU_FLASH_BWD_FUSE", "1")
+    elif pin == "split_env":
+        flash_env.setenv("APEX_TPU_FLASH_BWD_FUSE", "0")
+        want = "split"
+    elif pin == "blocks_arg":
+        pins = dict(bq=256, bk=256)
+    elif pin == "blocks_env":
+        flash_env.setenv("APEX_TPU_FLASH_BWD_BLOCK_K", "256")
+    elif pin == "dkv_blocks_arg":
+        pins = dict(dkv_blocks=(128, 512))
+    else:
+        flash_env.setattr(tuning, "get_on_tpu", lambda key, default=None: {
+            "flash_bwd_dkv_block_q": 256}.get(key, default))
+    ev = _traced_bwd_path(F, flash_counts, *shape, **pins)
+    assert ev["path"] == want and ev["nk"] > 1
+
+
+def test_flash_bwd_resident_vmem_estimate_counts_the_head():
+    """The resident model holds what the kernel holds for a head: K, V and
+    the dk / dv blocks twice (double-buffered), the f32 accumulators once,
+    all at the padded key length and at whole 128-lane rows (what Mosaic
+    allocates for D 64); the tile as the fused model counts it."""
+    from apex_tpu.contrib.multihead_attn import flash as F
+    est = functools.partial(F.vmem_estimate, D=64, esz=2, bias_per_q=False,
+                            bwd="resident")
+    MiB = 2 ** 20
+    s4096 = est(512, 512, sk=4096)
+    assert s4096 == 18.25 * MiB and est(256, 512, sk=8192) == 27.5 * MiB
+    # D 64 costs what D 128 costs: half of every 128-lane row is padding
+    assert s4096 == F.vmem_estimate(512, 512, 128, 2, False, "resident",
+                                    sk=4096)
+    assert s4096 <= F._resident_budget() < est(128, 128, sk=16384)
+    # a key more is a piece more: the head is held in whole pieces
+    assert est(512, 512, sk=4097) == est(512, 512, sk=4608)
+    # per key: 4 streams x 2 buffers x 2 B + 2 accumulators x 4 B, x 128
+    # lanes, plus the bias row (8 sublanes x 4 B x 2 buffers)
+    per_key = (4 * 2 * 2 + 2 * 4) * 128 + 8 * 4 * 2
+    assert est(512, 512, sk=8192) - s4096 == 4096 * per_key
+    # the tile is the fused model's tile
+    tile = 512 * 512 * (3 * 4 + 2 * 2)
+    assert est(512, 512, sk=4096) - est(512, 256, sk=4096) == tile // 2
+    # a per-query bias holds (bq, Sk) f32 twice: 512 rows do not fit
+    assert F.vmem_estimate(512, 512, 64, 2, True, "resident",
+                           sk=4096) > F._resident_budget()
+
+
+def test_flash_grad_jaxpr_at_s4096_is_one_fused_kernel(flash_env):
+    """grad(flash_attention) at the LFM2 cell's shape (b8 x 32 heads cut to
+    BH 8; S 4096, D 64, bf16, causal): ONE ``apex_flash_bwd_fused`` call
+    writes dq, dk and dv in their own dtypes; no dq / dkv pair, no f32
+    (BH, nk, Sq, D) partials, no XLA sum over them."""
+    BH, S, D = 8, 4096, 64
+    q = jax.ShapeDtypeStruct((BH, S, D), jnp.bfloat16)
+    bias = jnp.zeros((1, 1, S), jnp.float32)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, bias, 0, True, 0.0, 4,
+                               "pallas").astype(jnp.float32).sum()
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, q)
+    eqns = list(_walk_eqns(jaxpr.jaxpr))
+    kernels = [e.params["name"] for e in eqns
+               if e.primitive.name == "pallas_call"]
+    assert sorted(kernels) == ["apex_flash_bwd_fused", "apex_flash_fwd"]
+    fused = next(e for e in eqns if e.primitive.name == "pallas_call"
+                 and e.params["name"] == "apex_flash_bwd_fused")
+    assert [(v.aval.shape, v.aval.dtype) for v in fused.outvars] == [
+        ((BH, S, D), jnp.bfloat16)] * 3
+    assert fused.params["grid_mapping"].grid == (BH, S // 512)
+    for e in eqns:
+        for var in e.outvars:
+            aval = var.aval
+            assert not (getattr(aval, "ndim", 0) == 4
+                        and aval.dtype == jnp.float32), (e.primitive.name,
+                                                         aval)
+    # forced back to the split pair, the two kernels are there: the check
+    # can see
+    flash_env.setenv("APEX_TPU_FLASH_BWD_FUSE", "0")
+    split = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, q)
+    names = sorted(e.params["name"] for e in _walk_eqns(split.jaxpr)
+                   if e.primitive.name == "pallas_call")
+    assert names == ["apex_flash_bwd_dkv", "apex_flash_bwd_dq",
+                     "apex_flash_fwd"]
+
+
+def test_flash_bwd_resident_skips_what_lies_above_the_diagonal(small_vmem):
+    """Causal: the pieces wholly above a q tile's diagonal are neither
+    computed nor read — poisoned keys there change no gradient of the
+    rows that cannot see them (Sq < Sk: the last 256 keys are above every
+    row), and their own dk / dv come out zero."""
+    from apex_tpu.contrib.multihead_attn import flash as F
+    sq, sk, d = 256, 512, 64
+    ks = jax.random.split(jax.random.PRNGKey(5), 4)
+    q, do = (0.5 * jax.random.normal(kk, (1, sq, d)) for kk in ks[:2])
+    k, v = (0.5 * jax.random.normal(kk, (1, sk, d)) for kk in ks[2:])
+    bias = jnp.zeros((1, 1, sk), jnp.float32)
+
+    out, lse = F._flash_fwd(q, k, v, bias, True, 0.0, 0, 1)
+
+    def grads(k, v):
+        return F._flash_bwd(q, k, v, bias, True, 0.0, 0, 1, out, lse, do)
+
+    clean = grads(k, v)
+    poisoned = grads(k.at[:, sq:].set(jnp.nan), v.at[:, sq:].set(jnp.nan))
+    for name, a, b in zip(("dq", "dk", "dv"), clean, poisoned):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=name)
+    assert not np.any(np.asarray(clean[1])[:, sq:])
+    assert not np.any(np.asarray(clean[2])[:, sq:])
