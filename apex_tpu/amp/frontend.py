@@ -145,7 +145,7 @@ def initialize(params, optimizer=None, opt_level="O1", *,
 
     # flash-attention gradient route: a session-level amp knob applied
     # process-wide (the flash custom_vjp has no handle on AmpState) — it
-    # sits between the env override and the tuning profile in
+    # sits between the env override and the built-in in
     # flash._resolve_backward's "auto" chain
     from ..contrib.multihead_attn import flash as _flash
     _flash.set_default_backward(props.flash_attn_backward)

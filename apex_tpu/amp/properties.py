@@ -27,7 +27,7 @@ _ALLOWED = {
 }
 
 # flash-attention gradient route (contrib.multihead_attn.flash): "auto"
-# defers to env/tuning-profile resolution; "pallas"/"xla" force the path
+# defers to the env pin, else the Pallas kernels; "pallas"/"xla" force the path
 # process-wide via flash.set_default_backward (applied by initialize()).
 # flash.BACKWARD_IMPLS is the single source of truth for the valid
 # values; imported lazily so this module never pulls Pallas in at

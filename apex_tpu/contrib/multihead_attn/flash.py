@@ -43,9 +43,8 @@ backward : recompute-based (flash bwd).  One algorithm whose tile and
         ``vmem_estimate``).
     The whole Pallas backward can also be swapped for the XLA math path via
     ``backward="pallas"|"xla"|"auto"`` on :func:`flash_attention` — ``auto``
-    consults the measured tuning profile (``flash_bwd_impl``) so a recorded
-    Pallas-backward loss routes training to the fast XLA pair instead of
-    shipping a regression.
+    is the Pallas kernels unless ``APEX_TPU_FLASH_BWD_IMPL`` or amp's
+    ``flash_attn_backward`` says otherwise (:func:`_resolve_backward`).
 """
 from __future__ import annotations
 
@@ -97,7 +96,7 @@ _FUSE_BUFFER_CAP_MB = 1024.0
 
 # Process-level default for flash_attention(backward="auto"), set by
 # apex_tpu.amp.initialize (Properties.flash_attn_backward) — sits between
-# the env override and the tuning profile in _resolve_backward's chain.
+# the env override and the built-in in _resolve_backward's chain.
 _DEFAULT_BACKWARD = "auto"
 
 BACKWARD_IMPLS = ("auto", "pallas", "xla")
@@ -105,7 +104,7 @@ BACKWARD_IMPLS = ("auto", "pallas", "xla")
 
 def set_default_backward(value: str) -> None:
     """Set the process-level default consulted by ``backward="auto"``
-    (``"auto"`` defers on to the tuning profile)."""
+    (``"auto"`` defers on to the built-in)."""
     global _DEFAULT_BACKWARD
     if value not in BACKWARD_IMPLS:
         raise ValueError(f"backward must be one of {BACKWARD_IMPLS}, "
@@ -117,11 +116,8 @@ def _resolve_backward(backward: str) -> str:
     """Collapse ``backward`` to a concrete impl at trace time.
 
     Precedence: explicit "pallas"/"xla" argument > APEX_TPU_FLASH_BWD_IMPL
-    env > amp-config default (:func:`set_default_backward`) > measured
-    tuning profile (``flash_bwd_impl``, TPU only) > "pallas" built-in.
-    The profile key is written by ``tools/apply_perf_results.py`` from the
-    ``flash_bwd_autotune`` grads(q,k,v) A/B — a measured Pallas-backward
-    loss flips ``auto`` to the XLA pair automatically."""
+    env > amp-config default (:func:`set_default_backward`) > "pallas"
+    built-in."""
     import os
     if backward not in BACKWARD_IMPLS:
         raise ValueError(f"backward must be one of {BACKWARD_IMPLS}, "
@@ -133,10 +129,6 @@ def _resolve_backward(backward: str) -> str:
         return env
     if _DEFAULT_BACKWARD != "auto":
         return _DEFAULT_BACKWARD
-    from ...utils import tuning
-    prof = tuning.get_on_tpu("flash_bwd_impl", None)
-    if prof in ("pallas", "xla"):
-        return prof
     return "pallas"
 
 # Mosaic fails at compile time (or spills) when a step's blocks exceed VMEM
@@ -204,82 +196,56 @@ def _resident_blocks(sq, sk, D, esz, bias_per_q):
 
 
 def _chosen_blocks(bq, bk, bwd):
-    """``(bq, bq_pinned, bk, bk_pinned)`` as somebody CHOSE them for the
-    kernel ``bwd`` names — argument > env pin > tuning profile — with None
-    where nobody did and the built-in end of the chain decides."""
+    """``(bq, bk)`` as somebody CHOSE them for the kernel ``bwd`` names —
+    argument > env pin — with None where nobody did and the built-in end
+    of the chain decides.
+
+    The backward kernels have their own optimum (fwd blocks that stream
+    k/v differ from bwd blocks that also stream do and accumulate dk/dv),
+    so bwd reads ONLY the bwd env pins: per-kernel (``bwd="dq"|"dkv"|
+    "fused"``; fused rides the dkv names, it runs on the dkv grid), then
+    the shared ``APEX_TPU_FLASH_BWD_BLOCK_Q`` / ``_K``."""
     import os
-    # the backward kernels have their own optimum (the r5 on-chip sweep
-    # measures them separately — fwd blocks that stream k/v differ from
-    # bwd blocks that also stream do and accumulate dk/dv), so bwd
-    # consults ONLY the bwd env pin / tuning key / built-in chain.  The
-    # fwd winner deliberately does not leak into bwd: a partial autotune
-    # window may write the fwd profile key without the bwd one.
-    # Per-kernel chain (bwd="dq"|"dkv"|"fused"; fused rides the dkv keys,
-    # it runs on the dkv grid): argument > per-kernel env pin > shared bwd
-    # env pin > per-kernel profile > shared bwd profile > built-in (the
-    # shape's whole-key tile for "fused", else 128x128).
-    chains_q, chains_k = [], []
-    if bwd in ("dq", "dkv", "fused"):
-        kern = "DKV" if bwd in ("dkv", "fused") else "DQ"
-        tkern = kern.lower()
-        chains_q.append((f"APEX_TPU_FLASH_BWD_{kern}_BLOCK_Q",
-                         f"flash_bwd_{tkern}_block_q"))
-        chains_k.append((f"APEX_TPU_FLASH_BWD_{kern}_BLOCK_K",
-                         f"flash_bwd_{tkern}_block_k"))
-    if bwd:
-        chains_q.append(("APEX_TPU_FLASH_BWD_BLOCK_Q", "flash_bwd_block_q"))
-        chains_k.append(("APEX_TPU_FLASH_BWD_BLOCK_K", "flash_bwd_block_k"))
+    if not bwd:
+        pins_q, pins_k = ["APEX_TPU_FLASH_BLOCK_Q"], ["APEX_TPU_FLASH_BLOCK_K"]
     else:
-        chains_q.append(("APEX_TPU_FLASH_BLOCK_Q", "flash_block_q"))
-        chains_k.append(("APEX_TPU_FLASH_BLOCK_K", "flash_block_k"))
-    # pinned = explicitly chosen, by argument OR by the env var the value
-    # actually came from (docs tell users to pin the autotune winner via
-    # env; a pin that got silently re-clamped would run a different
-    # kernel than the one measured).  Values sourced from the tuning
-    # PROFILE are not pins: the autotune sweeps one shape, and the VMEM
-    # clamp below must still protect other shapes from a config that
-    # only fit where it was measured.
-    # precedence (per path): argument > env pin > profile > built-in.
-    from ...utils import tuning
+        pins_q = ["APEX_TPU_FLASH_BWD_BLOCK_Q"]
+        pins_k = ["APEX_TPU_FLASH_BWD_BLOCK_K"]
+        if bwd in ("dq", "dkv", "fused"):
+            kern = "DQ" if bwd == "dq" else "DKV"
+            pins_q.insert(0, f"APEX_TPU_FLASH_BWD_{kern}_BLOCK_Q")
+            pins_k.insert(0, f"APEX_TPU_FLASH_BWD_{kern}_BLOCK_K")
 
-    def _pick(chain):
-        for env, _ in chain:
+    def _pick(pins):
+        for env in pins:
             if env in os.environ:
-                return int(os.environ[env]), True
-        for _, tune in chain:
-            v = tuning.get_on_tpu(tune, None)
-            if v is not None:
-                return int(v), False
-        return None, False
+                return int(os.environ[env])
+        return None
 
-    bq_pinned = bq is not None
-    bk_pinned = bk is not None
-    if bq is None:
-        bq, bq_pinned = _pick(chains_q)
-    if bk is None:
-        bk, bk_pinned = _pick(chains_k)
-    return bq, bq_pinned, bk, bk_pinned
+    return (_pick(pins_q) if bq is None else bq,
+            _pick(pins_k) if bk is None else bk)
 
 
 def _clamp_blocks(bq, bk, D, esz, bias_per_q, bwd=False, sq=None, sk=None,
                   causal=False):
     """Shrink (bq, bk) until the kernel's per-step VMEM estimate fits the
-    budget.  ``bq``/``bk`` None means "default, overridable by env", and
-    only those are budget-clamped; explicit values (an autotune sweep, a
-    user who measured) are taken as-is so what runs is what was asked for —
-    a config that genuinely exceeds VMEM then fails loudly at compile.
+    budget.  Only the built-in defaults are budget-clamped; a value
+    somebody chose (argument or env pin, :func:`_chosen_blocks`) is taken
+    as-is so what runs is what was asked for — a config that genuinely
+    exceeds VMEM then fails loudly at compile.
     ``sq``/``sk`` (the actual sequence lengths) cap the blocks BEFORE
     estimating, so short sequences aren't shrunk below what fits anyway;
     for ``bwd="fused"`` they (with ``causal``) also decide the built-in
     end of the chain — :func:`_whole_key_blocks` where the keys fit, the
     128x128 constants where they do not or where no shape is given.
-    ``bwd`` selects the footprint model AND the env/profile chain:
+    ``bwd`` selects the footprint model AND the env names:
     ``False`` (forward), ``"dq"`` / ``"dkv"`` / ``"fused"`` (the three
-    backward kernels — per-kernel keys, falling back to the shared bwd
-    keys), or ``True`` (legacy combined backward model, shared keys only).
+    backward kernels — per-kernel pins, falling back to the shared bwd
+    pins), or ``True`` (legacy combined backward model, shared pins only).
     Alignment floors: bk multiple of 128 (lane dim of the bias block), bq
     multiple of 8 (sublane)."""
-    bq, bq_pinned, bk, bk_pinned = _chosen_blocks(bq, bk, bwd)
+    bq, bk = _chosen_blocks(bq, bk, bwd)
+    bq_pinned, bk_pinned = bq is not None, bk is not None
     if (bq is None and bk is None and bwd == "fused"
             and sq is not None and sk is not None):
         whole = _whole_key_blocks(sq, sk, D, esz, bias_per_q, causal)
@@ -329,11 +295,7 @@ def vmem_estimate(bq, bk, D, esz, bias_per_q, bwd=False, sk=None) -> int:
     f32 intermediates of the recompute (``s``/``p``, ``dp``, ``ds``) and
     the stream-dtype copies of ``p`` and ``ds`` the MXU takes — 4 MiB at
     512 x 512 in bf16 beside ~1.5 MiB of streams — and the (bq, 1) f32
-    ``lse`` / ``delta`` columns at the 128 lanes a VMEM block pads them to.
-
-    Module-level so ``bench_kernels.py``'s ``flash_vmem_probe`` leg can
-    validate the model against real Mosaic compiles (round-4 verdict
-    weak #4: the estimate had never been checked on silicon)."""
+    ``lse`` / ``delta`` columns at the 128 lanes a VMEM block pads them to."""
     qkv_io = (bq * D + 2 * bk * D + bq * D) * esz   # q, k, v, out|dq
     bias = (bq if bias_per_q else 1) * bk * 4
     scratch = bq * (2 + D) * 4 + bq * 4
@@ -1050,8 +1012,7 @@ def _flash_bwd_resident(q, k, v, bias, causal, dropout_rate, seed, heads,
 
 def _forced_fuse(fuse):
     """A fused-vs-split choice somebody MADE, else None: explicit argument
-    > APEX_TPU_FLASH_BWD_FUSE env (0/1) > tuning profile
-    ``flash_bwd_fuse`` (TPU only)."""
+    > APEX_TPU_FLASH_BWD_FUSE env (0/1)."""
     import os
     if fuse is not None:
         return bool(fuse)
@@ -1060,14 +1021,12 @@ def _forced_fuse(fuse):
         # same disable vocabulary as telemetry's _env_enabled: 'off' and
         # 'no' disable (they used to read as truthy — ROADMAP deferral b)
         return env.lower() not in ("0", "off", "false", "no", "")
-    from ...utils import tuning
-    prof = tuning.get_on_tpu("flash_bwd_fuse", None)
-    return None if prof is None else bool(prof)
+    return None
 
 
 def _resolve_fuse(fuse, BH, Sq, Sk, D, bk):
     """Fused-vs-split strategy where dq leaves the kernel as partials
-    (nk > 1).  :func:`_forced_fuse` (argument > env > profile) > built-in
+    (nk > 1).  :func:`_forced_fuse` (argument > env) > built-in
     heuristic: fuse while the dq-partials buffer stays under the byte cap
     (it grows as Sq*Sk/bk — "where the grid allows")."""
     import os
@@ -1093,13 +1052,13 @@ def _flash_bwd(q, k, v, bias, causal, dropout_rate, seed, heads, out, lse,
     pieces, nothing partial leaves it); and only then does the f32
     partials buffer of the 128x128 grid stay under :func:`_resolve_fuse`'s
     cap (``partials``, else ``split``).  A tile or a strategy somebody
-    CHOSE (argument, env pin, profile key) keeps its meaning: it names the
+    CHOSE (argument, env pin) keeps its meaning: it names the
     128x128 grid's kernels, so ``resident`` is not asked.
 
-    ``bq``/``bk`` pin BOTH kernels (the legacy shared knob the autotune
-    sweeps use); ``dq_blocks``/``dkv_blocks`` (each an optional (bq, bk)
-    tuple) pin the kernels separately — their VMEM footprints differ, so
-    their optima do too.  ``fuse`` forces the fused/split strategy
+    ``bq``/``bk`` pin BOTH kernels (the legacy shared knob);
+    ``dq_blocks``/``dkv_blocks`` (each an optional (bq, bk) tuple) pin the
+    kernels separately — their VMEM footprints differ, so their optima do
+    too.  ``fuse`` forces the fused/split strategy
     (None = auto)."""
     # delta_i = rowsum(dO * O): tiny elementwise+reduce, XLA fuses it —
     # computed ONCE here and streamed to whichever backward kernels run
@@ -1117,7 +1076,7 @@ def _flash_bwd(q, k, v, bias, causal, dropout_rate, seed, heads, out, lse,
     if nk == 1:
         fuse = forced is not False
     else:
-        c_bq, _, c_bk, _ = _chosen_blocks(kv_bq, kv_bk, "fused")
+        c_bq, c_bk = _chosen_blocks(kv_bq, kv_bk, "fused")
         resident = None
         if forced is None and c_bq is None and c_bk is None:
             resident = _resident_blocks(Sq, Sk, D, esz, per_q)
@@ -1192,10 +1151,9 @@ def _xla_reference(q, k, v, bias, causal, dropout_rate, seed, heads):
 
 
 def _xla_bwd(q, k, v, bias, causal, dropout_rate, seed, heads, out, lse, do):
-    """(dq, dk, dv) via autodiff of :func:`_xla_reference` — the measured
-    fallback when the tuning profile records a Pallas-backward loss.  The
-    saved out/lse residuals are unused; XLA refuses nothing at these
-    shapes and fuses its own recompute."""
+    """(dq, dk, dv) via autodiff of :func:`_xla_reference`
+    (``backward="xla"``).  The saved out/lse residuals are unused; XLA
+    refuses nothing at these shapes and fuses its own recompute."""
     del out, lse
     _, vjp = jax.vjp(
         lambda q_, k_, v_: _xla_reference(q_, k_, v_, bias, causal,
@@ -1217,9 +1175,8 @@ def flash_attention(q, k, v, bias, seed=0, causal=False, dropout_rate=0.0,
 
     ``backward`` selects the gradient path while the Pallas forward stays:
     ``"pallas"`` (recompute kernels), ``"xla"`` (autodiff of the XLA math
-    with the identical dropout mask — the honest fallback when the kernels
-    measure slower), or ``"auto"`` (:func:`_resolve_backward`: env >
-    amp-config > measured tuning profile > pallas).
+    with the identical dropout mask), or ``"auto"``
+    (:func:`_resolve_backward`: env > amp-config > pallas).
 
     ``bias`` is NOT differentiated on this path (cotangent is zero): it
     models masks — data, not parameters — exactly like the reference's CUDA

@@ -73,8 +73,8 @@ class SelfMultiheadAttn:
     ``seq_inner_impl="fast"`` core): gradient route for the Pallas
     forward — ``"pallas"`` recompute kernels, ``"xla"`` autodiff of the
     equivalent XLA math (identical dropout mask), or ``"auto"``
-    (default), which consults the measured tuning profile so a recorded
-    Pallas-backward loss falls back to the XLA pair automatically.
+    (default: ``APEX_TPU_FLASH_BWD_IMPL`` > amp's ``flash_attn_backward``
+    > the Pallas kernels).
     """
 
     def __init__(self, embed_dim, num_heads, dropout=0.0, bias=False,

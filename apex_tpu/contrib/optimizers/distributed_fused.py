@@ -90,12 +90,7 @@ class _DistributedFusedBase:
                  state_dtype=None, collective_scheme=None,
                  allgather_scheme=None):
         if impl is None:
-            # measured tuning profile ("zero_impl", written by
-            # tools/apply_perf_results.py from the on-chip adam_update /
-            # lamb_stage1 A/B), falling back to the PERF_NOTES §2
-            # measured default: the XLA fusion over flat buffers
-            from ...utils import tuning
-            impl = tuning.get_on_tpu("zero_impl", "xla")
+            impl = "xla"     # the XLA fusion over flat buffers
         if impl not in ("xla", "fused"):
             raise ValueError(f"impl must be 'xla' or 'fused', got {impl!r}")
         self.lr = lr
@@ -148,16 +143,13 @@ class _DistributedFusedBase:
         (explicit constructor arg > env for the gradient reduce-scatter;
         the param ALLGATHER honors only the explicit arg — quantizing
         params is a deliberate accuracy trade the ambient
-        APEX_TPU_COLLECTIVES A/B knob must not flip implicitly.  The
-        DDP-path tuning key is never consulted — a measured DDP winner
-        says nothing about the ZeRO wire, whose knob is the
-        constructor)."""
+        APEX_TPU_COLLECTIVES A/B knob must not flip implicitly)."""
         from ...parallel import collectives as _coll
         if which == "ag":
             if self.allgather_scheme is None:
                 return None
-            return _coll.resolve(self.allgather_scheme, tuning_key=None)
-        return _coll.resolve(self.collective_scheme, tuning_key=None)
+            return _coll.resolve(self.allgather_scheme)
+        return _coll.resolve(self.collective_scheme)
 
     def _meter(self, op, logical, wire, seconds, scheme, dtype):
         """ZeRO collective meter: one record_collective per traced

@@ -151,14 +151,10 @@ def softmax_xentropy_loss(logits, labels, smoothing=0.0, padding_idx=0,
 
 def _fwd(logits, labels, smoothing, impl):
     if impl == "auto":
-        # APEX_TPU_XENT_IMPL overrides the auto choice — the bench
-        # harness's safety hatch for first-contact Mosaic failures;
-        # next, the measured tuning profile (tools/apply_perf_results.py
-        # records the on-chip pallas-vs-xla winner); else pallas on TPU
+        # APEX_TPU_XENT_IMPL overrides the auto choice (the hatch for a
+        # first-contact Mosaic failure); else pallas on TPU
         import os
-        from ...utils import tuning
         impl = (os.environ.get("APEX_TPU_XENT_IMPL", "")
-                or tuning.get_on_tpu("xent_auto_impl")
                 or ("pallas" if jax.default_backend() == "tpu" else "xla"))
     if impl == "pallas":
         return _xent_fwd_pallas(logits, labels, smoothing)

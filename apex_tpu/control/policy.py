@@ -1,11 +1,10 @@
 """Declarative controller policies: a named signal, a tolerance band,
 and hysteresis gates in front of a named action.
 
-The posture is ``tools/bench_trend.py``'s tolerance band moved
-in-process: a signal is healthy while it sits INSIDE its band
-(edges inclusive — a value sitting exactly ON the edge is in-band, so
-a signal oscillating at the edge can never flap an action), and a
-single excursion is noise, not a regime.  Three gates stand between a
+The posture is a tolerance band: a signal is healthy while it sits
+INSIDE its band (edges inclusive — a value sitting exactly ON the edge
+is in-band, so a signal oscillating at the edge can never flap an
+action), and a single excursion is noise, not a regime.  Three gates stand between a
 breach and an action:
 
   * **K-consecutive** — the breach must hold for ``k_consecutive``

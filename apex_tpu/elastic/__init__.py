@@ -16,9 +16,7 @@ elastic resume:
      (:class:`~apex_tpu.resilience.ckpt.CheckpointManager` meta); the
      guard compares it against the live mesh at resume;
   2. **re-plan** — :func:`replan` re-runs ``plan.search()`` for the NEW
-     chip count (and :func:`install` hooks
-     ``plan.from_tuning``'s chips mismatch so a stale tuned plan
-     triggers the same re-search instead of an error/None);
+     chip count;
   3. **reshard** — :func:`reshard_payload` re-slices the N-way state
      into M-way shards.  The zero1/ZeRO flat layout is *canonical*:
      ``jax.device_get`` of the P("data")-sharded global buffer already
@@ -378,25 +376,18 @@ class ElasticResume:
 
 def install(profile=None, **search_kw) -> ElasticResume:
     """Make the process elastic: register an :class:`ElasticResume` as
-    the guard's default resharder AND hook
-    ``plan.from_tuning``'s chips mismatch into :func:`replan` (a tuned
-    plan for the old fleet re-searches instead of degrading to None).
-    Returns the installed object; :func:`uninstall` reverses both."""
+    the guard's default resharder.  Returns the installed object;
+    :func:`uninstall` reverses it."""
     from ..resilience import guard as _guard
     er = ElasticResume(profile=profile, search_kw=dict(search_kw))
     _guard.set_resharder(er)
-    _plan.set_replan_hook(
-        lambda tuned, chips: replan(chips, profile=er.profile,
-                                    saved_knobs=tuned.knobs(),
-                                    **er.search_kw))
     return er
 
 
 def uninstall() -> None:
-    """Remove the process-default resharder and the re-plan hook."""
+    """Remove the process-default resharder."""
     from ..resilience import guard as _guard
     _guard.set_resharder(None)
-    _plan.set_replan_hook(None)
 
 
 def installed() -> Optional[ElasticResume]:

@@ -52,7 +52,7 @@ class MLP:
     """
 
     def __init__(self, mlp_sizes: Sequence[int], bias=True, relu=True,
-                 activation=None, use_pallas=None):
+                 activation=None, use_pallas=False):
         if activation is None:
             activation = "relu" if relu else "none"
         if activation not in ("none", "relu", "sigmoid"):
@@ -61,12 +61,8 @@ class MLP:
         self.bias = bias
         self.activation = activation
         # Pallas fused GEMM+epilogue per layer (ops/fused_mlp.py) — the
-        # mlp_cuda perf-ceiling analog (SURVEY §2.2).  None = measured
-        # tuning profile ("mlp_use_pallas"), falling back to XLA.
-        if use_pallas is None:
-            from ..utils import tuning
-            use_pallas = bool(tuning.get_on_tpu("mlp_use_pallas", False))
-        self.use_pallas = use_pallas
+        # mlp_cuda perf-ceiling analog (SURVEY §2.2); the default is XLA.
+        self.use_pallas = bool(use_pallas)
 
     def init(self, rng):
         """Matches the reference's reset_parameters (mlp.py:64-72):

@@ -80,13 +80,6 @@ class TransformerConfig:
 def bert_large_config(**overrides) -> TransformerConfig:
     base = dict(vocab_size=30592, max_len=512, num_layers=24, d_model=1024,
                 num_heads=16, d_ff=4096)
-    # measured winner from the on-chip attn_seq_sweep (tuning profile,
-    # written by tools/apply_perf_results.py) — an explicit attn_impl
-    # override always wins
-    from ..utils import tuning
-    tuned_attn = tuning.get_on_tpu("bert_attn_impl")
-    if tuned_attn and "attn_impl" not in overrides:
-        base["attn_impl"] = tuned_attn
     base.update(overrides)
     return TransformerConfig(**base)
 

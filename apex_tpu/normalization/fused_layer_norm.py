@@ -33,17 +33,12 @@ def _norm_axes(x, normalized_shape):
 
 
 def fused_layer_norm_affine(x, weight, bias, normalized_shape, eps=1e-5,
-                            *, use_pallas=None):
-    """``use_pallas``: True/False select explicitly; None (default) =
-    the measured tuning profile's ``layer_norm_use_pallas`` (written by
-    tools/apply_perf_results.py from the on-chip A/B), falling back to
-    the XLA custom-vjp path."""
+                            *, use_pallas=False):
+    """``use_pallas`` selects the Pallas kernel (ops/layer_norm.py);
+    the default is the XLA custom-vjp path."""
     if isinstance(normalized_shape, int):
         normalized_shape = (normalized_shape,)
     normalized_shape = tuple(normalized_shape)   # hashable nondiff argnum
-    if use_pallas is None:
-        from ..utils import tuning
-        use_pallas = bool(tuning.get_on_tpu("layer_norm_use_pallas", False))
     if use_pallas:
         from ..ops.layer_norm import layer_norm_pallas
         return layer_norm_pallas(x, weight, bias, normalized_shape, eps)
@@ -100,7 +95,7 @@ def _ln_bwd_vjp(normalized_shape, eps, res, g):
 _fused_layer_norm_affine_xla.defvjp(_ln_fwd_vjp, _ln_bwd_vjp)
 
 
-def fused_layer_norm(x, normalized_shape, eps=1e-5, *, use_pallas=None):
+def fused_layer_norm(x, normalized_shape, eps=1e-5, *, use_pallas=False):
     """Non-affine variant (``FusedLayerNormFunction``, fused_layer_norm.py:39)."""
     return fused_layer_norm_affine(x, None, None, normalized_shape, eps,
                                    use_pallas=use_pallas)

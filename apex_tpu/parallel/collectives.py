@@ -47,7 +47,6 @@ Built-in schemes
 Selection and the per-bucket threshold
 --------------------------------------
 Precedence everywhere: explicit argument > ``APEX_TPU_COLLECTIVES`` env
-> tuning profile (``ddp_collective_scheme`` — DDP path only, TPU only)
 > off (the legacy native-dtype psum).  The env/arg spec grammar::
 
     APEX_TPU_COLLECTIVES="int8_blockscale"
@@ -159,7 +158,7 @@ _OPT = re.compile(r"^(block|min_bytes)=(\d+)$")
 # -- live override (apex_tpu.control comm retune) ---------------------------
 # The run controller's actuation surface: a process-wide spec that
 # :func:`resolve` consults for DEFAULT resolutions (scheme=None) ahead
-# of the APEX_TPU_COLLECTIVES env and the tuning profile.  Explicitly
+# of the APEX_TPU_COLLECTIVES env.  Explicitly
 # passed schemes still win — a caller that pinned a wire stays pinned.
 # Takes effect at the next engine build (resolve time): overlap.Reducer
 # / spmd.build_plan_step re-resolve when (re)constructed, which is
@@ -209,18 +208,13 @@ def parse_spec(text: str) -> CollectiveSpec:
 
 
 def resolve(scheme=None, *, min_bytes: Optional[int] = None,
-            block: Optional[int] = None,
-            tuning_key: Optional[str] = "ddp_collective_scheme"
-            ) -> Optional[CollectiveSpec]:
+            block: Optional[int] = None) -> Optional[CollectiveSpec]:
     """Resolve a scheme choice to a spec (or None = legacy psum).
 
     Precedence: explicit ``scheme`` (name / spec string /
     :class:`CollectiveSpec`) > the controller's live override
-    (:func:`set_live_spec`) > ``APEX_TPU_COLLECTIVES`` env > the
-    measured tuning profile under ``tuning_key`` (TPU only; pass
-    ``tuning_key=None`` to opt out — the ZeRO paths do, their knob is
-    the constructor argument) > None.  ``min_bytes``/``block`` override
-    the spec's own values when given.
+    (:func:`set_live_spec`) > ``APEX_TPU_COLLECTIVES`` env > None.
+    ``min_bytes``/``block`` override the spec's own values when given.
     """
     spec: Optional[CollectiveSpec] = None
     if scheme is None:
@@ -236,14 +230,6 @@ def resolve(scheme=None, *, min_bytes: Optional[int] = None,
             return None
         if env:
             spec = parse_spec(env)
-        elif tuning_key is not None:
-            from ..utils import tuning
-            name = tuning.get_on_tpu(tuning_key)
-            if name:
-                spec = CollectiveSpec(
-                    scheme=name,
-                    min_bytes=tuning.get_on_tpu(
-                        "collective_min_compress_bytes", DEFAULT_MIN_BYTES))
     elif isinstance(scheme, CollectiveSpec):
         spec = scheme
     else:
@@ -269,8 +255,8 @@ def leaf_scheme(spec: CollectiveSpec, leaf_bytes: int) -> str:
 def wire_bytes(scheme: str, nelems: int,
                block: int = DEFAULT_BLOCK) -> int:
     """Static per-device payload bytes for an ``nelems`` leaf under
-    ``scheme`` — the number the telemetry compressed-bytes counter and
-    the bench.py collectives leg both account with."""
+    ``scheme`` — the number the telemetry compressed-bytes counter
+    accounts with."""
     return get_scheme(scheme).wire_bytes(int(nelems), int(block))
 
 

@@ -25,8 +25,8 @@ What survives as *semantics* (and is implemented here):
 
 Async overlap execution (``parallel.overlap``, docs/parallel.md): the
 reference's comm-ready-bucket machinery DOES translate one level down —
-``overlap="bucketed"`` (or ``APEX_TPU_OVERLAP`` / the measured
-``ddp_overlap`` tuning key) partitions the grad pytree into
+``overlap="bucketed"`` (or ``APEX_TPU_OVERLAP``) partitions the grad
+pytree into
 ``message_size``-element buckets in reverse flat (≈ grad-production)
 order and issues one collective per bucket, each depending only on its
 own leaves, so XLA's latency-hiding scheduler overlaps them with the
@@ -49,7 +49,7 @@ and stream fan-out have no SPMD meaning; XLA owns scheduling.
 Beyond the reference: per-bucket compressed/adaptive collective schemes
 (``parallel.collectives`` — bf16, block-scaled int8 with error-feedback
 residuals, Adasum adaptive merge), selected via ``collective_scheme=`` /
-``APEX_TPU_COLLECTIVES`` / the tuning profile and metered as
+``APEX_TPU_COLLECTIVES`` and metered as
 logical-vs-wire bytes by the telemetry collective counters.  See
 docs/parallel.md "Collective schemes".  And weight-update sharding
 (``parallel.weight_update``, arXiv:2004.13336): the opt-in
@@ -105,9 +105,8 @@ def allreduce_tree(grads, *, axis_name: str = DATA_AXIS,
     "adasum"), a spec string ("int8_blockscale:block=128"), a
     :class:`~apex_tpu.parallel.collectives.CollectiveSpec`, or a
     callable ``(path, leaf) -> scheme|None`` for custom routing.
-    ``scheme=None`` consults ``APEX_TPU_COLLECTIVES`` then the tuning
-    profile (``ddp_collective_scheme``, TPU only); with neither set the
-    legacy native-dtype psum below runs unchanged.  Leaves smaller than
+    ``scheme=None`` consults ``APEX_TPU_COLLECTIVES``; with that unset
+    the legacy native-dtype psum below runs unchanged.  Leaves smaller than
     ``min_compress_bytes`` (default spec ``min_bytes``) stay fp32.
     ``residuals`` threads the int8 error-feedback residual pytree
     (:func:`collectives.init_residuals`) — when passed, the return
@@ -283,8 +282,8 @@ class DistributedDataParallel:
                     "SPMD: XLA owns collective scheduling (see module "
                     "docstring vs distributed.py:162-175)")
         # async overlap execution (parallel.overlap): "off" | "bucketed";
-        # None resolves APEX_TPU_OVERLAP then the tuning profile's
-        # ddp_overlap AT TRACE TIME (so a Plan.apply env pin flips it).
+        # None resolves APEX_TPU_OVERLAP AT TRACE TIME (so a Plan.apply
+        # env pin flips it).
         # delay_allreduce=True is the explicit deferred path and pins
         # overlap off — the reference's own semantics (delayed
         # allreduce ⇔ no comm-ready buckets, distributed.py:171-175).
@@ -308,12 +307,12 @@ class DistributedDataParallel:
         self.gradient_predivide_factor = gradient_predivide_factor
         self.allreduce_always_fp32 = allreduce_always_fp32
         # compressed/adaptive collective scheme, resolved per-bucket at
-        # trace time (parallel.collectives; None = env/tuning/legacy)
+        # trace time (parallel.collectives; None = env/legacy)
         self.collective_scheme = collective_scheme
         self.collective_min_bytes = collective_min_bytes
         # weight-update sharding (parallel.weight_update): "off" | "zero1";
-        # None resolves env APEX_TPU_UPDATE_SHARDING then the tuning
-        # profile's ddp_update_sharding at weight_update() time.  An
+        # None resolves env APEX_TPU_UPDATE_SHARDING at weight_update()
+        # time.  An
         # invalid explicit value fails HERE, not at first step.
         if update_sharding is not None:
             from . import weight_update as _wu
@@ -351,8 +350,8 @@ class DistributedDataParallel:
         returns ``(grads, new_residuals)``.
 
         Overlap dispatch happens HERE, at trace time: the resolved mode
-        (constructor ``overlap`` > ``APEX_TPU_OVERLAP`` > tuning
-        ``ddp_overlap``; ``delay_allreduce=True`` pins ``"off"``)
+        (constructor ``overlap`` > ``APEX_TPU_OVERLAP`` > ``"off"``;
+        ``delay_allreduce=True`` pins ``"off"``)
         selects the backward-bucketed path
         (:func:`~apex_tpu.parallel.overlap.bucketed_allreduce` — one
         collective per ``message_size``-element bucket, schedulable
@@ -398,8 +397,7 @@ class DistributedDataParallel:
         when the resolved mode is ``"off"`` — the caller then keeps the
         classic ``allreduce_grads`` + replicated-update path, which is
         bitwise-unchanged by this knob.  Resolution: the constructor's
-        ``update_sharding`` > ``APEX_TPU_UPDATE_SHARDING`` >
-        tuning ``ddp_update_sharding`` (TPU only) > off."""
+        ``update_sharding`` > ``APEX_TPU_UPDATE_SHARDING`` > off."""
         from . import weight_update as _wu
         if _wu.resolve_mode(self.update_sharding) == "off":
             return None

@@ -51,8 +51,7 @@ reference bought with streams:
     and scale — is unchanged).
 
 Mode resolution (``resolve_mode``): explicit ``overlap=`` argument >
-``APEX_TPU_OVERLAP`` env > tuning profile ``ddp_overlap`` (TPU only —
-a measured winner applies where it was measured) > ``"off"``.
+``APEX_TPU_OVERLAP`` env > ``"off"``.
 ``DistributedDataParallel(delay_allreduce=True)`` is the explicit
 deferred path and pins ``"off"`` (the reference's own escape hatch for
 models whose backward graph varies per step).  Schemes that cannot
@@ -63,11 +62,10 @@ path with a one-time warning (``can_stream`` / ``warn_once``).
 
 Success is self-measuring: the per-bucket collectives meter through the
 same ``record_collective`` counters (logical bytes sum exactly to the
-deferred path's), and the A/B that proves loss parity is the same one
+deferred path's), and a run that proves loss parity is the same one
 in which the timeline's ``exposed_comm_fraction`` and the ledger's
-``badput.exposed_comm_ms`` must drop (``bench.py --overlap``).  See
-docs/parallel.md "Async overlap
-execution".
+``badput.exposed_comm_ms`` must drop.  See docs/parallel.md "Async
+overlap execution".
 """
 from __future__ import annotations
 
@@ -86,7 +84,7 @@ from .mesh import DATA_AXIS, axis_is_bound
 from ..utils.pallas import presummed
 from ..multi_tensor_apply.flattener import LANE
 
-__all__ = ["MODES", "ENV_KNOB", "TUNING_KEY", "DEFAULT_MESSAGE_SIZE",
+__all__ = ["MODES", "ENV_KNOB", "DEFAULT_MESSAGE_SIZE",
            "resolve_mode", "can_stream", "warn_once",
            "Bucket", "BucketLayout", "partition_buckets",
            "bucketed_allreduce", "shard_chunk_bounds",
@@ -94,7 +92,6 @@ __all__ = ["MODES", "ENV_KNOB", "TUNING_KEY", "DEFAULT_MESSAGE_SIZE",
 
 MODES = ("off", "bucketed")
 ENV_KNOB = "APEX_TPU_OVERLAP"
-TUNING_KEY = "ddp_overlap"
 #: reference default bucket threshold, in ELEMENTS (``message_size``,
 #: apex/parallel/distributed.py:162: 10M elements ≈ 40 MB fp32)
 DEFAULT_MESSAGE_SIZE = 10_000_000
@@ -102,17 +99,11 @@ DEFAULT_MESSAGE_SIZE = 10_000_000
 
 def resolve_mode(mode: Optional[str] = None) -> str:
     """Resolve the overlap mode: explicit ``mode`` >
-    ``APEX_TPU_OVERLAP`` env > tuning profile ``ddp_overlap`` (TPU
-    only) > ``"off"``.  Trace-time, like every other knob in the
-    family — a ``Plan.apply`` env pin flips it with no signature
-    changes anywhere."""
+    ``APEX_TPU_OVERLAP`` env > ``"off"``.  Trace-time, like every
+    other knob in the family — a ``Plan.apply`` env pin flips it with
+    no signature changes anywhere."""
     if mode is None:
-        env = os.environ.get(ENV_KNOB)
-        if env is not None and env.strip():
-            mode = env.strip().lower()
-        else:
-            from ..utils import tuning
-            mode = tuning.get_on_tpu(TUNING_KEY, "off")
+        mode = os.environ.get(ENV_KNOB, "").strip().lower() or "off"
     if mode not in MODES:
         raise ValueError(f"overlap must be one of {MODES}, got {mode!r}")
     return mode
@@ -138,7 +129,7 @@ def can_stream(scheme) -> bool:
     deferred per-leaf path — the reference analogue is that adasum
     needs the full grad set.  Callable per-leaf routing has no
     bucket-level meaning either.  ``scheme=None`` resolves the ambient
-    env/tuning choice, exactly as the reduction itself will."""
+    env choice, exactly as the reduction itself will."""
     if callable(scheme):
         return False
     from . import collectives as _coll
@@ -281,7 +272,7 @@ def bucketed_allreduce(grads, *, axis_name: str = DATA_AXIS,
             "bucketed_allreduce cannot stream a callable per-leaf scheme; "
             "gate on can_stream() and use the deferred allreduce_tree")
     # a scheme=None default consults the controller's live override
-    # (collectives.set_live_spec) ahead of env/tuning — the comm-retune
+    # (collectives.set_live_spec) ahead of the env — the comm-retune
     # actuator's surface; effective at the next traced build
     spec = _coll.resolve(scheme, min_bytes=min_compress_bytes)
     if spec is not None and _coll.get_scheme(spec.scheme).self_scaling:

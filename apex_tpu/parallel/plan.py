@@ -48,19 +48,9 @@ through their existing env/arg surfaces — applying a plan is
 bitwise-identical to configuring the same run by hand (asserted by
 tests/L0/test_plan.py).
 
-Verify/persist loop: ``bench.py --plan`` measures the top-k predicted
-plans and reports predicted-vs-measured step time (the model's
-calibration error, after a one-point calibration on the all-defaults
-baseline); ``tools/apply_perf_results.py`` audits the artifact (a
-measured winner disagreeing with the predicted winner by >25% step
-time fails — calibration drift) and persists the measured winner's
-knobs as ``plan_*`` keys in ``tuned_defaults.json``, which
-:func:`from_tuning` consumes on the next run.
-
 CLI::
 
     python -m apex_tpu.parallel.plan --chips 8 --model flagship
-    python -m apex_tpu.parallel.plan --artifact PLAN_AB_r5.json
 """
 from __future__ import annotations
 
@@ -79,9 +69,8 @@ __all__ = [
     "ModelProfile", "Plan", "profile_step", "flagship_profile",
     "collective_time_s", "compute_time_s", "predict", "plan_hbm_bytes",
     "resolve_overlap_fraction", "ENV_OVERLAP",
-    "enumerate_plans", "search", "default_plan", "from_tuning",
-    "set_replan_hook", "get_replan_hook",
-    "build_flagship_step", "format_plans", "PLAN_SCHEMES", "TUNING_KEYS",
+    "enumerate_plans", "search", "default_plan",
+    "build_flagship_step", "format_plans", "PLAN_SCHEMES",
 ]
 
 #: wire schemes the search enumerates for the dp gradient exchange.
@@ -120,44 +109,19 @@ EP_DEFAULT_EXPERTS = 8
 
 #: env override for the comm model's overlap factor (the measured
 #: exposed-comm fraction) — precedence: explicit ``predict`` arg > this
-#: env pin > the ``overlap_measured_fraction`` tuning key > 1.0 (fully
-#: synchronous collectives, today's engine reality)
+#: env pin > 1.0 (fully synchronous collectives, today's engine reality)
 ENV_OVERLAP = "APEX_TPU_OVERLAP_FRACTION"
 
 
-def resolve_overlap_fraction(explicit: Optional[float] = None, *,
-                             scheme: Optional[str] = None) -> float:
+def resolve_overlap_fraction(explicit: Optional[float] = None) -> float:
     """The dp-comm overlap factor: the fraction of modeled collective
-    time the step actually EXPOSES (``telemetry.timeline``'s measured
-    ``exposed_comm_fraction``, persisted by ``apply_perf_results`` as
-    the ``overlap_measured_fraction`` tuning key).  Clamped to [0, 1];
-    without any measurement the model keeps charging the full wire
-    time — exactly the synchronous engine it describes.
-
-    ``scheme`` names the plan's collective scheme: overlap-capable
-    plans (the dp family, where bucketed execution applies) consult the
-    per-scheme measurement ``overlap_fraction_<scheme>`` first — how
-    much wire time bucketed execution exposes depends on the wire
-    (int8's ~4x fewer bytes hide far more easily than fp32's), so one
-    global fraction would mis-price the codec trade the planner exists
-    to settle (EQuARX, arXiv:2506.17615).  Precedence: explicit arg >
-    ``APEX_TPU_OVERLAP_FRACTION`` env > ``overlap_fraction_<scheme>``
-    (when ``scheme`` given) > global ``overlap_measured_fraction`` >
-    1.0."""
+    time the step actually EXPOSES (what ``telemetry.timeline`` measures
+    as ``exposed_comm_fraction``).  Clamped to [0, 1]; without a
+    measurement handed in the model keeps charging the full wire time —
+    exactly the synchronous engine it describes.  Precedence: explicit
+    arg > ``APEX_TPU_OVERLAP_FRACTION`` env > 1.0."""
     if explicit is None:
-        env = os.environ.get(ENV_OVERLAP)
-        if env:
-            explicit = float(env)
-        else:
-            from ..utils import tuning
-            v = None
-            if scheme:
-                v = tuning.get(f"overlap_fraction_{scheme}")
-            if not (isinstance(v, (int, float))
-                    and not isinstance(v, bool)):
-                v = tuning.get("overlap_measured_fraction")
-            explicit = v if isinstance(v, (int, float)) \
-                and not isinstance(v, bool) else 1.0
+        explicit = float(os.environ.get(ENV_OVERLAP) or 1.0)
     return min(max(float(explicit), 0.0), 1.0)
 
 
@@ -261,7 +225,7 @@ def _flagship_cfg(on_tpu: bool, **overrides):
     from ..models import bert_large_config
     if on_tpu:
         return bert_large_config(**overrides)
-    # the CPU stand-in the bench uses: small enough for tier-1, same
+    # the CPU stand-in: small enough for tier-1, same
     # structure (stacked layers, tied embeddings) as the flagship
     base = dict(num_layers=2, d_model=128, d_ff=512, vocab_size=1024,
                 max_len=64, num_heads=4)
@@ -271,8 +235,8 @@ def _flagship_cfg(on_tpu: bool, **overrides):
 
 def flagship_profile(cfg=None, *, global_batch: Optional[int] = None,
                      **overrides) -> Tuple[ModelProfile, object, int]:
-    """Profile the flagship transformer train step (fused-flat Adam —
-    the same per-chip program ``bench.py --plan`` measures).  Returns
+    """Profile the flagship transformer train step (fused-flat Adam).
+    Returns
     ``(profile, cfg, global_batch)``."""
     import jax
     on_tpu = jax.default_backend() == "tpu"
@@ -432,7 +396,7 @@ class Plan:
     """One point of the search space: mesh axis sizes + the knob dict,
     with the model's predictions attached.  :meth:`apply` materializes
     it through the existing surfaces; :meth:`knobs` is the serializable
-    form bench artifacts and ``tuned_defaults.json`` carry."""
+    form."""
     dp: int = 1
     tp: int = 1
     sp: int = 1
@@ -470,9 +434,8 @@ class Plan:
 
     @property
     def family(self) -> str:
-        """Which step engine (``parallel.spmd``) materializes this plan
-        — also the one-point-calibration bucket ``bench.py --plan``
-        uses: ``zero`` (contrib ZeRO) / ``tp`` (consistent-SPMD GSPMD
+        """Which step engine (``parallel.spmd``) materializes this
+        plan: ``zero`` (contrib ZeRO) / ``tp`` (consistent-SPMD GSPMD
         jit) / ``sp`` (ring/ulysses shard_map) / ``pp`` (GPipe
         microbatched stages) / ``ep`` (switch-MoE expert sharding) /
         ``dp`` (the classic DDP harness)."""
@@ -490,7 +453,7 @@ class Plan:
 
     @property
     def measurable(self) -> bool:
-        """Can ``bench.py --plan`` time this plan?  True across the
+        """Can this plan be run and timed?  True across the
         whole search space since the ``parallel.spmd`` step engine
         (ISSUE 12; pp/ep families ISSUE 17): every family — dp, dp x tp
         (GSPMD), dp x sp (ring/ulysses), dp x pp (GPipe), dp x ep
@@ -678,13 +641,7 @@ def predict(profile: ModelProfile, plan: Plan, ceilings=None,
     modeled comm stays visible in ``breakdown["dp_comm_ms"]``;
     ``breakdown["dp_comm_exposed_ms"]`` is what the total charges."""
     ceil = _resolve_ceil(ceilings, platform or profile.platform)
-    # overlap-capable plans (the dp family — the wire bucketed
-    # execution streams) consume the per-scheme measured fraction;
-    # other families keep the single global measurement (their dp wire,
-    # if any, is not bucket-scheduled by this engine)
-    overlap = resolve_overlap_fraction(
-        overlap_fraction,
-        scheme=(plan.collective_scheme if plan.family == "dp" else None))
+    overlap = resolve_overlap_fraction(overlap_fraction)
     dp, tp, sp = plan.dp, plan.tp, plan.sp
     pp, ep = plan.pp_stages, plan.ep
     shards = dp * tp * sp * pp * ep
@@ -928,8 +885,8 @@ def search(profile: ModelProfile, chips: int, *,
     by predicted step time with near-ties broken toward the simpler
     plan.  Never returns an HBM-infeasible plan (property-tested).
 
-    Invoked between runs (bench/tuning, elastic resume at a new chip
-    count) and MID-RUN by the controller's ``replan_reshard`` actuator
+    Invoked between runs (elastic resume at a new chip count) and
+    MID-RUN by the controller's ``replan_reshard`` actuator
     (``apex_tpu.control`` via :func:`apex_tpu.elastic.replan`) — the
     search is pure host arithmetic over the cost model, so an in-run
     call costs milliseconds, no compiles, no device syncs."""
@@ -957,7 +914,7 @@ def search(profile: ModelProfile, chips: int, *,
 
 
 # ---------------------------------------------------------------------------
-# measurement harness: the dp-family training step bench.py --plan times
+# the dp-family training step (``spmd.build_plan_step``'s dp engine)
 # ---------------------------------------------------------------------------
 
 def build_flagship_step(cfg, mesh, *, global_batch: int,
@@ -1051,74 +1008,6 @@ def build_flagship_step(cfg, mesh, *, global_batch: int,
 
 
 # ---------------------------------------------------------------------------
-# persistence: the tuned_defaults.json loop
-# ---------------------------------------------------------------------------
-
-#: tuning-profile keys the apply_perf_results decision rule writes (and
-#: :func:`from_tuning` consumes) — kept in one place so the two ends of
-#: the loop cannot drift
-TUNING_KEYS = ("plan_dp", "plan_tp", "plan_sp", "plan_sp_strategy",
-               "plan_pp_stages", "plan_pp_microbatches", "plan_ep",
-               "plan_zero", "plan_update_sharding",
-               "plan_collective_scheme", "plan_allgather_scheme")
-
-#: elastic re-plan hook: ``hook(tuned_plan, chips) -> Optional[Plan]``.
-#: ``apex_tpu.elastic.install()`` registers one so a tuned plan whose
-#: chip count no longer matches the fleet triggers a fresh
-#: :func:`search` at the NEW chip count (AMP's re-run-the-search-when-
-#: the-pool-changes posture) instead of silently falling back to
-#: all-defaults.  Without a hook the legacy behavior stands: a winner
-#: measured at one topology says nothing about another -> None.
-_REPLAN_HOOK = None
-
-
-def set_replan_hook(hook):
-    """Install the chips-mismatch re-plan hook (None uninstalls).
-    Returns the previous hook so callers can restore it."""
-    global _REPLAN_HOOK
-    prev = _REPLAN_HOOK
-    _REPLAN_HOOK = hook
-    return prev
-
-
-def get_replan_hook():
-    return _REPLAN_HOOK
-
-
-def from_tuning(chips: Optional[int] = None, *,
-                tpu_only: bool = True) -> Optional[Plan]:
-    """The persisted measured-winner plan from ``tuned_defaults.json``
-    (``plan_*`` keys), or None when absent.  ``chips`` given: a plan
-    tuned for a different topology is a *re-plan trigger* when an
-    elastic hook is installed (:func:`set_replan_hook` — the hook
-    re-runs the cost-model search for the live chip count), else None —
-    a winner measured at one chip count says nothing about another.
-    ``tpu_only`` follows the tuning posture (measured winners apply
-    where they were measured); pass False for rendering/tooling."""
-    from ..utils import tuning
-    get = tuning.get_on_tpu if tpu_only else tuning.get
-    dp = get("plan_dp")
-    if dp is None:
-        return None
-    plan = Plan(
-        dp=int(dp), tp=int(get("plan_tp", 1)), sp=int(get("plan_sp", 1)),
-        sp_strategy=get("plan_sp_strategy", "none"),
-        pp_stages=int(get("plan_pp_stages", 1) or 1),
-        pp_microbatches=int(get("plan_pp_microbatches", 1) or 1),
-        ep=int(get("plan_ep", 1) or 1),
-        zero=bool(get("plan_zero", False)),
-        update_sharding=get("plan_update_sharding", "off"),
-        collective_scheme=get("plan_collective_scheme", "fp32"),
-        allgather_scheme=get("plan_allgather_scheme", "fp32"),
-    )
-    if chips is not None and plan.chips != int(chips):
-        if _REPLAN_HOOK is not None:
-            return _REPLAN_HOOK(plan, int(chips))
-        return None
-    return plan
-
-
-# ---------------------------------------------------------------------------
 # rendering / CLI
 # ---------------------------------------------------------------------------
 
@@ -1128,17 +1017,15 @@ def _human_bytes(n) -> str:
 
 
 def format_plans(plans: Sequence[Plan], *, chips: Optional[int] = None,
-                 measured: Optional[Dict[int, float]] = None,
                  top: int = 12) -> str:
     """The ranked plan table: predicted ms (+ breakdown), HBM/replica,
-    knob summary; ``measured`` maps plan index -> measured ms."""
-    measured = measured or {}
+    knob summary."""
     head = "auto-parallel plans"
     if chips:
         head += f" @ {chips} chips"
     lines = [
         head,
-        f"{'rank':<5}{'pred ms':>9} {'meas ms':>9} {'HBM/replica':>12}  "
+        f"{'rank':<5}{'pred ms':>9} {'HBM/replica':>12}  "
         f"{'comm ms (dp/tp/sp)':>20}  plan",
     ]
     for i, p in enumerate(plans[:top]):
@@ -1146,10 +1033,8 @@ def format_plans(plans: Sequence[Plan], *, chips: Optional[int] = None,
         comm = (f"{b.get('dp_comm_ms', 0.0):.2f}/"
                 f"{b.get('tp_comm_ms', 0.0):.2f}/"
                 f"{b.get('sp_comm_ms', 0.0):.2f}")
-        m = measured.get(i)
         lines.append(
             f"{i:<5}{p.predicted_step_ms:>9.3f} "
-            f"{(f'{m:.3f}' if m is not None else '-'):>9} "
             f"{_human_bytes(p.predicted_hbm_bytes):>12}  {comm:>20}  "
             f"{p.describe() or 'all-defaults'}")
     if len(plans) > top:
@@ -1159,46 +1044,12 @@ def format_plans(plans: Sequence[Plan], *, chips: Optional[int] = None,
     return "\n".join(lines)
 
 
-def _plans_from_artifact(art: dict) -> Tuple[List[Plan], Dict[int, float]]:
-    """Rebuild (plans, measured) from a bench artifact: a full bench
-    JSON (``detail.plan``), a ``plan_ab`` artifact (``plan``), or a
-    bare plan-leg dict."""
-    leg = art
-    for key in ("detail", "plan"):
-        if isinstance(leg, dict) and key in leg:
-            leg = leg[key]
-    rows = (leg or {}).get("plans") if isinstance(leg, dict) else None
-    if not rows:
-        raise ValueError("artifact carries no plan leg "
-                         "(expected detail.plan.plans / plan.plans)")
-    plans, measured = [], {}
-    for i, row in enumerate(rows):
-        kn = dict(row.get("knobs") or {})
-        plans.append(Plan(
-            dp=kn.get("dp", 1), tp=kn.get("tp", 1), sp=kn.get("sp", 1),
-            sp_strategy=kn.get("sp_strategy", "none"),
-            pp_stages=kn.get("pp_stages", 1),
-            pp_microbatches=kn.get("pp_microbatches", 1),
-            ep=kn.get("ep", 1),
-            zero=kn.get("zero", False),
-            update_sharding=kn.get("update_sharding", "off"),
-            collective_scheme=kn.get("collective_scheme", "fp32"),
-            allgather_scheme=kn.get("allgather_scheme", "fp32"),
-            predicted_step_ms=row.get("predicted_ms") or 0.0,
-            predicted_hbm_bytes=row.get("hbm_bytes") or 0,
-        ))
-        if isinstance(row.get("measured_ms"), (int, float)):
-            measured[i] = float(row["measured_ms"])
-    return plans, measured
-
-
 def _main(argv=None):   # pragma: no cover - exercised via CLI test
     import argparse
-    import json
 
     ap = argparse.ArgumentParser(
         description="Auto-parallel planner: ranked plan table from a "
-                    "bench artifact or a fresh CPU cost-model run.")
+                    "fresh cost-model run.")
     ap.add_argument("--chips", type=int, default=None,
                     help="device count to plan for (default: visible "
                          "devices)")
@@ -1208,21 +1059,11 @@ def _main(argv=None):   # pragma: no cover - exercised via CLI test
     ap.add_argument("--layers", type=int)
     ap.add_argument("--batch", type=int, help="GLOBAL batch")
     ap.add_argument("--seq", type=int)
-    ap.add_argument("--artifact",
-                    help="render a measured bench.py --plan artifact "
-                         "instead of running the cost model")
     ap.add_argument("--capacity-gb", type=float,
                     help="override the HBM capacity the feasibility "
                          "check prunes against")
     ap.add_argument("--top", type=int, default=12)
     args = ap.parse_args(argv)
-
-    if args.artifact:
-        with open(args.artifact) as f:
-            art = json.load(f)
-        plans, measured = _plans_from_artifact(art)
-        print(format_plans(plans, measured=measured, top=args.top))
-        return 0
 
     if args.model != "flagship":
         ap.error(f"unknown model {args.model!r} (only 'flagship')")
@@ -1245,9 +1086,6 @@ def _main(argv=None):   # pragma: no cover - exercised via CLI test
           f"peak {_human_bytes(prof.peak_hbm_bytes)}")
     print(f"{n_all} candidates, {len(ranked)} HBM-feasible")
     print(format_plans(ranked, chips=chips, top=args.top))
-    tuned = from_tuning(chips, tpu_only=False)
-    if tuned is not None:
-        print(f"tuned_defaults.json plan: {tuned.describe() or 'defaults'}")
     return 0
 
 

@@ -74,11 +74,7 @@ copy (and activations) at bf16 off the same master — the O2 contract —
 with the overflow-skip select keeping non-finite steps out of the
 master, exactly like the dp harness.
 
-``bench.py --plan`` drives this engine for every ranked candidate (one-
-point calibration per family), and ``bench.py --spmd`` A/Bs one
-representative per family against the dp baseline with the compiled
-collective sub-table embedded.  See docs/parallel.md "SPMD step
-engine".
+See docs/parallel.md "SPMD step engine".
 """
 from __future__ import annotations
 
@@ -301,10 +297,9 @@ def build_plan_step(cfg, mesh, plan, *, global_batch: int, lr: float = 1e-2,
         return _build_ep_step(cfg, mesh, plan, global_batch, lr, meter)
     from .plan import build_flagship_step
     # async overlap execution rides the dp engine: resolve the ambient
-    # mode here (env APEX_TPU_OVERLAP / tuning ddp_overlap — what
-    # Plan.apply or an A/B run sets) and surface it both to the
-    # DDP harness and in the engine info, so the A/B artifact records
-    # which execution actually ran
+    # mode here (env APEX_TPU_OVERLAP — what Plan.apply or an A/B run
+    # sets) and surface it both to the DDP harness and in the engine
+    # info, so a run records which execution actually ran
     from . import overlap as _ov
     ov_mode = _ov.resolve_mode(None)
     ddp_kwargs = {"overlap": ov_mode} if ov_mode != "off" else None

@@ -12,19 +12,17 @@ the allreduce-then-replicated-update with
   1. **reduce-scatter** of the flat gradient buffer — each replica
      receives its contiguous 1/N slice of the summed gradients
      (compressed schemes from ``parallel.collectives`` ride the same
-     wire as the DDP allreduce: ``APEX_TPU_COLLECTIVES`` /
-     ``ddp_collective_scheme``, with optional int8 error-feedback
-     residuals);
+     wire as the DDP allreduce: ``APEX_TPU_COLLECTIVES``, with
+     optional int8 error-feedback residuals);
   2. a **``step_flat``-style update over the 1/N slice** of the
      permanently-flat master/moment buffers (PERF_NOTES §1 — the flat
      engine makes slicing trivial; elementwise optimizers run their
      ``step_flat`` unchanged, LAMB/NovoGrad override
      ``step_flat_shard`` with psum'd per-tensor reductions);
   3. an **allgather of the updated params** back to every replica,
-     optionally bf16/int8_blockscale (explicit ``allgather_scheme`` or
-     the measured ``ddp_update_allgather_scheme`` tuning key — the
-     ambient ``APEX_TPU_COLLECTIVES`` env never quantizes params,
-     same posture as the ZeRO allgather).
+     optionally bf16/int8_blockscale (explicit ``allgather_scheme``
+     only — the ambient ``APEX_TPU_COLLECTIVES`` env never quantizes
+     params, same posture as the ZeRO allgather).
 
 Per-replica optimizer-state HBM and update FLOPs drop by 1/N while the
 training loop stays DDP-shaped: replicated params in, local grads in,
@@ -42,17 +40,16 @@ skips identically even when a compressed scatter would mangle the
 non-finite values, matching ``amp``'s skip-step contract.
 
 Knob precedence (``resolve_mode``): explicit ``update_sharding``
-argument > ``APEX_TPU_UPDATE_SHARDING`` env > tuning profile
-``ddp_update_sharding`` (TPU only) > ``"off"``.
+argument > ``APEX_TPU_UPDATE_SHARDING`` env > ``"off"``.
 
 Telemetry: the two collectives meter as ``ddp.reduce_scatter`` /
 ``ddp.param_allgather`` through ``record_collective`` (logical vs wire
 bytes, scheme, dtype), and ``ddp.opt_state_bytes_per_replica`` /
 ``ddp.update_shard_world`` gauges carry the sharded-state footprint —
-the numbers the bench ``update_sharding`` A/B leg and the acceptance
-tests assert.  The sharded state is a plain pytree (the optimizer's own
-state class with shard-length flat fields), so it snapshots/restores
-bitwise through ``resilience.TrainGuard`` like any other step carry.
+the numbers the acceptance tests assert.  The sharded state is a plain
+pytree (the optimizer's own state class with shard-length flat fields),
+so it snapshots/restores bitwise through ``resilience.TrainGuard`` like
+any other step carry.
 """
 from __future__ import annotations
 
@@ -68,27 +65,18 @@ from .mesh import DATA_AXIS
 from ..utils.pallas import presummed
 from ..multi_tensor_apply.flattener import TreeFlattener, LANE
 
-__all__ = ["MODES", "ENV_KNOB", "TUNING_KEY", "AG_TUNING_KEY",
-           "resolve_mode", "ShardContext", "ShardedUpdate"]
+__all__ = ["MODES", "ENV_KNOB", "resolve_mode", "ShardContext",
+           "ShardedUpdate"]
 
 MODES = ("off", "zero1")
 ENV_KNOB = "APEX_TPU_UPDATE_SHARDING"
-TUNING_KEY = "ddp_update_sharding"
-AG_TUNING_KEY = "ddp_update_allgather_scheme"
 
 
 def resolve_mode(mode: Optional[str] = None) -> str:
     """Resolve the update-sharding mode: explicit ``mode`` >
-    ``APEX_TPU_UPDATE_SHARDING`` env > tuning profile
-    ``ddp_update_sharding`` (TPU only — a measured winner applies where
-    it was measured) > ``"off"``."""
+    ``APEX_TPU_UPDATE_SHARDING`` env > ``"off"``."""
     if mode is None:
-        env = os.environ.get(ENV_KNOB)
-        if env is not None and env.strip():
-            mode = env.strip().lower()
-        else:
-            from ..utils import tuning
-            mode = tuning.get_on_tpu(TUNING_KEY, "off")
+        mode = os.environ.get(ENV_KNOB, "").strip().lower() or "off"
     if mode not in MODES:
         raise ValueError(
             f"update_sharding must be one of {MODES}, got {mode!r}")
@@ -182,12 +170,10 @@ class ShardedUpdate:
         params, state = wu.step(state, grads, params, scale=loss_scale)
 
     ``collective_scheme``/``collective_min_bytes`` ride the gradient
-    reduce-scatter (default: ``APEX_TPU_COLLECTIVES`` env > the
-    measured ``ddp_collective_scheme`` tuning key — the same wire the
-    DDP allreduce tunes); ``allgather_scheme`` rides the param gather
-    (explicit arg > ``ddp_update_allgather_scheme`` tuning key >
-    fp32).  ``residual`` support mirrors the DDP/ZeRO error-feedback
-    contract (:meth:`init_residual`)."""
+    reduce-scatter (default: ``APEX_TPU_COLLECTIVES`` env — the same
+    wire as the DDP allreduce); ``allgather_scheme`` rides the param
+    gather (explicit arg > fp32).  ``residual`` support mirrors the
+    DDP/ZeRO error-feedback contract (:meth:`init_residual`)."""
 
     def __init__(self, optimizer, *, axis_name: str = DATA_AXIS,
                  gradient_average: bool = True,
@@ -218,7 +204,7 @@ class ShardedUpdate:
         # so XLA can overlap each chunk's wire time with the backward
         # compute behind the next one / the forward compute consuming
         # the previous one.  Resolution is TRACE-TIME (explicit arg >
-        # APEX_TPU_OVERLAP > tuning ddp_overlap); fp32 chunking is
+        # APEX_TPU_OVERLAP > off); fp32 chunking is
         # bitwise vs the whole-buffer path, block-aligned int8 too.
         if overlap is not None:
             from . import overlap as _ov
@@ -260,25 +246,20 @@ class ShardedUpdate:
 
     def _resolve_rs(self):
         """Gradient reduce-scatter scheme: explicit arg >
-        ``APEX_TPU_COLLECTIVES`` env > the DDP tuning winner — this IS
-        the DDP gradient wire, just scattered instead of allreduced."""
+        ``APEX_TPU_COLLECTIVES`` env — this IS the DDP gradient wire,
+        just scattered instead of allreduced."""
         from . import collectives as _coll
         return _coll.resolve(self.collective_scheme,
                              min_bytes=self.collective_min_bytes)
 
     def _resolve_ag(self):
-        """Param allgather scheme: explicit arg > the measured
-        ``ddp_update_allgather_scheme`` tuning key > fp32.  The ambient
+        """Param allgather scheme: explicit arg > fp32.  The ambient
         ``APEX_TPU_COLLECTIVES`` env is deliberately NOT consulted —
         quantizing params is an accuracy trade an A/B knob must not
         flip implicitly (the ZeRO posture)."""
         from . import collectives as _coll
         if self.allgather_scheme is not None:
-            return _coll.resolve(self.allgather_scheme, tuning_key=None)
-        from ..utils import tuning
-        name = tuning.get_on_tpu(AG_TUNING_KEY)
-        if name and name != "fp32":
-            return _coll.resolve(name, tuning_key=None)
+            return _coll.resolve(self.allgather_scheme)
         return None
 
     # -- metering ------------------------------------------------------------

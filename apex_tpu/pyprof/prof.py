@@ -100,55 +100,6 @@ CEILING_KEYS = ("peak_flops", "peak_bw", "ici_bw", "ici_alpha_s",
 ENV_CEILINGS = "APEX_TPU_CEILINGS"
 
 
-def calibrate_ceilings(base: dict, artifact: dict) -> dict:
-    """Fold a measured ``bench.py --plan`` artifact (``PLAN_AB.json``
-    / a full bench JSON with a ``plan`` leg) into a ceilings row: the
-    leg's one-point calibration scale ``s = measured / predicted`` says
-    this machine runs ``s``x slower than the datasheet row models, so
-    every rate ceiling divides by ``s`` and every latency multiplies —
-    after which the analytic model's ABSOLUTE predictions land on the
-    measured baseline by construction, and its relative rankings carry
-    the on-chip correction.  A per-family calibration table
-    (``family_calibration``) refines the comm tier: when the dp
-    family's scale differs from the overall scale, the ratio lands on
-    the ICI/DCN terms (comm mispredicts independently of compute).
-
-    Raises ``ValueError`` when the artifact carries no measured plan
-    leg — a calibration request against an empty artifact must fail
-    loudly, not silently return the datasheet row."""
-    leg = artifact
-    for key in ("detail", "plan"):
-        if isinstance(leg, dict) and key in leg:
-            leg = leg[key]
-    if not (isinstance(leg, dict) and leg.get("leg") == "plan"
-            and isinstance(leg.get("calibration_scale"), (int, float))
-            and leg["calibration_scale"] > 0):
-        raise ValueError(
-            "ceilings calibration needs a measured plan leg with a "
-            "calibration_scale (bench.py --plan artifact); got none")
-    s = float(leg["calibration_scale"])
-    out = dict(base)
-    for k in ("peak_flops", "peak_bw", "ici_bw", "dcn_bw"):
-        if k in out:
-            out[k] = out[k] / s
-    for k in ("ici_alpha_s", "dcn_alpha_s"):
-        if k in out:
-            out[k] = out[k] * s
-    fams = leg.get("family_calibration")
-    if isinstance(fams, dict):
-        dp_s = fams.get("dp")
-        comm_fams = [v for k, v in fams.items()
-                     if k != "dp" and isinstance(v, (int, float)) and v > 0]
-        if isinstance(dp_s, (int, float)) and dp_s > 0 and comm_fams:
-            # comm tier correction: the non-dp families' extra scale
-            # relative to dp is dominated by their collective terms
-            comm_ratio = (sum(comm_fams) / len(comm_fams)) / dp_s
-            out["ici_bw"] = out["ici_bw"] / comm_ratio
-            if "dcn_bw" in out:
-                out["dcn_bw"] = out["dcn_bw"] / comm_ratio
-    return out
-
-
 def ceilings_row(device=None) -> str:
     """The ``HW_CEILINGS`` row name for ``device``: a ``jax.Device``
     (default ``jax.devices()[0]``), a ``device_kind`` string, or a row
@@ -181,27 +132,14 @@ def resolve_ceilings(device="cpu") -> dict:
         APEX_TPU_CEILINGS="v5p"                      # named generation row
         APEX_TPU_CEILINGS="peak_flops=2.75e14"       # key override
         APEX_TPU_CEILINGS="v4,ici_bw=5e10"           # row, then override
-        APEX_TPU_CEILINGS="v5e,@PLAN_AB.json"        # measured calibration
 
     A bare token names an ``HW_CEILINGS`` row (``v4``/``v5e``/``v5p``
     shorthands resolve to their ``tpu_*`` rows); ``key=value`` tokens
-    override individual ceilings; an ``@path`` token ingests a measured
-    ``bench.py --plan`` artifact through :func:`calibrate_ceilings` —
-    the on-chip correction loop."""
+    override individual ceilings."""
     base = dict(HW_CEILINGS[ceilings_row(device)])
     spec = os.environ.get(ENV_CEILINGS, "").strip()
     for tok in filter(None, (t.strip() for t in spec.split(","))):
-        if tok.startswith("@"):
-            import json
-            try:
-                with open(tok[1:]) as f:
-                    art = json.load(f)
-            except (OSError, ValueError) as e:
-                raise ValueError(
-                    f"{ENV_CEILINGS}: cannot read calibration artifact "
-                    f"{tok[1:]!r}: {e}") from None
-            base = calibrate_ceilings(base, art)
-        elif "=" in tok:
+        if "=" in tok:
             key, _, val = tok.partition("=")
             key = key.strip()
             if key not in CEILING_KEYS:

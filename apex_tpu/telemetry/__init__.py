@@ -27,8 +27,8 @@ Ten pieces (see docs/telemetry.md):
     EXPOSED collective ms (exact interval subtraction), idle/stall
     time, cross-device straggler z-scores (``timeline.straggler``
     events), a correlated host+device Chrome merge, and the measured
-    ``exposed_comm_fraction`` that feeds the planner's
-    ``overlap_measured_fraction`` tuning key;
+    ``exposed_comm_fraction`` (the planner's overlap factor,
+    ``APEX_TPU_OVERLAP_FRACTION``);
   * :mod:`goodput`   — the run-level goodput ledger: every wall-clock
     second of a run attributed to exactly one class (productive step
     compute, exposed collective, data stall, exposed checkpoint save,

@@ -5,10 +5,9 @@ renders the span-timeline summary from a Chrome-trace file (a
 ``Tracer.write`` export, a streaming never-closed event array, or a
 jax-profiler run dir); ``python -m apex_tpu.telemetry mem [artifact]``
 renders the per-class peak-HBM attribution table (the flagship
-transformer step, a bench artifact's MFU/peak-HBM fields, or a
-``flight-oom-*.json`` post-mortem); ``python -m apex_tpu.telemetry
-timeline <trace|profiler-dir>`` renders the per-device step
-decomposition (compute / comm / exposed-comm / idle ms + straggler
+transformer step or a ``flight-oom-*.json`` post-mortem);
+``python -m apex_tpu.telemetry timeline <trace|profiler-dir>`` renders
+the per-device step decomposition (compute / comm / exposed-comm / idle ms + straggler
 skew) from a device trace; ``python -m apex_tpu.telemetry goodput
 <jsonl|run-dir>`` renders the run-level goodput ledger (wall-clock
 badput attribution) from a ``GOODPUT.json`` artifact or a run's

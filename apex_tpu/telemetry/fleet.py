@@ -40,9 +40,7 @@ the same records, asserted by ``tests/L0/test_fleet.py``.
 
 Like goodput/report this module is file-based and jax-free — merging
 run dirs must never pay backend bring-up — and performs zero host
-syncs ever (the host-sync lint covers it with no waivers).  It also
-imports standalone (no package context) so ``tools/bench_trend.py``
-can file-load it to audit FLEET artifacts, exactly like goodput.
+syncs ever (the host-sync lint covers it with no waivers).
 """
 from __future__ import annotations
 
@@ -52,10 +50,7 @@ import os
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
-try:                        # package import (the normal case)
-    from . import goodput as _goodput
-except ImportError:         # standalone file-based load (bench_trend
-    _goodput = None         # audits the schema, never merges)
+from . import goodput as _goodput
 
 __all__ = [
     "ARTIFACT_NAME", "TIMELINE_NAME", "GOODPUT_CLASSES",
@@ -73,8 +68,7 @@ TIMELINE_NAME = "FLEET_TRACE.json"
 GOODPUT_CLASSES = ("recompile", "reshard", "restore_replay",
                    "ckpt_exposed", "data_stall", "exposed_comm",
                    "pipeline_bubble", "productive", "idle")
-if _goodput is not None:
-    assert tuple(_goodput.CLASSES) == GOODPUT_CLASSES
+assert tuple(_goodput.CLASSES) == GOODPUT_CLASSES
 
 _PARTITION_TOL_MS = 1e-3
 
@@ -180,8 +174,7 @@ def load_host(path: str, name: Optional[str] = None) -> dict:
     records = _host_records(path)
     good = None
     try:
-        if _goodput is not None:
-            good = _goodput.load_artifact(path)
+        good = _goodput.load_artifact(path)
     except ValueError:
         good = None
     control = serve = None
@@ -410,8 +403,7 @@ def build_fleet(dirs: List[str], *, host_names: Optional[List[str]] = None,
                 # the load-bearing assertion: this host's classes must
                 # still partition ITS wall exactly — a fleet view that
                 # tolerated a torn partition would launder the books
-                bad = (_goodput.goodput_violations(good)
-                       if _goodput is not None else [])
+                bad = _goodput.goodput_violations(good)
                 entry["partition_ok"] = not bad
                 if bad:
                     raise ValueError(
@@ -508,7 +500,7 @@ def build_fleet(dirs: List[str], *, host_names: Optional[List[str]] = None,
 
 
 # ---------------------------------------------------------------------------
-# schema (writer-validates; standalone-loadable for bench_trend)
+# schema (writer-validates)
 # ---------------------------------------------------------------------------
 
 def fleet_violations(doc: Any) -> List[str]:
@@ -600,9 +592,8 @@ def fleet_violations(doc: Any) -> List[str]:
                     out.append(f"per_host.{name}: classes sum "
                                f"{host_total} != wall {w} — the host "
                                "partition is torn")
-            if _goodput is not None:
-                for v in _goodput.goodput_violations(good)[:2]:
-                    out.append(f"per_host.{name}: {v}")
+            for v in _goodput.goodput_violations(good)[:2]:
+                out.append(f"per_host.{name}: {v}")
     skew = doc.get("skew")
     if not (isinstance(skew, dict) and _is_int(skew.get("steps_compared"))
             and _is_num(skew.get("max_skew_ms"))

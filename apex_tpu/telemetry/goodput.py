@@ -94,8 +94,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 # NOTE: the interval-arithmetic core (timeline._merge/_subtract/_clip/
 # _total_us) is imported INSIDE the methods that partition — this
-# module must import standalone (no package context) for the tooling
-# layer (tools/apply_perf_results.py, tools/bench_trend.py), which
+# module must import standalone (no package context) for tooling that
 # file-loads it to audit GOODPUT artifacts without paying the jax
 # import, exactly like registry.py's SCHEMA
 

@@ -41,9 +41,9 @@ cost model.  Three pieces:
     deterministic, retry/rollback would only burn the budget.
 
 ``python -m apex_tpu.telemetry mem`` renders the attribution table
-from the flagship transformer step, a bench artifact, or a flight-oom
-dump.  Like the registry, no jax at module scope; ``memory_stats()``
-calls live ONLY here (the host-sync lint enforces it).
+from the flagship transformer step or a flight-oom dump.  Like the
+registry, no jax at module scope; ``memory_stats()`` calls live ONLY
+here (the host-sync lint enforces it).
 """
 from __future__ import annotations
 
@@ -359,8 +359,7 @@ def compiled_memory_stats(fn_or_jitted, *args, **kwargs) -> Optional[dict]:
     Accepts a plain callable or an already-``jax.jit``-ed one.  NOTE:
     ``lower().compile()`` bypasses the in-memory jit executable cache
     (it may hit the persistent XLA cache when one is configured) — on
-    a TPU this can re-pay a full compile, which is why ``bench.py``
-    only takes this path off-TPU."""
+    a TPU this can re-pay a full compile."""
     import jax
     jitted = (fn_or_jitted if hasattr(fn_or_jitted, "lower")
               else jax.jit(fn_or_jitted))
@@ -828,34 +827,8 @@ def _render_artifact(path: str, top: int) -> int:
         doc = json.load(f)
     if isinstance(doc, dict) and doc.get("kind") == "flight_recorder":
         return _render_oom_dump(doc, top)
-    rows: List[tuple] = []
-
-    def walk(node, label):
-        if isinstance(node, list):
-            for i, v in enumerate(node):
-                walk(v, f"{label}[{i}]")
-            return
-        if not isinstance(node, dict):
-            return
-        mfu = node.get("mfu_pct", node.get("mfu_analytic_pct"))
-        hbm = node.get("hbm_compiled_peak_bytes",
-                       node.get("hbm_device_process_peak_bytes"))
-        if mfu is not None or hbm is not None:
-            rows.append((label, mfu, hbm, node.get("hbm_temp_bytes")))
-        for k, v in node.items():
-            if k != "telemetry":
-                walk(v, f"{label}.{k}" if label else k)
-
-    walk(doc, "")
-    if not rows:
-        print(f"no MFU / peak-HBM fields in {path}")
-        return 1
-    print(f"{'leg':<40} {'MFU %':>8} {'peak HBM':>12} {'temps':>12}")
-    for label, mfu, hbm, temps in rows:
-        print(f"{(label or 'artifact'):<40} "
-              f"{mfu if mfu is not None else 'n/a':>8} "
-              f"{_human(hbm, 'B'):>12} {_human(temps, 'B'):>12}")
-    return 0
+    print(f"{path} is not a flight-oom dump")
+    return 1
 
 
 def cli(argv=None) -> int:
@@ -866,10 +839,10 @@ def cli(argv=None) -> int:
         description="Peak-HBM attribution: with no argument, compile the "
                     "flagship transformer train step on the ambient "
                     "backend and render the per-class liveness table; "
-                    "with a path, render a bench artifact's MFU/peak-HBM "
-                    "fields or a flight-oom-*.json post-mortem.")
+                    "with a path, render a flight-oom-*.json "
+                    "post-mortem.")
     ap.add_argument("artifact", nargs="?", default=None,
-                    help="bench artifact JSON or flight-oom dump")
+                    help="flight-oom dump")
     ap.add_argument("--layers", type=int, default=2)
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--seq", type=int, default=32)

@@ -16,13 +16,13 @@ registry that warning asks for:
     nothing is stored, and zero host syncs happen (asserted by
     ``tests/L0/test_telemetry.py``);
   * emission is rank-0 gated (``utils.logging.is_rank0``) and lands as
-    JSONL records validated against a committed :data:`SCHEMA` — the
-    same writer-validates posture as ``utils/tuning.SCHEMA``.
+    JSONL records validated against a committed :data:`SCHEMA` (the
+    writer validates).
 
-No jax import at module scope: schema validation and the tooling that
-consumes telemetry artifacts (``tools/apply_perf_results.py``) must
-never pay backend bring-up.  jax is imported inside :meth:`Registry.flush`,
-the only place device values are resolved.
+No jax import at module scope: schema validation and tooling that
+consumes telemetry artifacts must never pay backend bring-up.  jax is
+imported inside :meth:`Registry.flush`, the only place device values
+are resolved.
 """
 from __future__ import annotations
 
@@ -35,10 +35,10 @@ from typing import Any, Dict, List, Optional
 
 try:                        # package import (the normal case)
     from . import trace as _trace
-except ImportError:         # standalone file-based load: tools/
-    # apply_perf_results.py execs this file OUTSIDE the package to
-    # audit SCHEMA without importing jax — the tracing hooks (span
-    # ring, sentinel) become no-ops there
+except ImportError:         # standalone file-based load: a tool may
+    # exec this file OUTSIDE the package to audit SCHEMA without
+    # importing jax — the tracing hooks (span ring, sentinel) become
+    # no-ops there
     class _trace:           # noqa: N801 - module-shaped shim
         note_event = staticmethod(lambda *a, **k: None)
         note_flush = staticmethod(lambda *a, **k: None)
@@ -184,8 +184,8 @@ class JsonlSink:
 
 
 class MemorySink:
-    """In-memory record list — tests, and benches that embed telemetry
-    records into their JSON artifacts (``bench.py`` bert leg)."""
+    """In-memory record list — tests, and runs that embed telemetry
+    records into their JSON artifacts."""
 
     def __init__(self):
         self.records: List[dict] = []

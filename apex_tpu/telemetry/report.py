@@ -21,7 +21,7 @@ from . import registry as _registry
 def load_records(path: str, validate: bool = False) -> List[dict]:
     """Parse a JSONL telemetry file.  ``validate=True`` raises on the
     first off-schema record (the round-trip test path); otherwise bad
-    lines are skipped like ``bench_legs.read_legs`` skips corrupt legs.
+    lines are skipped.
     """
     out: List[dict] = []
     with open(path) as f:
@@ -143,9 +143,8 @@ def summarize(records: List[dict]) -> dict:
             events.get("elastic.data_repartition", ())),
         # memory (docs/telemetry.md Memory): live allocator high-water
         # from the monitor's mem.* gauges (max over the run — a gauge's
-        # last value would under-report a mid-run spike), the
-        # compiled-model peak bench legs embed, and the guard's OOM
-        # post-mortem events
+        # last value would under-report a mid-run spike), an embedded
+        # compiled-model peak, and the guard's OOM post-mortem events
         "mem_peak_bytes": mem_peak,
         "mem_in_use_bytes": gauge_last("mem.bytes_in_use"),
         "oom_events": len(events.get("memory.oom", ())),
@@ -362,8 +361,8 @@ def main(argv=None) -> int:
         return _trace.cli(argv[1:])
     if argv and argv[0] == "mem":
         # `python -m apex_tpu.telemetry mem [artifact]`: the per-class
-        # peak-HBM attribution table (flagship step, bench artifact, or
-        # a flight-oom post-mortem)
+        # peak-HBM attribution table (flagship step or a flight-oom
+        # post-mortem)
         from . import memory as _memory
         return _memory.cli(argv[1:])
     if argv and argv[0] == "timeline":

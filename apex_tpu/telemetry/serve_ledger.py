@@ -38,8 +38,8 @@ writes a schema-valid ``SERVE.json`` artifact.  ``python -m
 apex_tpu.telemetry serve <SERVE.json|run-dir>`` renders the table.
 
 Like the rest of the tooling layer this module imports no jax at module
-scope — ``tools/apply_perf_results.py`` file-loads it to audit SERVE
-artifacts without paying backend bring-up — and the ledger itself does
+scope — auditing a SERVE artifact never pays backend bring-up — and
+the ledger itself does
 ZERO host syncs: every number is a host ``perf_counter`` microsecond.
 """
 from __future__ import annotations

@@ -38,13 +38,9 @@ exposed.  This module closes the measurement half of that loop:
     per-device skew section (``--json`` for the machine-readable
     form).
 
-The measured ``exposed_comm_fraction`` is what ``bench.py``'s opt-in
-one-step profiled capture embeds in its artifact and
-``tools/apply_perf_results.py`` persists as the
-``overlap_measured_fraction`` tuning key — the overlap factor
-``parallel.plan``'s comm model consumes (exposed dp comm = comm x
-fraction).  Measurement first; the async-collective rewrite that will
-actually LOWER the fraction is a later PR.
+The measured ``exposed_comm_fraction`` is the overlap factor
+``parallel.plan``'s comm model takes (``predict(overlap_fraction=)`` /
+``APEX_TPU_OVERLAP_FRACTION``: exposed dp comm = comm x fraction).
 
 Like the rest of the tooling layer this module imports no jax at
 module scope — rendering a profiler capture must never pay backend
@@ -248,9 +244,8 @@ def _total_us(intervals: List[Tuple[float, float]]) -> float:
 # ---------------------------------------------------------------------------
 
 #: host span names that delimit one training step on the shared
-#: timeline (``Registry.step()`` emits ``train.step``; bench legs may
-#: emit their own)
-_STEP_SPAN_NAMES = frozenset(("train.step", "bench.step", "step"))
+#: timeline (``Registry.step()`` emits ``train.step``)
+_STEP_SPAN_NAMES = frozenset(("train.step", "step"))
 
 
 def step_windows(events: Sequence[dict]) -> List[Tuple[int, float, float]]:

@@ -3,7 +3,7 @@
 A process uses whatever backend jax brings up — the TPU on a machine that
 has one.  Nothing here probes, retries or falls back: an entry point that
 needs the chip asks ``found_tpu`` and exits non-zero when the answer is no
-(``chip_smoke.py``, ``bench.py``, ``bench_kernels.py``).
+(``chip_smoke.py``).
 
 ``force_cpu`` is for the processes that must NOT take the chip: the test
 suite and its worker subprocesses pin the CPU platform (with N virtual

@@ -1,8 +1,8 @@
-"""BERT-style masked-LM pretraining — BASELINE config 4's workload
-("BERT-large pretrain — FusedLAMB + multi_tensor_l2norm grad-clip").
+"""BERT-style masked-LM pretraining — the ``bert_large`` benchmark's
+workload (FusedLAMB + multi_tensor_l2norm grad-clip).
 
 The reference has no BERT example (its LAMB cites "BERT in 76 minutes");
-this harness makes config 4 runnable end-to-end: transformer encoder + amp
+this harness makes it runnable end-to-end: transformer encoder + amp
 O5 (bf16 + fp32 masters on the flat engine) + FusedLAMB with global-norm
 clipping, on synthetic MLM batches.  Distributed options:
 
@@ -83,14 +83,11 @@ def parse_args(argv=None):
                         "parallelism use --plan, which materializes the "
                         "ep engine)")
     p.add_argument("--plan", action="store_true",
-                   help="planner-driven parallelism: resolve the "
-                        "parallel plan from the measured tuning profile "
-                        "(plan.from_tuning) when one matches the ambient "
-                        "topology, else cost-model search (plan.search) "
-                        "over this config's own profiled step, then run "
-                        "the winner through spmd.build_plan_step — "
-                        "dp/tp/sp/pp/ep as measured engine families "
-                        "instead of hand-wired sharding flags")
+                   help="planner-driven parallelism: cost-model search "
+                        "(plan.search) over this config's own profiled "
+                        "step, then run the winner through "
+                        "spmd.build_plan_step — dp/tp/sp/pp/ep as engine "
+                        "families instead of hand-wired sharding flags")
     p.add_argument("--attn", default="default",
                    choices=("default", "fast"),
                    help="attention impl: 'fast' = the contrib flash "
@@ -350,11 +347,9 @@ def run_zero(args, cfg, mesh):
 
 
 def run_plan(args, cfg):
-    """Planner-driven parallelism (``--plan``): the measured tuning
-    winner (``plan.from_tuning`` — the bench ``plan`` leg's persisted
-    ``plan_*`` keys) when one matches the ambient chip count, else the
-    cost-model search (``plan.search``) over a profile of THIS config's
-    train step; the chosen plan is materialized through
+    """Planner-driven parallelism (``--plan``): the cost-model search
+    (``plan.search``) over a profile of THIS config's train step; the
+    chosen plan is materialized through
     ``spmd.build_plan_step``.  This replaces hand-wired sharding flags
     for the model-parallel families: tp, sp, pipeline (GPipe stages x
     microbatches) and expert parallelism all arrive as plannable,
@@ -364,18 +359,15 @@ def run_plan(args, cfg):
     from apex_tpu.parallel import spmd as spmdmod
 
     n_dev = len(jax.devices())
-    chosen = planmod.from_tuning(n_dev)
-    source = "tuned_defaults.json"
-    if chosen is None:
-        prof, _, _ = planmod.flagship_profile(
-            cfg=cfg, global_batch=args.batch_size)
-        ranked = planmod.search(prof, n_dev)
-        if not ranked:
-            raise SystemExit(f"--plan: no feasible plan at {n_dev} chips "
-                             f"for batch {args.batch_size}")
-        chosen = ranked[0]
-        source = f"cost-model search ({len(ranked)} feasible)"
-    print(f"=> plan [{source}]: {chosen.describe()}")
+    prof, _, _ = planmod.flagship_profile(
+        cfg=cfg, global_batch=args.batch_size)
+    ranked = planmod.search(prof, n_dev)
+    if not ranked:
+        raise SystemExit(f"--plan: no feasible plan at {n_dev} chips "
+                         f"for batch {args.batch_size}")
+    chosen = ranked[0]
+    print(f"=> plan [cost-model search ({len(ranked)} feasible)]: "
+          f"{chosen.describe()}")
 
     rng = np.random.RandomState(args.seed)
     losses, tput = AverageMeter("mlm_loss"), Throughput()

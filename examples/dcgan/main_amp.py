@@ -1,4 +1,4 @@
-"""DCGAN with two optimizers and per-loss scalers — BASELINE config 5.
+"""DCGAN with two optimizers and per-loss scalers.
 
 TPU-native rebuild of the reference's ``examples/dcgan/main_amp.py``, the one
 example that exercises ``amp.initialize(..., num_losses=3)`` and

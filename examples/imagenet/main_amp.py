@@ -2,7 +2,7 @@
 
 TPU-native rebuild of ``examples/imagenet/main_amp.py`` in the reference
 (ResNet-50 + amp + DDP + optional SyncBN; the ``images/sec`` Speed print at
-main_amp.py:391 is BASELINE's primary metric).  Differences by design:
+main_amp.py:391 is the reference's own metric).  Differences by design:
 
 - SPMD instead of process-per-GPU: one process drives every visible device
   through a ``jax.sharding.Mesh``; ``--distributed`` shards the batch over
@@ -12,17 +12,17 @@ main_amp.py:391 is BASELINE's primary metric).  Differences by design:
   so ``--sync-bn`` semantics come free under pjit.
 - Synthetic ImageNet-shaped data by default (``--data`` accepts a directory
   of ``.npz`` shards with ``images``/``labels`` arrays): the container has
-  no dataset, and BASELINE measures step throughput, not input pipelines.
+  no dataset, and the metric is step throughput, not input pipelines.
 
 Usage (CPU smoke):
     PYTHONPATH=. JAX_PLATFORMS=cpu python examples/imagenet/main_amp.py \
         --arch resnet18 --batch-size 8 --steps 10 --print-freq 2
 
-TPU (single chip, BASELINE config 2):
+TPU (single chip):
     python examples/imagenet/main_amp.py --arch resnet50 --batch-size 128 \
         --opt-level O2 --steps 100
 
-Multi-device (BASELINE config 3; on CPU use
+Multi-device (on CPU use
 XLA_FLAGS=--xla_force_host_platform_device_count=8):
     python examples/imagenet/main_amp.py --distributed --sync-bn ...
 """

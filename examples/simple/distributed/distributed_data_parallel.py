@@ -1,4 +1,4 @@
-"""Minimal data-parallel + amp training — BASELINE config 1 (CPU-runnable).
+"""Minimal data-parallel + amp training (CPU-runnable).
 
 TPU-native rebuild of the reference's
 ``examples/simple/distributed/distributed_data_parallel.py`` (toy model +

@@ -119,7 +119,7 @@ def test_initialize_bad_opt_level():
 def test_initialize_flash_attn_backward_knob():
     """The amp-level flash_attn_backward option validates and lands in the
     flash module's process default, where backward="auto" resolution picks
-    it up (between the env override and the tuning profile)."""
+    it up (between the env override and the built-in)."""
     from apex_tpu.contrib.multihead_attn import flash as F
     params = {"w": jnp.ones((4, 4))}
     try:

@@ -27,8 +27,8 @@ def _load_smoke():
 
 @pytest.fixture
 def compile_cache_config():
-    """chip_smoke / the bench mains turn the persistent compile cache on
-    for their process; put this process's settings back afterwards."""
+    """chip_smoke turns the persistent compile cache on for its
+    process; put this process's settings back afterwards."""
     keys = ("jax_compilation_cache_dir",
             "jax_persistent_cache_min_compile_time_secs",
             "jax_persistent_cache_min_entry_size_bytes")
@@ -129,27 +129,3 @@ def test_force_cpu_after_another_backend_is_an_error(monkeypatch):
     plat.force_cpu(jax.device_count())            # already satisfied
     with pytest.raises(RuntimeError, match="before the first jax"):
         plat.force_cpu(jax.device_count() + 1)
-
-
-@pytest.mark.parametrize("script", ["bench.py", "bench_kernels.py"])
-def test_bench_mains_refuse_to_run_off_the_chip(script, capsys,
-                                                compile_cache_config):
-    """No CPU stand-in: off the chip the bench mains exit non-zero and
-    print no metric line."""
-    spec = importlib.util.spec_from_file_location(
-        script[:-3] + "_main_test", os.path.join(ROOT, script))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    assert mod.main([]) == 2
-    captured = capsys.readouterr()
-    assert "'cpu'" in captured.err and "refusing" in captured.err
-    assert "{" not in captured.out
-
-
-def test_errored_legs_are_what_makes_the_bench_exit_code_nonzero():
-    from apex_tpu.utils.bench_legs import errored
-    detail = {"rn50": {"images_per_sec": 1.0},
-              "bert_e2e": {"error": "XlaRuntimeError(...)"},
-              "n_params": 3}
-    assert errored(detail) == ["bert_e2e"]
-    assert errored({"rn50": {"batch": 1}}) == []

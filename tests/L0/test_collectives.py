@@ -711,41 +711,3 @@ def test_report_summary_uncompressed_line_unchanged():
     assert s["collective_bytes"] == s["collective_wire_bytes"] == 100
     out = treport.format_summary(s)
     assert "collective bytes    100 (1 calls)" in out
-
-
-def test_bench_collectives_leg_shape():
-    """The bench leg: schemes x sizes with the >=3.5x int8 ratio and
-    schema-valid embedded telemetry carrying the compressed-bytes
-    counters (what apply_perf_results' collective audit checks)."""
-    import importlib.util
-    ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    spec = importlib.util.spec_from_file_location(
-        "bench", os.path.join(ROOT, "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    leg = bench.bench_collectives(on_tpu=False)
-    assert leg["leg"] == "collectives"
-    assert set(leg["schemes"]) == {"fp32", "bf16", "int8_blockscale",
-                                   "adasum"}
-    assert leg["schemes"]["int8_blockscale"]["ratio"] >= 3.5
-    assert leg["schemes"]["fp32"]["ratio"] == 1.0
-    assert records_violations(leg["telemetry"]["records"]) == []
-    names = {r.get("name") for r in leg["telemetry"]["records"]}
-    assert "ddp.allreduce_compressed_bytes" in names
-
-    spec2 = importlib.util.spec_from_file_location(
-        "apply_perf_results", os.path.join(ROOT, "tools",
-                                           "apply_perf_results.py"))
-    apr = importlib.util.module_from_spec(spec2)
-    spec2.loader.exec_module(apr)
-    art = {"backend": "tpu", "detail": {"collectives": leg}}
-    assert apr.collective_violations(art) == []
-    # the collectives leg is exempt from the MFU/HBM audit (its
-    # evidence is bytes, not FLOPs)
-    assert apr.perf_field_violations(art) == []
-    # a drifted ratio is flagged
-    bad = {"backend": "tpu", "detail": {"collectives": {
-        "leg": "collectives", "telemetry": leg["telemetry"],
-        "schemes": {"int8_blockscale": {"ratio": 2.0}}}}}
-    assert any("ratio" in v for v in apr.collective_violations(bad))

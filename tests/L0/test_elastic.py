@@ -24,8 +24,7 @@ Covers the tentpole and its acceptance gates:
     land in the registry and ``report.summarize``'s resilience line;
   * the 4 -> 8 grow path at fp32 tolerance (the reshard is exact; the
     wider axis reorders the int8 dequant-sum of the next step);
-  * the ``plan.from_tuning`` chips mismatch becoming a re-plan trigger
-    once ``elastic.install()`` hooks it.
+  * ``elastic.install()`` registering the process-default resharder.
 """
 import functools
 import json
@@ -64,16 +63,14 @@ SEQ = 20          # pos-embed 20*32 makes `used` a non-multiple of 1024,
 
 @pytest.fixture(autouse=True)
 def _clean_hooks():
-    """No leaked resharder, replan hook, fault plan, or registry."""
+    """No leaked resharder, fault plan, or registry."""
     prev_reg = events.set_default(None)
     prev_plan = faults.install(None)
     prev_rs = guard.set_resharder(None)
-    prev_hook = plan_mod.set_replan_hook(None)
     yield
     events.set_default(prev_reg)
     faults.install(prev_plan)
     guard.set_resharder(prev_rs)
-    plan_mod.set_replan_hook(prev_hook)
 
 
 # ---------------------------------------------------------------------------
@@ -684,37 +681,15 @@ def test_old_manifest_degrades_with_typed_warning(harnesses, tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# plan.from_tuning chips mismatch -> re-plan trigger (satellite: plan.py)
+# install / uninstall: the process-default resharder
 # ---------------------------------------------------------------------------
 
-def test_from_tuning_mismatch_replans_when_installed(tmp_path, monkeypatch):
-    from apex_tpu.utils import tuning
-    prof_file = tmp_path / "tuned_defaults.json"
-    prof_file.write_text(json.dumps({"plan_dp": 8}))
-    monkeypatch.setenv("APEX_TPU_TUNING_FILE", str(prof_file))
-    tuning.reload()
-    try:
-        # legacy behavior without the hook: mismatch -> None
-        assert plan_mod.from_tuning(4, tpu_only=False) is None
-        # installed: mismatch -> a fresh search at the live chip count
-        reg = Registry(sink=MemorySink(), flush_interval=0,
-                       rank0_only=False)
-        events.set_default(reg)
-        er = elastic.install(profile=_tiny_profile())
-        assert elastic.installed() is er
-        replanned = plan_mod.from_tuning(4, tpu_only=False)
-        assert replanned is not None and replanned.chips == 4
-        # matching chips never consults the hook
-        assert plan_mod.from_tuning(8, tpu_only=False).dp == 8
-        evs = [r for r in reg.flush() if r.get("name") == "elastic.replan"]
-        assert len(evs) == 1
-        assert evs[0]["fields"]["old_knobs"]["dp"] == 8
-        elastic.uninstall()
-        assert elastic.installed() is None
-        assert plan_mod.from_tuning(4, tpu_only=False) is None
-    finally:
-        monkeypatch.delenv("APEX_TPU_TUNING_FILE")
-        tuning.reload()
+def test_install_registers_the_process_default_resharder():
+    er = elastic.install(profile=_tiny_profile(), capacity_bytes=1 << 40)
+    assert elastic.installed() is er and guard.get_resharder() is er
+    assert er.search_kw == {"capacity_bytes": 1 << 40}
+    elastic.uninstall()
+    assert elastic.installed() is None
 
 
 def test_reshard_payload_rejects_model_change():

@@ -23,13 +23,10 @@ What is proven here:
     classes partition measured wall-clock exactly, with each injected
     fault landing in its declared badput class, ``goodput.fraction``
     < 1 under faults and ~1 on a clean run; the ``goodput`` CLI
-    renders the same numbers from the artifact;
-  * ``tools/bench_trend.py`` passes on the committed trajectory and
-    fails on a synthetically-regressed one.
+    renders the same numbers from the artifact.
 """
 import functools
 import gc
-import importlib.util
 import json
 import os
 import time
@@ -59,9 +56,6 @@ from apex_tpu.telemetry import trace as trace_mod
 from apex_tpu.telemetry.report import format_summary, load_records, \
     summarize
 from apex_tpu.utils.pallas import to_varying
-
-ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
 
 MS = 1000.0   # trace timestamps are microseconds
 
@@ -727,80 +721,3 @@ def test_goodput_cli_renders_artifact_and_jsonl(tmp_path, capsys):
     junk = tmp_path / "junk.txt"
     junk.write_text("not a ledger\n")
     assert goodput.cli([str(junk)]) == 1
-
-
-# ---------------------------------------------------------------------------
-# the regression watchdog + the apply_perf audit
-# ---------------------------------------------------------------------------
-
-def _load_tool(name):
-    spec = importlib.util.spec_from_file_location(
-        name, os.path.join(ROOT, "tools", f"{name}.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def test_bench_trend_passes_committed_trajectory():
-    bt = _load_tool("bench_trend")
-    assert bt.main(["--dir", ROOT]) == 0
-
-
-def test_bench_trend_flags_synthetic_regression(tmp_path, capsys):
-    bt = _load_tool("bench_trend")
-
-    def art(ms):
-        return {"metric": "m", "value": ms, "unit": "ms",
-                "backend": "tpu",
-                "detail": {"rn50": {"step_ms": ms, "model": "resnet50",
-                                    "batch": 128}}}
-
-    (tmp_path / "BENCH_r01.json").write_text(json.dumps(art(50.0)))
-    (tmp_path / "BENCH_r02.json").write_text(json.dumps(art(110.0)))
-    assert bt.main(["--dir", str(tmp_path), "--json"]) == 1
-    doc = json.loads(capsys.readouterr().out)
-    assert doc["ok"] is False and doc["regressions"]
-    assert any("rn50" in d["series"] for d in doc["regressions"])
-    # within the tolerance band the same trajectory passes
-    (tmp_path / "BENCH_r02.json").write_text(json.dumps(art(55.0)))
-    assert bt.main(["--dir", str(tmp_path)]) == 0
-    capsys.readouterr()
-    # a goodput-fraction collapse across run artifacts is drift too
-    good = _valid_doc()
-    bad = json.loads(json.dumps(good))
-    # halve the productive share honestly (move it to idle)
-    moved = bad["classes"]["productive"]["ms"] / 2
-    bad["classes"]["productive"]["ms"] -= moved
-    bad["classes"]["idle"]["ms"] += moved
-    wall = bad["wall_ms"]
-    for c in bad["classes"].values():
-        c["fraction"] = c["ms"] / wall
-    bad["goodput_fraction"] = bad["classes"]["productive"]["fraction"]
-    bad["ts"] = "2099-01-01T00:00:00Z"      # sorts after `good`
-    (tmp_path / "GOODPUT-a.json").write_text(json.dumps(good))
-    (tmp_path / "GOODPUT-b.json").write_text(json.dumps(bad))
-    assert bt.main(["--dir", str(tmp_path)]) == 1
-    capsys.readouterr()
-    # a schema-invalid ledger fails regardless of drift
-    broken = json.loads(json.dumps(good))
-    broken["classes"]["idle"]["ms"] += 100.0
-    (tmp_path / "GOODPUT-b.json").write_text(json.dumps(good))
-    (tmp_path / "GOODPUT-c.json").write_text(json.dumps(broken))
-    assert bt.main(["--dir", str(tmp_path)]) == 1
-    # nothing to ingest is its own (visible) exit
-    empty = tmp_path / "empty"
-    empty.mkdir()
-    assert bt.main(["--dir", str(empty)]) == 2
-
-
-def test_apply_perf_goodput_audit():
-    mod = _load_tool("apply_perf_results")
-    good = _valid_doc()
-    assert mod.goodput_violations(
-        {"backend": "tpu", "detail": {"goodput": {"leg": "goodput",
-                                                  "goodput": good}}}) == []
-    broken = json.loads(json.dumps(good))
-    broken["classes"]["data_stall"]["ms"] += 50.0
-    out = mod.goodput_violations(
-        {"backend": "tpu", "detail": {"goodput": {"goodput": broken}}})
-    assert any("partition" in v for v in out)

@@ -481,7 +481,7 @@ def test_cli_mem_table_total_matches_liveness_sweep():
     assert "memory_model: peak" in r.stdout
 
 
-def test_cli_mem_renders_oom_dump_and_bench_artifact(tmp_path):
+def test_cli_mem_renders_oom_dump(tmp_path):
     # an OOM dump round-trips through the renderer
     memory.set_attribution({"peak_hbm_bytes": 999,
                             "by_class": {"params": 999}})
@@ -495,13 +495,7 @@ def test_cli_mem_renders_oom_dump_and_bench_artifact(tmp_path):
     assert "OOM post-mortem" in r.stdout
     assert "bad_step=7" in r.stdout
 
-    # a bench artifact with per-leg fields renders the MFU/HBM table
-    art = tmp_path / "bench.json"
-    art.write_text(json.dumps({"detail": {"bert_e2e": {
-        "mfu_pct": 41.2, "hbm_compiled_peak_bytes": 123456}}}))
-    r2 = subprocess.run(
-        [sys.executable, "-m", "apex_tpu.telemetry", "mem", str(art)],
-        capture_output=True, text=True, cwd=ROOT, timeout=120,
-        env={**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": ROOT})
-    assert r2.returncode == 0, r2.stderr[-2000:]
-    assert "bert_e2e" in r2.stdout and "41.2" in r2.stdout
+    # anything else is a clean rc=1, not a traceback
+    other = tmp_path / "other.json"
+    other.write_text(json.dumps({"detail": {}}))
+    assert memory.cli([str(other)]) == 1

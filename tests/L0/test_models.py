@@ -1,5 +1,5 @@
 """Model zoo smoke + semantics tests (shapes, train/eval BN behavior, grads,
-SyncBN-on-mesh parity for the RN50 workload of BASELINE configs 2-3)."""
+SyncBN-on-mesh parity for the RN50 workload)."""
 import jax
 import jax.numpy as jnp
 import numpy as np

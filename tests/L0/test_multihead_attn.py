@@ -356,26 +356,20 @@ def test_flash_bwd_fused_matches_split(monkeypatch, causal, rate):
 
 
 def test_flash_backward_auto_resolution_chain(monkeypatch):
-    """backward="auto" resolves env > amp-config default > tuning profile
-    > pallas built-in; explicit arguments bypass the chain entirely."""
+    """backward="auto" resolves env > amp-config default > pallas
+    built-in; explicit arguments bypass the chain entirely."""
     from apex_tpu.contrib.multihead_attn import flash as F
-    from apex_tpu.utils import tuning
     monkeypatch.delenv("APEX_TPU_FLASH_BWD_IMPL", raising=False)
     assert F._resolve_backward("auto") == "pallas"      # built-in
-    # a recorded Pallas-backward loss in the profile flips auto to xla
-    monkeypatch.setattr(tuning, "get_on_tpu",
-                        lambda key, default=None:
-                        "xla" if key == "flash_bwd_impl" else default)
-    assert F._resolve_backward("auto") == "xla"
-    # the amp-config default beats the profile
-    F.set_default_backward("pallas")
+    # the amp-config default beats the built-in
+    F.set_default_backward("xla")
     try:
+        assert F._resolve_backward("auto") == "xla"
+        # env beats the amp-config default
+        monkeypatch.setenv("APEX_TPU_FLASH_BWD_IMPL", "pallas")
         assert F._resolve_backward("auto") == "pallas"
     finally:
         F.set_default_backward("auto")
-    # env beats both
-    monkeypatch.setenv("APEX_TPU_FLASH_BWD_IMPL", "pallas")
-    assert F._resolve_backward("auto") == "pallas"
     # explicit argument beats everything
     assert F._resolve_backward("xla") == "xla"
     with pytest.raises(ValueError):
@@ -384,16 +378,12 @@ def test_flash_backward_auto_resolution_chain(monkeypatch):
         F.set_default_backward("cuda")
 
 
-def test_flash_backward_auto_routes_to_xla_on_recorded_loss(monkeypatch):
-    """Functional proof of the auto-fallback: with the tuning profile
-    recording a Pallas-bwd loss, a grad through backward="auto" runs the
-    XLA backward (and matches the Pallas kernels numerically)."""
+def test_flash_backward_auto_routes_to_xla_under_the_env_pin(monkeypatch):
+    """Functional proof of the route: with ``APEX_TPU_FLASH_BWD_IMPL=xla``
+    a grad through backward="auto" runs the XLA backward (and matches the
+    Pallas kernels numerically)."""
     from apex_tpu.contrib.multihead_attn import flash as F
-    from apex_tpu.utils import tuning
-    monkeypatch.delenv("APEX_TPU_FLASH_BWD_IMPL", raising=False)
-    monkeypatch.setattr(tuning, "get_on_tpu",
-                        lambda key, default=None:
-                        "xla" if key == "flash_bwd_impl" else default)
+    monkeypatch.setenv("APEX_TPU_FLASH_BWD_IMPL", "xla")
     h, s, d = 2, 32, 16
     q = jax.random.normal(jax.random.PRNGKey(0), (h, s, d))
     bias = jnp.zeros((1, 1, s), jnp.float32)
@@ -549,7 +539,7 @@ def test_flash_block_clamp():
         del os.environ["APEX_TPU_FLASH_VMEM_MB"]
         assert F._clamp_blocks(None, None, 64, 4, False) == (64, 256)
         # ... but never rewrite PINNED block sizes — explicit arguments
-        # (autotune sweeps) or env pins — even under a budget that would
+        # or env pins — even under a budget that would
         # otherwise shrink them
         os.environ["APEX_TPU_FLASH_VMEM_MB"] = "0.25"
         assert F._clamp_blocks(512, 512, 64, 4, False) == (512, 512)
@@ -769,13 +759,12 @@ def test_flash_bwd_tile_rule_vmem_estimate_counts_the_tile():
         assert grown > tile // 2, bwd
 
 
-@pytest.mark.parametrize("source", ["argument", "env", "env_dkv", "profile",
-                                    "profile_dkv", "budget"])
+@pytest.mark.parametrize("source", ["argument", "env", "env_dkv",
+                                    "env_one_side", "env_dq", "budget"])
 def test_flash_bwd_tile_rule_is_the_last_link(flash_env, source):
-    """Explicit blocks, env pins and profile keys still win over the rule,
-    in today's order; a moved VMEM budget moves the rule's answer."""
+    """Explicit blocks and env pins still win over the rule, in today's
+    order; a moved VMEM budget moves the rule's answer."""
     from apex_tpu.contrib.multihead_attn import flash as F
-    from apex_tpu.utils import tuning
     shape = dict(D=64, esz=2, bias_per_q=False, bwd="fused", sq=512, sk=512)
     assert F._clamp_blocks(None, None, **shape) == (512, 512)
     if source == "argument":
@@ -792,17 +781,22 @@ def test_flash_bwd_tile_rule_is_the_last_link(flash_env, source):
         flash_env.setenv("APEX_TPU_FLASH_BWD_DKV_BLOCK_Q", "256")
         flash_env.setenv("APEX_TPU_FLASH_BWD_DKV_BLOCK_K", "256")
         assert F._clamp_blocks(None, None, **shape) == (256, 256)
-    elif source in ("profile", "profile_dkv"):
-        prof = {"flash_bwd_block_q": 128, "flash_bwd_block_k": 256}
-        if source == "profile_dkv":
-            prof.update(flash_bwd_dkv_block_q=256, flash_bwd_dkv_block_k=128)
-        flash_env.setattr(tuning, "get_on_tpu",
-                          lambda key, default=None: prof.get(key, default))
-        want = (256, 128) if source == "profile_dkv" else (128, 256)
-        assert F._clamp_blocks(None, None, **shape) == want
-        flash_env.setenv("APEX_TPU_FLASH_BWD_BLOCK_Q", "64")
-        flash_env.setenv("APEX_TPU_FLASH_BWD_BLOCK_K", "512")
-        assert F._clamp_blocks(None, None, **shape) == (64, 512)
+    elif source == "env_one_side":
+        # one pinned side: the other falls to the constant, as with an
+        # argument, and the argument beats the pin
+        flash_env.setenv("APEX_TPU_FLASH_BWD_BLOCK_K", "256")
+        assert F._clamp_blocks(None, None, **shape) == (128, 256)
+        assert F._clamp_blocks(64, 512, **shape) == (64, 512)
+    elif source == "env_dq":
+        # the dq kernel's own pins name the dq kernel only: the fused
+        # kernel rides the dkv names, and the forward reads neither
+        flash_env.setenv("APEX_TPU_FLASH_BWD_DQ_BLOCK_Q", "256")
+        flash_env.setenv("APEX_TPU_FLASH_BWD_DQ_BLOCK_K", "256")
+        assert F._clamp_blocks(None, None, **shape) == (512, 512)
+        assert F._clamp_blocks(None, None, **{**shape, "bwd": "dq"}) \
+            == (256, 256)
+        assert F._clamp_blocks(None, None, 64, 2, False, sq=512,
+                               sk=512) == (512, 512)
     else:
         flash_env.setenv("APEX_TPU_FLASH_VMEM_MB", "4")
         assert F._clamp_blocks(None, None, **shape) == (256, 512)
@@ -1021,13 +1015,12 @@ def test_flash_bwd_dispatch_rule(flash_env, flash_counts, BH, sq, sk, D,
 
 @pytest.mark.parametrize("pin", ["fuse_arg", "split_arg", "fuse_env",
                                  "split_env", "blocks_arg", "blocks_env",
-                                 "dkv_blocks_arg", "profile"])
+                                 "dkv_blocks_arg", "dkv_blocks_env"])
 def test_flash_bwd_pins_keep_their_meaning_at_s4096(flash_env, flash_counts,
                                                     pin):
     """A strategy or a tile somebody chose names the 128 x 128 grid's
     kernels, as before: ``resident`` is asked only where nobody chose."""
     from apex_tpu.contrib.multihead_attn import flash as F
-    from apex_tpu.utils import tuning
     shape = (2, 4096, 4096, 64, jnp.bfloat16)
     assert _traced_bwd_path(F, flash_counts, *shape)["path"] == "resident"
     pins, want = {}, "partials"
@@ -1047,8 +1040,7 @@ def test_flash_bwd_pins_keep_their_meaning_at_s4096(flash_env, flash_counts,
     elif pin == "dkv_blocks_arg":
         pins = dict(dkv_blocks=(128, 512))
     else:
-        flash_env.setattr(tuning, "get_on_tpu", lambda key, default=None: {
-            "flash_bwd_dkv_block_q": 256}.get(key, default))
+        flash_env.setenv("APEX_TPU_FLASH_BWD_DKV_BLOCK_Q", "256")
     ev = _traced_bwd_path(F, flash_counts, *shape, **pins)
     assert ev["path"] == want and ev["nk"] > 1
 

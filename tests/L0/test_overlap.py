@@ -22,16 +22,13 @@ Covers the tentpole and its acceptance gates:
   * zero1: chunked reduce-scatter + segmented allgather are bitwise the
     whole-buffer ``ShardedUpdate`` trajectory (fp32 and block-aligned
     int8 wires);
-  * the planner consumes per-scheme measured overlap fractions
-    (``overlap_fraction_<scheme>`` > global ``overlap_measured_fraction``);
+  * the planner prices every scheme's dp wire with the one overlap
+    factor it is handed;
   * the measured-drop contract: a device-trace fixture decomposed by
     ``telemetry.timeline`` shows the bucketed ``exposed_comm_fraction``
-    strictly below the deferred one in the same artifact that proves
-    parity, and ``apply_perf_results.overlap_exec_violations`` accepts
-    it (and flags a regressed capture).
+    strictly below the deferred one.
 """
 import functools
-import importlib.util
 import json
 import os
 import subprocess
@@ -44,8 +41,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from apex_tpu.parallel import (DistributedDataParallel, collectives,
-                               create_mesh, overlap)
+from apex_tpu.parallel import (DistributedDataParallel, create_mesh,
+                               overlap)
 from apex_tpu.parallel import weight_update as wu
 from apex_tpu.parallel.distributed import allreduce_tree
 from jax import shard_map
@@ -551,48 +548,17 @@ def test_shard_chunk_bounds_contract():
 
 
 # ---------------------------------------------------------------------------
-# planner: per-scheme overlap fractions
+# planner: the overlap factor
 # ---------------------------------------------------------------------------
 
-@pytest.fixture
-def profile_file(tmp_path, monkeypatch):
-    from apex_tpu.utils import tuning
-    path = tmp_path / "tuned.json"
 
-    def write(d):
-        path.write_text(json.dumps(d))
-        tuning.reload()
-
-    monkeypatch.setenv("APEX_TPU_TUNING_FILE", str(path))
-    tuning.reload()
-    yield write
-    monkeypatch.delenv("APEX_TPU_TUNING_FILE")
-    tuning.reload()
-
-
-def test_per_scheme_overlap_fraction_precedence(profile_file):
+def test_overlap_fraction_is_one_factor_for_every_scheme(monkeypatch):
+    """The planner prices every dp wire with the ONE overlap factor it
+    is handed (argument > ``APEX_TPU_OVERLAP_FRACTION`` > 1.0): an int8
+    dp plan and an fp32 one expose the same share of their own modeled
+    comm."""
     from apex_tpu.parallel import plan as pm
-    profile_file({"overlap_measured_fraction": 0.9,
-                  "overlap_fraction_int8_blockscale": 0.25})
-    # per-scheme measurement wins for its scheme ...
-    assert pm.resolve_overlap_fraction(
-        scheme="int8_blockscale") == 0.25
-    # ... the global fraction covers unmeasured schemes and scheme=None
-    assert pm.resolve_overlap_fraction(scheme="fp32") == 0.9
-    assert pm.resolve_overlap_fraction() == 0.9
-    # explicit arg beats both
-    assert pm.resolve_overlap_fraction(0.5, scheme="int8_blockscale") \
-        == 0.5
-
-
-def test_predict_consumes_per_scheme_fraction(profile_file):
-    """Overlap-capable dp plans are priced with THEIR scheme's measured
-    fraction: with int8's wire measured as fully hidden, the int8 dp
-    plan's exposed comm drops to zero while fp32 keeps the global
-    charge."""
-    from apex_tpu.parallel import plan as pm
-    profile_file({"overlap_measured_fraction": 1.0,
-                  "overlap_fraction_int8_blockscale": 0.0})
+    monkeypatch.setenv(pm.ENV_OVERLAP, "0.25")
     prof = pm.ModelProfile(
         name="synth", flops=1e9, bytes_accessed=1e8, params_bytes=1 << 22,
         optimizer_bytes=3 << 22, activations_bytes=8192, batch_bytes=1024,
@@ -603,14 +569,18 @@ def test_predict_consumes_per_scheme_fraction(profile_file):
                                   collective_scheme="int8_blockscale"),
                     platform="tpu_v5e")
     p32 = pm.predict(prof, pm.Plan(dp=N_DEV), platform="tpu_v5e")
-    assert p8.breakdown["dp_comm_ms"] > 0
-    assert p8.breakdown["dp_comm_exposed_ms"] == 0.0
-    assert p32.breakdown["dp_comm_exposed_ms"] == pytest.approx(
-        p32.breakdown["dp_comm_ms"])
+    for p in (p8, p32):
+        assert p.breakdown["dp_comm_ms"] > 0
+        assert p.breakdown["overlap_fraction"] == 0.25
+        assert p.breakdown["dp_comm_exposed_ms"] == pytest.approx(
+            0.25 * p.breakdown["dp_comm_ms"])
+    hidden = pm.predict(prof, pm.Plan(dp=N_DEV), platform="tpu_v5e",
+                        overlap_fraction=0.0)
+    assert hidden.breakdown["dp_comm_exposed_ms"] == 0.0
 
 
 # ---------------------------------------------------------------------------
-# the measured-drop contract (device-trace fixture -> timeline -> audit)
+# the measured-drop contract (device-trace fixture -> timeline)
 # ---------------------------------------------------------------------------
 
 def _write_capture(root, exposed_comm_events):
@@ -630,14 +600,12 @@ def _write_capture(root, exposed_comm_events):
         f.write(json.dumps({"traceEvents": events_}))
 
 
-def test_exposed_comm_drop_fixture_and_audit(tmp_path):
+def test_exposed_comm_drop_fixture(tmp_path):
     """ACCEPTANCE (CPU form): deferred and bucketed device-trace
     fixtures decomposed by ``telemetry.timeline`` show the bucketed
-    ``exposed_comm_fraction`` STRICTLY below the deferred one; embedded
-    in the same artifact that proves parity, the
-    ``overlap_exec_violations`` audit accepts it — and flags the
-    regressed capture.  (The real on-chip drop is a chip run's job;
-    this pins the measurement + audit contract.)"""
+    ``exposed_comm_fraction`` STRICTLY below the deferred one on the
+    same wire.  (The real on-chip drop is a chip run's job; this pins
+    the measurement.)"""
     from apex_tpu.telemetry import timeline as tl
     # deferred: 50ms of all-reduce entirely AFTER compute (all exposed)
     _write_capture(str(tmp_path / "off"), [
@@ -656,48 +624,3 @@ def test_exposed_comm_drop_fixture_and_audit(tmp_path):
     assert f_off == 1.0
     assert f_b < f_off                    # the strict drop
     assert d_b["totals"]["comm_ms"] == d_off["totals"]["comm_ms"]
-
-    def block(d):
-        t = d["totals"]
-        return {"compute_ms": t["compute_ms"], "comm_ms": t["comm_ms"],
-                "exposed_comm_ms": t["exposed_comm_ms"],
-                "exposed_comm_fraction": t["exposed_comm_fraction"]}
-
-    spec = importlib.util.spec_from_file_location(
-        "apply_perf_results",
-        os.path.join(ROOT, "tools", "apply_perf_results.py"))
-    apr = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(apr)
-    leg = {"leg": "overlap", "scheme": "fp32", "parity_ok": True,
-           "loss_abs_diff": 0.0, "logical_bytes_equal": True,
-           "modes": {"off": {"step_ms": 10.0, "overlap": block(d_off)},
-                     "bucketed": {"step_ms": 9.0,
-                                  "overlap": block(d_b)}}}
-    assert apr.overlap_exec_violations({"detail": {"overlap": leg}}) == []
-    # the decision engine elects bucketed + persists the fraction
-    prof, _rows = apr.decide(
-        {"backend": "tpu", "detail": {"overlap": leg}}, {})
-    assert prof["ddp_overlap"] == "bucketed"
-    assert prof["overlap_fraction_fp32"] == pytest.approx(f_b)
-    # a REGRESSED capture (bucketed exposes more) is flagged
-    bad = json.loads(json.dumps(leg))
-    bad["modes"]["off"], bad["modes"]["bucketed"] = (
-        bad["modes"]["bucketed"], bad["modes"]["off"])
-    v = apr.overlap_exec_violations({"detail": {"overlap": bad}})
-    assert v and "exceeds deferred" in v[0]
-
-
-def test_bench_overlap_leg_schema(mesh):
-    """The ``bench.py --overlap`` leg at test scale: both modes
-    measured, parity + logical-byte fields present and TRUE on the CPU
-    mesh, telemetry records schema-valid."""
-    import bench
-    from apex_tpu.telemetry import records_violations
-    out = bench.bench_overlap(False, steps=1, cfg=_tiny_cfg(),
-                              global_batch=N_DEV)
-    assert set(out["modes"]) == {"off", "bucketed"}
-    assert out["parity_ok"] is True
-    assert out["loss_bitwise_equal"] is True
-    assert out["logical_bytes_equal"] is True
-    assert out["modes"]["off"]["allreduce_logical_bytes"] > 0
-    assert records_violations(out["telemetry"]["records"]) == []

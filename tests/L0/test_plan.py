@@ -11,17 +11,9 @@ Covers the tentpole and its acceptance gates:
   * ``Plan.apply()`` reproducing the BITWISE-identical loss/params of
     the same manually-configured run (mesh + env knobs vs explicit
     args);
-  * THE verify loop: ``bench.bench_plan`` measures the top predicted
-    plans, the predicted pick lands within 25% of its calibrated
-    prediction and no slower than the all-defaults baseline, and the
-    winning knobs round-trip ``apply_perf_results.decide`` ->
-    schema-valid ``tuned_defaults.json`` -> ``plan.from_tuning`` on
-    the next run;
   * the ranked-table CLI (``python -m apex_tpu.parallel.plan``) from
-    both a measured artifact and a fresh CPU cost-model run.
+    a fresh CPU cost-model run.
 """
-import importlib.util
-import json
 import os
 import subprocess
 import sys
@@ -36,7 +28,6 @@ from apex_tpu.parallel import plan as pm
 from apex_tpu.parallel import collectives
 from apex_tpu.parallel import weight_update as wu
 from apex_tpu.parallel.mesh import create_mesh
-from apex_tpu.utils import tuning
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -57,28 +48,6 @@ def _clean_env():
         os.environ.pop(k, None)
         if v is not None:
             os.environ[k] = v
-
-
-@pytest.fixture
-def profile_file(tmp_path, monkeypatch):
-    """Point the tuning profile at a temp file (test_tuning idiom)."""
-    path = tmp_path / "tuned.json"
-
-    def write(d):
-        path.write_text(json.dumps(d))
-        tuning.reload()
-
-    monkeypatch.setenv("APEX_TPU_TUNING_FILE", str(path))
-    tuning.reload()
-    yield write
-    monkeypatch.delenv("APEX_TPU_TUNING_FILE")
-    tuning.reload()
-
-
-@pytest.fixture
-def fake_tpu(monkeypatch):
-    jax.devices()                      # ensure backends_initialized()
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
 
 @pytest.fixture(scope="module")
@@ -493,150 +462,18 @@ def test_apply_reproduces_manual_run_bitwise(ddp_kwargs):
 
 
 # ---------------------------------------------------------------------------
-# the verify/persist loop (bench.py --plan -> apply_perf_results ->
-# tuned_defaults.json -> from_tuning)
-# ---------------------------------------------------------------------------
-
-def _load_bench():
-    spec = importlib.util.spec_from_file_location(
-        "bench_for_plan", os.path.join(ROOT, "bench.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def _load_apply():
-    spec = importlib.util.spec_from_file_location(
-        "apply_perf_for_plan",
-        os.path.join(ROOT, "tools", "apply_perf_results.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-@pytest.mark.slow   # ~120s: eleven real measured rows on the emulated
-# mesh, and on a single-core host the family-calibration margins sit AT
-# the 25% plan_violations bar (back-to-back runs of identical configs
-# spread 10-40%) — the leg's mechanics (coverage-row selection, audit,
-# decide() -> from_tuning round-trip) stay tier-1 through the synthetic
-# planner tests above; the real leg belongs on the TPU backend, which
-# gives stable measurements
-def test_bench_plan_acceptance_loop(profile_file, monkeypatch):
-    """ACCEPTANCE: ``bench_plan`` on the CPU mesh — >= 12 candidates,
-    the predicted-fastest plan's measured step time within 25% of its
-    calibrated prediction and no slower than the all-defaults
-    baseline, the artifact passes the drift-guard audit, and the
-    winning knobs round-trip decide -> schema-valid tuned_defaults ->
-    ``from_tuning`` on the 'next run'."""
-    bench = _load_bench()
-    out = bench.bench_plan(False, top_k=2, steps=2)
-    assert out["candidates_enumerated"] >= 12
-    assert out["feasible"] >= 1
-    rows = out["plans"]
-    assert len(rows) >= 2
-    # rows[0] is the ranked pick (the leg's contract): within 25% of
-    # its calibrated prediction, and no slower than the baseline
-    top = rows[0]
-    assert out["calibration_error_pct"] <= 25.0, out
-    assert top["measured_ms"] <= out["baseline_step_ms"] * 1.0001, out
-    # audit: no drift, telemetry well-formed
-    mod = _load_apply()
-    artifact = {"backend": "tpu", "detail": {"plan": out}}
-    assert mod.plan_violations(artifact) == []
-    from apex_tpu.telemetry import records_violations
-    assert records_violations(out["telemetry"]["records"]) == []
-
-    # persist: decide -> schema-valid profile -> consumed next run
-    prof_keys, rows_tbl = mod.decide(artifact, None)
-    plan_keys = {k: v for k, v in prof_keys.items()
-                 if k.startswith("plan_")}
-    assert plan_keys, rows_tbl
-    assert tuning.schema_violations(prof_keys) == []
-    profile_file(prof_keys)
-    jax.devices()
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    tuned = pm.from_tuning(N_DEV)
-    assert tuned is not None
-    win = out["measured_winner"]
-    assert tuned.dp == win["dp"]
-    assert tuned.update_sharding == win["update_sharding"]
-    assert tuned.collective_scheme == win["collective_scheme"]
-    # a winner measured at another topology never applies
-    assert pm.from_tuning(N_DEV * 2) is None
-
-
-def test_from_tuning_posture(profile_file, fake_tpu):
-    profile_file({"plan_dp": 8, "plan_update_sharding": "zero1"})
-    p = pm.from_tuning(8)
-    assert p is not None and p.update_sharding == "zero1"
-    assert p.tp == 1 and p.collective_scheme == "fp32"   # defaults
-    assert pm.from_tuning(4) is None                     # chips mismatch
-    profile_file({})
-    assert pm.from_tuning(8) is None                     # no plan keys
-
-
-def test_from_tuning_pp_ep_roundtrip(profile_file, fake_tpu):
-    """The pp/ep knobs round-trip tuned_defaults.json: schema-valid,
-    consumed by ``from_tuning``, and the chip count includes the new
-    axes (a 4x2 lattice IS an 8-chip plan)."""
-    pp_keys = {"plan_dp": 4, "plan_pp_stages": 2,
-               "plan_pp_microbatches": 2}
-    assert tuning.schema_violations(pp_keys) == []
-    profile_file(pp_keys)
-    p = pm.from_tuning(N_DEV)
-    assert p is not None and p.family == "pp"
-    assert (p.pp_stages, p.pp_microbatches) == (2, 2)
-    assert p.chips == N_DEV
-    assert pm.from_tuning(4) is None       # dp alone is NOT the plan
-
-    ep_keys = {"plan_dp": 4, "plan_ep": 2}
-    assert tuning.schema_violations(ep_keys) == []
-    profile_file(ep_keys)
-    p = pm.from_tuning(N_DEV)
-    assert p is not None and p.family == "ep" and p.ep == 2
-    assert p.chips == N_DEV
-
-
-def test_from_tuning_ignored_off_tpu(profile_file):
-    """Measured winners apply where they were measured — the CPU
-    backend must not consume a TPU-measured plan (tooling can opt in
-    with tpu_only=False)."""
-    profile_file({"plan_dp": 8})
-    assert pm.from_tuning(8) is None
-    assert pm.from_tuning(8, tpu_only=False) is not None
-
-
-# ---------------------------------------------------------------------------
 # CLI
 # ---------------------------------------------------------------------------
 
-def test_cli_renders_artifact_and_fresh_run(tmp_path):
+def test_cli_renders_fresh_run():
     """``python -m apex_tpu.parallel.plan`` renders the ranked table
-    from a measured artifact AND from a fresh CPU cost-model run."""
-    art = {"metric": "plan_ab", "backend": "cpu", "plan": {
-        "leg": "plan", "chips": 8, "plans": [
-            {"knobs": {"dp": 8, "update_sharding": "zero1"},
-             "predicted_ms": 1.5, "measured_ms": 1.4,
-             "hbm_bytes": 1 << 20},
-            {"knobs": {"dp": 8}, "predicted_ms": 2.0,
-             "measured_ms": 2.0, "hbm_bytes": 1 << 20}]}}
-    path = tmp_path / "plan_ab.json"
-    path.write_text(json.dumps(art))
+    from a fresh CPU cost-model run."""
     env = {**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": ROOT}
     r = subprocess.run(
-        [sys.executable, "-m", "apex_tpu.parallel.plan",
-         "--artifact", str(path)],
-        capture_output=True, text=True, env=env, timeout=120)
-    assert r.returncode == 0, r.stderr
-    assert "winner knobs" in r.stdout
-    assert "us=zero1" in r.stdout
-    assert "1.400" in r.stdout                 # measured column rendered
-
-    r2 = subprocess.run(
         [sys.executable, "-m", "apex_tpu.parallel.plan",
          "--chips", "8", "--model", "flagship",
          "--layers", "1", "--seq", "16", "--batch", "8"],
         capture_output=True, text=True, env=env, timeout=300)
-    assert r2.returncode == 0, r2.stderr
-    assert "HBM-feasible" in r2.stdout
-    assert "winner knobs" in r2.stdout
+    assert r.returncode == 0, r.stderr
+    assert "HBM-feasible" in r.stdout
+    assert "winner knobs" in r.stdout

@@ -25,11 +25,7 @@ The load-bearing contracts, in test order:
      (integer microseconds, tolerance zero) across the five classes.
   6. ``request_flood`` chaos: a synthetic admission burst exhausts the
      pool into typed, metered shedding.
-  7. The perf loop closes: bench-serve-leg-shaped artifact ->
-     ``serve_violations`` clean -> ``decide()`` persists
-     ``serve_decode_batch`` / ``serve_olevel`` -> tuning schema valid.
 """
-import importlib.util
 import json
 import os
 
@@ -515,101 +511,3 @@ def test_acceptance_32_requests_bf16(eng_bf16):
         assert sum(row["classes_us"].values()) == row["wall_us"]
     assert doc["requests"]["served"] == 32
     assert serve_violations(doc) == []
-
-
-# ---------------------------------------------------------------------------
-# 7. the perf loop: leg artifact -> audit -> decide -> tuning schema
-# ---------------------------------------------------------------------------
-
-def _load_apply():
-    spec = importlib.util.spec_from_file_location(
-        "apply_perf_results", os.path.join(ROOT, "tools",
-                                           "apply_perf_results.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def _leg_artifact(eng_fp32):
-    """A bench-serve-leg-shaped detail node carrying REAL ledger docs
-    (one measured run, snapshotted per variant the way the leg embeds
-    them)."""
-    led = ServeLedger()
-    bat = ContinuousBatcher(eng_fp32, ledger=led)
-    for i in range(3):
-        bat.submit(Request(rid=f"b{i}", prompt=[4 + i, 2, 9],
-                           max_new_tokens=3))
-    bat.run()
-    def variant(olevel, width, tps, cr=None):
-        doc = led.snapshot(olevel=olevel, decode_width=width,
-                           compression_ratio=cr)
-        return {"olevel": olevel, "decode_width": width,
-                "tokens_per_sec": tps, "p50_ms": 2.0, "p99_ms": 4.0,
-                "ttft_p50_ms": 1.0, "served": 3, "shed": 0,
-                "compression_ratio": cr, "ledger": doc}
-    variants = [variant("bf16", 4, 900.0), variant("bf16", 8, 1400.0),
-                variant("fp32", 4, 700.0),
-                variant("int8", 4, 1100.0, cr=3.5)]
-    return {"leg": "serve", "variants": variants,
-            "winner": {"olevel": "bf16", "decode_width": 8,
-                       "tokens_per_sec": 1400.0}}
-
-
-def test_serve_leg_audit_and_decide_round_trip(eng_fp32):
-    from apex_tpu.utils import tuning
-    mod = _load_apply()
-    leg = _leg_artifact(eng_fp32)
-    artifact = {"backend": "tpu", "detail": {"serve": leg}}
-    assert mod.serve_violations(artifact) == []
-    prof, rows = mod.decide(artifact, None)
-    assert prof["serve_decode_batch"] == 8
-    assert prof["serve_olevel"] == "bf16"
-    assert tuning.schema_violations(prof) == []
-    assert any("serve" in r[0] for r in rows)
-
-    # audit teeth: a winner no variant measured is a violation
-    broken = json.loads(json.dumps(leg))
-    broken["winner"]["decode_width"] = 16
-    assert mod.serve_violations({"serve": broken})
-    # ... and decide() must then refuse to persist
-    prof2, _ = mod.decide({"backend": "tpu",
-                           "detail": {"serve": broken}}, None)
-    assert "serve_decode_batch" not in prof2
-
-    # a winner that shed its way to the throughput crown is refused
-    shedder = json.loads(json.dumps(leg))
-    for v in shedder["variants"]:
-        if v["olevel"] == "bf16" and v["decode_width"] == 8:
-            v["shed"] = 2
-    prof3, _ = mod.decide({"backend": "tpu",
-                           "detail": {"serve": shedder}}, None)
-    assert "serve_decode_batch" not in prof3
-
-
-def test_decide_ignores_cpu_measured_serve_leg(eng_fp32):
-    mod = _load_apply()
-    leg = _leg_artifact(eng_fp32)
-    leg["_backend"] = "cpu"
-    prof, _ = mod.decide({"backend": "tpu", "detail": {"serve": leg}},
-                         None)
-    assert "serve_decode_batch" not in prof
-
-
-@pytest.mark.slow   # ~25s: the full measured serve A/B leg; the decide()
-# contract tests above keep the profile gating in tier-1
-def test_bench_serve_leg_end_to_end():
-    """The real leg: ``bench.bench_serve`` on the CPU mesh — variants
-    measured, audit clean, decide() persists a schema-valid profile."""
-    from apex_tpu.utils import tuning
-    spec = importlib.util.spec_from_file_location(
-        "bench", os.path.join(ROOT, "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    out = bench.bench_serve(False, n_requests=6)
-    assert len(out["variants"]) == 4
-    mod = _load_apply()
-    artifact = {"backend": "tpu", "detail": {"serve": out}}
-    assert mod.serve_violations(artifact) == []
-    prof, _rows = mod.decide(artifact, None)
-    if "serve_decode_batch" in prof:        # winner may have shed on CPU
-        assert tuning.schema_violations(prof) == []

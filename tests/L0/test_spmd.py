@@ -15,7 +15,6 @@ Covers the tentpole and its acceptance gates:
   * the multi-slice DCN alpha-beta terms and the ``@artifact``
     ceilings-calibration hook.
 """
-import json
 import os
 
 import numpy as np
@@ -377,32 +376,3 @@ def test_multislice_dcn_terms_oracle():
     p2 = pm.predict(prof, pm.Plan(dp=8),
                     ceilings=dict(CEIL, num_slices=2))
     assert p2.breakdown["dp_comm_ms"] > t1
-
-
-def test_ceilings_calibration_ingests_plan_artifact(tmp_path,
-                                                    monkeypatch):
-    """APEX_TPU_CEILINGS="@PLAN_AB.json" folds a measured plan leg's
-    one-point calibration into the ceilings row (the HW_CEILINGS
-    calibration hook)."""
-    from apex_tpu.pyprof.prof import resolve_ceilings, calibrate_ceilings
-    art = {"metric": "plan_ab", "backend": "tpu",
-           "plan": {"leg": "plan", "calibration_scale": 2.0,
-                    "family_calibration": {"dp": 2.0, "tp": 4.0},
-                    "plans": []}}
-    path = tmp_path / "PLAN_AB.json"
-    path.write_text(json.dumps(art))
-    base = resolve_ceilings("cpu")
-    monkeypatch.setenv("APEX_TPU_CEILINGS", f"@{path}")
-    cal = resolve_ceilings("cpu")
-    assert cal["peak_flops"] == pytest.approx(base["peak_flops"] / 2.0)
-    assert cal["ici_alpha_s"] == pytest.approx(base["ici_alpha_s"] * 2.0)
-    # family spread: tp measured 2x slower than its dp-calibrated
-    # prediction -> the comm tier takes the extra hit
-    assert cal["ici_bw"] == pytest.approx(base["ici_bw"] / 2.0 / 2.0)
-    # a calibration artifact without a measured leg fails loudly
-    with pytest.raises(ValueError, match="calibration"):
-        calibrate_ceilings(base, {"nope": 1})
-    bad = tmp_path / "missing.json"
-    monkeypatch.setenv("APEX_TPU_CEILINGS", f"@{bad}")
-    with pytest.raises(ValueError, match="cannot read"):
-        resolve_ceilings("cpu")

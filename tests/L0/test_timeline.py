@@ -14,16 +14,14 @@ The acceptance gates:
     as schema-valid records;
   * ``python -m apex_tpu.telemetry timeline <profiler-dir>`` renders
     the decomposition from a jax-profiler run-dir fixture;
-  * the measured ``exposed_comm_fraction`` round-trips
-    ``apply_perf_results.decide()`` -> ``tuned_defaults.json`` ->
-    ``parallel.plan.predict``'s overlap factor, changing the predicted
-    exposed-comm time;
+  * a measured ``exposed_comm_fraction`` handed to
+    ``parallel.plan.predict`` as its overlap factor changes the
+    predicted exposed-comm time;
   * a closing SlowStepSentinel capture window feeds the profiler dir
     through the decomposition and attaches the per-step table to a
     flight-dump ``sections`` block.
 """
 import gzip
-import importlib.util
 import json
 import os
 import subprocess
@@ -33,19 +31,9 @@ import pytest
 
 from apex_tpu.telemetry import (MemorySink, Registry, records_violations,
                                 timeline, trace)
-from apex_tpu.utils import tuning
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
-
-
-def _load_apply():
-    spec = importlib.util.spec_from_file_location(
-        "apply_perf_results", os.path.join(ROOT, "tools",
-                                           "apply_perf_results.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 def dev(name, ts, dur, device=0, args=None):
@@ -369,46 +357,16 @@ def test_cli_timeline_no_device_lanes_rc1(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# the overlap tuning loop: artifact -> decide() -> tuning -> plan
+# the overlap factor: measured fraction -> plan.predict
 # ---------------------------------------------------------------------------
 
-@pytest.fixture
-def profile_file(tmp_path, monkeypatch):
-    path = tmp_path / "tuned.json"
-    monkeypatch.setenv("APEX_TPU_TUNING_FILE", str(path))
-    tuning.reload()
-    yield path
-    tuning.reload()
 
-
-def _spmd_artifact(overlap):
-    return {"metric": "m", "value": 1.0, "unit": "ms",
-            "vs_baseline": 1.0, "backend": "tpu",
-            "detail": {"backend": "tpu",
-                       "spmd": {"leg": "spmd", "chips": 8,
-                                "families": {}, "overlap": overlap}}}
-
-
-def test_overlap_roundtrip_decide_to_plan(profile_file):
-    """The acceptance loop: a profiled-capture artifact's measured
-    exposed-comm fraction -> decide() -> schema-valid
-    tuned_defaults.json -> plan.predict charges only the exposed dp
-    comm, changing the predicted step time."""
-    mod = _load_apply()
-    overlap = {"profile_dir": "SPMD_PROFILE_r5", "devices": 8, "steps": 1,
-               "compute_ms": 10.0, "comm_ms": 4.0,
-               "exposed_comm_ms": 1.0, "idle_ms": 0.5,
-               "exposed_comm_fraction": 0.25, "stragglers": 0}
-    prof, rows = mod.decide(_spmd_artifact(overlap), None)
-    assert prof["overlap_measured_fraction"] == 0.25
-    assert any("overlap_measured_fraction" in r[0] for r in rows)
-    assert tuning.schema_violations(prof) == []
-    # the audit passes a consistent block
-    assert mod.overlap_violations(_spmd_artifact(overlap)) == []
-
-    # persist -> consume: predict() under the tuned fraction charges
-    # 0.25x the modeled dp comm
+def test_overlap_fraction_reaches_plan_predict(monkeypatch):
+    """A measured exposed-comm fraction handed to the planner (env pin
+    or argument) makes plan.predict charge only the exposed dp comm,
+    changing the predicted step time by exactly the hidden part."""
     from apex_tpu.parallel import plan as planmod
+    monkeypatch.delenv(planmod.ENV_OVERLAP, raising=False)
     prof_model = planmod.ModelProfile(
         name="oracle", flops=1e12, bytes_accessed=1e11,
         params_bytes=400 << 20, optimizer_bytes=800 << 20,
@@ -420,93 +378,32 @@ def test_overlap_roundtrip_decide_to_plan(profile_file):
     assert p_full.breakdown["dp_comm_exposed_ms"] == \
         pytest.approx(p_full.breakdown["dp_comm_ms"])
 
-    profile_file.write_text(json.dumps(prof))
-    tuning.reload()
-    p_tuned = planmod.predict(prof_model, planmod.Plan(dp=8),
-                              platform="tpu_v5e")
-    assert p_tuned.breakdown["overlap_fraction"] == 0.25
-    assert p_tuned.breakdown["dp_comm_exposed_ms"] == \
-        pytest.approx(0.25 * p_tuned.breakdown["dp_comm_ms"])
+    monkeypatch.setenv(planmod.ENV_OVERLAP, "0.25")
+    p_pinned = planmod.predict(prof_model, planmod.Plan(dp=8),
+                               platform="tpu_v5e")
+    assert p_pinned.breakdown["overlap_fraction"] == 0.25
+    assert p_pinned.breakdown["dp_comm_exposed_ms"] == \
+        pytest.approx(0.25 * p_pinned.breakdown["dp_comm_ms"])
     # the overlap factor changes the predicted step time by exactly the
     # hidden comm
     hidden = p_full.breakdown["dp_comm_ms"] * 0.75
-    assert p_full.predicted_step_ms - p_tuned.predicted_step_ms == \
+    assert p_full.predicted_step_ms - p_pinned.predicted_step_ms == \
         pytest.approx(hidden, rel=1e-6)
-    # explicit argument beats the tuning profile
+    # explicit argument beats the env pin
     p_exp = planmod.predict(prof_model, planmod.Plan(dp=8),
                             platform="tpu_v5e", overlap_fraction=0.5)
     assert p_exp.breakdown["overlap_fraction"] == 0.5
 
 
-def test_overlap_env_pin_beats_tuning(profile_file, monkeypatch):
-    profile_file.write_text(json.dumps({"overlap_measured_fraction": 0.3}))
-    tuning.reload()
-    assert timeline and tuning.get("overlap_measured_fraction") == 0.3
+def test_overlap_env_pin_beats_builtin(monkeypatch):
     from apex_tpu.parallel import plan as planmod
-    assert planmod.resolve_overlap_fraction() == 0.3
+    monkeypatch.delenv(planmod.ENV_OVERLAP, raising=False)
+    assert planmod.resolve_overlap_fraction() == 1.0
     monkeypatch.setenv(planmod.ENV_OVERLAP, "0.7")
     assert planmod.resolve_overlap_fraction() == 0.7
     assert planmod.resolve_overlap_fraction(0.1) == 0.1   # arg wins
     # clamped to [0, 1]
     assert planmod.resolve_overlap_fraction(7.0) == 1.0
-
-
-def test_decide_skips_unmeasured_or_commfree_overlap():
-    mod = _load_apply()
-    # an honestly-failed capture never decides
-    prof, _ = mod.decide(_spmd_artifact({"error": "no profiler"}), None)
-    assert "overlap_measured_fraction" not in prof
-    # a comm-free capture (fraction None) never decides
-    prof, _ = mod.decide(_spmd_artifact(
-        {"compute_ms": 5.0, "comm_ms": 0.0, "exposed_comm_ms": 0.0,
-         "exposed_comm_fraction": None}), None)
-    assert "overlap_measured_fraction" not in prof
-
-
-def test_overlap_violations_flag_inconsistent_blocks():
-    mod = _load_apply()
-    bad = _spmd_artifact({"compute_ms": 1.0, "comm_ms": 2.0,
-                          "exposed_comm_ms": 3.0,     # > comm: impossible
-                          "exposed_comm_fraction": 1.5})
-    out = mod.overlap_violations(bad)
-    assert any("exposed_comm_ms" in v for v in out)
-    assert any("exposed_comm_fraction" in v for v in out)
-    # error-only blocks pass (honest failure)
-    assert mod.overlap_violations(_spmd_artifact({"error": "x"})) == []
-
-
-# ---------------------------------------------------------------------------
-# the bench capture helper (real profiler; skips where unavailable)
-# ---------------------------------------------------------------------------
-
-def test_bench_profiled_overlap_capture_real_profiler(tmp_path):
-    """bench._profiled_overlap_capture drives a REAL jax.profiler
-    window around one jitted step and decomposes the capture — the
-    CPU-mesh flagship acceptance path, scaled to a toy psum step."""
-    import jax
-    import jax.numpy as jnp
-    import bench
-
-    mesh_step = jax.jit(lambda x: x * 2.0 + jnp.sum(x))
-    x = jnp.ones((256, 256))
-    mesh_step(x).block_until_ready()              # compile outside capture
-
-    def one_step():
-        mesh_step(x).block_until_ready()
-
-    d = str(tmp_path / "cap")
-    block, decomp = bench._profiled_overlap_capture(one_step, d)
-    if "error" in block:
-        pytest.skip(f"profiler capture unavailable: {block['error']}")
-    assert block["profile_dir"] == d
-    assert block["devices"] >= 1 and decomp is not None
-    assert block["compute_ms"] >= 0.0
-    # fraction is None (no collectives in this step) or within [0,1]
-    frac = block["exposed_comm_fraction"]
-    assert frac is None or 0.0 <= frac <= 1.0
-    # a schema-valid leg shape: the audit accepts it
-    mod = _load_apply()
-    assert mod.overlap_violations({"overlap": block}) == []
 
 
 # ---------------------------------------------------------------------------

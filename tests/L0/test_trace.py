@@ -333,22 +333,6 @@ def test_sentinel_rejects_warmup_larger_than_window():
         trace.SlowStepSentinel(window=8, warmup=16)
 
 
-def test_bench_trace_env_overrides_ambient_disable(monkeypatch, tmp_path):
-    """APEX_BENCH_TRACE is its own opt-in: an ambient APEX_TPU_TRACE=0
-    must not yield a silently empty bench timeline."""
-    import bench
-    monkeypatch.setenv("APEX_TPU_TRACE", "0")
-    monkeypatch.setenv("APEX_BENCH_TRACE", str(tmp_path / "b.json"))
-    tracer, path, prev = bench._maybe_install_bench_tracer()
-    try:
-        assert tracer.enabled is True
-        with bench._leg_span("unit"):
-            pass
-        assert tracer.n_spans == 1
-    finally:
-        trace.set_tracer(prev)
-
-
 def test_cli_trace_renders_guard_driven_span_summary(tmp_path):
     """ISSUE acceptance: ``python -m apex_tpu.telemetry trace <file>``
     renders the per-name count/total/p50/p99 self-time summary from a

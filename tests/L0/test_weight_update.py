@@ -4,7 +4,7 @@ mesh.
 Covers the tentpole and its acceptance gates:
 
   * knob resolution (``update_sharding`` arg > ``APEX_TPU_UPDATE_SHARDING``
-    env > tuning > off) and the ``DistributedDataParallel.weight_update``
+    env > off) and the ``DistributedDataParallel.weight_update``
     factory returning None when off;
   * THE A/B: the flagship transformer trained N steps with
     ``update_sharding="zero1"`` is BITWISE-identical to the unsharded
@@ -83,7 +83,7 @@ def _clean_hooks():
 # ---------------------------------------------------------------------------
 
 def test_resolve_mode_precedence():
-    assert wu.resolve_mode() == "off"            # no env, no tuning (CPU)
+    assert wu.resolve_mode() == "off"            # no env: the built-in
     os.environ[wu.ENV_KNOB] = "zero1"
     assert wu.resolve_mode() == "zero1"
     assert wu.resolve_mode("off") == "off"       # explicit beats env
@@ -660,133 +660,6 @@ def test_guard_preempt_resume_with_sharded_state_bitwise(mesh, tmp_path):
     res_final = jax.tree_util.tree_leaves(ref_state[2])
     assert any(float(jnp.abs(r).max()) > 0 for r in res_final)
 
-
-# ---------------------------------------------------------------------------
-# bench leg + apply_perf_results audit/decide + tuning schema
-# ---------------------------------------------------------------------------
-
-def _load_tool(name, rel):
-    import importlib.util
-    ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    spec = importlib.util.spec_from_file_location(
-        name, os.path.join(ROOT, *rel))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def test_bench_update_sharding_leg_shape():
-    """The bench leg: off vs zero1 (+int8 allgather) with the ~1/N
-    opt-state shrink, schema-valid embedded telemetry carrying the new
-    counters and the HBM fields (what apply_perf_results'
-    update_sharding audit checks)."""
-    bench = _load_tool("bench", ["bench.py"])
-    leg = bench.bench_update_sharding(on_tpu=False)
-    assert leg["leg"] == "update_sharding"
-    assert set(leg["modes"]) == {"off", "zero1", "zero1_int8ag"}
-    assert leg["world"] == N_DEV
-    # ~1/N optimizer-state shrink, layout-matched
-    assert leg["opt_state_shrink"] == pytest.approx(N_DEV, rel=0.05)
-    assert leg["modes"]["zero1_int8ag"]["ag_ratio"] >= 3.5
-    assert leg["modes"]["zero1"]["ag_ratio"] == 1.0
-    assert leg["modes"]["zero1"]["rs_logical_bytes"] > 0
-    # HBM evidence: the CPU path carries the compiled footprint
-    assert leg.get("hbm_compiled_peak_bytes") or leg.get(
-        "hbm_device_process_peak_bytes")
-    assert records_violations(leg["telemetry"]["records"]) == []
-    names = {r.get("name") for r in leg["telemetry"]["records"]}
-    assert {"ddp.reduce_scatter_bytes", "ddp.param_allgather_bytes",
-            "ddp.opt_state_bytes_per_replica"} <= names
-
-    apr = _load_tool("apply_perf_results",
-                     ["tools", "apply_perf_results.py"])
-    art = {"backend": "tpu", "detail": {"update_sharding": leg}}
-    assert apr.update_sharding_violations(art) == []
-    # exempt from the MFU/HBM audit (its own audit covers the evidence)
-    assert apr.perf_field_violations(art) == []
-    # drifted legs are flagged: bad shrink, bad int8 ratio, bare counters
-    bad = {"backend": "tpu", "detail": {"update_sharding": {
-        "leg": "update_sharding", "world": 8, "opt_state_shrink": 2.0,
-        "telemetry": leg["telemetry"],
-        "modes": {"zero1_int8ag": {"ag_ratio": 2.0}}}}}
-    vs = apr.update_sharding_violations(bad)
-    assert any("opt_state_shrink" in v for v in vs)
-    assert any("ratio" in v for v in vs)
-    assert any("update_sharding leg embeds no telemetry" in v
-               for v in apr.update_sharding_violations(
-                   {"leg": "update_sharding", "modes": {}}))
-
-
-def test_decide_writes_ddp_update_sharding():
-    """The decide() rule: zero1 wins when no slower than off; the
-    winning int8 variant with its metered ratio pins the allgather
-    scheme; both keys pass the committed tuning schema."""
-    apr = _load_tool("apply_perf_results",
-                     ["tools", "apply_perf_results.py"])
-    from apex_tpu.utils import tuning
-
-    def art(off_ms, z_ms, z8_ms, ratio=3.9):
-        return {"backend": "tpu", "detail": {"update_sharding": {
-            "leg": "update_sharding", "world": 8, "opt_state_shrink": 7.9,
-            "modes": {
-                "off": {"step_ms": off_ms},
-                "zero1": {"step_ms": z_ms, "ag_ratio": 1.0},
-                "zero1_int8ag": {"step_ms": z8_ms, "ag_ratio": ratio},
-            }}}}
-
-    prof, rows = apr.decide(art(10.0, 8.0, 7.0), None)
-    assert prof["ddp_update_sharding"] == "zero1"
-    assert prof["ddp_update_allgather_scheme"] == "int8_blockscale"
-    assert tuning.schema_violations(prof) == []
-
-    # zero1 slower -> off; no allgather key written
-    prof, _ = apr.decide(art(5.0, 8.0, 7.0), None)
-    assert prof["ddp_update_sharding"] == "off"
-    assert "ddp_update_allgather_scheme" not in prof
-
-    # int8 wins on ms but its ratio drifted -> the variant is excluded
-    # from the election entirely; zero1 is still elected here because
-    # the fp32 variant beats off ON ITS OWN timing
-    prof, _ = apr.decide(art(10.0, 8.0, 7.0, ratio=2.0), None)
-    assert prof["ddp_update_sharding"] == "zero1"
-    assert "ddp_update_allgather_scheme" not in prof
-
-    # drifted int8 is fastest but the consumable fp32 variant is slower
-    # than off -> off (the drifted timing must not elect zero1 on the
-    # fp32 variant's behalf)
-    prof, _ = apr.decide(art(7.5, 8.0, 7.0, ratio=2.0), None)
-    assert prof["ddp_update_sharding"] == "off"
-    assert "ddp_update_allgather_scheme" not in prof
-
-    # fp32 zero1 wins -> no allgather key
-    prof, _ = apr.decide(art(10.0, 6.0, 7.0), None)
-    assert prof["ddp_update_sharding"] == "zero1"
-    assert "ddp_update_allgather_scheme" not in prof
-    assert tuning.schema_violations(
-        {"ddp_update_sharding": "zero1",
-         "ddp_update_allgather_scheme": "int8_blockscale"}) == []
-    assert tuning.schema_violations(
-        {"ddp_update_sharding": "maybe"}) != []
-
-
-def test_tuning_profile_drives_resolve_mode(tmp_path, monkeypatch):
-    """resolve_mode consults the ddp_update_sharding tuning key — but
-    only on TPU (get_on_tpu); on the CPU backend the profile must NOT
-    flip the mode (measured winners apply where they were measured)."""
-    import json
-    from apex_tpu.utils import tuning
-    prof = tmp_path / "tuned_defaults.json"
-    prof.write_text(json.dumps({"ddp_update_sharding": "zero1"}))
-    monkeypatch.setenv("APEX_TPU_TUNING_FILE", str(prof))
-    tuning.reload()
-    try:
-        assert tuning.get("ddp_update_sharding") == "zero1"
-        assert wu.resolve_mode() == "off"       # CPU: profile not applied
-        assert wu.resolve_mode("zero1") == "zero1"
-    finally:
-        monkeypatch.delenv("APEX_TPU_TUNING_FILE")
-        tuning.reload()
 
 
 # ---------------------------------------------------------------------------

@@ -76,7 +76,8 @@ def test_bert_example_fast_attention():
 
 def test_bert_example_plan_smoke():
     """--plan resolves the parallel plan through the cost-model search
-    (no tuning profile on CPU) and materializes the winner through
+    (its ``=> plan [cost-model search ...]`` line says so) and
+    materializes the winner through
     spmd.build_plan_step — at these tiny dims the search picks a
     sharded expert-parallel plan, so this smoke drives the ep engine
     end to end through the example entry point (the path that replaced
