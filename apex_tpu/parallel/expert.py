@@ -26,14 +26,16 @@ init/apply around it.
 
 :func:`routed_experts` is the other family (docs/lfm2.md): sigmoid scores,
 a selection bias that chooses and does not weigh, top-k of ALL experts, and
-a share of them held here.  It drops no assignment — its buffer has a row
-for every one of the T·k — and its three matrix products are grouped ones
+a share of them held here.  Its three matrix products are grouped ones
 (``jax.lax.ragged_dot``) over the rows the held experts were sent, sorted by
-expert, so their time follows the load and not the buffer.
+expert, in a buffer of twice their even share of the T·k assignments
+(:func:`buffer_rows`); a load that outgrows the buffer is walked again by
+the same body, so it drops no assignment at any imbalance.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Optional
 
 import jax
@@ -198,59 +200,118 @@ def route_top_k(x, router_w, expert_bias, top_k: int, *,
     return ids, weights * routed_scaling_factor
 
 
-@jax.custom_vjp
-def _dispatch(x, order, place, here):
-    """Buffer row r holds the token of assignment ``order[r]`` (a token's k
-    assignments are consecutive: assignment a belongs to token a // k)."""
-    return x[order // place.shape[1]]
+def buffer_rows(tokens: int, top_k: int, experts: int, held: int) -> int:
+    """Rows of the dispatch buffer: twice the held experts' even share of
+    the T·k assignments, in whole 512s, and never more than all of them —
+    which is what it is where every expert is held.  A rule of the shapes,
+    not a knob (docs/performance.md)."""
+    full = tokens * top_k
+    return min(full, -(-2 * full * held // (experts * 512)) * 512)
 
 
-def _dispatch_fwd(x, order, place, here):
-    return _dispatch(x, order, place, here), (place, here)
+def _walk(i, order, ends, top_k, c):
+    """Walk ``i`` covers rows ``[i·c, (i + 1)·c)`` of the sorted order:
+    ``(lo, token (c,), assignment (c,), group sizes (held,), valid (c,))`` —
+    ``valid`` where the row is a held assignment's."""
+    lo = i * c
+    seg = jax.lax.dynamic_slice(order, (lo,), (c,))
+    hi = jnp.clip(ends - lo, 0, c)
+    sizes = jnp.diff(hi, prepend=0)
+    return lo, seg // top_k, seg, sizes, jnp.arange(c) < hi[-1]
 
 
-def _dispatch_bwd(res, g):
-    # the transpose of a gather is a scatter-add; over a permutation it is
-    # the gather by the inverse permutation, summed over a token's k rows —
-    # those of its held assignments: rows past the held groups are not
-    # numbers anybody wrote
-    place, here = res
-    dx = sum(jnp.where(here[:, j, None], g[place[:, j]], 0)
-             for j in range(place.shape[1]))
-    return dx, None, None, None
+def _gated(xs, w13, w2, sizes):
+    """``W2ᵉ(silu(W1ᵉ x) ⊙ W3ᵉ x)`` over the rows of each held group."""
+    with annotate("apex.experts"):
+        h = jax.lax.ragged_dot(xs, w13.astype(xs.dtype), sizes)
+        half = h.shape[1] // 2
+        h = jax.nn.silu(h[:, :half]) * h[:, half:]
+        return jax.lax.ragged_dot(h, w2.astype(xs.dtype), sizes)
 
 
-_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+def _slots(place, here, lo, c):
+    """Where in a walk's buffer each of a token's k slots lies, ``(at, mine)``
+    (T, k): ``mine`` where this walk holds the row.  A row past the held
+    groups is not numbers anybody wrote — the transposed grouped product
+    leaves it unwritten on a TPU — and is never read as one."""
+    at = place - lo
+    return jnp.clip(at, 0, c - 1), here & (at >= 0) & (at < c)
 
 
-@jax.custom_vjp
-def _combine(ys, weights, order, place):
-    """``Σ_j weights[t, j] · ys[place[t, j]]`` in float32; a zero weight (an
-    assignment to an absent expert) reads no row."""
-    return sum(jnp.where(weights[:, j, None] != 0,
-                         ys[place[:, j]].astype(jnp.float32), 0.0)
-               * weights[:, j, None] for j in range(place.shape[1]))
+def _token_sum(src, at, mine, scale=None):
+    """``Σ_j src[at[t, j]]`` over the slots this walk holds (times
+    ``scale[t, j]``, in its dtype): the transpose of the walk's row gather,
+    as k gathers of T rows."""
+    total = 0
+    for j in range(at.shape[1]):
+        term = jnp.where(mine[:, j, None], src[at[:, j]], 0)
+        total = total + (term if scale is None
+                         else term.astype(scale.dtype) * scale[:, j, None])
+    return total
 
 
-def _combine_fwd(ys, weights, order, place):
-    return _combine(ys, weights, order, place), (ys, weights, order, place)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _held_experts(c, x, w13, w2, weights, order, place, here, ends):
+    """``Σ_j weights[t, j] · FFN_{e(t, j)}(x[t])`` over the held assignments,
+    (T, D) float32, through a buffer of ``c`` rows: the held rows are the
+    first ``ends[-1]`` of the sorted ``order`` (padded to whole walks), and
+    the same body walks them ``c`` at a time until none is left — once where
+    the load fits.  The trip count is the device's, so the reverse pass is
+    stated here and not derived: its residuals are the arguments, and it
+    recomputes a walk's rows as remat would."""
+    tokens, top_k = place.shape
+
+    def body(carry):
+        i, out = carry
+        lo, token, _, sizes, _ = _walk(i, order, ends, top_k, c)
+        ys = _gated(x[token], w13, w2, sizes)
+        return i + 1, out + _token_sum(ys, *_slots(place, here, lo, c),
+                                       weights)
+
+    walks = -(-ends[-1] // c)
+    return jax.lax.while_loop(
+        lambda carry: carry[0] < walks, body,
+        (jnp.int32(0), jnp.zeros((tokens, x.shape[1]), jnp.float32)))[1]
 
 
-def _combine_bwd(res, g):
-    ys, weights, order, place = res
+def _held_experts_fwd(c, *args):
+    return _held_experts(c, *args), args
+
+
+def _held_experts_bwd(c, res, g):
+    x, w13, w2, weights, order, place, here, ends = res
     top_k = place.shape[1]
-    # row r's cotangent is its assignment's weight times its token's: again
-    # a gather, and zero for every row past the held groups
-    d_ys = (weights.reshape(-1)[order][:, None]
-            * g.astype(ys.dtype)[order // top_k]).astype(ys.dtype)
-    d_weights = jnp.stack(
-        [jnp.sum(jnp.where(weights[:, j, None] != 0,
-                           ys[place[:, j]].astype(jnp.float32), 0.0) * g,
-                 axis=-1) for j in range(top_k)], axis=1)
-    return d_ys, d_weights, None, None
+    g = g.astype(x.dtype)
+
+    def body(carry):
+        i, dx, dw13, dw2, d_weights = carry
+        lo, token, assignment, sizes, valid = _walk(i, order, ends, top_k, c)
+        ys, transpose = jax.vjp(
+            lambda xs, w13, w2: _gated(xs, w13, w2, sizes), x[token], w13, w2)
+        # row r's cotangent is its assignment's weight times its token's,
+        # and its weight's the product of the two rows: both in row space
+        g_rows = g[token]
+        weight = jnp.where(valid, weights.reshape(-1)[assignment], 0.0)
+        d_xs, d13, d2 = transpose((weight[:, None] * g_rows).astype(x.dtype))
+        d_weight = jnp.sum(ys.astype(jnp.float32) * g_rows, axis=-1)
+        # an expert with no row in this walk adds nothing, whatever the
+        # grouped product left in its slice
+        sent = (sizes > 0)[:, None, None]
+        at, mine = _slots(place, here, lo, c)
+        return (i + 1, dx + _token_sum(d_xs, at, mine),
+                dw13 + jnp.where(sent, d13, 0), dw2 + jnp.where(sent, d2, 0),
+                d_weights + jnp.where(mine, d_weight[at], 0.0))
+
+    walks = -(-ends[-1] // c)
+    _, dx, dw13, dw2, d_weights = jax.lax.while_loop(
+        lambda carry: carry[0] < walks, body,
+        (jnp.int32(0), jnp.zeros_like(x), jnp.zeros(w13.shape, jnp.float32),
+         jnp.zeros(w2.shape, jnp.float32), jnp.zeros_like(weights)))
+    return (dx, dw13.astype(w13.dtype), dw2.astype(w2.dtype), d_weights,
+            None, None, None, None)
 
 
-_combine.defvjp(_combine_fwd, _combine_bwd)
+_held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 
 def routed_experts(x, router_w, expert_bias, w13, w2, *, top_k: int,
@@ -268,21 +329,35 @@ def routed_experts(x, router_w, expert_bias, w13, w2, *, top_k: int,
     replicated over the axis) device i holds experts ``i·held ..`` and the
     parts are summed over the axis; unbound there is no exchange.
 
-    No assignment is dropped at any imbalance: the buffer has T·k rows, one
-    for each, sorted by expert with the absent ones last, and the grouped
-    products run over the rows of the held groups only.  Returns
+    The assignments are sorted by expert with the absent ones last, and the
+    held rows at the front of that order go through a buffer of
+    :func:`buffer_rows` rows — gather, grouped products, weighted sum back to
+    the tokens — as many times as it takes: once where the load fits, T·k /
+    rows at total imbalance.  No assignment is dropped.  Returns
     ``(out (T, D), routing)``; ``routing`` holds ``ids`` (T, k) int32, the
     experts every token took, ``rows`` (held,) int32, the assignments each
-    held expert was sent, and ``dropped`` () int32, the held assignments
-    the buffer had no row for, which is 0."""
+    held expert was sent, ``dropped`` () int32, the held assignments no walk
+    reached, which is 0, and ``walks`` () int32, the times the buffer was
+    gone over (0 where no token took a held expert)."""
+    return _routed_experts(x, router_w, expert_bias, w13, w2, top_k=top_k,
+                           first=first, norm_topk_prob=norm_topk_prob,
+                           routed_scaling_factor=routed_scaling_factor,
+                           axis_name=axis_name)
+
+
+def _routed_experts(x, router_w, expert_bias, w13, w2, *, top_k, first=0,
+                    norm_topk_prob=True, routed_scaling_factor=1.0,
+                    axis_name=EXPERT_AXIS, rows_a_walk=None):
+    """:func:`routed_experts`; ``rows_a_walk`` pins the buffer under the
+    rule's (:func:`buffer_rows`) so that a test can make it walk."""
     tokens, _ = x.shape
-    held, _, two_f = w13.shape
+    held = w13.shape[0]
     bound = axis_name is not None and axis_is_bound(axis_name)
     if bound:
         first = jax.lax.axis_index(axis_name) * held
+    c = rows_a_walk or buffer_rows(tokens, top_k, router_w.shape[1], held)
     _tel_events.record_moe_layout(
-        experts=router_w.shape[1], held=held, top_k=top_k,
-        buffer_rows=tokens * top_k)
+        experts=router_w.shape[1], held=held, top_k=top_k, buffer_rows=c)
 
     with annotate("apex.router"):
         ids, weights = route_top_k(
@@ -298,18 +373,17 @@ def routed_experts(x, router_w, expert_bias, w13, w2, *, top_k: int,
     here = here.reshape(tokens, top_k)
     rows = jnp.sum(group[:, None] == jnp.arange(held)[None, :], axis=0,
                    dtype=jnp.int32)
-    dropped = jnp.maximum(jnp.sum(rows) - tokens * top_k, 0)
-    xs = _dispatch(x, order, place, here)
+    ends = jnp.cumsum(rows)
+    walks = -(-ends[-1] // c)
+    # whole walks cover every assignment, so the buffer has a row for each
+    covered = -(-tokens * top_k // c) * c
+    dropped = jnp.maximum(ends[-1] - covered, 0)
 
-    with annotate("apex.experts"):
-        h = jax.lax.ragged_dot(xs, w13.astype(x.dtype), rows)
-        h = jax.nn.silu(h[:, : two_f // 2]) * h[:, two_f // 2:]
-        ys = jax.lax.ragged_dot(h, w2.astype(x.dtype), rows)
-
-    # -- combine: a token's k rows, weighed; rows past the held groups hold
-    # whatever the grouped product left there and are never read as numbers
-    out = _combine(ys, jnp.where(here, weights, 0.0), order, place).astype(
-        x.dtype)
+    out = _held_experts(
+        c, x, w13, w2, jnp.where(here, weights, 0.0),
+        jnp.pad(order, (0, covered - tokens * top_k)), place, here, ends
+    ).astype(x.dtype)
     if bound:
         out = jax.lax.psum(out, axis_name)
-    return out, {"ids": ids, "rows": rows, "dropped": dropped}
+    return out, {"ids": ids, "rows": rows, "dropped": dropped,
+                 "walks": walks}

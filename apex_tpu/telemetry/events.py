@@ -204,8 +204,9 @@ def record_moe_layout(experts: int, held: int, top_k: int,
     (``parallel.expert.routed_experts``): one call per traced expert layer
     — trace time, like :func:`record_flash_bwd` — with the number of
     experts routed over, how many of them are held here, the experts a
-    token takes and the rows of the dispatch buffer (tokens x top_k: a row
-    for every assignment, so none can be dropped).  Counter
+    token takes and the rows of the dispatch buffer (twice the held
+    experts' even share of the tokens x top_k assignments; a load past it is
+    walked again, so none can be dropped).  Counter
     ``moe.layers_traced`` and one ``moe.layout`` event."""
     if not active():
         return
@@ -221,14 +222,15 @@ EXPERT_ROWS_KEPT = 64
 _expert_rows: "collections.deque" = collections.deque(maxlen=EXPERT_ROWS_KEPT)
 
 
-def record_expert_rows(rows, dropped) -> None:
+def record_expert_rows(rows, dropped, walks) -> None:
     """Step side of the routing meter: what one forward pass sent the held
     experts.  ``rows`` (expert layers, held) are the assignments each held
     expert of each layer was sent, ``dropped`` the held assignments that
-    found no row in the buffer (0: the buffer has a row for each).  A model
+    found no row in the buffer (0), ``walks`` (expert layers,) the times
+    each layer went over its buffer (1 where the load fit).  A model
     calls it through ``jax.debug.callback`` once a forward pass, and only
     where :func:`active` was true when the step was traced.  Counters
-    ``moe.rows_held`` / ``moe.rows_dropped``, histogram
+    ``moe.rows_held`` / ``moe.rows_dropped`` / ``moe.walks``, histogram
     ``moe.load_max_over_mean`` (the fullest held expert over the mean, worst
     layer), and the array itself in :func:`expert_rows`."""
     if not active():
@@ -239,6 +241,7 @@ def record_expert_rows(rows, dropped) -> None:
     reg = _default
     reg.counter("moe.rows_held").add(int(rows.sum()))
     reg.counter("moe.rows_dropped").add(int(np.sum(dropped)))
+    reg.counter("moe.walks").add(int(np.sum(walks)))
     reg.histogram("moe.load_max_over_mean").observe(float(
         (rows.max(axis=1) / np.maximum(rows.mean(axis=1), 1e-9)).max()))
 

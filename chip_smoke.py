@@ -16,7 +16,9 @@ user would call, at the full width of the models the repo supports:
               one dense layer and one period, 8 of 64 experts, b8 x s4096
               (the benchmark cell's shapes); the step holds the kernels and
               the grouped products, and loss and gradients match the
-              XLA-attention twin on one sequence
+              XLA-attention twin on one sequence; then one expert layer
+              at one walk of its buffer and at a forced three against
+              its float32 XLA twin, gradients included
   multichip   (when jax finds >= 4 devices) the trainers --distributed /
               --zero / --sync-bn and one step of every plan family on a
               4-device mesh, each device holding its share
@@ -708,6 +710,77 @@ def phase_lfm2(ctx) -> dict:
     if not (errors[0] < 2e-3 and errors[1] < 2e-2):
         raise AssertionError(f"flash step against its XLA-attention twin: "
                              f"{fast} vs {twin}")
+    del params
+    facts["expert_layer"] = _expert_layer_checks(ctx)
+    return facts
+
+
+def _expert_layer_checks(ctx) -> dict:
+    """One expert layer (8 of 64 held, top-4, the model's widths) at one
+    walk of its buffer and at a forced three, output and every gradient
+    against its float32 XLA twin: the same routing, then each held expert
+    over ALL tokens, weighed — no sort, no buffer, no grouped product.  The
+    TPU's grouped product leaves rows past the groups unwritten where the
+    CPU zero-fills, so only here can a masking fault show."""
+    import jax
+    import jax.numpy as jnp
+    from apex_tpu.parallel import expert
+    tokens, d, f = (8192, 2048, 1536) if ctx["full"] else (96, 64, 32)
+    experts, held, top_k, first = 64, 8, 4, 16
+    keys = jax.random.split(jax.random.PRNGKey(7), 5)
+    bf16 = jnp.bfloat16
+
+    def normal(key, shape, fan_in, dtype):
+        return (jax.random.normal(key, shape, jnp.float32)
+                * fan_in ** -0.5).astype(dtype)
+    args = (normal(keys[0], (tokens, d), 1, bf16),
+            normal(keys[1], (d, experts), d, jnp.float32),
+            normal(keys[2], (held, d, 2 * f), d, bf16),
+            normal(keys[3], (held, f, d), f, bf16))
+    probe = jax.random.normal(keys[4], (tokens, d), jnp.float32)
+    bias = jnp.zeros((experts,), jnp.float32)
+    names = ("out", "d_x", "d_router", "d_w13", "d_w2")
+
+    def system(rows_a_walk):
+        def loss(x, router, w13, w2):
+            out, routing = expert._routed_experts(
+                x, router, bias, w13, w2, top_k=top_k, first=first,
+                axis_name=None, rows_a_walk=rows_a_walk)
+            return jnp.sum(out.astype(jnp.float32) * probe), (out, routing)
+        (_, (out, routing)), grads = jax.jit(jax.value_and_grad(
+            jax.checkpoint(loss), argnums=(0, 1, 2, 3), has_aux=True))(*args)
+        return (out, *grads), routing
+
+    def twin(x, router, w13, w2):
+        ids, weights = expert.route_top_k(x, router, bias, top_k)
+        x, out = x.astype(jnp.float32), 0.0
+        for e in range(held):
+            weight = jnp.sum(jnp.where(ids == first + e, weights, 0.0), -1)
+            gate, up = jnp.split(x @ w13[e].astype(jnp.float32), 2, axis=-1)
+            out = out + weight[:, None] * (
+                (jax.nn.silu(gate) * up) @ w2[e].astype(jnp.float32))
+        return jnp.sum(out * probe), out
+
+    with jax.default_matmul_precision("highest"):
+        (_, want_out), want_grads = jax.jit(jax.value_and_grad(
+            twin, argnums=(0, 1, 2, 3), has_aux=True))(*args)
+    want = (want_out, *want_grads)
+
+    runs = {"one_walk": system(None)}
+    sent = int(runs["one_walk"][1]["rows"].sum())
+    runs["three_walks"] = system(-(-sent // 3))     # a third of the load
+    facts = {"rows_sent": sent, "buffer_rows": expert.buffer_rows(
+        tokens, top_k, experts, held)}
+    for (label, (got, routing)), walks in zip(runs.items(), (1, 3)):
+        errors = {name: float(f"{rel_err(g, w):.3e}")
+                  for name, g, w in zip(names, got, want)}
+        facts[label] = {"walks": int(routing["walks"]), "rel_err": errors}
+        if int(routing["walks"]) != walks or int(routing["dropped"]):
+            raise AssertionError(f"{label}: {routing['walks']} walks, "
+                                 f"{routing['dropped']} dropped")
+        if not max(errors.values()) < 3e-2:
+            raise AssertionError(f"{label} against the float32 twin: "
+                                 f"{errors}")
     return facts
 
 

@@ -8,6 +8,8 @@ platform.  This file pins that table:
     transformer cells (read from ``benchmarks/``, read-only) the flash
     forward tile, the backward path and tile as TRACED (nothing executed),
     the backward route and the xentropy ``auto`` choice on either platform;
+  * ``test_cell_buffer``: for the cell whose model routes, the rows of the
+    expert dispatch buffer the rule gives (``parallel.expert.buffer_rows``);
   * ``test_builtin_choice``: the cell-independent choosers, once each;
   * ``test_stale_profile_is_ignored``: a ``tuned_defaults.json`` left over
     from the retired measured-tuning loop, naming the OTHER value for every
@@ -37,7 +39,7 @@ from apex_tpu.contrib.xentropy import softmax_xentropy as sx
 from apex_tpu.mlp import MLP
 from apex_tpu.models import TransformerConfig, bert_large_config
 from apex_tpu.optimizers import FusedAdam
-from apex_tpu.parallel import collectives, overlap
+from apex_tpu.parallel import collectives, expert, overlap
 from apex_tpu.parallel import plan as planmod
 from apex_tpu.parallel import weight_update as wu
 from apex_tpu.telemetry import MemorySink, Registry, events
@@ -135,6 +137,31 @@ CELL_TILES = {
 }
 
 
+def _expert_cells():
+    """{cell: (tokens a chip, top_k, experts, held)} for the cells whose
+    model routes, from the same files."""
+    cells = {}
+    for w in _read("BENCHMARK.json")["workloads"]:
+        model = _read("benchmarks", "configs", w["config"] + ".json")["model"]
+        if "num_experts" not in model:
+            continue
+        traffic = _read("benchmarks", "workloads", w["name"] + ".json")
+        cells[w["name"]] = (
+            traffic["batch"] // w["chips"] * traffic["seq"],
+            model["num_experts_per_tok"], model["num_experts"],
+            model["experts_held"][1])
+    return cells
+
+
+EXPERT_CELLS = _expert_cells()
+
+#: cell -> (rows of the dispatch buffer, of the T·k assignments): the rule
+#: of ``parallel.expert.buffer_rows`` — twice the held experts' even share
+CELL_BUFFERS = {
+    "lfm2_24b_a2b.ep8_s4096": (32768, 131072),
+}
+
+
 def _walk_eqns(jaxpr):
     for eqn in jaxpr.eqns:
         yield eqn
@@ -180,6 +207,20 @@ CELL_CHOOSERS = ("fwd_tile", "bwd_tile", "bwd_kernels", "bwd_impl",
 
 def test_the_cell_table_covers_the_benchmark():
     assert set(CELLS) == set(CELL_TILES)
+
+
+def test_the_buffer_table_covers_the_routed_cells():
+    assert set(EXPERT_CELLS) == set(CELL_BUFFERS)
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_BUFFERS))
+def test_cell_buffer(cell):
+    tokens, top_k, experts, held = EXPERT_CELLS[cell]
+    assert (expert.buffer_rows(tokens, top_k, experts, held),
+            tokens * top_k) == CELL_BUFFERS[cell]
+    # every expert held: the buffer is every assignment, one walk always
+    assert expert.buffer_rows(tokens, top_k, experts, experts) \
+        == tokens * top_k
 
 
 @pytest.mark.parametrize("chooser", CELL_CHOOSERS)

@@ -23,6 +23,7 @@ from apex_tpu.models import (Lfm2Config, lfm2_24b_a2b_config,
                              lfm2_routing)
 from apex_tpu.models import lfm2 as lfm2_module
 from apex_tpu.parallel import create_mesh, use_mesh
+from apex_tpu.parallel import expert
 from apex_tpu.parallel.expert import route_top_k, routed_experts
 from apex_tpu.telemetry import events
 
@@ -125,19 +126,39 @@ def _share(layer, first, count):
                 w2=layer["w2"][first:first + count])
 
 
-def _routed(h, layer, first, **kw):
-    return routed_experts(h, layer["router"], layer["expert_bias"],
-                          layer["w13"], layer["w2"],
-                          top_k=CFG.num_experts_per_tok, first=first, **kw)
+def _routed(h, layer, first, rows_a_walk=None, **kw):
+    """``routed_experts`` — or, where ``rows_a_walk`` pins the buffer, the
+    function behind it."""
+    fn = routed_experts if rows_a_walk is None else functools.partial(
+        expert._routed_experts, rows_a_walk=rows_a_walk)
+    return fn(h, layer["router"], layer["expert_bias"], layer["w13"],
+              layer["w2"], top_k=CFG.num_experts_per_tok, first=first, **kw)
 
 
-def test_the_shares_sum_to_the_uncut_references_whole_layer():
+def _pinned(h, layer, first, walks):
+    """The buffer under which the share's rows take ``walks`` walks; None
+    (the rule's buffer, at these sizes all T·k rows: one walk) for None."""
+    if walks is None:
+        return None
+    sent = int(_routed(h, layer, first, axis_name=None)[1]["rows"].sum())
+    return -(-sent // walks)
+
+
+WALKS = [None, 2, 4]     # the rule's buffer (one walk), and pinned ones
+
+
+@pytest.mark.parametrize("walks", WALKS)
+def test_the_shares_sum_to_the_uncut_references_whole_layer(walks):
     whole, layer, h = _expert_layer(CFG)
     want, _ = reference._expert_ffn(h, layer, _model(whole))
     parts, rows = 0.0, 0
     for first in range(0, 16, 4):
-        out, routing = _routed(h, _share(layer, first, 4), first,
+        share = _share(layer, first, 4)
+        out, routing = _routed(h, share, first,
+                               _pinned(h, share, first, walks),
                                axis_name=None)
+        assert int(routing["walks"]) == (walks or 1)
+        assert int(routing["dropped"]) == 0
         model = _model(dataclasses.replace(CFG, experts_held=(first, 4)))
         np.testing.assert_allclose(
             out, reference._expert_ffn(h, _share(layer, first, 4), model)[0],
@@ -145,6 +166,65 @@ def test_the_shares_sum_to_the_uncut_references_whole_layer():
         parts, rows = parts + out, rows + int(routing["rows"].sum())
     np.testing.assert_allclose(parts, want, rtol=1e-4, atol=1e-6)
     assert rows == h.shape[0] * CFG.num_experts_per_tok
+
+
+def _layer_gradients(h, layer, first, walks):
+    """``(out, {leaf: gradient})`` of a probed sum of the share's part, by
+    the system under ``jax.checkpoint`` and by the reference."""
+    probe = jax.random.normal(jax.random.PRNGKey(11), h.shape)
+    model = _model(dataclasses.replace(
+        CFG, experts_held=(first, layer["w13"].shape[0])))
+    leaves = ("h", "router", "w13", "w2")
+    rows_a_walk = _pinned(h, layer, first, walks)
+
+    def system(h, router, w13, w2):
+        out, _ = _routed(h, dict(layer, router=router, w13=w13, w2=w2),
+                         first, rows_a_walk, axis_name=None)
+        return jnp.sum(out * probe), out
+
+    def plain(h, router, w13, w2):
+        out, _ = reference._expert_ffn(
+            h, dict(layer, router=router, w13=w13, w2=w2), model)
+        return jnp.sum(out * probe), out
+
+    args = (h, layer["router"], layer["w13"], layer["w2"])
+    both = []
+    for fn in (jax.checkpoint(system), plain):
+        (_, out), grads = jax.value_and_grad(
+            fn, argnums=(0, 1, 2, 3), has_aux=True)(*args)
+        both.append((out, dict(zip(leaves, grads))))
+    return both
+
+
+def _assert_same(got, want):
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-4, atol=1e-6)
+    for name, leaf in want[1].items():
+        assert np.any(leaf), name
+        np.testing.assert_allclose(
+            got[1][name], leaf, rtol=1e-4,
+            atol=1e-5 * float(jnp.max(jnp.abs(leaf))), err_msg=name)
+
+
+@pytest.mark.parametrize("walks", WALKS)
+def test_every_gradient_leaf_of_a_walked_layer_is_the_references(walks):
+    """x, w13, w2 and — through the weights — the router, whether the held
+    rows fit the buffer or the same body walks them in 2 or 4 pieces."""
+    _, layer, h = _expert_layer(CFG)
+    _assert_same(*_layer_gradients(h, _share(layer, 4, 4), 4, walks))
+
+
+def test_a_walk_with_nothing_to_do_takes_no_trip():
+    """No token takes a held expert: no walk, a zero part, zero gradients."""
+    _, layer, h = _expert_layer(CFG)
+    layer = dict(_share(layer, 4, 4),
+                 expert_bias=jnp.zeros((16,)).at[4:8].set(-50.0))
+    out, routing = _routed(h, layer, 4, axis_name=None)
+    assert int(routing["walks"]) == 0 and not np.any(routing["rows"])
+    assert not np.any(out)
+    grads = jax.grad(lambda h, w13: _routed(
+        h, dict(layer, w13=w13), 4, axis_name=None)[0].sum(),
+        argnums=(0, 1))(h, layer["w13"])
+    assert not np.any(grads[0]) and not np.any(grads[1])
 
 
 def test_bound_to_an_axis_the_devices_hold_the_shares_and_sum_them():
@@ -165,24 +245,64 @@ def test_bound_to_an_axis_the_devices_hold_the_shares_and_sum_them():
     assert int(rows.sum()) == h.shape[0] * CFG.num_experts_per_tok
 
 
+@pytest.mark.parametrize("walks", [None, 4])
 @pytest.mark.parametrize("favourite", [0, 3])
-def test_no_assignment_is_dropped_at_total_imbalance(favourite):
+def test_no_assignment_is_dropped_at_total_imbalance(favourite, walks):
     """Every token's four choices are the four held experts, so each of them
     takes a row from EVERY token: T·4 rows held, none dropped, and the
-    result is the reference's.  The bias alone does it (the router's scores
-    are whatever they are), so it also shows the bias choosing."""
+    result and its gradients are the reference's — in T·k / C walks: one
+    through the rule's buffer (all T·k rows at this size), four through a
+    quarter of it, as the cell's 32 768 rows would take its 131 072.  The
+    bias alone does it (the router's scores are whatever they are), so it
+    also shows the bias choosing."""
     _, layer, h = _expert_layer(CFG, tokens=64)
     first = 4
     bias = jnp.full((16,), -5.0).at[first:first + 4].set(5.0)
     bias = bias.at[first + favourite].add(1.0)
     layer = dict(_share(layer, first, 4), expert_bias=bias)
-    out, routing = _routed(h, layer, first, axis_name=None)
+    out, routing = _routed(h, layer, first, _pinned(h, layer, first, walks),
+                           axis_name=None)
     assert routing["rows"].tolist() == [64, 64, 64, 64]
     assert int(routing["dropped"]) == 0
+    buffer = expert.buffer_rows(64, 4, 16, 4) // (walks or 1)
+    assert int(routing["walks"]) == 64 * 4 // buffer == (walks or 1)
     assert sorted(np.unique(routing["ids"]).tolist()) == [4, 5, 6, 7]
-    model = _model(dataclasses.replace(CFG, experts_held=(first, 4)))
-    np.testing.assert_allclose(
-        out, reference._expert_ffn(h, layer, model)[0], rtol=1e-4, atol=1e-6)
+    got, want = _layer_gradients(h, layer, first, walks)
+    np.testing.assert_array_equal(out, got[0])
+    _assert_same(got, want)
+
+
+def _avals(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield from (v.aval for v in (*eqn.invars, *eqn.outvars))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _avals(sub)
+
+
+def test_no_array_of_t_k_rows_at_the_cells_shape():
+    """Traced at the benchmark cell's shape (abstract: nothing runs), the
+    expert layer and its gradient hold the 32 768-row buffer and no array of
+    T·k = 131 072 rows by D or by 2F — in any loop body or reverse rule."""
+    tokens, d, f, experts, held, top_k = 32768, 2048, 1536, 64, 8, 4
+    sds = jax.ShapeDtypeStruct
+    args = (sds((tokens, d), jnp.bfloat16), sds((d, experts), jnp.float32),
+            sds((held, d, 2 * f), jnp.bfloat16),
+            sds((held, f, d), jnp.bfloat16))
+
+    def layer(x, router, w13, w2):
+        out, _ = routed_experts(x, router, jnp.zeros((experts,)), w13, w2,
+                                top_k=top_k, axis_name=None)
+        return jnp.sum(out.astype(jnp.float32))
+
+    buffer = expert.buffer_rows(tokens, top_k, experts, held)
+    assert buffer == 32768 and tokens * top_k == 4 * buffer
+    for fn in (layer, jax.grad(jax.checkpoint(layer), argnums=(0, 1, 2, 3))):
+        shapes = {tuple(a.shape) for a in _avals(jax.make_jaxpr(fn)(*args).jaxpr)
+                  if hasattr(a, "shape")}
+        assert (buffer, d) in shapes and (buffer, 2 * f) in shapes
+        wide = {s for s in shapes if len(s) >= 2 and tokens * top_k in s
+                and set(s) & {d, f, 2 * f}}
+        assert not wide, wide
 
 
 def test_the_bias_chooses_and_does_not_weigh():
@@ -246,11 +366,13 @@ def test_the_routing_meter_counts_once_a_forward_pass_under_remat():
         jax.effects_barrier()
         assert len(events.expert_rows()) == before + 1
         rows = events.expert_rows()[-1]
+        assert sum(registry.counter("moe.walks")._pending_values()) == 4
     finally:
         events.set_default(previous)
     routing = lfm2_routing(params, batch["tokens"], cfg)
     np.testing.assert_array_equal(rows, routing["rows"])
     assert rows.shape == (4, 4) and not np.any(routing["dropped"])
+    assert routing["walks"].tolist() == [1, 1, 1, 1]
     layouts = [e["fields"] for e in registry._events
                if e["name"] == "moe.layout"]
     assert {"experts": 16, "held": 4, "top_k": 4,

@@ -116,11 +116,15 @@ def test_scope_occurs_in_the_step(name, step_ops, ddp_step_ops,
     one_chip = {path for _, path in step_ops if name in path}
     if name in LFM2_ONLY:
         # the BERT step has no such block; the LFM2 step enters it forward,
-        # backward and in remat's second forward
+        # backward and in remat's second forward — but for the experts: the
+        # reverse rule of ``parallel.expert._held_experts`` recomputes a
+        # walk itself (backward), so remat's own second forward of them, the
+        # block's last operation, is dead code and must stay gone
         assert not one_chip
         paths = [path for _, path in lfm2_step_ops if name in path]
         assert any("transpose(" in path for path in paths), name
-        assert any("rematted_computation" in path for path in paths), name
+        assert any("rematted_computation" in path for path in paths) \
+            == (name != "apex.experts"), name
         assert any("transpose(" not in path for path in paths), name
     elif name == "apex.ddp_allreduce":
         # only a step that reduces has it, and the collective lies under it
