@@ -18,6 +18,10 @@ from .moe_transformer import (MoETransformerConfig, moe_transformer_init,
                               moe_transformer_apply, moe_transformer_loss)
 from .lfm2 import (Lfm2Config, lfm2_24b_a2b_config, lfm2_cut_layer_types,
                    lfm2_init, lfm2_apply, lfm2_loss, lfm2_routing)
+from .nemotron_h import (NemotronHConfig, nemotron3_super_120b_a12b_config,
+                         nemotron_h_cut_pattern, nemotron_h_init,
+                         nemotron_h_share, nemotron_h_apply, nemotron_h_loss,
+                         nemotron_h_routing)
 
 __all__ = [
     "TransformerConfig", "transformer_init", "transformer_apply",
@@ -29,4 +33,7 @@ __all__ = [
     "moe_transformer_loss",
     "Lfm2Config", "lfm2_24b_a2b_config", "lfm2_cut_layer_types", "lfm2_init",
     "lfm2_apply", "lfm2_loss", "lfm2_routing",
+    "NemotronHConfig", "nemotron3_super_120b_a12b_config",
+    "nemotron_h_cut_pattern", "nemotron_h_init", "nemotron_h_share",
+    "nemotron_h_apply", "nemotron_h_loss", "nemotron_h_routing",
 ]

@@ -178,19 +178,26 @@ def _attention_mixer(u, lp, cfg):
     q = (q * hd ** -0.5).astype(dt).transpose(0, 2, 1, 3)
     k = jnp.repeat(k.transpose(0, 2, 1, 3), heads // kv_heads, axis=1)
     v = jnp.repeat(v.transpose(0, 2, 1, 3), heads // kv_heads, axis=1)
-    if cfg.attn_impl == "fast":
+    return causal_attention(q, k, v, cfg.attn_impl) @ lp["wo"].astype(dt)
+
+
+def causal_attention(q, k, v, impl: str):
+    """Causal softmax attention of ``q`` (pre-scaled), ``k``, ``v`` (B, H, S,
+    hd), every key/value head already repeated for its query heads: the flash
+    kernel under ``impl="fast"``, else the XLA core.  Returns (B, S, H·hd)."""
+    bsz, heads, seq, hd = q.shape
+    no_bias = jnp.zeros((1, 1, seq), jnp.float32)
+    if impl == "fast":
         from ..contrib.multihead_attn.flash import flash_attention
         ctx = flash_attention(
             q.reshape(bsz * heads, seq, hd), k.reshape(bsz * heads, seq, hd),
-            v.reshape(bsz * heads, seq, hd),
-            jnp.zeros((1, 1, seq), jnp.float32), causal=True, heads=heads
+            v.reshape(bsz * heads, seq, hd), no_bias, causal=True, heads=heads
         ).reshape(bsz, heads, seq, hd)
     else:
         from ..contrib.multihead_attn.functional import attention_core
-        ctx = attention_core(q, k, v, jnp.zeros((1, 1, seq), jnp.float32),
-                             causal=True)
-    ctx = ctx.astype(dt).transpose(0, 2, 1, 3).reshape(bsz, seq, heads * hd)
-    return ctx @ lp["wo"].astype(dt)
+        ctx = attention_core(q, k, v, no_bias, causal=True)
+    return ctx.astype(q.dtype).transpose(0, 2, 1, 3).reshape(
+        bsz, seq, heads * hd)
 
 
 def _block(x, lp, *, cfg: Lfm2Config, kind: str, dense: bool):
@@ -267,19 +274,25 @@ def lfm2_routing(params, tokens, cfg: Lfm2Config):
     return _forward(params, tokens, cfg)[1]
 
 
-def lfm2_loss(params, batch, cfg: Lfm2Config):
-    """Next-token cross entropy: ``batch["targets"]`` are the tokens shifted
-    by one, ``batch["weights"]`` (optional) 0 where a position has no
-    target.  Through the contrib xentropy kernel, as ``transformer_loss``."""
+def causal_lm_loss(logits, batch, xent_impl: str = "auto"):
+    """Next-token cross entropy of ``logits`` (B, S, V): ``batch["targets"]``
+    are the tokens shifted by one, ``batch["weights"]`` (optional) 0 where a
+    position has no target.  Through the contrib xentropy kernel, as
+    ``transformer_loss``."""
     from ..contrib.xentropy import softmax_xentropy_loss
-    logits = lfm2_apply(params, batch["tokens"], cfg)
     bsz, seq, vocab = logits.shape
     with annotate("apex.loss"):
         nll = softmax_xentropy_loss(
             logits.reshape(bsz * seq, vocab),
             batch["targets"].reshape(bsz * seq), 0.0, -1, False,
-            cfg.xent_impl).reshape(bsz, seq)
+            xent_impl).reshape(bsz, seq)
         w = batch.get("weights")
         if w is None:
             return nll.mean()
         return (nll * w).sum() / jnp.maximum(w.sum(), 1.0)
+
+
+def lfm2_loss(params, batch, cfg: Lfm2Config):
+    """:func:`causal_lm_loss` of the decoder's logits over ``batch``."""
+    return causal_lm_loss(lfm2_apply(params, batch["tokens"], cfg), batch,
+                          cfg.xent_impl)

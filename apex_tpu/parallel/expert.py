@@ -24,9 +24,11 @@ for XLA):
 bound; degrades to single-device MoE when unbound); ``MoELayer`` carries
 init/apply around it.
 
-:func:`routed_experts` is the other family (docs/lfm2.md): sigmoid scores,
-a selection bias that chooses and does not weigh, top-k of ALL experts, and
-a share of them held here.  Its three matrix products are grouped ones
+:func:`routed_experts` is the other family (docs/lfm2.md,
+docs/nemotron_h.md): sigmoid scores, a selection bias that chooses and does
+not weigh, top-k of ALL experts, and a share of them held here.  The
+expert's form (gated SiLU or squared ReLU) and the rows it reads are the
+model's; its matrix products are grouped ones
 (``jax.lax.ragged_dot``) over the rows the held experts were sent, sorted by
 expert, in a buffer of twice their even share of the T·k assignments
 (:func:`buffer_rows`); a load that outgrows the buffer is walked again by
@@ -220,12 +222,20 @@ def _walk(i, order, ends, top_k, c):
     return lo, seg // top_k, seg, sizes, jnp.arange(c) < hi[-1]
 
 
-def _gated(xs, w13, w2, sizes):
-    """``W2ᵉ(silu(W1ᵉ x) ⊙ W3ᵉ x)`` over the rows of each held group."""
+EXPERT_FORMS = ("gated_silu", "relu2")
+
+
+def _expert_ffn(form, xs, w13, w2, sizes):
+    """An expert's FFN over the rows of each held group: ``gated_silu``
+    ``W2ᵉ(silu(W1ᵉ x) ⊙ W3ᵉ x)``, ``w13`` holding W1 beside W3; ``relu2``
+    ``W2ᵉ relu(W1ᵉ x)²``, ``w13`` holding W1 alone."""
     with annotate("apex.experts"):
         h = jax.lax.ragged_dot(xs, w13.astype(xs.dtype), sizes)
-        half = h.shape[1] // 2
-        h = jax.nn.silu(h[:, :half]) * h[:, half:]
+        if form == "gated_silu":
+            half = h.shape[1] // 2
+            h = jax.nn.silu(h[:, :half]) * h[:, half:]
+        else:
+            h = jnp.square(jax.nn.relu(h))
         return jax.lax.ragged_dot(h, w2.astype(xs.dtype), sizes)
 
 
@@ -250,8 +260,8 @@ def _token_sum(src, at, mine, scale=None):
     return total
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _held_experts(c, x, w13, w2, weights, order, place, here, ends):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _held_experts(c, form, x, w13, w2, weights, order, place, here, ends):
     """``Σ_j weights[t, j] · FFN_{e(t, j)}(x[t])`` over the held assignments,
     (T, D) float32, through a buffer of ``c`` rows: the held rows are the
     first ``ends[-1]`` of the sorted ``order`` (padded to whole walks), and
@@ -264,7 +274,7 @@ def _held_experts(c, x, w13, w2, weights, order, place, here, ends):
     def body(carry):
         i, out = carry
         lo, token, _, sizes, _ = _walk(i, order, ends, top_k, c)
-        ys = _gated(x[token], w13, w2, sizes)
+        ys = _expert_ffn(form, x[token], w13, w2, sizes)
         return i + 1, out + _token_sum(ys, *_slots(place, here, lo, c),
                                        weights)
 
@@ -274,11 +284,11 @@ def _held_experts(c, x, w13, w2, weights, order, place, here, ends):
         (jnp.int32(0), jnp.zeros((tokens, x.shape[1]), jnp.float32)))[1]
 
 
-def _held_experts_fwd(c, *args):
-    return _held_experts(c, *args), args
+def _held_experts_fwd(c, form, *args):
+    return _held_experts(c, form, *args), args
 
 
-def _held_experts_bwd(c, res, g):
+def _held_experts_bwd(c, form, res, g):
     x, w13, w2, weights, order, place, here, ends = res
     top_k = place.shape[1]
     g = g.astype(x.dtype)
@@ -287,7 +297,8 @@ def _held_experts_bwd(c, res, g):
         i, dx, dw13, dw2, d_weights = carry
         lo, token, assignment, sizes, valid = _walk(i, order, ends, top_k, c)
         ys, transpose = jax.vjp(
-            lambda xs, w13, w2: _gated(xs, w13, w2, sizes), x[token], w13, w2)
+            lambda xs, w13, w2: _expert_ffn(form, xs, w13, w2, sizes),
+            x[token], w13, w2)
         # row r's cotangent is its assignment's weight times its token's,
         # and its weight's the product of the two rows: both in row space
         g_rows = g[token]
@@ -317,13 +328,19 @@ _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 def routed_experts(x, router_w, expert_bias, w13, w2, *, top_k: int,
                    first: int = 0, norm_topk_prob: bool = True,
                    routed_scaling_factor: float = 1.0,
-                   axis_name: Optional[str] = EXPERT_AXIS):
-    """The held experts' part of a routed gated FFN over ``x`` (T, D):
-    ``Σ_{e ∈ S(t), e held} w_e · W2ᵉ(silu(W1ᵉ h) ⊙ W3ᵉ h)``.
+                   axis_name: Optional[str] = EXPERT_AXIS,
+                   form: str = "gated_silu", rows=None):
+    """The held experts' part of a routed FFN over ``x`` (T, D):
+    ``Σ_{e ∈ S(t), e held} w_e · FFNᵉ(rows_t)``.
 
-    ``router_w`` (D, E) and ``expert_bias`` (E,) cover ALL E experts;
-    ``w13`` (held, D, 2·F) — W1 beside W3 — and ``w2`` (held, F, D) are the
-    experts ``first .. first + held`` that live here.  An assignment to an
+    ``router_w`` (D, E) and ``expert_bias`` (E,) cover ALL E experts and the
+    router reads ``x``; the experts compute on ``rows`` (T, R) — ``x`` itself
+    where None, a narrower projection of it in a latent expert layer — and
+    the result is (T, R).  ``form`` names the expert (:data:`EXPERT_FORMS`):
+    ``gated_silu`` ``W2ᵉ(silu(W1ᵉ h) ⊙ W3ᵉ h)`` with ``w13`` (held, R, 2·F) —
+    W1 beside W3 —, ``relu2`` ``W2ᵉ relu(W1ᵉ h)²`` with ``w13`` (held, R, F);
+    ``w2`` (held, F, R).  They are the experts ``first .. first + held``
+    that live here; every other size is the weights'.  An assignment to an
     absent expert adds nothing: nothing stands in for the chips that hold
     the others.  With ``axis_name`` bound (inside ``shard_map``, tokens
     replicated over the axis) device i holds experts ``i·held ..`` and the
@@ -342,14 +359,18 @@ def routed_experts(x, router_w, expert_bias, w13, w2, *, top_k: int,
     return _routed_experts(x, router_w, expert_bias, w13, w2, top_k=top_k,
                            first=first, norm_topk_prob=norm_topk_prob,
                            routed_scaling_factor=routed_scaling_factor,
-                           axis_name=axis_name)
+                           axis_name=axis_name, form=form, rows=rows)
 
 
 def _routed_experts(x, router_w, expert_bias, w13, w2, *, top_k, first=0,
                     norm_topk_prob=True, routed_scaling_factor=1.0,
-                    axis_name=EXPERT_AXIS, rows_a_walk=None):
+                    axis_name=EXPERT_AXIS, form="gated_silu", rows=None,
+                    rows_a_walk=None):
     """:func:`routed_experts`; ``rows_a_walk`` pins the buffer under the
     rule's (:func:`buffer_rows`) so that a test can make it walk."""
+    if form not in EXPERT_FORMS:
+        raise ValueError(f"form must be one of {EXPERT_FORMS}, got {form!r}")
+    rows = x if rows is None else rows
     tokens, _ = x.shape
     held = w13.shape[0]
     bound = axis_name is not None and axis_is_bound(axis_name)
@@ -371,19 +392,19 @@ def _routed_experts(x, router_w, expert_bias, w13, w2, *, top_k, first=0,
     order = jnp.argsort(group, stable=True).astype(jnp.int32)
     place = jnp.argsort(order).astype(jnp.int32).reshape(tokens, top_k)
     here = here.reshape(tokens, top_k)
-    rows = jnp.sum(group[:, None] == jnp.arange(held)[None, :], axis=0,
+    sent = jnp.sum(group[:, None] == jnp.arange(held)[None, :], axis=0,
                    dtype=jnp.int32)
-    ends = jnp.cumsum(rows)
+    ends = jnp.cumsum(sent)
     walks = -(-ends[-1] // c)
     # whole walks cover every assignment, so the buffer has a row for each
     covered = -(-tokens * top_k // c) * c
     dropped = jnp.maximum(ends[-1] - covered, 0)
 
     out = _held_experts(
-        c, x, w13, w2, jnp.where(here, weights, 0.0),
+        c, form, rows, w13, w2, jnp.where(here, weights, 0.0),
         jnp.pad(order, (0, covered - tokens * top_k)), place, here, ends
-    ).astype(x.dtype)
+    ).astype(rows.dtype)
     if bound:
         out = jax.lax.psum(out, axis_name)
-    return out, {"ids": ids, "rows": rows, "dropped": dropped,
+    return out, {"ids": ids, "rows": sent, "dropped": dropped,
                  "walks": walks}

@@ -55,6 +55,10 @@ SCOPES = (
     "apex.moe",            # models.lfm2: norm, parallel.expert.routed_experts
     "apex.router",         # inside it: scores, top-k, weights (float32)
     "apex.experts",        # inside it: the grouped products and their gate
+    "apex.ssm",            # models.nemotron_h: a whole Mamba-2 block
+    "apex.ssm_scan",       # inside it: discretisation and the chunked scan
+    "apex.latent",         # inside apex.moe: the two latent projections
+    "apex.shared_expert",  # inside apex.moe: the expert every token passes
 )
 
 # jax strips debug info - where a named scope lives - before it hashes the

@@ -216,6 +216,21 @@ def record_moe_layout(experts: int, held: int, top_k: int,
               top_k=int(top_k), buffer_rows=int(buffer_rows))
 
 
+def record_ssm_layout(heads: int, chunk: int, chunks: int) -> None:
+    """The scan layout a traced program holds (``models.nemotron_h``): one
+    call per traced Mamba-2 layer — trace time, like
+    :func:`record_moe_layout` — with the heads held here, the steps of a
+    chunk and the chunks a sequence is cut into (the length of the
+    recurrence across chunks).  Counter ``ssm.layers_traced`` and one
+    ``ssm.layout`` event."""
+    if not active():
+        return
+    reg = _default
+    reg.counter("ssm.layers_traced").add(1)
+    reg.event("ssm.layout", heads=int(heads), chunk=int(chunk),
+              chunks=int(chunks))
+
+
 #: the newest steps' ``rows`` as :func:`record_expert_rows` was given them
 #: (numpy (expert layers, held) int arrays), oldest first
 EXPERT_ROWS_KEPT = 64

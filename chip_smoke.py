@@ -19,6 +19,12 @@ user would call, at the full width of the models the repo supports:
               XLA-attention twin on one sequence; then one expert layer
               at one walk of its buffer and at a forced three against
               its float32 XLA twin, gradients included
+  nemotron_h  one Mamba-2 layer and one LatentMoE layer of
+              NVIDIA-Nemotron-3-Super-120B-A12B at the published widths and
+              the benchmark cell's share (16 heads, 8 of 512 experts top-22
+              in the 1024-wide latent), each against its float32 XLA twin,
+              forward and every gradient; the expert layer at one walk of
+              its buffer and at a forced three
   multichip   (when jax finds >= 4 devices) the trainers --distributed /
               --zero / --sync-bn and one step of every plan family on a
               4-device mesh, each device holding its share
@@ -784,6 +790,132 @@ def _expert_layer_checks(ctx) -> dict:
     return facts
 
 
+def phase_nemotron_h(ctx) -> dict:
+    """One ``M`` layer and one ``E`` layer of the ``--nemotron-h 8 64 1``
+    share at the published widths (the rehearsal: width 64), bfloat16 against
+    float32 twins at the highest matmul precision, output and every
+    gradient.  The Mamba-2 twin is the same block in float32 (the chunked
+    scan against the sequential recurrence is the CPU tests'); the expert
+    twin routes alike, then runs each held expert over ALL tokens, weighed —
+    no sort, no buffer, no grouped product: on a TPU the grouped product
+    leaves rows past the groups unwritten, so only here can a masking fault
+    at squared-ReLU experts on latent rows show."""
+    import dataclasses
+    import functools
+    import jax
+    import jax.numpy as jnp
+    from apex_tpu.models import nemotron_h, nemotron_h_init
+    from apex_tpu.parallel import expert
+    pretrain = load_example("examples/bert/pretrain.py")
+    cfg = pretrain.nemotron_h_config(pretrain.parse_args(
+        ["--nemotron-h", "8", "64", "1", "--vocab", "16384"]))
+    batch, seq = (1, 8192) if ctx["full"] else (2, 40)
+    if not ctx["full"]:
+        cfg = dataclasses.replace(
+            cfg, hidden_size=64, mamba_num_heads=16, mamba_head_dim=8,
+            mamba_heads_held=(0, 2), ssm_state_size=16, chunk_size=16,
+            n_routed_experts=64, num_experts_per_tok=6, moe_latent_size=32,
+            moe_intermediate_size=48, moe_shared_expert_intermediate_size=96)
+    cfg = dataclasses.replace(cfg, hybrid_override_pattern="ME", vocab_size=8,
+                              experts_held=(16, 8))
+    keys = jax.random.split(jax.random.PRNGKey(7), 3)
+    m_layer, e_layer = nemotron_h_init(keys[0], cfg)["layers"]
+    u = jax.random.normal(keys[1], (batch, seq, cfg.hidden_size))
+    probe = jax.random.normal(keys[2], u.shape)
+    half = functools.partial(jax.tree_util.tree_map,
+                             lambda x: x.astype(jnp.bfloat16))
+    # the values the system sees, in float32: what a twin is given
+    seen = functools.partial(jax.tree_util.tree_map, lambda x: x.astype(
+        jnp.bfloat16).astype(jnp.float32))
+    facts = {}
+
+    def graded(fn, *args):
+        # the probe is an argument: closed over it would be a constant of
+        # the program, 134 MB of it at the published widths
+        (_, aux), grads = jax.jit(jax.value_and_grad(
+            jax.checkpoint(fn), argnums=(0, 1), has_aux=True))(*args)
+        return aux, grads
+
+    def judge(label, got, want, **more):
+        errors = {name: float(f"{rel_err(g, w):.3e}")
+                  for name, g, w in zip(("out", "d_x"), got, want)}
+        errors.update({"d_" + name: float(f"{rel_err(g, want[2][name]):.3e}")
+                       for name, g in got[2].items()})
+        facts[label] = dict(rel_err=errors, **more)
+        # a per-head scalar's gradient is a sum of signed terms over every
+        # position: bfloat16's rounding does not average out of it
+        loose = ("d_A_log", "d_dt_bias", "d_D")
+        if not all(e < (0.15 if name in loose else 3e-2)
+                   for name, e in errors.items()):
+            raise AssertionError(f"{label} against the float32 twin: "
+                                 f"{errors}")
+
+    # -- the Mamba-2 block: norm, W_in, conv, chunked scan, gated norm, W_out
+    def ssm(dtype):
+        def loss(x, lp, probe):
+            y, _ = nemotron_h._block(
+                x, lp, cfg=dataclasses.replace(cfg, dtype=dtype), kind="M")
+            return jnp.sum(y.astype(jnp.float32) * probe), y
+        return loss
+    with jax.default_matmul_precision("highest"):
+        want, want_grads = graded(ssm(jnp.float32), seen(u), seen(m_layer),
+                                  probe)
+    got, grads = graded(ssm(jnp.bfloat16), half(u), half(m_layer), probe)
+    judge("ssm_layer", (got, *grads), (want, *want_grads),
+          heads=cfg.mamba_heads_held[1], chunks=-(-seq // cfg.chunk_size))
+
+    # -- the latent expert layer, at one walk and at a forced three
+    first, held = cfg.experts_held
+    flat, flat_probe = (t.reshape(-1, t.shape[-1]) for t in (u, probe))
+
+    def shared(x, lp):
+        return jnp.square(jax.nn.relu(x @ lp["shared_w1"])) @ lp["shared_w2"]
+
+    def system(rows_a_walk):
+        def loss(x, lp, probe):
+            routed, routing = expert._routed_experts(
+                x, lp["router"], lp["expert_bias"], lp["w1"], lp["w2"],
+                top_k=cfg.num_experts_per_tok, first=first,
+                routed_scaling_factor=cfg.routed_scaling_factor,
+                form="relu2", rows=x @ lp["latent_down"], axis_name=None,
+                rows_a_walk=rows_a_walk)
+            out = routed @ lp["latent_up"] + shared(x, lp)
+            return jnp.sum(out.astype(jnp.float32) * probe), (out, routing)
+        lp = dict(half(e_layer), router=e_layer["router"],
+                  expert_bias=e_layer["expert_bias"])
+        (out, routing), grads = graded(loss, half(flat), lp, flat_probe)
+        return (out, *grads), routing
+
+    def twin(x, lp, probe):
+        ids, weights = expert.route_top_k(
+            x.astype(jnp.bfloat16), lp["router"], lp["expert_bias"],
+            cfg.num_experts_per_tok,
+            routed_scaling_factor=cfg.routed_scaling_factor)
+        latent, routed = x @ lp["latent_down"], 0.0
+        for e in range(held):
+            weight = jnp.sum(jnp.where(ids == first + e, weights, 0.0), -1)
+            routed = routed + weight[:, None] * (
+                jnp.square(jax.nn.relu(latent @ lp["w1"][e])) @ lp["w2"][e])
+        out = routed @ lp["latent_up"] + shared(x, lp)
+        return jnp.sum(out * probe), out
+
+    with jax.default_matmul_precision("highest"):
+        want, want_grads = graded(twin, seen(flat), dict(
+            seen(e_layer), router=e_layer["router"]), flat_probe)
+    one, routing = system(None)
+    sent = int(routing["rows"].sum())
+    facts["rows_sent"], facts["buffer_rows"] = sent, expert.buffer_rows(
+        flat.shape[0], cfg.num_experts_per_tok, cfg.n_routed_experts, held)
+    for label, walks, (got, routing) in (
+            ("expert_layer_one_walk", 1, (one, routing)),
+            ("expert_layer_three_walks", 3, system(-(-sent // 3)))):
+        if int(routing["walks"]) != walks or int(routing["dropped"]):
+            raise AssertionError(f"{label}: {routing['walks']} walks, "
+                                 f"{routing['dropped']} dropped")
+        judge(label, got, (want, *want_grads), walks=walks)
+    return facts
+
+
 def _plan_families():
     from apex_tpu.parallel import plan as pm
     return [("dp2xtp2", pm.Plan(dp=2, tp=2)),
@@ -877,6 +1009,7 @@ PHASES = {
     "resnet50": phase_resnet50,
     "bert_large": phase_bert_large,
     "lfm2": phase_lfm2,
+    "nemotron_h": phase_nemotron_h,
     "multichip": phase_multichip,
 }
 MULTICHIP_DEVICES = 4

@@ -37,7 +37,10 @@ from apex_tpu.models import (TransformerConfig, bert_large_config,
                              MoETransformerConfig, moe_transformer_init,
                              moe_transformer_loss, Lfm2Config,
                              lfm2_24b_a2b_config, lfm2_cut_layer_types,
-                             lfm2_init, lfm2_loss)
+                             lfm2_init, lfm2_loss, NemotronHConfig,
+                             nemotron3_super_120b_a12b_config,
+                             nemotron_h_cut_pattern, nemotron_h_init,
+                             nemotron_h_loss)
 from apex_tpu.optimizers import FusedLAMB
 from apex_tpu.parallel import create_mesh, use_mesh
 from apex_tpu.utils.logging import AverageMeter, Throughput
@@ -74,6 +77,19 @@ def parse_args(argv=None):
                         "the 64 experts of each layer, --vocab rows of the "
                         "65536 of the embedding; the router still scores "
                         "all 64 (docs/lfm2.md)")
+    p.add_argument("--nemotron-h", type=int, nargs=3, default=None,
+                   metavar=("TP", "EP", "PERIODS"),
+                   help="NVIDIA-Nemotron-3-Super-120B-A12B at its published "
+                        "widths (causal LM on next-token batches), cut to "
+                        "one chip's share of a tensor-parallel-TP x "
+                        "expert-parallel-EP stage: the first 1/TP of the 128 "
+                        "Mamba heads (whole B/C groups) and of the 32 query "
+                        "heads with the key-value heads they read, the first "
+                        "1/EP of the 512 routed experts of each layer, "
+                        "PERIODS whole periods (MEMEMEMEM*E) of the 88-layer "
+                        "pattern, --vocab rows of the 131072 of embedding "
+                        "and head; the router still scores all 512 "
+                        "(docs/nemotron_h.md)")
     p.add_argument("--distributed", action="store_true")
     p.add_argument("--zero", action="store_true",
                    help="ZeRO sharded optimizer (DistributedFusedLAMB)")
@@ -238,18 +254,24 @@ def run_standard(args, cfg, mesh):
     init_fn, loss_impl = {
         MoETransformerConfig: (moe_transformer_init, moe_transformer_loss),
         Lfm2Config: (lfm2_init, lfm2_loss),
+        NemotronHConfig: (nemotron_h_init, nemotron_h_loss),
     }.get(type(cfg), (transformer_init, transformer_loss))
-    params = jax.jit(
-        lambda: init_fn(jax.random.PRNGKey(args.seed), cfg))()
     opt = FusedLAMB(lr=args.lr, weight_decay=0.01, max_grad_norm=1.0,
                     impl="fused",
                     state_dtype=jnp.bfloat16 if args.state_dtype else None)
-    state = amp.initialize(params, opt, opt_level=args.opt_level,
-                           verbosity=0)
-    # replicate over the mesh up front: left on the default device, the
-    # state would be re-laid-out by the first step and the step traced
-    # and compiled a second time for the new input shardings
-    state = jax.device_put(state, NamedSharding(mesh, P()))
+    # ONE program makes the float32 parameters and the amp state that owns
+    # its copies of them, replicated over the mesh where it is written: the
+    # initial parameters are the program's temporaries and no second copy
+    # of the state is placed, so set-up's high-water mark stays under the
+    # step's footprint (at 16 bytes a parameter the two it replaces — init,
+    # then amp.initialize and a device_put — peaked at 26).  Replicated up
+    # front: left on the default device the state would be re-laid-out by
+    # the first step and the step compiled a second time.  The key is an
+    # argument, so every seed runs the same cached program.
+    state = jax.jit(
+        lambda key: amp.initialize(init_fn(key, cfg), opt,
+                                   opt_level=args.opt_level, verbosity=0),
+        out_shardings=NamedSharding(mesh, P()))(jax.random.PRNGKey(args.seed))
     sharding = NamedSharding(mesh, P("data"))
 
     # donate the amp state: the flat fused engine writes fresh master/m/v
@@ -402,6 +424,20 @@ def lfm2_config(args):
         attn_impl=args.attn)
 
 
+def nemotron_h_config(args):
+    """``--nemotron-h TP EP PERIODS``: the published widths, and one chip's
+    share of heads, experts, depth and ``--vocab``."""
+    tp, ep, periods = args.nemotron_h
+    whole = nemotron3_super_120b_a12b_config()
+    return nemotron3_super_120b_a12b_config(
+        vocab_size=args.vocab,
+        hybrid_override_pattern=nemotron_h_cut_pattern(periods),
+        mamba_heads_held=(0, whole.mamba_num_heads // tp),
+        attention_heads_held=(0, whole.num_attention_heads // tp),
+        experts_held=(0, whole.n_routed_experts // ep),
+        dtype=jnp.bfloat16, remat=args.remat, attn_impl=args.attn)
+
+
 def main(argv=None, report=None):
     """Train; returns the last printed loss.  ``report``, a dict the
     caller owns, is filled (standard and ``--zero`` paths) with what a
@@ -416,6 +452,10 @@ def main(argv=None, report=None):
                       or args.data):
         raise SystemExit("--lfm2 is a model preset of the standard path on "
                          "synthetic next-token batches")
+    if args.nemotron_h and (args.bert_large or args.lfm2 or args.moe
+                            or args.zero or args.plan or args.data):
+        raise SystemExit("--nemotron-h is a model preset of the standard "
+                         "path on synthetic next-token batches")
     if args.plan and (args.moe or args.zero or args.distributed
                       or args.auto_resume):
         raise SystemExit("--plan owns the parallelism decision — it does "
@@ -426,6 +466,8 @@ def main(argv=None, report=None):
                                 attn_impl=args.attn)
     elif args.lfm2:
         cfg = lfm2_config(args)
+    elif args.nemotron_h:
+        cfg = nemotron_h_config(args)
     elif args.moe:
         cfg = MoETransformerConfig(
             vocab_size=args.vocab, max_len=args.seq_len,
@@ -445,15 +487,16 @@ def main(argv=None, report=None):
     if args.batch_size % n_dev:
         raise ValueError(f"batch {args.batch_size} must divide {n_dev}")
     mesh = create_mesh({"data": n_dev}, devices=jax.devices()[:n_dev])
+    causal_lm = bool(args.lfm2 or args.nemotron_h)
     print(f"=> {n_dev} device(s), {'ZeRO' if args.zero else 'standard'} "
           f"optimizer, layers="
-          f"{cfg.num_hidden_layers if args.lfm2 else cfg.num_layers} d="
-          f"{cfg.hidden_size if args.lfm2 else cfg.d_model} "
+          f"{cfg.num_hidden_layers if causal_lm else cfg.num_layers} d="
+          f"{cfg.hidden_size if causal_lm else cfg.d_model} "
           f"seq={args.seq_len}")
 
     rng = np.random.RandomState(args.seed)
     losses, tput = AverageMeter("mlm_loss"), Throughput()
-    synthetic = synthetic_next_token if args.lfm2 else synthetic_mlm
+    synthetic = synthetic_next_token if causal_lm else synthetic_mlm
 
     if args.auto_resume:
         if args.zero:

@@ -23,6 +23,7 @@ platform.  This file pins that table:
 import functools
 import importlib
 import json
+import math
 import os
 import re
 
@@ -116,6 +117,9 @@ def _attention_cells():
         elif cfg["job"] == "lfm2_pretrain":
             heads, width, causal = (model["num_attention_heads"],
                                     model["hidden_size"], True)
+        elif cfg["job"] == "nemotron_h_pretrain":
+            heads = model["attention_heads_held"][1]
+            width, causal = heads * model["head_dim"], True
         else:
             continue                    # resnet50: no attention
         cells[w["name"]] = (traffic["batch"] // w["chips"] * heads,
@@ -134,6 +138,9 @@ CELL_TILES = {
     "bert_large.s512_b8": ((512, 512), ("whole_key", 512, 512, 1)),
     "bert_large.dp4_s512": ((512, 512), ("whole_key", 512, 512, 1)),
     "lfm2_24b_a2b.ep8_s4096": ((512, 1024), ("resident", 512, 512, 8)),
+    "bert_large.s512_b136": ((512, 512), ("whole_key", 512, 512, 1)),
+    "nemotron3_super_120b_a12b.tp8_ep64_s8192": (
+        (512, 1024), ("resident", 512, 512, 16)),     # BH 8, D 128
 }
 
 
@@ -143,13 +150,13 @@ def _expert_cells():
     cells = {}
     for w in _read("BENCHMARK.json")["workloads"]:
         model = _read("benchmarks", "configs", w["config"] + ".json")["model"]
-        if "num_experts" not in model:
+        experts = model.get("num_experts", model.get("n_routed_experts"))
+        if experts is None:
             continue
         traffic = _read("benchmarks", "workloads", w["name"] + ".json")
         cells[w["name"]] = (
             traffic["batch"] // w["chips"] * traffic["seq"],
-            model["num_experts_per_tok"], model["num_experts"],
-            model["experts_held"][1])
+            model["num_experts_per_tok"], experts, model["experts_held"][1])
     return cells
 
 
@@ -159,6 +166,7 @@ EXPERT_CELLS = _expert_cells()
 #: of ``parallel.expert.buffer_rows`` — twice the held experts' even share
 CELL_BUFFERS = {
     "lfm2_24b_a2b.ep8_s4096": (32768, 131072),
+    "nemotron3_super_120b_a12b.tp8_ep64_s8192": (11264, 360448),
 }
 
 
@@ -176,7 +184,9 @@ def _traced_kernels(BH, S, D, dtype, causal, grad):
     bias = jnp.zeros((1, 1, S), jnp.float32)
 
     def fwd(q, k, v):
-        return flash_attention(q, k, v, bias, 0, causal, 0.0, 16, "auto")
+        # the bias is (1, 1, S): ``heads`` only has to divide BH
+        return flash_attention(q, k, v, bias, 0, causal, 0.0,
+                               math.gcd(BH, 16), "auto")
 
     fn = fwd if not grad else jax.grad(
         lambda q, k, v: fwd(q, k, v).astype(jnp.float32).sum(),
@@ -236,7 +246,8 @@ def test_cell_choice(cell, chooser, monkeypatch, flash_events):
         x = sds((BH, S, D), jnp.dtype(dtype))
         jax.eval_shape(
             lambda q, k, v, bias, out, lse, do: F._flash_bwd(
-                q, k, v, bias, causal, 0.0, 0, 16, out, lse, do),
+                q, k, v, bias, causal, 0.0, 0, math.gcd(BH, 16), out, lse,
+                do),
             x, x, x, sds((1, 1, S), jnp.float32), x,
             sds((BH, S, 1), jnp.float32), x)
         ev = [r["fields"] for r in flash_events.flush()

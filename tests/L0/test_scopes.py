@@ -20,8 +20,9 @@ import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
 from apex_tpu import amp, pyprof
-from apex_tpu.models import (Lfm2Config, TransformerConfig,
+from apex_tpu.models import (Lfm2Config, NemotronHConfig, TransformerConfig,
                              lfm2_cut_layer_types, lfm2_init, lfm2_loss,
+                             nemotron_h_init, nemotron_h_loss,
                              transformer_init, transformer_loss)
 from apex_tpu.optimizers import FusedLAMB
 from apex_tpu.parallel import DistributedDataParallel
@@ -39,8 +40,21 @@ LFM2 = Lfm2Config(vocab_size=256, hidden_size=64, intermediate_size=160,
                   num_attention_heads=8, num_key_value_heads=2,
                   experts_held=(0, 4), dtype=jnp.bfloat16, remat=True,
                   attn_impl="fast")
-#: the blocks only the LFM2 step enters
+# ... and the third: one layer of each kind of Nemotron-H, a share held
+NEMOTRON_H = NemotronHConfig(
+    vocab_size=256, hidden_size=64, hybrid_override_pattern="ME*",
+    mamba_num_heads=16, mamba_head_dim=8, n_groups=8, ssm_state_size=16,
+    chunk_size=16, num_attention_heads=8, num_key_value_heads=2, head_dim=16,
+    n_routed_experts=32, num_experts_per_tok=4, moe_latent_size=32,
+    moe_intermediate_size=48, moe_shared_expert_intermediate_size=96,
+    mamba_heads_held=(0, 4), attention_heads_held=(0, 2),
+    experts_held=(0, 8), dtype=jnp.bfloat16, remat=True, attn_impl="fast")
+#: the blocks only the LFM2 step enters (the Nemotron-H step enters the
+#: last three of them too)
 LFM2_ONLY = ("apex.conv", "apex.moe", "apex.router", "apex.experts")
+#: the blocks only the Nemotron-H step enters
+NEMOTRON_H_ONLY = ("apex.ssm", "apex.ssm_scan", "apex.latent",
+                   "apex.shared_expert")
 # "%name = <type, maybe a tuple> opcode(operands), ..., metadata={op_name=..."
 _INSTRUCTION = re.compile(
     r' = .*? ([a-z][\w-]*)\(.*metadata=\{[^}]*op_name="([^"]*)"')
@@ -95,13 +109,21 @@ def lfm2_step_ops():
         *_state_and_batch(2, lfm2_init, LFM2))
 
 
+@pytest.fixture(scope="module")
+def nemotron_h_step_ops():
+    return _op_names(
+        functools.partial(_step, loss_impl=nemotron_h_loss, cfg=NEMOTRON_H),
+        *_state_and_batch(2, nemotron_h_init, NEMOTRON_H))
+
+
 def test_scopes_are_a_fixed_vocabulary():
     assert len(set(pyprof.SCOPES)) == len(pyprof.SCOPES)
     for name in pyprof.SCOPES:
         assert re.fullmatch(r"apex\.[a-z_]+", name), name
 
 
-@pytest.mark.parametrize("ops", ["step_ops", "lfm2_step_ops"])
+@pytest.mark.parametrize("ops", ["step_ops", "lfm2_step_ops",
+                                 "nemotron_h_step_ops"])
 def test_every_matmul_belongs_to_a_block(ops, request):
     matmuls = [path for opcode, path in request.getfixturevalue(ops)
                if opcode in ("dot", "convolution", "ragged-dot")]
@@ -112,9 +134,21 @@ def test_every_matmul_belongs_to_a_block(ops, request):
 
 @pytest.mark.parametrize("name", pyprof.SCOPES)
 def test_scope_occurs_in_the_step(name, step_ops, ddp_step_ops,
-                                  lfm2_step_ops):
+                                  lfm2_step_ops, nemotron_h_step_ops):
     one_chip = {path for _, path in step_ops if name in path}
-    if name in LFM2_ONLY:
+    if name in NEMOTRON_H_ONLY:
+        # neither other step has such a block; the Nemotron-H step enters it
+        # forward, backward and in remat's second forward
+        assert not one_chip
+        assert not [path for _, path in lfm2_step_ops if name in path]
+        paths = [path for _, path in nemotron_h_step_ops if name in path]
+        for mark in ("transpose(", "rematted_computation"):
+            assert any(mark in path for path in paths), (name, mark)
+        assert any("transpose(" not in path for path in paths), name
+        outer = "apex.ssm" if name == "apex.ssm_scan" else "apex.moe"
+        if name != "apex.ssm":
+            assert all(outer in path for path in paths), name
+    elif name in LFM2_ONLY:
         # the BERT step has no such block; the LFM2 step enters it forward,
         # backward and in remat's second forward — but for the experts: the
         # reverse rule of ``parallel.expert._held_experts`` recomputes a
