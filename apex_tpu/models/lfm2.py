@@ -229,7 +229,7 @@ def _block(x, lp, *, cfg: Lfm2Config, kind: str, dense: bool):
 def _forward(params, tokens, cfg: Lfm2Config):
     """``(logits, routing)``: ``routing`` stacks every expert layer's record
     (``ids`` (L, T, k), ``rows`` (L, held), ``dropped`` (L,), ``walks``
-    (L,))."""
+    (L,), ``slots`` (L,))."""
     if cfg.attn_impl not in ("default", "fast"):
         raise ValueError(
             f"attn_impl must be 'default' or 'fast', got {cfg.attn_impl!r}")
@@ -254,7 +254,8 @@ def _forward(params, tokens, cfg: Lfm2Config):
         # the routing meter: once a forward pass, outside the checkpoint so
         # remat's second forward does not count twice
         jax.debug.callback(_tel_events.record_expert_rows, routing["rows"],
-                           jnp.sum(routing["dropped"]), routing["walks"])
+                           jnp.sum(routing["dropped"]), routing["walks"],
+                           slots=routing["slots"])
     with annotate("apex.head"):
         x = _rms_norm(x, params["head"]["norm"], cfg.norm_eps)
         return x @ params["embed"]["tok"].astype(dt).T, routing
@@ -270,7 +271,8 @@ def lfm2_routing(params, tokens, cfg: Lfm2Config):
     stacked: ``ids`` (L, B·S, k) the experts each token took, ``rows``
     (L, held) the assignments each held expert was sent, ``dropped`` (L,)
     those that found no row in the buffer (0), ``walks`` (L,) the times the
-    layer went over its buffer (1 where the load fit it)."""
+    layer went over its buffer (1 where the load fit it), ``slots`` (L,) the
+    most held assignments any token has."""
     return _forward(params, tokens, cfg)[1]
 
 

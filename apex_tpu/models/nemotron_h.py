@@ -405,7 +405,7 @@ def _block(x, lp, *, cfg: NemotronHConfig, kind: str):
 def _forward(params, tokens, cfg: NemotronHConfig):
     """``(logits, routing)``: ``routing`` stacks every ``E`` layer's record
     (``ids`` (L, T, k), ``rows`` (L, held), ``dropped`` (L,), ``walks``
-    (L,))."""
+    (L,), ``slots`` (L,))."""
     if cfg.attn_impl not in ("default", "fast"):
         raise ValueError(
             f"attn_impl must be 'default' or 'fast', got {cfg.attn_impl!r}")
@@ -428,7 +428,8 @@ def _forward(params, tokens, cfg: NemotronHConfig):
     if records and _tel_events.active():
         # the routing meter, as models.lfm2 has it: once a forward pass
         jax.debug.callback(_tel_events.record_expert_rows, routing["rows"],
-                           jnp.sum(routing["dropped"]), routing["walks"])
+                           jnp.sum(routing["dropped"]), routing["walks"],
+                           slots=routing["slots"])
     with annotate("apex.head"):
         x = _rms_norm(x, params["head"]["norm"], cfg.layer_norm_epsilon)
         return x @ params["head"]["out"].astype(dt), routing

@@ -32,7 +32,11 @@ model's; its matrix products are grouped ones
 (``jax.lax.ragged_dot``) over the rows the held experts were sent, sorted by
 expert, in a buffer of twice their even share of the T·k assignments
 (:func:`buffer_rows`); a load that outgrows the buffer is walked again by
-the same body, so it drops no assignment at any imbalance.
+the same body, so it drops no assignment at any imbalance.  A walk's rows
+are summed back to the tokens in the form that moves fewer rows
+(:func:`sums_in_row_space`): sorted by token, neighbours added and one row a
+token gathered where few of a token's experts are held, a gather a slot
+where the buffer is as long as the tokens.
 """
 from __future__ import annotations
 
@@ -248,7 +252,19 @@ def _slots(place, here, lo, c):
     return jnp.clip(at, 0, c - 1), here & (at >= 0) & (at < c)
 
 
-def _token_sum(src, at, mine, scale=None):
+def sums_in_row_space(tokens: int, top_k: int, held: int, c: int) -> bool:
+    """Which form a walk's sum back to the tokens takes: the one that moves
+    fewer rows.  In row space (:func:`_row_sum`) the buffer's c rows are
+    gathered once, gone over again for each further row a token can have
+    there — ``min(top_k, held)`` at most — and T rows are gathered; a slot at
+    a time (:func:`_slot_sum`) it is k gathers of T rows, whatever is held.
+    A rule of the shapes, not a knob (docs/performance.md): 8 of 512 experts
+    held at k 22 sums in row space, 8 of 64 at k 4 — where the buffer is as
+    long as the tokens — a slot at a time, as does every expert held."""
+    return min(top_k, held, c) * c + tokens < top_k * tokens
+
+
+def _slot_sum(src, at, mine, scale=None):
     """``Σ_j src[at[t, j]]`` over the slots this walk holds (times
     ``scale[t, j]``, in its dtype): the transpose of the walk's row gather,
     as k gathers of T rows."""
@@ -258,6 +274,32 @@ def _token_sum(src, at, mine, scale=None):
         total = total + (term if scale is None
                          else term.astype(scale.dtype) * scale[:, j, None])
     return total
+
+
+def _row_sum(src, token, valid, mine, slots, weight=None):
+    """The same sum, ``out[t] = Σ_r src[r]`` over the walk's valid rows ``r``
+    of token ``t`` (each times ``weight[r]``, in its dtype), in row space.
+    The rows are sorted by token — ONE gather of c rows, the rows past the
+    held groups masked before anything is added to them —, a token's rows
+    are then neighbours and at most ``slots`` of them, so ``slots - 1``
+    shifted slices add them onto the first, and ONE gather of T rows reads
+    each token's first (``mine`` (T, k) counts the rows this walk holds of
+    each token).  The gathers write c + T rows, not T·k."""
+    c, tokens = token.shape[0], mine.shape[0]
+    key, perm = jax.lax.sort(
+        (jnp.where(valid, token, tokens), jnp.arange(c, dtype=jnp.int32)),
+        num_keys=1, is_stable=True)
+    rows = jnp.where((key < tokens)[:, None], src[perm], 0)
+    scale = None if weight is None else weight[perm]
+    total = 0
+    for j in range(min(slots, c)):      # row r + j, where it is r's token's
+        term = jnp.where((key[j:] == key[:c - j])[:, None], rows[j:], 0)
+        if scale is not None:
+            term = term.astype(scale.dtype) * scale[j:, None]
+        total = total + jnp.pad(term, ((0, j), (0, 0)))
+    count = jnp.sum(mine, axis=1, dtype=jnp.int32)
+    first = jnp.minimum(jnp.cumsum(count) - count, c - 1)
+    return jnp.where((count > 0)[:, None], total[first], 0)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
@@ -270,13 +312,20 @@ def _held_experts(c, form, x, w13, w2, weights, order, place, here, ends):
     stated here and not derived: its residuals are the arguments, and it
     recomputes a walk's rows as remat would."""
     tokens, top_k = place.shape
+    slots = min(top_k, w13.shape[0])
+    in_rows = sums_in_row_space(tokens, top_k, w13.shape[0], c)
 
     def body(carry):
         i, out = carry
-        lo, token, _, sizes, _ = _walk(i, order, ends, top_k, c)
+        lo, token, assignment, sizes, valid = _walk(i, order, ends, top_k, c)
         ys = _expert_ffn(form, x[token], w13, w2, sizes)
-        return i + 1, out + _token_sum(ys, *_slots(place, here, lo, c),
-                                       weights)
+        at, mine = _slots(place, here, lo, c)
+        if in_rows:
+            part = _row_sum(ys, token, valid, mine, slots,
+                            weights.reshape(-1)[assignment])
+        else:
+            part = _slot_sum(ys, at, mine, weights)
+        return i + 1, out + part
 
     walks = -(-ends[-1] // c)
     return jax.lax.while_loop(
@@ -290,7 +339,9 @@ def _held_experts_fwd(c, form, *args):
 
 def _held_experts_bwd(c, form, res, g):
     x, w13, w2, weights, order, place, here, ends = res
-    top_k = place.shape[1]
+    tokens, top_k = place.shape
+    slots = min(top_k, w13.shape[0])
+    in_rows = sums_in_row_space(tokens, top_k, w13.shape[0], c)
     g = g.astype(x.dtype)
 
     def body(carry):
@@ -309,9 +360,16 @@ def _held_experts_bwd(c, form, res, g):
         # grouped product left in its slice
         sent = (sizes > 0)[:, None, None]
         at, mine = _slots(place, here, lo, c)
-        return (i + 1, dx + _token_sum(d_xs, at, mine),
-                dw13 + jnp.where(sent, d13, 0), dw2 + jnp.where(sent, d2, 0),
-                d_weights + jnp.where(mine, d_weight[at], 0.0))
+        part = (_row_sum(d_xs, token, valid, mine, slots) if in_rows
+                else _slot_sum(d_xs, at, mine))
+        # a row's weight gradient goes to its assignment's place in (T, k):
+        # a scatter of the c rows, not a gather over the T·k places
+        d_weights = d_weights.reshape(-1).at[
+            jnp.where(valid, assignment, d_weights.size)].add(
+            jnp.where(valid, d_weight, 0.0), mode="drop"
+        ).reshape(d_weights.shape)
+        return (i + 1, dx + part, dw13 + jnp.where(sent, d13, 0),
+                dw2 + jnp.where(sent, d2, 0), d_weights)
 
     walks = -(-ends[-1] // c)
     _, dx, dw13, dw2, d_weights = jax.lax.while_loop(
@@ -349,13 +407,16 @@ def routed_experts(x, router_w, expert_bias, w13, w2, *, top_k: int,
     The assignments are sorted by expert with the absent ones last, and the
     held rows at the front of that order go through a buffer of
     :func:`buffer_rows` rows — gather, grouped products, weighted sum back to
-    the tokens — as many times as it takes: once where the load fits, T·k /
-    rows at total imbalance.  No assignment is dropped.  Returns
-    ``(out (T, D), routing)``; ``routing`` holds ``ids`` (T, k) int32, the
-    experts every token took, ``rows`` (held,) int32, the assignments each
-    held expert was sent, ``dropped`` () int32, the held assignments no walk
-    reached, which is 0, and ``walks`` () int32, the times the buffer was
-    gone over (0 where no token took a held expert)."""
+    the tokens in the form that moves fewer rows
+    (:func:`sums_in_row_space`) — as many times as it takes: once where the
+    load fits, T·k / rows at total imbalance.  No assignment is dropped.
+    Returns ``(out (T, D), routing)``; ``routing`` holds ``ids`` (T, k)
+    int32, the experts every token took, ``rows`` (held,) int32, the
+    assignments each held expert was sent, ``dropped`` () int32, the held
+    assignments no walk reached, which is 0, ``walks`` () int32, the times
+    the buffer was gone over (0 where no token took a held expert), and
+    ``slots`` () int32, the most held assignments any token has (at most
+    ``min(top_k, held)``: the neighbours a sum in row space adds)."""
     return _routed_experts(x, router_w, expert_bias, w13, w2, top_k=top_k,
                            first=first, norm_topk_prob=norm_topk_prob,
                            routed_scaling_factor=routed_scaling_factor,
@@ -378,7 +439,9 @@ def _routed_experts(x, router_w, expert_bias, w13, w2, *, top_k, first=0,
         first = jax.lax.axis_index(axis_name) * held
     c = rows_a_walk or buffer_rows(tokens, top_k, router_w.shape[1], held)
     _tel_events.record_moe_layout(
-        experts=router_w.shape[1], held=held, top_k=top_k, buffer_rows=c)
+        experts=router_w.shape[1], held=held, top_k=top_k, buffer_rows=c,
+        sum_rows=(c + tokens if sums_in_row_space(tokens, top_k, held, c)
+                  else top_k * tokens))
 
     with annotate("apex.router"):
         ids, weights = route_top_k(
@@ -407,4 +470,5 @@ def _routed_experts(x, router_w, expert_bias, w13, w2, *, top_k, first=0,
     if bound:
         out = jax.lax.psum(out, axis_name)
     return out, {"ids": ids, "rows": sent, "dropped": dropped,
-                 "walks": walks}
+                 "walks": walks,
+                 "slots": jnp.max(jnp.sum(here, axis=1, dtype=jnp.int32))}

@@ -199,21 +199,24 @@ def record_flash_bwd(path: str, bq: Optional[int] = None,
 
 
 def record_moe_layout(experts: int, held: int, top_k: int,
-                      buffer_rows: int) -> None:
+                      buffer_rows: int, sum_rows: int) -> None:
     """The routing layout a traced program holds
     (``parallel.expert.routed_experts``): one call per traced expert layer
     — trace time, like :func:`record_flash_bwd` — with the number of
     experts routed over, how many of them are held here, the experts a
     token takes and the rows of the dispatch buffer (twice the held
     experts' even share of the tokens x top_k assignments; a load past it is
-    walked again, so none can be dropped).  Counter
+    walked again, so none can be dropped) and ``sum_rows``, the rows the
+    gathers of one sum back to the tokens write (buffer + tokens in row
+    space, tokens x top_k a slot at a time).  Counter
     ``moe.layers_traced`` and one ``moe.layout`` event."""
     if not active():
         return
     reg = _default
     reg.counter("moe.layers_traced").add(1)
     reg.event("moe.layout", experts=int(experts), held=int(held),
-              top_k=int(top_k), buffer_rows=int(buffer_rows))
+              top_k=int(top_k), buffer_rows=int(buffer_rows),
+              sum_rows=int(sum_rows))
 
 
 def record_ssm_layout(heads: int, chunk: int, chunks: int) -> None:
@@ -237,17 +240,20 @@ EXPERT_ROWS_KEPT = 64
 _expert_rows: "collections.deque" = collections.deque(maxlen=EXPERT_ROWS_KEPT)
 
 
-def record_expert_rows(rows, dropped, walks) -> None:
+def record_expert_rows(rows, dropped, walks, slots=None) -> None:
     """Step side of the routing meter: what one forward pass sent the held
     experts.  ``rows`` (expert layers, held) are the assignments each held
     expert of each layer was sent, ``dropped`` the held assignments that
     found no row in the buffer (0), ``walks`` (expert layers,) the times
-    each layer went over its buffer (1 where the load fit).  A model
+    each layer went over its buffer (1 where the load fit), ``slots``
+    (expert layers,; None where the caller has none) the most held
+    assignments any token of each layer has.  A model
     calls it through ``jax.debug.callback`` once a forward pass, and only
     where :func:`active` was true when the step was traced.  Counters
-    ``moe.rows_held`` / ``moe.rows_dropped`` / ``moe.walks``, histogram
+    ``moe.rows_held`` / ``moe.rows_dropped`` / ``moe.walks``, histograms
     ``moe.load_max_over_mean`` (the fullest held expert over the mean, worst
-    layer), and the array itself in :func:`expert_rows`."""
+    layer) and ``moe.slots_max`` (the fullest token's held assignments,
+    worst layer), and the array itself in :func:`expert_rows`."""
     if not active():
         return
     import numpy as np
@@ -259,6 +265,8 @@ def record_expert_rows(rows, dropped, walks) -> None:
     reg.counter("moe.walks").add(int(np.sum(walks)))
     reg.histogram("moe.load_max_over_mean").observe(float(
         (rows.max(axis=1) / np.maximum(rows.mean(axis=1), 1e-9)).max()))
+    if slots is not None:
+        reg.histogram("moe.slots_max").observe(float(np.max(slots)))
 
 
 def expert_rows() -> list:

@@ -721,6 +721,26 @@ def phase_lfm2(ctx) -> dict:
     return facts
 
 
+def _sum_facts(tokens, top_k, held, c):
+    """The form a walk's sum back to the tokens takes through a buffer of
+    ``c`` rows, and the rows its gathers write."""
+    from apex_tpu.parallel import expert
+    in_rows = expert.sums_in_row_space(tokens, top_k, held, c)
+    return {"sum_in_row_space": in_rows,
+            "sum_rows": c + tokens if in_rows else top_k * tokens}
+
+
+def _check_routing(label, routing, walks, most_slots):
+    """A routed layer's record: the walks it was made to take, nothing
+    dropped, no token with more held assignments than there can be."""
+    if int(routing["walks"]) != walks or int(routing["dropped"]):
+        raise AssertionError(f"{label}: {routing['walks']} walks, "
+                             f"{routing['dropped']} dropped")
+    if not 1 <= int(routing["slots"]) <= most_slots:
+        raise AssertionError(f"{label}: a token with {routing['slots']} "
+                             f"held assignments")
+
+
 def _expert_layer_checks(ctx) -> dict:
     """One expert layer (8 of 64 held, top-4, the model's widths) at one
     walk of its buffer and at a forced three, output and every gradient
@@ -775,15 +795,17 @@ def _expert_layer_checks(ctx) -> dict:
     runs = {"one_walk": system(None)}
     sent = int(runs["one_walk"][1]["rows"].sum())
     runs["three_walks"] = system(-(-sent // 3))     # a third of the load
-    facts = {"rows_sent": sent, "buffer_rows": expert.buffer_rows(
-        tokens, top_k, experts, held)}
+    buffer = expert.buffer_rows(tokens, top_k, experts, held)
+    facts = {"rows_sent": sent, "buffer_rows": buffer}
     for (label, (got, routing)), walks in zip(runs.items(), (1, 3)):
         errors = {name: float(f"{rel_err(g, w):.3e}")
                   for name, g, w in zip(names, got, want)}
-        facts[label] = {"walks": int(routing["walks"]), "rel_err": errors}
-        if int(routing["walks"]) != walks or int(routing["dropped"]):
-            raise AssertionError(f"{label}: {routing['walks']} walks, "
-                                 f"{routing['dropped']} dropped")
+        facts[label] = {
+            "walks": int(routing["walks"]), "slots": int(routing["slots"]),
+            **_sum_facts(tokens, top_k, held,
+                         buffer if walks == 1 else -(-sent // 3)),
+            "rel_err": errors}
+        _check_routing(label, routing, walks, min(top_k, held))
         if not max(errors.values()) < 3e-2:
             raise AssertionError(f"{label} against the float32 twin: "
                                  f"{errors}")
@@ -909,10 +931,12 @@ def phase_nemotron_h(ctx) -> dict:
     for label, walks, (got, routing) in (
             ("expert_layer_one_walk", 1, (one, routing)),
             ("expert_layer_three_walks", 3, system(-(-sent // 3)))):
-        if int(routing["walks"]) != walks or int(routing["dropped"]):
-            raise AssertionError(f"{label}: {routing['walks']} walks, "
-                                 f"{routing['dropped']} dropped")
-        judge(label, got, (want, *want_grads), walks=walks)
+        _check_routing(label, routing, walks,
+                       min(cfg.num_experts_per_tok, held))
+        judge(label, got, (want, *want_grads), walks=walks,
+              slots=int(routing["slots"]), **_sum_facts(
+                  flat.shape[0], cfg.num_experts_per_tok, held,
+                  facts["buffer_rows"] if walks == 1 else -(-sent // 3)))
     return facts
 
 

@@ -8,8 +8,11 @@ platform.  This file pins that table:
     transformer cells (read from ``benchmarks/``, read-only) the flash
     forward tile, the backward path and tile as TRACED (nothing executed),
     the backward route and the xentropy ``auto`` choice on either platform;
-  * ``test_cell_buffer``: for the cell whose model routes, the rows of the
+  * ``test_cell_buffer``: for the cells whose model routes, the rows of the
     expert dispatch buffer the rule gives (``parallel.expert.buffer_rows``);
+  * ``test_cell_token_sum``: for the same cells, the form of the sum back
+    to the tokens (``parallel.expert.sums_in_row_space``: the one that moves
+    fewer rows) and the rows its gathers write, as TRACED;
   * ``test_builtin_choice``: the cell-independent choosers, once each;
   * ``test_stale_profile_is_ignored``: a ``tuned_defaults.json`` left over
     from the retired measured-tuning loop, naming the OTHER value for every
@@ -170,6 +173,17 @@ CELL_BUFFERS = {
 }
 
 
+#: cell -> (the sum back to the tokens is taken in row space, rows the
+#: gathers of ONE sum write): ``parallel.expert.sums_in_row_space`` — in row
+#: space the buffer's rows sorted by token and a row a token, where that
+#: moves fewer rows than k gathers of T; a gather of T rows a slot where the
+#: buffer is as long as the tokens
+CELL_SUM_ROWS = {
+    "lfm2_24b_a2b.ep8_s4096": (False, 131072),
+    "nemotron3_super_120b_a12b.tp8_ep64_s8192": (True, 27648),
+}
+
+
 def _walk_eqns(jaxpr):
     for eqn in jaxpr.eqns:
         yield eqn
@@ -220,7 +234,7 @@ def test_the_cell_table_covers_the_benchmark():
 
 
 def test_the_buffer_table_covers_the_routed_cells():
-    assert set(EXPERT_CELLS) == set(CELL_BUFFERS)
+    assert set(EXPERT_CELLS) == set(CELL_BUFFERS) == set(CELL_SUM_ROWS)
 
 
 @pytest.mark.parametrize("cell", sorted(CELL_BUFFERS))
@@ -231,6 +245,40 @@ def test_cell_buffer(cell):
     # every expert held: the buffer is every assignment, one walk always
     assert expert.buffer_rows(tokens, top_k, experts, experts) \
         == tokens * top_k
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_SUM_ROWS))
+def test_cell_token_sum(cell, flash_events):
+    """Traced at the cell's tokens, top-k, experts and share (abstract, the
+    widths small: they choose nothing): the form the rule of shapes gives,
+    the layout event's ``sum_rows``, and the row gathers of a forward walk
+    themselves — the buffer's rows of the input, then either the same rows
+    sorted by token and one row a token, or T rows a slot."""
+    tokens, top_k, experts, held = EXPERT_CELLS[cell]
+    in_rows, sum_rows = CELL_SUM_ROWS[cell]
+    buffer = CELL_BUFFERS[cell][0]
+    assert expert.sums_in_row_space(tokens, top_k, held, buffer) == in_rows
+    # every expert held: the buffer is every assignment, a slot at a time
+    assert not expert.sums_in_row_space(tokens, top_k, experts,
+                                        tokens * top_k)
+    d, r, f = 16, 24, 8
+    sds = jax.ShapeDtypeStruct
+    jaxpr = jax.make_jaxpr(lambda *a: expert.routed_experts(
+        a[0], a[1], jnp.zeros((experts,)), a[3], a[4], top_k=top_k,
+        form="relu2", rows=a[2], axis_name=None)[0])(
+        sds((tokens, d), jnp.bfloat16), sds((d, experts), jnp.float32),
+        sds((tokens, r), jnp.bfloat16), sds((held, r, f), jnp.bfloat16),
+        sds((held, f, r), jnp.bfloat16))
+    layout, = [rec["fields"] for rec in flash_events.flush()
+               if rec.get("name") == "moe.layout"]
+    assert (layout["buffer_rows"], layout["sum_rows"]) == (buffer, sum_rows)
+    assert sum_rows == (buffer + tokens if in_rows else tokens * top_k)
+    written = sorted(e.outvars[0].aval.shape[0]
+                     for e in _walk_eqns(jaxpr.jaxpr)
+                     if e.primitive.name == "gather"
+                     and e.outvars[0].aval.shape[1:] == (r,))
+    assert written == sorted([buffer] + (
+        [buffer, tokens] if in_rows else [tokens] * top_k))
 
 
 @pytest.mark.parametrize("chooser", CELL_CHOOSERS)

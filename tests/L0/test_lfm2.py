@@ -210,7 +210,12 @@ def test_every_gradient_leaf_of_a_walked_layer_is_the_references(walks):
     """x, w13, w2 and — through the weights — the router, whether the held
     rows fit the buffer or the same body walks them in 2 or 4 pieces."""
     _, layer, h = _expert_layer(CFG)
-    _assert_same(*_layer_gradients(h, _share(layer, 4, 4), 4, walks))
+    share = _share(layer, 4, 4)
+    # the rule's buffer (every assignment) sums back to the tokens a slot at
+    # a time, a pinned one in row space: both forms are held to the reference
+    buffer = _pinned(h, share, 4, walks) or expert.buffer_rows(50, 4, 16, 4)
+    assert expert.sums_in_row_space(50, 4, 4, buffer) == (walks is not None)
+    _assert_same(*_layer_gradients(h, share, 4, walks))
 
 
 def test_a_walk_with_nothing_to_do_takes_no_trip():
@@ -270,6 +275,111 @@ def test_no_assignment_is_dropped_at_total_imbalance(favourite, walks):
     got, want = _layer_gradients(h, layer, first, walks)
     np.testing.assert_array_equal(out, got[0])
     _assert_same(got, want)
+
+
+def _plain_token_sum(src, at, mine, scale=None):
+    """The sum back to the tokens as its plain statement: ``Σ_j src[at[t,
+    j]]`` over the slots a walk holds (times ``scale[t, j]``, in its dtype)
+    — a gather of T rows a slot, k of them."""
+    total = 0
+    for j in range(at.shape[1]):
+        term = jnp.where(mine[:, j, None], src[at[:, j]], 0)
+        total = total + (term if scale is None
+                         else term.astype(scale.dtype) * scale[:, j, None])
+    return total
+
+
+def _plain_dispatch(ids, first, held, c):
+    """``(order, place, here, ends)`` of ``routed_experts``' sort, in numpy:
+    the assignments by held expert with the absent ones last, padded to
+    whole walks of ``c`` rows."""
+    tokens, top_k = ids.shape
+    local = (ids - first).reshape(-1)
+    here = (local >= 0) & (local < held)
+    group = np.where(here, local, held)
+    order = np.argsort(group, kind="stable").astype(np.int32)
+    place = np.argsort(order).astype(np.int32).reshape(tokens, top_k)
+    ends = np.cumsum(np.bincount(group, minlength=held + 1)[:held])
+    return (jnp.asarray(np.pad(order, (0, -len(order) % c))),
+            jnp.asarray(place), jnp.asarray(here.reshape(tokens, top_k)),
+            jnp.asarray(ends.astype(np.int32)))
+
+
+def _hand_routed_ids(tokens, imbalance, first=4, held=4, top_k=4, experts=16):
+    """Distinct experts a token, as ``top_k`` gives them.  ``imbalance``:
+    every token takes the same held experts; else token t holds 0, 1,
+    ``min(top_k, held)`` or 2 of them by t % 4, the rest absent ones."""
+    rng = np.random.RandomState(5)
+    inside = np.arange(first, first + held)
+    outside = np.setdiff1d(np.arange(experts), inside)
+    ids = np.empty((tokens, top_k), np.int32)
+    for t in range(tokens):
+        n = min(top_k, held)
+        if not imbalance:
+            n = (0, 1, n, 2)[t % 4]
+        ids[t] = rng.permutation(np.concatenate([
+            rng.choice(inside, n, replace=False),
+            rng.choice(outside, top_k - n, replace=False)]))
+    return ids
+
+
+@pytest.mark.parametrize("form, width", [("gated_silu", None), ("relu2", 24)])
+@pytest.mark.parametrize("scaled", [False, True], ids=["plain", "scaled"])
+@pytest.mark.parametrize("imbalance", [False, True],
+                         ids=["mixed", "imbalance"])
+@pytest.mark.parametrize("walks", [1, 3])
+def test_the_row_space_sum_is_a_gather_a_slot(walks, imbalance, scaled, form,
+                                              width):
+    """``_row_sum`` — rows sorted by token, neighbours added, one gather
+    of T rows — against a gather of T rows a slot, walk by walk, over the
+    rows the held experts computed with the buffer's rows past the groups
+    set to NaN, as a TPU leaves them: tokens holding 0, 1 and min(k, held)
+    rows in one walk, or every token the same held experts; with the
+    assignment's weight in float32 and without; gated SiLU on the model's
+    rows and squared ReLU on narrower ones."""
+    tokens, top_k, first, held = 48, 4, 4, 4
+    _, layer, h = _expert_layer(CFG, tokens=tokens)
+    rows = h if width is None else h[:, :width]
+    r, f = rows.shape[1], CFG.moe_intermediate_size
+    w13 = layer["w13"][first:first + held, :r,
+                       :2 * f if form == "gated_silu" else f]
+    w2 = layer["w2"][first:first + held, :, :r]
+    ids = _hand_routed_ids(tokens, imbalance)
+    weights = jax.random.uniform(jax.random.PRNGKey(7), ids.shape)
+    sent = int(((ids >= first) & (ids < first + held)).sum())
+    c = -(-sent // walks) + 3       # the last walk's buffer is never full
+    assert -(-sent // c) == walks
+    order, place, here, ends = _plain_dispatch(ids, first, held, c)
+    slots = min(top_k, held)
+    counts = set()
+    for i in range(walks):
+        lo, token, assignment, sizes, valid = expert._walk(
+            i, order, ends, top_k, c)
+        src = jnp.where(valid[:, None], expert._expert_ffn(
+            form, rows[token], w13, w2, sizes), jnp.nan)
+        at, mine = expert._slots(place, here, lo, c)
+        counts |= set(np.sum(mine, axis=1).tolist())
+        want = _plain_token_sum(src, at, mine, weights if scaled else None)
+        got = expert._row_sum(
+            src, token, valid, mine, slots,
+            weights.reshape(-1)[assignment] if scaled else None)
+        assert got.shape == (tokens, r) and np.all(np.isfinite(got))
+        assert bool(jnp.all(valid)) == (i < walks - 1)
+        np.testing.assert_allclose(
+            got, want, rtol=1e-6, atol=1e-6 * float(jnp.max(jnp.abs(want))))
+    assert max(counts) <= slots
+    if walks == 1:
+        assert counts == ({slots} if imbalance else {0, 1, 2, slots})
+
+
+@pytest.mark.parametrize("first", [0, 4, 12])
+def test_slots_is_the_fullest_tokens_held_assignments(first):
+    _, layer, h = _expert_layer(CFG)
+    _, routing = _routed(h, _share(layer, first, 4), first, axis_name=None)
+    ids = np.asarray(routing["ids"])
+    held = (ids >= first) & (ids < first + 4)
+    assert int(routing["slots"]) == held.sum(axis=1).max() <= 4
+    assert routing["slots"].dtype == jnp.int32
 
 
 def _avals(jaxpr):
@@ -367,19 +477,45 @@ def test_the_routing_meter_counts_once_a_forward_pass_under_remat():
         assert len(events.expert_rows()) == before + 1
         rows = events.expert_rows()[-1]
         assert sum(registry.counter("moe.walks")._pending_values()) == 4
+        fullest = list(registry.histogram("moe.slots_max")._pending_values())
     finally:
         events.set_default(previous)
     routing = lfm2_routing(params, batch["tokens"], cfg)
     np.testing.assert_array_equal(rows, routing["rows"])
     assert rows.shape == (4, 4) and not np.any(routing["dropped"])
     assert routing["walks"].tolist() == [1, 1, 1, 1]
+    held = (routing["ids"] >= 4) & (routing["ids"] < 8)
+    np.testing.assert_array_equal(routing["slots"],
+                                  held.sum(axis=2).max(axis=1))
+    assert fullest == [int(routing["slots"].max())]
     layouts = [e["fields"] for e in registry._events
                if e["name"] == "moe.layout"]
-    assert {"experts": 16, "held": 4, "top_k": 4,
-            "buffer_rows": 2 * SEQ * 4} in layouts
+    assert {"experts": 16, "held": 4, "top_k": 4, "buffer_rows": 2 * SEQ * 4,
+            "sum_rows": 2 * SEQ * 4} in layouts
     # off, the step holds no callback at all
     text = jax.jit(lambda p: lfm2_loss(p, batch, cfg)).lower(params).as_text()
     assert "callback" not in text
+
+
+@pytest.mark.parametrize("slots", [None, [2, 3]], ids=["without", "with"])
+def test_the_step_side_of_the_meter_takes_slots_or_goes_without(slots):
+    """``record_expert_rows`` as the benchmark calls it — rows, dropped,
+    walks — and as the models do, with each layer's fullest token."""
+    registry = telemetry.Registry(enabled=True, memory=False)
+    previous = events.set_default(registry)
+    try:
+        rows, more = np.array([[3, 1], [2, 2]]), {}
+        if slots is not None:
+            more["slots"] = np.asarray(slots)
+        events.record_expert_rows(rows, 0, np.array([1, 1]), **more)
+        np.testing.assert_array_equal(events.expert_rows()[-1], rows)
+        assert sum(registry.counter("moe.rows_held")._pending_values()) == 8
+        assert list(registry.histogram("moe.load_max_over_mean")
+                    ._pending_values()) == [1.5]
+        assert list(registry.histogram("moe.slots_max")._pending_values()) \
+            == ([] if slots is None else [3.0])
+    finally:
+        events.set_default(previous)
 
 
 def test_preset_is_the_published_configuration():

@@ -136,6 +136,11 @@ def test_routing_record_covers_every_expert_layer():
     assert np.take_along_axis(chosen, np.asarray(record["ids"]), 2).all()
     held = (record["ids"] >= 8) & (record["ids"] < 16)
     assert int(record["rows"].sum()) == int(held.sum())
+    # the fullest token's held assignments, a layer: what the sum back to
+    # the tokens adds as neighbours
+    np.testing.assert_array_equal(record["slots"],
+                                  held.sum(axis=2).max(axis=1))
+    assert record["slots"].shape == (2,)
 
 
 # ---------------------------------------------------------------------------
