@@ -22,6 +22,9 @@ from .nemotron_h import (NemotronHConfig, nemotron3_super_120b_a12b_config,
                          nemotron_h_cut_pattern, nemotron_h_init,
                          nemotron_h_share, nemotron_h_apply, nemotron_h_loss,
                          nemotron_h_routing)
+from .qwen3_next import (Qwen3NextConfig, qwen3_next_80b_a3b_config,
+                         qwen3_next_init, qwen3_next_share, qwen3_next_apply,
+                         qwen3_next_loss, qwen3_next_routing)
 
 __all__ = [
     "TransformerConfig", "transformer_init", "transformer_apply",
@@ -36,4 +39,7 @@ __all__ = [
     "NemotronHConfig", "nemotron3_super_120b_a12b_config",
     "nemotron_h_cut_pattern", "nemotron_h_init", "nemotron_h_share",
     "nemotron_h_apply", "nemotron_h_loss", "nemotron_h_routing",
+    "Qwen3NextConfig", "qwen3_next_80b_a3b_config", "qwen3_next_init",
+    "qwen3_next_share", "qwen3_next_apply", "qwen3_next_loss",
+    "qwen3_next_routing",
 ]

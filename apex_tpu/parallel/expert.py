@@ -25,8 +25,9 @@ bound; degrades to single-device MoE when unbound); ``MoELayer`` carries
 init/apply around it.
 
 :func:`routed_experts` is the other family (docs/lfm2.md,
-docs/nemotron_h.md): sigmoid scores, a selection bias that chooses and does
-not weigh, top-k of ALL experts, and a share of them held here.  The
+docs/nemotron_h.md, docs/qwen3_next.md): the model's scores (sigmoid with a
+selection bias that chooses and does not weigh, or a softmax over all),
+top-k of ALL experts, and a share of them held here.  The
 expert's form (gated SiLU or squared ReLU) and the rows it reads are the
 model's; its matrix products are grouped ones
 (``jax.lax.ragged_dot``) over the rows the held experts were sent, sorted by
@@ -185,24 +186,37 @@ class MoELayer:
 # top-k routing over all experts, a share of them held here, nothing dropped
 # ---------------------------------------------------------------------------
 
+ROUTER_SCORES = ("sigmoid", "softmax")
+
+
 def route_top_k(x, router_w, expert_bias, top_k: int, *,
                 norm_topk_prob: bool = True,
-                routed_scaling_factor: float = 1.0):
+                routed_scaling_factor: float = 1.0, score: str = "sigmoid"):
     """``(ids (T, k) int32, weights (T, k) float32)`` of every token's
-    experts.  ``s = sigmoid(x W_g)``; the top-k of ``s + b`` choose — the
-    bias ``b`` is a buffer, it carries no gradient and weighs nothing —
-    and the weights are the chosen ``s``, normalised over the k where
+    experts.  ``s = score(x W_g)`` — each expert's own ``sigmoid`` or a
+    ``softmax`` over all of them, the model's (:data:`ROUTER_SCORES`); the
+    top-k of ``s + b`` choose — the bias ``b`` is a buffer, it carries no
+    gradient and weighs nothing; None where the model has none — and the
+    weights are the chosen ``s``, normalised over the k where
     ``norm_topk_prob``.  All of it in float32 at the highest matmul
     precision, whatever ``x`` is: which experts a token takes must not turn
     on bfloat16 rounding of a score."""
-    scores = jax.nn.sigmoid(jnp.dot(
-        x.astype(jnp.float32), router_w.astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST))
-    choose = scores + jax.lax.stop_gradient(expert_bias.astype(jnp.float32))
+    if score not in ROUTER_SCORES:
+        raise ValueError(f"score must be one of {ROUTER_SCORES}, "
+                         f"got {score!r}")
+    logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    scores = (jax.nn.sigmoid(logits) if score == "sigmoid"
+              else jax.nn.softmax(logits, axis=-1))
+    choose = scores if expert_bias is None else scores \
+        + jax.lax.stop_gradient(expert_bias.astype(jnp.float32))
     _, ids = jax.lax.top_k(choose, top_k)
     weights = jnp.take_along_axis(scores, ids, axis=-1)
     if norm_topk_prob:
-        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-6)
+        # k sigmoids can all be 0 in float32; the largest of a softmax over
+        # E is at least 1/E, and the published rule divides by the bare sum
+        floor = 1e-6 if score == "sigmoid" else 0.0
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + floor)
     return ids, weights * routed_scaling_factor
 
 
@@ -387,12 +401,14 @@ def routed_experts(x, router_w, expert_bias, w13, w2, *, top_k: int,
                    first: int = 0, norm_topk_prob: bool = True,
                    routed_scaling_factor: float = 1.0,
                    axis_name: Optional[str] = EXPERT_AXIS,
-                   form: str = "gated_silu", rows=None):
+                   form: str = "gated_silu", rows=None,
+                   score: str = "sigmoid"):
     """The held experts' part of a routed FFN over ``x`` (T, D):
     ``Σ_{e ∈ S(t), e held} w_e · FFNᵉ(rows_t)``.
 
-    ``router_w`` (D, E) and ``expert_bias`` (E,) cover ALL E experts and the
-    router reads ``x``; the experts compute on ``rows`` (T, R) — ``x`` itself
+    ``router_w`` (D, E) and ``expert_bias`` (E,; None: no selection bias)
+    cover ALL E experts and the router reads ``x`` through the model's
+    ``score`` function (:func:`route_top_k`); the experts compute on ``rows`` (T, R) — ``x`` itself
     where None, a narrower projection of it in a latent expert layer — and
     the result is (T, R).  ``form`` names the expert (:data:`EXPERT_FORMS`):
     ``gated_silu`` ``W2ᵉ(silu(W1ᵉ h) ⊙ W3ᵉ h)`` with ``w13`` (held, R, 2·F) —
@@ -420,13 +436,14 @@ def routed_experts(x, router_w, expert_bias, w13, w2, *, top_k: int,
     return _routed_experts(x, router_w, expert_bias, w13, w2, top_k=top_k,
                            first=first, norm_topk_prob=norm_topk_prob,
                            routed_scaling_factor=routed_scaling_factor,
-                           axis_name=axis_name, form=form, rows=rows)
+                           axis_name=axis_name, form=form, rows=rows,
+                           score=score)
 
 
 def _routed_experts(x, router_w, expert_bias, w13, w2, *, top_k, first=0,
                     norm_topk_prob=True, routed_scaling_factor=1.0,
                     axis_name=EXPERT_AXIS, form="gated_silu", rows=None,
-                    rows_a_walk=None):
+                    score="sigmoid", rows_a_walk=None):
     """:func:`routed_experts`; ``rows_a_walk`` pins the buffer under the
     rule's (:func:`buffer_rows`) so that a test can make it walk."""
     if form not in EXPERT_FORMS:
@@ -446,7 +463,7 @@ def _routed_experts(x, router_w, expert_bias, w13, w2, *, top_k, first=0,
     with annotate("apex.router"):
         ids, weights = route_top_k(
             x, router_w, expert_bias, top_k, norm_topk_prob=norm_topk_prob,
-            routed_scaling_factor=routed_scaling_factor)
+            routed_scaling_factor=routed_scaling_factor, score=score)
 
     # -- dispatch: sort the T·k assignments by held expert, absent last ------
     local = (ids - first).reshape(-1)

@@ -59,6 +59,8 @@ SCOPES = (
     "apex.ssm_scan",       # inside it: discretisation and the chunked scan
     "apex.latent",         # inside apex.moe: the two latent projections
     "apex.shared_expert",  # inside apex.moe: the expert every token passes
+    "apex.gdn",            # models.qwen3_next: a whole Gated DeltaNet mixer
+    "apex.gdn_rule",       # inside it: decays and the chunked delta rule
 )
 
 # jax strips debug info - where a named scope lives - before it hashes the

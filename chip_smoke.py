@@ -25,6 +25,13 @@ user would call, at the full width of the models the repo supports:
               in the 1024-wide latent), each against its float32 XLA twin,
               forward and every gradient; the expert layer at one walk of
               its buffer and at a forced three
+  qwen3_next  Qwen3-Next-80B-A3B-Instruct at the published widths and the
+              benchmark cell's share (32 of 512 experts top-10): the chunked
+              gated delta rule against the sequential recurrence at S 4096
+              x 32 heads, a Gated DeltaNet mixer and the gated attention
+              mixer (flash at D 256) against float32 twins, the sparse FFN
+              at one walk of its buffer and at a forced three, forward and
+              every gradient
   multichip   (when jax finds >= 4 devices) the trainers --distributed /
               --zero / --sync-bn and one step of every plan family on a
               4-device mesh, each device holding its share
@@ -940,6 +947,193 @@ def phase_nemotron_h(ctx) -> dict:
     return facts
 
 
+def phase_qwen3_next(ctx) -> dict:
+    """The ``--qwen3-next 16 1`` share at the published widths (the
+    rehearsal: width 64), bfloat16 against float32 twins at the highest
+    matmul precision, output and every gradient: the chunked gated delta
+    rule against the reference's sequential recurrence at S 4096 x 32
+    heads; a Gated DeltaNet mixer and the gated attention mixer (the flash
+    kernel at D 256 — no other configuration has a head wider than 128)
+    against the same block in float32 with XLA attention; and the sparse FFN
+    — softmax scores, gated-SiLU experts, the gated shared expert — at one
+    walk of its buffer and at a forced three against a twin that routes
+    alike, then runs each held expert over ALL tokens, weighed: on a TPU the
+    grouped product leaves rows past the groups unwritten, so only here can
+    a masking fault show."""
+    import dataclasses
+    import functools
+    import jax
+    import jax.numpy as jnp
+    from apex_tpu.models import qwen3_next, qwen3_next_init
+    from apex_tpu.parallel import expert
+    pretrain = load_example("examples/bert/pretrain.py")
+    reference = load_example("benchmarks/reference/qwen3_next_80b_a3b.py")
+    cfg = pretrain.qwen3_next_config(pretrain.parse_args(
+        ["--qwen3-next", "16", "1", "--vocab", "19072", "--attn", "fast"]))
+    batch, seq = (2, 4096) if ctx["full"] else (2, 40)
+    if not ctx["full"]:
+        cfg = dataclasses.replace(
+            cfg, hidden_size=64, num_attention_heads=4, num_key_value_heads=2,
+            head_dim=16, linear_num_key_heads=2, linear_key_head_dim=8,
+            linear_num_value_heads=4, linear_value_head_dim=8, chunk_size=16,
+            num_experts=64, num_experts_per_tok=6, moe_intermediate_size=32,
+            shared_expert_intermediate_size=32)
+    cfg = dataclasses.replace(cfg, vocab_size=8, experts_held=(
+        cfg.num_experts // 16, cfg.num_experts // 16))
+    keys = jax.random.split(jax.random.PRNGKey(7), 9)
+    gdn_layer, _, _, full_layer = qwen3_next_init(keys[0], cfg)["layers"]
+    u = jax.random.normal(keys[1], (batch, seq, cfg.hidden_size))
+    probe = jax.random.normal(keys[2], u.shape)
+    half = functools.partial(jax.tree_util.tree_map,
+                             lambda x: x.astype(jnp.bfloat16))
+    # the values the system sees, in float32: what a twin is given
+    seen = functools.partial(jax.tree_util.tree_map, lambda x: x.astype(
+        jnp.bfloat16).astype(jnp.float32))
+    facts = {}
+
+    def graded(fn, *args, argnums=(0, 1)):
+        # the probe is an argument: closed over it would be a constant of
+        # the program
+        (_, aux), grads = jax.jit(jax.value_and_grad(
+            jax.checkpoint(fn), argnums=argnums, has_aux=True))(*args)
+        return aux, grads
+
+    def judge(label, got, want, loose=(), **more):
+        errors = {name: float(f"{rel_err(g, w):.3e}")
+                  for name, g, w in zip(("out", "d_x"), got, want)}
+        errors.update({"d_" + name: float(f"{rel_err(g, want[2][name]):.3e}")
+                       for name, g in got[2].items()})
+        facts[label] = dict(rel_err=errors, **more)
+        # a per-head scalar's gradient (and that of the 64 columns that make
+        # the decays) is a sum of signed terms over every position:
+        # bfloat16's rounding does not average out of it
+        if not all(e < (0.15 if name in loose else 3e-2)
+                   for name, e in errors.items()):
+            raise AssertionError(f"{label} against the float32 twin: "
+                                 f"{errors}")
+
+    # -- the chunked rule against the sequential recurrence ------------------
+    groups, heads = cfg.linear_num_key_heads, cfg.linear_num_value_heads
+    dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
+    rule_in = {
+        "q": qwen3_next._l2_norm(jax.random.normal(
+            keys[3], (1, seq, groups, dk))) * dk ** -0.5,
+        "k": qwen3_next._l2_norm(jax.random.normal(
+            keys[4], (1, seq, groups, dk))),
+        "v": jax.random.normal(keys[5], (1, seq, heads, dv)),
+        "g": -jax.nn.softplus(jax.random.normal(keys[6], (1, seq, heads))),
+        "beta": jax.nn.sigmoid(jax.random.normal(keys[7], (1, seq, heads)))}
+    rule_probe = jax.random.normal(keys[8], (1, seq, heads, dv))
+    rounded = dict(seen(rule_in), g=rule_in["g"], beta=rule_in["beta"])
+
+    def chunked(t, probe):
+        o = qwen3_next.gated_delta_rule(
+            *(t[n].astype(jnp.bfloat16) for n in "qkv"), t["g"], t["beta"],
+            cfg.chunk_size)
+        return jnp.sum(o.astype(jnp.float32) * probe), o
+
+    def sequential(t, probe):
+        per = heads // groups
+        o = reference._delta_recurrence(
+            jnp.repeat(t["q"], per, axis=2), jnp.repeat(t["k"], per, axis=2),
+            t["v"], t["g"], t["beta"])
+        return jnp.sum(o * probe), o
+
+    with jax.default_matmul_precision("highest"):
+        want, (want_grads,) = graded(sequential, rounded, rule_probe,
+                                     argnums=(0,))
+    got, (grads,) = graded(chunked, rounded, rule_probe, argnums=(0,))
+    errors = {"out": float(f"{rel_err(got, want):.3e}"), **{
+        "d_" + n: float(f"{rel_err(grads[n], want_grads[n]):.3e}")
+        for n in grads}}
+    facts["rule"] = {"seq": seq, "heads": heads,
+                     "chunks": -(-seq // cfg.chunk_size), "rel_err": errors}
+    if not max(errors.values()) < 3e-2:
+        raise AssertionError(f"the chunked rule against the recurrence: "
+                             f"{errors}")
+
+    # -- the two mixers: norm, projections, (conv, rule, gated norm | q/k
+    #    norms, quarter rotary, flash, gate), output projection
+    def mixer(fn, dtype, attn):
+        def loss(x, lp, probe):
+            y = fn(x, lp, dataclasses.replace(cfg, dtype=dtype,
+                                              attn_impl=attn))
+            return jnp.sum(y.astype(jnp.float32) * probe), y
+        return loss
+    for label, fn, lp, names in (
+            ("gdn_mixer", qwen3_next._gdn_mixer, gdn_layer,
+             ("in_proj_qkvz", "in_proj_ba", "conv_w", "dt_bias", "A_log",
+              "gate_norm", "out_proj")),
+            ("attention_mixer", qwen3_next._attention_mixer, full_layer,
+             ("wq", "wk", "wv", "wo", "q_norm", "k_norm"))):
+        lp = {n: lp[n] for n in names}
+        with jax.default_matmul_precision("highest"):
+            want, want_grads = graded(mixer(fn, jnp.float32, "default"),
+                                      seen(u), seen(lp), probe)
+        got, grads = graded(mixer(fn, jnp.bfloat16, "fast"), half(u),
+                            half(lp), probe)
+        judge(label, (got, *grads), (want, *want_grads),
+              loose=("d_A_log", "d_dt_bias", "d_in_proj_ba"), batch=batch,
+              seq=seq)
+
+    # -- the sparse FFN, at one walk and at a forced three --------------------
+    first, held = cfg.experts_held
+    e_names = ("router", "w13", "w2", "shared_w13", "shared_w2",
+               "shared_gate")
+    e_layer = {n: gdn_layer[n] for n in e_names}
+    flat, flat_probe = (t.reshape(-1, t.shape[-1]) for t in (u, probe))
+
+    def system(rows_a_walk):
+        def loss(x, lp, probe):
+            routed, routing = expert._routed_experts(
+                x, lp["router"], None, lp["w13"], lp["w2"],
+                top_k=cfg.num_experts_per_tok, first=first, score="softmax",
+                axis_name=None, rows_a_walk=rows_a_walk)
+            out = routed + qwen3_next._shared_expert(x, lp)
+            return jnp.sum(out.astype(jnp.float32) * probe), (out, routing)
+        lp = dict(half(e_layer), router=e_layer["router"])
+        (out, routing), grads = graded(loss, half(flat), lp, flat_probe)
+        return (out, *grads), routing
+
+    def twin(x, lp, probe):
+        ids, weights = expert.route_top_k(
+            x.astype(jnp.bfloat16), lp["router"], None,
+            cfg.num_experts_per_tok, score="softmax")
+        out = qwen3_next._shared_expert(x, lp)
+        for e in range(held):
+            weight = jnp.sum(jnp.where(ids == first + e, weights, 0.0), -1)
+            gate, up = jnp.split(x @ lp["w13"][e], 2, axis=-1)
+            out = out + weight[:, None] * (
+                (jax.nn.silu(gate) * up) @ lp["w2"][e])
+        return jnp.sum(out * probe), out
+
+    with jax.default_matmul_precision("highest"):
+        want, want_grads = graded(twin, seen(flat), dict(
+            seen(e_layer), router=e_layer["router"]), flat_probe)
+    one, routing = system(None)
+    sent = int(routing["rows"].sum())
+    facts["rows_sent"], facts["buffer_rows"] = sent, expert.buffer_rows(
+        flat.shape[0], cfg.num_experts_per_tok, cfg.num_experts, held)
+    # a third of the load — in whole 128s on the chip, as the rule's buffers
+    # are whole 512s: at 1707 rows and ten neighbours to add XLA's own
+    # gather fusion asked for 16.26 MiB of its 16 MiB of scoped VMEM and
+    # the program was refused (PR 34)
+    third = -(-sent // 3)
+    if ctx["full"]:
+        third = -(-third // 128) * 128
+    facts["forced_buffer_rows"] = third
+    for label, walks, (got, routing) in (
+            ("expert_layer_one_walk", 1, (one, routing)),
+            ("expert_layer_three_walks", 3, system(third))):
+        _check_routing(label, routing, walks,
+                       min(cfg.num_experts_per_tok, held))
+        judge(label, got, (want, *want_grads), walks=walks,
+              slots=int(routing["slots"]), **_sum_facts(
+                  flat.shape[0], cfg.num_experts_per_tok, held,
+                  facts["buffer_rows"] if walks == 1 else third))
+    return facts
+
+
 def _plan_families():
     from apex_tpu.parallel import plan as pm
     return [("dp2xtp2", pm.Plan(dp=2, tp=2)),
@@ -1034,6 +1228,7 @@ PHASES = {
     "bert_large": phase_bert_large,
     "lfm2": phase_lfm2,
     "nemotron_h": phase_nemotron_h,
+    "qwen3_next": phase_qwen3_next,
     "multichip": phase_multichip,
 }
 MULTICHIP_DEVICES = 4

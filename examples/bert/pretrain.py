@@ -40,7 +40,9 @@ from apex_tpu.models import (TransformerConfig, bert_large_config,
                              lfm2_init, lfm2_loss, NemotronHConfig,
                              nemotron3_super_120b_a12b_config,
                              nemotron_h_cut_pattern, nemotron_h_init,
-                             nemotron_h_loss)
+                             nemotron_h_loss, Qwen3NextConfig,
+                             qwen3_next_80b_a3b_config, qwen3_next_init,
+                             qwen3_next_loss)
 from apex_tpu.optimizers import FusedLAMB
 from apex_tpu.parallel import create_mesh, use_mesh
 from apex_tpu.utils.logging import AverageMeter, Throughput
@@ -90,6 +92,17 @@ def parse_args(argv=None):
                         "pattern, --vocab rows of the 131072 of embedding "
                         "and head; the router still scores all 512 "
                         "(docs/nemotron_h.md)")
+    p.add_argument("--qwen3-next", type=int, nargs=2, default=None,
+                   metavar=("EP", "PERIODS"),
+                   help="Qwen3-Next-80B-A3B-Instruct at its published widths "
+                        "(causal LM on next-token batches), cut to one "
+                        "chip's share of an EP-way expert-parallel group: "
+                        "the first 1/EP of the 512 routed experts of each "
+                        "layer, PERIODS whole periods (three Gated DeltaNet "
+                        "layers, one gated attention layer) of the 48, "
+                        "--vocab rows of the 151936 of embedding and head; "
+                        "mixers, router (all 512 outputs) and shared expert "
+                        "whole (docs/qwen3_next.md)")
     p.add_argument("--distributed", action="store_true")
     p.add_argument("--zero", action="store_true",
                    help="ZeRO sharded optimizer (DistributedFusedLAMB)")
@@ -255,6 +268,7 @@ def run_standard(args, cfg, mesh):
         MoETransformerConfig: (moe_transformer_init, moe_transformer_loss),
         Lfm2Config: (lfm2_init, lfm2_loss),
         NemotronHConfig: (nemotron_h_init, nemotron_h_loss),
+        Qwen3NextConfig: (qwen3_next_init, qwen3_next_loss),
     }.get(type(cfg), (transformer_init, transformer_loss))
     opt = FusedLAMB(lr=args.lr, weight_decay=0.01, max_grad_norm=1.0,
                     impl="fused",
@@ -438,6 +452,18 @@ def nemotron_h_config(args):
         dtype=jnp.bfloat16, remat=args.remat, attn_impl=args.attn)
 
 
+def qwen3_next_config(args):
+    """``--qwen3-next EP PERIODS``: the published widths, and one chip's
+    share of experts, depth and ``--vocab``."""
+    ep, periods = args.qwen3_next
+    whole = qwen3_next_80b_a3b_config()
+    return qwen3_next_80b_a3b_config(
+        vocab_size=args.vocab,
+        num_hidden_layers=periods * whole.full_attention_interval,
+        experts_held=(0, whole.num_experts // ep),
+        dtype=jnp.bfloat16, remat=args.remat, attn_impl=args.attn)
+
+
 def main(argv=None, report=None):
     """Train; returns the last printed loss.  ``report``, a dict the
     caller owns, is filled (standard and ``--zero`` paths) with what a
@@ -456,6 +482,11 @@ def main(argv=None, report=None):
                             or args.zero or args.plan or args.data):
         raise SystemExit("--nemotron-h is a model preset of the standard "
                          "path on synthetic next-token batches")
+    if args.qwen3_next and (args.bert_large or args.lfm2 or args.nemotron_h
+                            or args.moe or args.zero or args.plan
+                            or args.data):
+        raise SystemExit("--qwen3-next is a model preset of the standard "
+                         "path on synthetic next-token batches")
     if args.plan and (args.moe or args.zero or args.distributed
                       or args.auto_resume):
         raise SystemExit("--plan owns the parallelism decision — it does "
@@ -468,6 +499,8 @@ def main(argv=None, report=None):
         cfg = lfm2_config(args)
     elif args.nemotron_h:
         cfg = nemotron_h_config(args)
+    elif args.qwen3_next:
+        cfg = qwen3_next_config(args)
     elif args.moe:
         cfg = MoETransformerConfig(
             vocab_size=args.vocab, max_len=args.seq_len,
@@ -487,7 +520,7 @@ def main(argv=None, report=None):
     if args.batch_size % n_dev:
         raise ValueError(f"batch {args.batch_size} must divide {n_dev}")
     mesh = create_mesh({"data": n_dev}, devices=jax.devices()[:n_dev])
-    causal_lm = bool(args.lfm2 or args.nemotron_h)
+    causal_lm = bool(args.lfm2 or args.nemotron_h or args.qwen3_next)
     print(f"=> {n_dev} device(s), {'ZeRO' if args.zero else 'standard'} "
           f"optimizer, layers="
           f"{cfg.num_hidden_layers if causal_lm else cfg.num_layers} d="

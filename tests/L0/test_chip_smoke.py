@@ -59,6 +59,13 @@ def test_rehearsal_runs_every_phase_on_cpu():
     # the BERT step was shown to hold the flash + l2norm kernels
     traced = report["phases"]["bert_large"]["pallas_calls_traced"]
     assert {"apex_flash_fwd", "apex_l2norm"} <= set(traced)
+    # the Qwen3-Next leg held the chunked rule to the recurrence and its
+    # sparse FFN to the twin at one walk of the buffer and at three
+    qwen = report["phases"]["qwen3_next"]
+    assert qwen["rule"]["chunks"] == 3 and max(
+        qwen["rule"]["rel_err"].values()) < 3e-2
+    assert (qwen["expert_layer_one_walk"]["walks"],
+            qwen["expert_layer_three_walks"]["walks"]) == (1, 3)
     # every plan family took its step on the 4-device mesh
     legs = report["phases"]["multichip"]["legs"]
     assert sum(k.startswith("family_") for k in legs) == 7

@@ -123,6 +123,9 @@ def _attention_cells():
         elif cfg["job"] == "nemotron_h_pretrain":
             heads = model["attention_heads_held"][1]
             width, causal = heads * model["head_dim"], True
+        elif cfg["job"] == "qwen3_next_pretrain":
+            heads = model["num_attention_heads"]
+            width, causal = heads * model["head_dim"], True
         else:
             continue                    # resnet50: no attention
         cells[w["name"]] = (traffic["batch"] // w["chips"] * heads,
@@ -144,6 +147,9 @@ CELL_TILES = {
     "bert_large.s512_b136": ((512, 512), ("whole_key", 512, 512, 1)),
     "nemotron3_super_120b_a12b.tp8_ep64_s8192": (
         (512, 1024), ("resident", 512, 512, 16)),     # BH 8, D 128
+    "bert_large.s128_b544": ((128, 128), ("whole_key", 128, 128, 1)),
+    "qwen3_next_80b_a3b.ep16_s4096": (
+        (512, 1024), ("resident", 512, 512, 8)),      # BH 128, D 256
 }
 
 
@@ -170,6 +176,7 @@ EXPERT_CELLS = _expert_cells()
 CELL_BUFFERS = {
     "lfm2_24b_a2b.ep8_s4096": (32768, 131072),
     "nemotron3_super_120b_a12b.tp8_ep64_s8192": (11264, 360448),
+    "qwen3_next_80b_a3b.ep16_s4096": (40960, 327680),
 }
 
 
@@ -181,6 +188,8 @@ CELL_BUFFERS = {
 CELL_SUM_ROWS = {
     "lfm2_24b_a2b.ep8_s4096": (False, 131072),
     "nemotron3_super_120b_a12b.tp8_ep64_s8192": (True, 27648),
+    # 10 · 40 960 + 32 768 > 327 680: ten gathers of T rows a sum
+    "qwen3_next_80b_a3b.ep16_s4096": (False, 327680),
 }
 
 
@@ -231,6 +240,17 @@ CELL_CHOOSERS = ("fwd_tile", "bwd_tile", "bwd_kernels", "bwd_impl",
 
 def test_the_cell_table_covers_the_benchmark():
     assert set(CELLS) == set(CELL_TILES)
+
+
+def test_the_widest_head_leaves_the_resident_backward_at_8k():
+    """D 256 (no other configuration's head is wider than 128): at S 4096 a
+    head's K, V and float32 dk / dv fit the ``resident`` backward's budget on
+    512 x 512 tiles; from S 8192 they do not, and the backward falls to the
+    128 x 128 ``split`` pair PR 28 measured ten times slower — why the
+    Qwen3-Next cell runs at S 4096 (ROADMAP W5)."""
+    assert F._resident_blocks(4096, 4096, 256, 2, False) == (512, 512)
+    assert F._resident_blocks(8192, 8192, 256, 2, False) is None
+    assert F._resident_blocks(8192, 8192, 128, 2, False) == (512, 512)
 
 
 def test_the_buffer_table_covers_the_routed_cells():

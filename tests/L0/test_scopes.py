@@ -20,10 +20,12 @@ import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
 from apex_tpu import amp, pyprof
-from apex_tpu.models import (Lfm2Config, NemotronHConfig, TransformerConfig,
-                             lfm2_cut_layer_types, lfm2_init, lfm2_loss,
-                             nemotron_h_init, nemotron_h_loss,
-                             transformer_init, transformer_loss)
+from apex_tpu.models import (Lfm2Config, NemotronHConfig, Qwen3NextConfig,
+                             TransformerConfig, lfm2_cut_layer_types,
+                             lfm2_init, lfm2_loss, nemotron_h_init,
+                             nemotron_h_loss, qwen3_next_init,
+                             qwen3_next_loss, transformer_init,
+                             transformer_loss)
 from apex_tpu.optimizers import FusedLAMB
 from apex_tpu.parallel import DistributedDataParallel
 
@@ -49,12 +51,25 @@ NEMOTRON_H = NemotronHConfig(
     moe_intermediate_size=48, moe_shared_expert_intermediate_size=96,
     mamba_heads_held=(0, 4), attention_heads_held=(0, 2),
     experts_held=(0, 8), dtype=jnp.bfloat16, remat=True, attn_impl="fast")
+# ... and the fourth: a Gated DeltaNet and a gated attention layer of
+# Qwen3-Next, a share of the experts held
+QWEN3_NEXT = Qwen3NextConfig(
+    vocab_size=256, hidden_size=64, num_hidden_layers=2,
+    full_attention_interval=2, num_attention_heads=4, num_key_value_heads=2,
+    head_dim=16, linear_num_key_heads=2, linear_key_head_dim=8,
+    linear_num_value_heads=4, linear_value_head_dim=8, chunk_size=16,
+    num_experts=32, num_experts_per_tok=4, moe_intermediate_size=24,
+    shared_expert_intermediate_size=24, experts_held=(0, 8),
+    dtype=jnp.bfloat16, remat=True, attn_impl="fast")
 #: the blocks only the LFM2 step enters (the Nemotron-H step enters the
 #: last three of them too)
 LFM2_ONLY = ("apex.conv", "apex.moe", "apex.router", "apex.experts")
-#: the blocks only the Nemotron-H step enters
+#: the blocks only the Nemotron-H step enters (the Qwen3-Next step enters
+#: the last of them too)
 NEMOTRON_H_ONLY = ("apex.ssm", "apex.ssm_scan", "apex.latent",
                    "apex.shared_expert")
+#: the blocks only the Qwen3-Next step enters
+QWEN3_NEXT_ONLY = ("apex.gdn", "apex.gdn_rule")
 # "%name = <type, maybe a tuple> opcode(operands), ..., metadata={op_name=..."
 _INSTRUCTION = re.compile(
     r' = .*? ([a-z][\w-]*)\(.*metadata=\{[^}]*op_name="([^"]*)"')
@@ -116,6 +131,13 @@ def nemotron_h_step_ops():
         *_state_and_batch(2, nemotron_h_init, NEMOTRON_H))
 
 
+@pytest.fixture(scope="module")
+def qwen3_next_step_ops():
+    return _op_names(
+        functools.partial(_step, loss_impl=qwen3_next_loss, cfg=QWEN3_NEXT),
+        *_state_and_batch(2, qwen3_next_init, QWEN3_NEXT))
+
+
 def test_scopes_are_a_fixed_vocabulary():
     assert len(set(pyprof.SCOPES)) == len(pyprof.SCOPES)
     for name in pyprof.SCOPES:
@@ -123,7 +145,8 @@ def test_scopes_are_a_fixed_vocabulary():
 
 
 @pytest.mark.parametrize("ops", ["step_ops", "lfm2_step_ops",
-                                 "nemotron_h_step_ops"])
+                                 "nemotron_h_step_ops",
+                                 "qwen3_next_step_ops"])
 def test_every_matmul_belongs_to_a_block(ops, request):
     matmuls = [path for opcode, path in request.getfixturevalue(ops)
                if opcode in ("dot", "convolution", "ragged-dot")]
@@ -134,9 +157,26 @@ def test_every_matmul_belongs_to_a_block(ops, request):
 
 @pytest.mark.parametrize("name", pyprof.SCOPES)
 def test_scope_occurs_in_the_step(name, step_ops, ddp_step_ops,
-                                  lfm2_step_ops, nemotron_h_step_ops):
+                                  lfm2_step_ops, nemotron_h_step_ops,
+                                  qwen3_next_step_ops):
     one_chip = {path for _, path in step_ops if name in path}
-    if name in NEMOTRON_H_ONLY:
+    if name in QWEN3_NEXT_ONLY:
+        # no other step has such a block; the Qwen3-Next step enters it
+        # forward, backward and in remat's second forward, the rule inside
+        # the mixer
+        assert not one_chip
+        for ops in (lfm2_step_ops, nemotron_h_step_ops):
+            assert not [path for _, path in ops if name in path]
+        paths = [path for _, path in qwen3_next_step_ops if name in path]
+        for mark in ("transpose(", "rematted_computation"):
+            assert any(mark in path for path in paths), (name, mark)
+        assert any("transpose(" not in path for path in paths), name
+        if name == "apex.gdn_rule":
+            # also inside the checkpointed loop over sequences, whose body
+            # starts a name stack of its own
+            assert all("apex.gdn" in path.replace("apex.gdn_rule", "")
+                       for path in paths), name
+    elif name in NEMOTRON_H_ONLY:
         # neither other step has such a block; the Nemotron-H step enters it
         # forward, backward and in remat's second forward
         assert not one_chip
@@ -202,6 +242,21 @@ def test_lfm2_step_reuses_the_shared_blocks(name, lfm2_step_ops):
     """The dense gated FFN is ``apex.mlp``; attention, embedding, head, loss
     and the update are the blocks the BERT step has."""
     assert any(name in path for _, path in lfm2_step_ops), name
+
+
+@pytest.mark.parametrize("name", ["apex.embed", "apex.attn", "apex.flash",
+                                  "apex.moe", "apex.router", "apex.experts",
+                                  "apex.shared_expert", "apex.head",
+                                  "apex.loss", "apex.amp_step"])
+def test_qwen3_next_step_reuses_the_shared_blocks(name, qwen3_next_step_ops):
+    """Gated attention is ``apex.attn`` with ``apex.flash`` inside, the
+    sparse FFN ``apex.moe`` with router, experts and the gated shared expert
+    inside; embedding, head, loss and the update are the BERT step's."""
+    paths = [path for _, path in qwen3_next_step_ops if name in path]
+    assert paths, name
+    if name in ("apex.router", "apex.experts", "apex.shared_expert"):
+        assert all(path.index("apex.moe") < path.index(name)
+                   for path in paths), name
 
 
 def test_update_blocks_nest_inside_amp_step(step_ops):
