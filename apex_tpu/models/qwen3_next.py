@@ -31,8 +31,12 @@ code; :func:`qwen3_next_share` cuts a share's parameters out of the whole
 model's.
 
 Layers are a python loop (they differ in shape), each under
-``jax.checkpoint`` where ``remat``.  Plain ``jax.numpy`` around the flash
-kernel, the grouped products and the loss kernel; XLA fuses the rest.
+``jax.checkpoint`` where ``remat`` — which keeps, by name, the delta rule's
+output where the kernel pair made it and recomputes the rest.  Plain
+``jax.numpy`` around the kernels — flash attention, the gated delta rule's
+pair (``ops.gated_delta_rule``, at the published head width 128 and chunk 64;
+its ``jax.numpy`` twin :func:`_chunked_rule` otherwise), the loss — and the
+grouped products; XLA fuses the rest.
 """
 from __future__ import annotations
 
@@ -42,7 +46,9 @@ from typing import Any, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
+from ..ops import gated_delta_rule as _rule_kernel
 from ..parallel.expert import routed_experts
 from ..pyprof import annotate, annotate_function
 from ..telemetry import events as _tel_events
@@ -212,8 +218,28 @@ _unit_lower_inverse.defvjp(_unit_lower_inverse_fwd, _unit_lower_inverse_bwd)
 
 
 def gated_delta_rule(q, k, v, g, beta, chunk: int):
-    """``o_t = S_tᵀ q_t`` of ``S_t = e^{g_t} S_{t-1} + k_t ⊗ β_t (v_t -
-    (e^{g_t} S_{t-1})ᵀ k_t)``, ``S_0 = 0``, in the chunked form: with ``γ``
+    """:func:`_chunked_rule`'s ``o``, by the Pallas kernel pair
+    (``ops.gated_delta_rule``) where the shape is the one it takes — heads
+    128 wide, a chunk of 64: the published shape — and by the ``jax.numpy``
+    form otherwise: the same arithmetic, the chunk-local matrices in VMEM and
+    not in HBM.  Which of the two a traced call took is recorded
+    (``telemetry.events.record_gdn_rule``).  The pair's output carries the
+    name ``"gdn_rule_out"`` for :data:`_KEEP_RULE_OUT`; the ``jax.numpy``
+    form's carries none, so a checkpoint around it keeps nothing and
+    recomputes it whole, as before the kernels."""
+    kernel = _rule_kernel.takes(q.shape[-1], v.shape[-1], chunk)
+    _tel_events.record_gdn_rule("kernel" if kernel else "jnp")
+    if not kernel:
+        return _chunked_rule(q, k, v, g, beta, chunk)
+    return checkpoint_name(
+        _rule_kernel.gated_delta_rule(q, k, v, g, beta, chunk),
+        "gdn_rule_out")
+
+
+def _chunked_rule(q, k, v, g, beta, chunk: int):
+    """The ``jax.numpy`` form — the kernel pair's twin, and the path of the
+    shapes it does not take: ``o_t = S_tᵀ q_t`` of ``S_t = e^{g_t} S_{t-1} +
+    k_t ⊗ β_t (v_t - (e^{g_t} S_{t-1})ᵀ k_t)``, ``S_0 = 0``, chunked: with ``γ``
     the cumulative sum of ``g`` within a chunk of ``chunk`` steps and ``u_t
     = β_t (v_t - (e^{g_t} S_{t-1})ᵀ k_t)`` the value a step writes,
 
@@ -302,6 +328,10 @@ def _rule_of_a_sequence(inputs):
         (v_tilde, w, scores, q_in, k_out, whole))[1]
 
 
+#: what a recompute does not run again: the kernel pair's output, by name
+_KEEP_RULE_OUT = jax.checkpoint_policies.save_only_these_names("gdn_rule_out")
+
+
 def _l2_norm(x, eps=1e-6):
     return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
 
@@ -309,11 +339,14 @@ def _l2_norm(x, eps=1e-6):
 def _gdn_mixer(u, lp, cfg: Qwen3NextConfig):
     """Projections and convolution over the batch; decays, normalised q and
     k, the rule and the gated norm ONE sequence at a time (``lax.map``), each
-    sequence recomputed in the reverse pass: the float32 statistics and
-    chunk-local matrices of one sequence are alive at once, not the batch's.
-    A chunk-local matrix is C·H_v floats a token (8 KiB at the published
-    sizes, 16 padded to the TPU's tiles) and the rule keeps several: over 8
-    x 4096 tokens at once the step wants 20.7 GiB (PERF.md §6, PR 34)."""
+    sequence recomputed in the reverse pass — but for the kernel pair's
+    output, kept by name (32 MiB a sequence in bfloat16), so the recompute
+    runs no kernel: what is alive at once is one sequence's float32
+    statistics and, where the kernel pair runs, its backward's sweep (the
+    states the chunks entered, ``T``, ``w``, ``U``: 224 MiB a sequence at the
+    published sizes); where the ``jax.numpy`` form runs — nothing of it is
+    kept —, its chunk-local matrices: C·H_v floats a token each, 20.7 GiB of
+    step over 8 x 4096 tokens at once (PERF.md §6, PR 34)."""
     dt = u.dtype
     bsz, seq, _ = u.shape
     groups, dk = cfg.linear_num_key_heads, cfg.linear_key_head_dim
@@ -334,9 +367,9 @@ def _gdn_mixer(u, lp, cfg: Qwen3NextConfig):
             q = _l2_norm(q.astype(jnp.float32).reshape(1, seq, groups, dk)) \
                 * dk ** -0.5
             k = _l2_norm(k.astype(jnp.float32).reshape(1, seq, groups, dk))
-            o = gated_delta_rule(q.astype(dt), k.astype(dt),
-                                 v.reshape(1, seq, heads, dv), g, beta,
-                                 cfg.chunk_size)
+            o = gated_delta_rule(
+                q.astype(dt), k.astype(dt), v.reshape(1, seq, heads, dv), g,
+                beta, cfg.chunk_size)
         # y <- rms(o; w) ⊙ silu(z) over each head, in float32
         o = o.astype(jnp.float32)
         o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
@@ -348,8 +381,9 @@ def _gdn_mixer(u, lp, cfg: Qwen3NextConfig):
     # a checkpointed loop body starts a name stack of its own: the mixer's
     # scope is entered again, or a trace would find these instructions under
     # no block
-    a_sequence = jax.checkpoint(annotate_function(a_sequence,
-                                                  name="apex.gdn"))
+    a_sequence = jax.checkpoint(
+        annotate_function(a_sequence, name="apex.gdn"),
+        policy=_KEEP_RULE_OUT)
     y = jax.lax.map(a_sequence, tuple(t[:, None] for t in (qkv, z, ba)))
     return y.reshape(bsz, seq, value_w) @ lp["out_proj"].astype(dt)
 
@@ -434,7 +468,7 @@ def _forward(params, tokens, cfg: Qwen3NextConfig):
     for kind, lp in zip(cfg.layer_types, params["layers"]):
         block = functools.partial(_block, cfg=cfg, kind=kind)
         if cfg.remat:
-            block = jax.checkpoint(block)
+            block = jax.checkpoint(block, policy=_KEEP_RULE_OUT)
         x, record = block(x, lp)
         records.append(record)
     routing = jax.tree_util.tree_map(lambda *r: jnp.stack(r), *records)

@@ -234,6 +234,26 @@ def record_ssm_layout(heads: int, chunk: int, chunks: int) -> None:
               chunks=int(chunks))
 
 
+GDN_RULE_PATHS = ("kernel", "jnp")
+
+
+def record_gdn_rule(path: str) -> None:
+    """Which form of the gated delta rule a traced call took
+    (``models.qwen3_next.gated_delta_rule``): one call per traced rule —
+    trace time, like :func:`record_flash_bwd` — with ``path`` ``"kernel"``
+    (the Pallas pair of ``ops.gated_delta_rule``) or ``"jnp"`` (the shapes
+    it does not take).  Counter ``gdn.rule_calls.<path>`` and one
+    ``gdn.rule`` event."""
+    if not active():
+        return
+    if path not in GDN_RULE_PATHS:
+        raise ValueError(f"path must be one of {GDN_RULE_PATHS}, "
+                         f"got {path!r}")
+    reg = _default
+    reg.counter(f"gdn.rule_calls.{path}").add(1)
+    reg.event("gdn.rule", path=path)
+
+
 #: the newest steps' ``rows`` as :func:`record_expert_rows` was given them
 #: (numpy (expert layers, held) int arrays), oldest first
 EXPERT_ROWS_KEPT = 64

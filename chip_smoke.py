@@ -26,9 +26,11 @@ user would call, at the full width of the models the repo supports:
               forward and every gradient; the expert layer at one walk of
               its buffer and at a forced three
   qwen3_next  Qwen3-Next-80B-A3B-Instruct at the published widths and the
-              benchmark cell's share (32 of 512 experts top-10): the chunked
-              gated delta rule against the sequential recurrence at S 4096
-              x 32 heads, a Gated DeltaNet mixer and the gated attention
+              benchmark cell's share (32 of 512 experts top-10): the gated
+              delta rule — at the published shape the Pallas kernel pair,
+              which the phase insists on — against the sequential
+              recurrence at S 4096 x 32 heads and timed beside its
+              jax.numpy twin, a Gated DeltaNet mixer and the gated attention
               mixer (flash at D 256) against float32 twins, the sparse FFN
               at one walk of its buffer and at a forced three, forward and
               every gradient
@@ -157,6 +159,19 @@ def rel_err(got, want) -> float:
         denom = float(jnp.max(jnp.abs(w))) or 1.0
         worst = max(worst, float(jnp.max(jnp.abs(g - w))) / denom)
     return worst
+
+
+def median_ms(fn, *args, runs: int = 10) -> float:
+    """Median wall milliseconds of ``fn(*args)`` run to its end, after one
+    warm-up: a fact about this run on this device, not a benchmark metric."""
+    import jax
+    jax.block_until_ready(fn(*args))
+    took = []
+    for _ in range(runs):
+        start = time.perf_counter()
+        jax.block_until_ready(fn(*args))
+        took.append(time.perf_counter() - start)
+    return round(sorted(took)[len(took) // 2] * 1e3, 3)
 
 
 def pallas_kernel_names(traced) -> set:
@@ -950,10 +965,12 @@ def phase_nemotron_h(ctx) -> dict:
 def phase_qwen3_next(ctx) -> dict:
     """The ``--qwen3-next 16 1`` share at the published widths (the
     rehearsal: width 64), bfloat16 against float32 twins at the highest
-    matmul precision, output and every gradient: the chunked gated delta
-    rule against the reference's sequential recurrence at S 4096 x 32
-    heads; a Gated DeltaNet mixer and the gated attention mixer (the flash
-    kernel at D 256 — no other configuration has a head wider than 128)
+    matmul precision, output and every gradient: the gated delta rule —
+    the Pallas kernel pair at the published shape (``facts["rule"]["path"]``
+    from the program's own record; anything else fails the phase), timed
+    alone beside its ``jax.numpy`` twin — against the reference's sequential
+    recurrence at S 4096 x 32 heads; a Gated DeltaNet mixer and the gated
+    attention mixer (the flash kernel at D 256 — no other configuration has a head wider than 128)
     against the same block in float32 with XLA attention; and the sparse FFN
     — softmax scores, gated-SiLU experts, the gated shared expert — at one
     walk of its buffer and at a forced three against a twin that routes
@@ -966,6 +983,7 @@ def phase_qwen3_next(ctx) -> dict:
     import jax.numpy as jnp
     from apex_tpu.models import qwen3_next, qwen3_next_init
     from apex_tpu.parallel import expert
+    from apex_tpu.telemetry import MemorySink, Registry, events
     pretrain = load_example("examples/bert/pretrain.py")
     reference = load_example("benchmarks/reference/qwen3_next_80b_a3b.py")
     cfg = pretrain.qwen3_next_config(pretrain.parse_args(
@@ -1042,15 +1060,43 @@ def phase_qwen3_next(ctx) -> dict:
     with jax.default_matmul_precision("highest"):
         want, (want_grads,) = graded(sequential, rounded, rule_probe,
                                      argnums=(0,))
-    got, (grads,) = graded(chunked, rounded, rule_probe, argnums=(0,))
+    # which form a traced call of the rule takes is the program's own record
+    reg = Registry(sink=MemorySink(), flush_interval=0, rank0_only=False)
+    prev = events.set_default(reg)
+    try:
+        got, (grads,) = graded(chunked, rounded, rule_probe, argnums=(0,))
+    finally:
+        events.set_default(prev)
+    paths = sorted({r["fields"]["path"] for r in reg.flush()
+                    if r.get("name") == "gdn.rule"})
     errors = {"out": float(f"{rel_err(got, want):.3e}"), **{
         "d_" + n: float(f"{rel_err(grads[n], want_grads[n]):.3e}")
         for n in grads}}
-    facts["rule"] = {"seq": seq, "heads": heads,
+    facts["rule"] = {"seq": seq, "heads": heads, "path": paths,
                      "chunks": -(-seq // cfg.chunk_size), "rel_err": errors}
     if not max(errors.values()) < 3e-2:
         raise AssertionError(f"the chunked rule against the recurrence: "
                              f"{errors}")
+    if paths != (["kernel"] if ctx["full"] else ["jnp"]):
+        raise AssertionError(
+            f"the rule at d_k {dk}, d_v {dv}, chunk {cfg.chunk_size} took "
+            f"{paths}: the published shape is the kernel pair's, the "
+            "rehearsal's 8-wide heads the jax.numpy form's")
+    if ctx["full"]:
+        # the pair beside its jax.numpy twin, one sequence alone: a forward
+        # pass, and the gradient program (the pair's sweep and reverse
+        # kernel; the twin's forward and reverse)
+        operands = tuple(rounded[n].astype(jnp.bfloat16) for n in "qkv") \
+            + (rounded["g"], rounded["beta"])
+        for label, rule in (("kernel", qwen3_next.gated_delta_rule),
+                            ("jnp", qwen3_next._chunked_rule)):
+            rule = functools.partial(rule, chunk=cfg.chunk_size)
+            facts["rule"][label + "_ms"] = {
+                "forward": median_ms(jax.jit(rule), *operands),
+                "backward": median_ms(jax.jit(jax.grad(
+                    lambda *a, rule=rule: jnp.sum(
+                        rule(*a[:5]).astype(jnp.float32) * a[5]),
+                    argnums=range(5))), *operands, rule_probe)}
 
     # -- the two mixers: norm, projections, (conv, rule, gated norm | q/k
     #    norms, quarter rotary, flash, gate), output projection

@@ -64,6 +64,9 @@ def test_rehearsal_runs_every_phase_on_cpu():
     qwen = report["phases"]["qwen3_next"]
     assert qwen["rule"]["chunks"] == 3 and max(
         qwen["rule"]["rel_err"].values()) < 3e-2
+    # ... by the form its 8-wide heads take (the program's own record; on
+    # the chip the phase insists on the kernel pair, and times it)
+    assert qwen["rule"]["path"] == ["jnp"] and "kernel_ms" not in qwen["rule"]
     assert (qwen["expert_layer_one_walk"]["walks"],
             qwen["expert_layer_three_walks"]["walks"]) == (1, 3)
     # every plan family took its step on the 4-device mesh
