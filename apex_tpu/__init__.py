@@ -15,6 +15,12 @@ twin is CHOSEN — by an argument (``impl=``, ``use_pallas=``, ``attn_impl=``,
 substituted when a kernel fails: a kernel that does not compile is an error.
 """
 
+import sys as _sys
+import time as _time
+
+_import_t0_ns = _time.perf_counter_ns()     # setup.import starts here ...
+_jax_preloaded = "jax" in _sys.modules
+
 from . import amp
 from . import checkpoint
 from . import fp16_utils
@@ -34,3 +40,10 @@ from . import RNN
 from . import reparameterization
 
 __version__ = "0.1.0"
+
+# the set-up record (docs/telemetry.md): jax's compile events, by program
+# name, from here on, and this import's own span
+telemetry.events.install_compile_listener()
+telemetry.trace.setup_tracer().add(     # ... and ends here
+    "setup.import", (_time.perf_counter_ns() - _import_t0_ns) / 1e9,
+    t0_ns=_import_t0_ns, jax_preloaded=_jax_preloaded)

@@ -35,6 +35,7 @@ code stays free when telemetry is off.
 from __future__ import annotations
 
 import collections
+import threading
 from typing import Optional
 
 from . import registry as _registry
@@ -395,42 +396,112 @@ def record_ckpt(seconds: float, nbytes: int, reg=None) -> None:
     reg.gauge("ckpt.bytes_written").set(float(nbytes))
 
 
-# -- jax compilation meter (docs/telemetry.md Goodput ledger) -----------------
+# -- jax compilation meter (docs/telemetry.md Goodput ledger, Set-up record) ---
 # Recompilation is a first-class badput source: a shape-churn retrace
 # silently inflates "step time" unless compile time is metered on its
 # own.  ``jax.monitoring`` publishes per-phase compile durations
 # (`/jax/core/compile/{jaxpr_trace,jaxpr_to_mlir_module,backend_compile}
-# _duration`); the listener turns each into a post-hoc ``compile.<phase>``
-# span through the default tracer (which streams into an attached
-# GoodputLedger as ``recompile`` badput) and accumulates ``compile.ms``
-# / ``compile.count`` counters through the default registry.  The
-# listener registers ONCE per process (jax.monitoring has no unregister
-# short of clearing everyone's listeners) and costs one prefix check
-# per monitoring event; with no registry/tracer installed every hook
-# inside is a single attribute check — the disabled-mode bar.
+# _duration`, each with the program's ``fun_name``); the listener turns
+# each into a post-hoc ``compile.<phase>`` span in the set-up record
+# (``trace.setup_tracer()``, always) and through the default tracer (when
+# one is installed: it streams into an attached GoodputLedger as
+# ``recompile`` badput), and accumulates ``compile.ms`` / ``compile.count``
+# / ``compile.cache_hits`` counters through the default registry.
+#
+# jax times ``backend_compile`` around ``compile_or_get_cached``, so a
+# persistent-cache HIT fires it too.  What the cache did arrives inside
+# that interval, on the same thread, as events of its own
+# (`/jax/compilation_cache/{cache_hits,cache_misses}`,
+# `cache_retrieval_time_sec`): they are held per thread until the interval
+# closes and land on its span as ``cache`` = ``hit`` (loaded) | ``miss``
+# (compiled and written) | ``none`` (the persistent cache neither served
+# nor stored it: no directory, or an entry under jax's size / compile-time
+# thresholds) and, on a hit, ``retrieval_s``.
+#
+# jax's intervals nest: every ``jnp`` function is a ``jit`` of its own and
+# reports a ``jaxpr_trace`` inside the trace of the program that calls it
+# (thousands a model, tens of microseconds each; 5560 entries before the
+# step's own in the CPU rehearsal of the smallest ResNet cell).  jax marks
+# the START of each phase with a scalar event of the same name, so the
+# listener keeps a depth per thread, and a trace that closes inside another
+# open phase leaves no entry in the set-up record: the outer interval
+# covers it, and the record's bound is spent on programs.  (The default
+# tracer and the counters hear every event, as before.)
+#
+# The listeners register ONCE per process (jax.monitoring has no
+# unregister short of clearing everyone's listeners), ``import apex_tpu``
+# does it, and cost one prefix check per monitoring event; jax fires none
+# on a cached ``jit`` call, so a warmed-up step sees no code of this.
 
 _COMPILE_EVENT_PREFIX = "/jax/core/compile/"
+_CACHE_EVENT_PREFIX = "/jax/compilation_cache/"
+_CACHE_OUTCOMES = {"cache_misses": "miss", "cache_hits": "hit"}
 _compile_listener_installed = False
+_compiling = threading.local()      # .depth: open phases; .seen: see below
+
+
+def _cache_seen() -> dict:
+    """What the persistent cache said inside this thread's open
+    ``backend_compile`` interval."""
+    seen = getattr(_compiling, "seen", None)
+    if seen is None:
+        seen = _compiling.seen = {}
+    return seen
+
+
+def _on_compile_start(event, value, **kw) -> None:
+    if isinstance(event, str) and event.startswith(_COMPILE_EVENT_PREFIX):
+        _compiling.depth = getattr(_compiling, "depth", 0) + 1
+
+
+def _on_cache_event(event, **kw) -> None:
+    if not isinstance(event, str) \
+            or not event.startswith(_CACHE_EVENT_PREFIX):
+        return
+    outcome = _CACHE_OUTCOMES.get(event[len(_CACHE_EVENT_PREFIX):])
+    if outcome is not None:
+        _cache_seen()["cache"] = outcome
 
 
 def _on_compile_event(event, duration_secs, **kw) -> None:
-    if not isinstance(event, str) \
-            or not event.startswith(_COMPILE_EVENT_PREFIX):
+    if not isinstance(event, str):
+        return
+    if event == _CACHE_EVENT_PREFIX + "cache_retrieval_time_sec":
+        _cache_seen()["retrieval_s"] = float(duration_secs)
+        return
+    if not event.startswith(_COMPILE_EVENT_PREFIX):
         return
     phase = event[len(_COMPILE_EVENT_PREFIX):]
     if phase.endswith("_duration"):
         phase = phase[: -len("_duration")]
+    # phases of this thread still open around the one that closes (a
+    # listener installed inside a phase sees its end without its start)
+    _compiling.depth = outer = max(getattr(_compiling, "depth", 1) - 1, 0)
+    attrs = {}
+    if kw.get("fun_name") is not None:
+        attrs["fun_name"] = str(kw["fun_name"])
+    if phase == "backend_compile":
+        seen = _cache_seen()
+        attrs.update({"cache": "none", **seen})
+        seen.clear()
     # post-hoc span ending now: the listener fires right as the phase
     # completes, so the interval lands where the compile actually ran
-    _trace.note_span(f"compile.{phase}", float(duration_secs))
+    if not (outer and phase == "jaxpr_trace"):
+        _trace.setup_tracer().add(f"compile.{phase}", float(duration_secs),
+                                  **attrs)
+    _trace.note_span(f"compile.{phase}", float(duration_secs), **attrs)
     if not active():
         return
     reg = _default
     reg.counter("compile.ms").add(float(duration_secs) * 1e3)
     if phase == "backend_compile":
-        # one backend_compile per compilation: the honest compile COUNT
-        # (trace/lowering phases also fire for cache hits and retraces)
-        reg.counter("compile.count").add(1)
+        # one backend_compile per program built or loaded (trace/lowering
+        # phases also fire for retraces): a load from the persistent cache
+        # is no compilation, so the honest compile COUNT leaves it out
+        if attrs["cache"] == "hit":
+            reg.counter("compile.cache_hits").add(1)
+        else:
+            reg.counter("compile.count").add(1)
 
 
 def install_compile_listener() -> bool:
@@ -444,6 +515,8 @@ def install_compile_listener() -> bool:
         import jax.monitoring
         jax.monitoring.register_event_duration_secs_listener(
             _on_compile_event)
+        jax.monitoring.register_event_listener(_on_cache_event)
+        jax.monitoring.register_scalar_listener(_on_compile_start)
     except Exception:   # pragma: no cover - monitoring API unavailable
         return False
     _compile_listener_installed = True

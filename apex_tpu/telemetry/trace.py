@@ -33,7 +33,8 @@ only appears inside the sentinel's optional profiler capture), so the
 tooling that renders traces (``python -m apex_tpu.telemetry trace``)
 never pays backend bring-up.  Library hooks route through the
 process-default tracer (:func:`set_tracer`); with none installed every
-hook is one attribute check.
+hook is one attribute check.  One more :class:`Tracer`, the set-up record
+(:func:`setup_tracer`), is always there and hears the set-up hooks only.
 """
 from __future__ import annotations
 
@@ -49,7 +50,7 @@ from typing import Any, Dict, List, Optional
 
 __all__ = [
     "Tracer", "FlightRecorder", "SlowStepSentinel", "NULL_SPAN",
-    "set_tracer", "get_tracer", "active", "span", "traced",
+    "set_tracer", "get_tracer", "setup_tracer", "active", "span", "traced",
     "note_span", "note_event", "note_flush", "note_step", "note_counter",
     "load_chrome", "span_summary", "format_span_summary",
     "dump_violations", "cli",
@@ -113,16 +114,22 @@ class _Span:
         self._tracer = tracer
         self.name = name
         self.attrs = attrs
-        self._t0s: List[int] = []
+        self._t0s: List[tuple] = []     # (start ns, parent) of each entry
 
     def __enter__(self):
-        self._t0s.append(time.perf_counter_ns())
+        open_spans = self._tracer._open_spans()
+        self._t0s.append((time.perf_counter_ns(),
+                          open_spans[-1] if open_spans else None))
+        open_spans.append(self.name)
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter_ns()
-        t0 = self._t0s.pop() if self._t0s else t1
-        self._tracer._record(self.name, t0, t1 - t0, self.attrs)
+        t0, parent = self._t0s.pop() if self._t0s else (t1, None)
+        open_spans = self._tracer._open_spans()
+        if open_spans:
+            open_spans.pop()
+        self._tracer._record(self.name, t0, t1 - t0, self.attrs, parent)
         return False
 
     def __call__(self, fn):
@@ -498,15 +505,22 @@ class Tracer:
     ``ring`` bounds the flight recorder; ``max_spans`` bounds the full
     export buffer (oldest spans drop first — the ring still holds the
     newest, and ``dropped_spans`` counts the loss so a truncated export
-    can't read as a complete one).  ``enabled=None`` reads
-    ``APEX_TPU_TRACE`` (default on).  Disabled: ``span()`` returns
+    can't read as a complete one; with ``keep_first`` the buffer keeps
+    the FIRST ``max_spans`` entries and drops what comes after, the
+    bound of the set-up record, :func:`setup_tracer`).  ``enabled=None``
+    reads ``APEX_TPU_TRACE`` (default on).  Disabled: ``span()`` returns
     :data:`NULL_SPAN` and every note is a no-op.
+
+    A span records its ``parent``: the name of the innermost span of its
+    thread that was open IN THIS TRACER when it began (None at the top);
+    a post-hoc :meth:`add` takes the span open when it is noted.  It is a
+    key of the exported event beside ``args`` and of the ring's entry.
     """
 
     def __init__(self, *, enabled: Optional[bool] = None, ring: int = 512,
                  max_spans: int = 100_000, flight_dir: Optional[str] = None,
                  sentinel: Optional[SlowStepSentinel] = None,
-                 process_name: str = "apex_tpu"):
+                 process_name: str = "apex_tpu", keep_first: bool = False):
         self.enabled = _env_enabled() if enabled is None else bool(enabled)
         self.recorder = FlightRecorder(ring, directory=flight_dir)
         self.sentinel = sentinel
@@ -517,6 +531,7 @@ class Tracer:
         # early intervals.  One attribute check when detached.
         self.ledger = None
         self.max_spans = int(max_spans)
+        self.keep_first = bool(keep_first)
         self.process_name = process_name
         self.dropped_spans = 0
         # chrome-shaped, lock-protected; deque so eviction at max_spans
@@ -527,6 +542,14 @@ class Tracer:
         self._threads: Dict[int, str] = {}
         self._lock = threading.Lock()
         self._pid = os.getpid()
+        self._open = threading.local()     # .spans: names open, innermost last
+
+    def _open_spans(self) -> List[str]:
+        try:
+            return self._open.spans
+        except AttributeError:
+            self._open.spans = []
+            return self._open.spans
 
     # -- recording ----------------------------------------------------------
     def span(self, name: str, **attrs):
@@ -544,8 +567,9 @@ class Tracer:
             return
         t1 = time.perf_counter_ns()
         dur_ns = max(int(dur_s * 1e9), 0)
+        open_spans = self._open_spans()
         self._record(name, t1 - dur_ns if t0_ns is None else t0_ns,
-                     dur_ns, attrs)
+                     dur_ns, attrs, open_spans[-1] if open_spans else None)
 
     def counter(self, name: str, step: Optional[int] = None,
                 **values) -> None:
@@ -590,21 +614,25 @@ class Tracer:
         # caller holds the lock; the deque evicts the oldest itself
         if len(self._events) >= self.max_spans:
             self.dropped_spans += 1
+            if self.keep_first:
+                return
         self._events.append(ev)
 
     def _record(self, name: str, t0_ns: int, dur_ns: int,
-                attrs: dict) -> None:
+                attrs: dict, parent: Optional[str] = None) -> None:
         th = threading.current_thread()
         args = _clean_fields(attrs)
         ev = {"ph": "X", "name": name, "cat": "host",
               "ts": t0_ns / 1e3, "dur": dur_ns / 1e3,
-              "pid": self._pid, "tid": th.ident, "args": args}
+              "pid": self._pid, "tid": th.ident, "args": args,
+              "parent": parent}
         with self._lock:
             self._threads[th.ident] = th.name   # latest wins (ident reuse)
             self._append(ev)
         self.recorder.record({"kind": "span", "name": name,
                               "t_us": ev["ts"], "dur_us": ev["dur"],
-                              "thread": th.name, "attrs": args})
+                              "thread": th.name, "attrs": args,
+                              "parent": parent})
         led = self.ledger
         if led is not None:
             led.note_span(name, ev["ts"], ev["dur"],
@@ -690,6 +718,26 @@ def set_tracer(tracer: Optional[Tracer]) -> Optional[Tracer]:
 
 def get_tracer() -> Optional[Tracer]:
     return _default
+
+
+#: entries the set-up record keeps: the first ones, the rest are counted
+SETUP_RECORD_ENTRIES = 4096
+_setup = Tracer(enabled=True, max_spans=SETUP_RECORD_ENTRIES,
+                keep_first=True, process_name="apex_tpu set-up")
+
+
+def setup_tracer() -> Tracer:
+    """The process's set-up record: what happened between ``import
+    apex_tpu`` and the first step, kept apart from the default tracer and
+    always on.  Only the set-up hooks write to it — ``setup.import``
+    (``apex_tpu/__init__.py``), ``setup.state`` (the examples' state
+    build) and the ``compile.*`` entries of ``events.
+    install_compile_listener`` — so nothing lands in it from a step whose
+    programs are compiled; every other library hook goes to the default
+    tracer alone.  It keeps its first :data:`SETUP_RECORD_ENTRIES` entries
+    and counts the rest in ``dropped_spans``; ``export()`` / ``write(path)``
+    serve it like any tracer (docs/telemetry.md "Set-up record")."""
+    return _setup
 
 
 def active() -> bool:

@@ -31,7 +31,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from apex_tpu import amp
+from apex_tpu import amp, telemetry
 from apex_tpu.models import (TransformerConfig, bert_large_config,
                              transformer_init, transformer_loss,
                              MoETransformerConfig, moe_transformer_init,
@@ -274,18 +274,18 @@ def run_standard(args, cfg, mesh):
                     impl="fused",
                     state_dtype=jnp.bfloat16 if args.state_dtype else None)
     # ONE program makes the float32 parameters and the amp state that owns
-    # its copies of them, replicated over the mesh where it is written: the
-    # initial parameters are the program's temporaries and no second copy
-    # of the state is placed, so set-up's high-water mark stays under the
-    # step's footprint (at 16 bytes a parameter the two it replaces — init,
-    # then amp.initialize and a device_put — peaked at 26).  Replicated up
-    # front: left on the default device the state would be re-laid-out by
-    # the first step and the step compiled a second time.  The key is an
-    # argument, so every seed runs the same cached program.
-    state = jax.jit(
-        lambda key: amp.initialize(init_fn(key, cfg), opt,
-                                   opt_level=args.opt_level, verbosity=0),
-        out_shardings=NamedSharding(mesh, P()))(jax.random.PRNGKey(args.seed))
+    # its copies of them, replicated over the mesh where it is written: no
+    # second copy is placed (init, then amp.initialize and a device_put,
+    # peaked at 26 bytes a parameter; the step's footprint is 16) and the
+    # first step neither re-lays-out the state nor compiles twice.  The key
+    # is an argument, so every seed runs the same cached program.  The span
+    # is the host's time in the call: nobody waits for the device here.
+    with telemetry.trace.setup_tracer().span("setup.state"):
+        state = jax.jit(
+            lambda key: amp.initialize(init_fn(key, cfg), opt,
+                                       opt_level=args.opt_level, verbosity=0),
+            out_shardings=NamedSharding(mesh, P()))(
+                jax.random.PRNGKey(args.seed))
     sharding = NamedSharding(mesh, P("data"))
 
     # donate the amp state: the flat fused engine writes fresh master/m/v

@@ -40,7 +40,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from apex_tpu import amp, checkpoint
+from apex_tpu import amp, checkpoint, telemetry
 from apex_tpu.models import (resnet18_config, resnet50_config, resnet_init,
                              resnet_apply)
 from apex_tpu.optimizers import FusedAdam, FusedSGD, FusedLAMB
@@ -294,9 +294,6 @@ def main(argv=None, report=None):
     compute_dtype = (jnp.bfloat16 if args.opt_level in
                      ("O1", "O2", "O3", "O4", "O5") else jnp.float32)
     cfg = cfg_fn(dtype=compute_dtype)
-    params, bn_state = jax.jit(
-        lambda: resnet_init(jax.random.PRNGKey(args.seed), cfg))()
-
     opt_cls = {"adam": functools.partial(FusedAdam, lr=args.lr),
                "sgd": functools.partial(FusedSGD, lr=args.lr, momentum=0.9),
                "lamb": functools.partial(FusedLAMB, lr=args.lr)}[args.optimizer]
@@ -306,8 +303,13 @@ def main(argv=None, report=None):
     if loss_scale not in (None, "dynamic"):
         loss_scale = float(loss_scale)
     kbn = {None: None, "True": True, "False": False}[args.keep_batchnorm_fp32]
-    state = amp.initialize(params, opt, opt_level=args.opt_level,
-                           loss_scale=loss_scale, keep_batchnorm_fp32=kbn)
+    # the host's time in building the state, for the set-up record
+    # (docs/telemetry.md): nobody waits for the device here
+    with telemetry.trace.setup_tracer().span("setup.state"):
+        params, bn_state = jax.jit(
+            lambda: resnet_init(jax.random.PRNGKey(args.seed), cfg))()
+        state = amp.initialize(params, opt, opt_level=args.opt_level,
+                               loss_scale=loss_scale, keep_batchnorm_fp32=kbn)
 
     start_step = 0
     if args.resume:
