@@ -28,6 +28,7 @@ from . import amp as _amp
 from . import scaler as _scaler
 from .properties import Properties, opt_levels
 from ..pyprof import annotate, annotate_function
+from ..telemetry import events as _tel_events
 from ..utils import pytree as _pt
 
 
@@ -271,6 +272,8 @@ def amp_step_multi(amp_state: AmpState, grads_and_ids, *, lr=None):
     if amp_state.optimizer is None:
         raise RuntimeError("amp_step_multi requires an optimizer passed to "
                            "initialize()")
+    flat = _flat_masters_active(amp_state)
+    _tel_events.record_update_path("flat" if flat else "leafwise")
     with annotate("apex.unscale"):
         total32 = None
         finites = {}
@@ -288,7 +291,7 @@ def amp_step_multi(amp_state: AmpState, grads_and_ids, *, lr=None):
         _scaler.update(s, finites[i]) if i in finites else s
         for i, s in enumerate(amp_state.scalers))
 
-    if _flat_masters_active(amp_state):
+    if flat:
         # flat fast path: pack grads once, update the flat master in place,
         # one fused unflatten-with-cast produces the model copy
         opt = amp_state.optimizer

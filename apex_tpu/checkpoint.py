@@ -189,9 +189,39 @@ def verify(path: str) -> None:
     load(path)
 
 
+#: the conversion between the two optimizer layouts
+FLAT_STATE_HINT = (
+    "one side is a flat optimizer state (impl='fused': 1-D master / m / v), "
+    "the other a per-leaf one (impl='xla': trees); convert with "
+    "apex_tpu.multi_tensor_apply.TreeFlattener(params).unflatten(buffer, "
+    "dtype=jnp.float32) (flat to tree; .flatten(tree) goes back)")
+
+
+def _holds_flat_state(leaves) -> bool:
+    """Whether ``leaves`` look like a flat optimizer state: some 1-D buffer
+    is at least as long as every matrix and stacked leaf put together (the
+    flat master / m / v span the whole model; a per-leaf state's vectors are
+    biases and norm scales)."""
+    arrays = [x for x in leaves if hasattr(x, "shape")]
+    longest = max((x.size for x in arrays if x.ndim == 1), default=0)
+    return 0 < longest >= sum(x.size for x in arrays if x.ndim >= 2)
+
+
+def flat_state_hint(template_leaves, saved_leaves) -> str:
+    """``"; " + FLAT_STATE_HINT`` where exactly one side holds a flat
+    optimizer state, else ``""``: a mismatch of any other kind (a changed
+    model) is not sent towards this conversion."""
+    if _holds_flat_state(template_leaves) != _holds_flat_state(saved_leaves):
+        return "; " + FLAT_STATE_HINT
+    return ""
+
+
 def restore_like(template, host_tree):
     """Device-put ``host_tree`` with the dtypes/shardings of ``template``
-    (leaf-wise).  Shapes must match; dtypes are cast to the template's."""
+    (leaf-wise).  Shapes and structure must match (where one side is a flat
+    optimizer state and the other per-leaf, the refusal names the
+    conversion: :data:`FLAT_STATE_HINT`); dtypes are cast to the
+    template's."""
     from jax.sharding import NamedSharding
 
     def put(t, h):
@@ -206,6 +236,14 @@ def restore_like(template, host_tree):
         if not isinstance(sh, NamedSharding):
             sh = None
         return jax.device_put(arr.astype(t.dtype), sh)
+    if (jax.tree_util.tree_structure(template)
+            != jax.tree_util.tree_structure(host_tree)):
+        raise ValueError(
+            "checkpoint tree structure differs from the template's: "
+            f"{jax.tree_util.tree_structure(host_tree)} against "
+            f"{jax.tree_util.tree_structure(template)}"
+            + flat_state_hint(jax.tree_util.tree_leaves(template),
+                              jax.tree_util.tree_leaves(host_tree)))
     return jax.tree_util.tree_map(put, template, host_tree)
 
 

@@ -27,7 +27,11 @@ Tuned to the on-chip measurements in PERF_NOTES.md §2 (round 3, v5e):
   penalty.  Our own jit sites (``__graft_entry__._dryrun_zero_leg``,
   the 2-process ZeRO worker) do this.
 - ``multi_tensor_l2norm`` keeps its sequential single-cell accumulation:
-  it measured FASTER than the XLA reduce (1.17 ms vs 1.65 ms on 1.34 GB).
+  it measured FASTER than the XLA reduce (1.17 ms vs 1.65 ms on 1.34 GB) —
+  on a buffer that is ALREADY flat.  Getting a tree of tiled leaves into
+  that layout is what costs (``pad_bitcast_fusion`` 4.1 ms + the kernel's
+  1.9 ms a BERT-large step, PERF.md section 5): the per-leaf step reduces
+  each gradient leaf where it lies instead.
 
 On non-TPU backends (CPU tests) kernels run in Pallas interpret mode.
 """
@@ -275,5 +279,11 @@ def fused_lamb_stage1_flat(flat_g, flat_p, flat_m, flat_v, scalars, *,
 # NOTE: the SGD/Adagrad Pallas kernels were retired in round 3 — the fused
 # optimizers now do their elementwise math as XLA fusions over the
 # permanently-flat state, which measured faster than any Pallas elementwise
-# variant on TPU (PERF_NOTES.md §2).  The Adam/LAMB-stage1 kernels above
-# remain in use by the sharded ZeRO optimizers (contrib/optimizers).
+# variant on TPU (PERF_NOTES.md §2).  The reason, found by PR 37 on the chip
+# (PERF.md section 6), is layout, not arithmetic: an XLA fusion reads each
+# operand in the tiling it already has, while a kernel over a flat (rows, 128)
+# view first needs every tiled 2-D leaf relaid into it — at BERT-large that
+# packing and unpacking was half of the flat update's 100 B a parameter, and
+# a replicated update now runs leaf by leaf and keeps no flat buffer.  The
+# Adam/LAMB-stage1 kernels above remain in use by the sharded ZeRO optimizers
+# (contrib/optimizers), where a replica's shard is a slice of one buffer.

@@ -9,14 +9,21 @@ implementations:
 - ``impl="xla"``: per-leaf ``tree_map`` updates.  Under jit, XLA emits one
   fused elementwise loop per leaf inside a single executable — the kernel
   -launch-overhead problem the CUDA multi-tensor engine solves does not exist
-  inside one XLA program.
+  inside one XLA program — and every leaf is read and written in the tiled
+  layout it already has.  What a REPLICATED update runs (the bert example's
+  ``run_standard``, every cell of the benchmark): at BERT-large 49 B a
+  parameter and no scratch where the flat engine moves 100 B and keeps 8.7
+  (PERF.md section 6, PR 37).
 - ``impl="fused"``: the flat-buffer engine (``multi_tensor_apply``) —
   optimizer state AND master params live permanently in one contiguous fp32
   buffer per field; the update is expressed as XLA elementwise math over the
-  flat buffers (plus the flattener's static per-tensor reductions), which on
-  TPU measures at full HBM bandwidth.  This is the architectural mirror of
-  ``amp_C``'s multi-tensor engine, and the perf-measurement vehicle for
-  BASELINE's "FusedLAMB step-time" metric.  See PERF_NOTES.md for the
+  flat buffers (plus the flattener's static per-tensor reductions).  This is
+  the architectural mirror of ``amp_C``'s multi-tensor engine, and what a
+  SHARDED update needs — a replica's shard is a slice of one buffer
+  (``parallel.weight_update``, the plan engines, ZeRO).  Its math streams at
+  the chip's rate; its cost on a TPU is the packing: a flat 1-D buffer does
+  not have the memory order of a tiled 2-D leaf, so the gradient tree in and
+  the model copy out are a relayout each way.  See PERF_NOTES.md for the
   measurements that chose XLA-on-flat over Pallas elementwise kernels.
 
 The fused impl's native API is flat: ``step_flat(state, flat_grads)`` updates
@@ -82,7 +89,7 @@ def resolve_state_dtype(state_dtype):
 class FusedOptimizer:
     """Base: handles impl selection and the flattener for the fused path.
 
-    ``state_dtype`` (fused impl only, optimizers that opt in): storage
+    ``state_dtype`` (fused impl; FusedLAMB's per-leaf step too): storage
     dtype for the m/v moment buffers.  The flat optimizer step is HBM-
     bandwidth-bound (r5 on-chip: 23.0 ms at 334M params ~= 16 GB of
     buffer traffic); storing moments in bf16 cuts ~2.7 GB/step (~17%) at
@@ -99,10 +106,15 @@ class FusedOptimizer:
     #: :meth:`step_flat_shard` with the cross-shard form.
     elementwise_flat_update = True
 
+    #: whether the per-leaf step (impl='xla') stores its moments in
+    #: ``state_dtype`` too; where it does not, asking for one is an error
+    leafwise_state_dtype = False
+
     def __init__(self, lr, weight_decay=0.0, impl="xla", state_dtype=None):
         if impl not in ("xla", "fused"):
             raise ValueError(f"impl must be 'xla' or 'fused', got {impl!r}")
-        if state_dtype is not None and impl != "fused":
+        if (state_dtype is not None and impl != "fused"
+                and not self.leafwise_state_dtype):
             raise ValueError("state_dtype is a flat-engine (impl='fused') "
                              "knob; the xla impl keeps fp32 moments")
         self.lr = lr

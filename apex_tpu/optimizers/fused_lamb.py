@@ -3,9 +3,13 @@
 Re-design of ``apex/optimizers/fused_lamb.py:4-214`` (kernels
 ``csrc/multi_tensor_lamb.cu`` Stage1/Stage2): global-grad-norm clipping
 (``max_grad_norm``), per-tensor trust ratios, AdamW-style decoupled decay.
-The CUDA two-stage structure maps to: Pallas stage-1 kernel (m/v + step
-direction) → per-tensor norms via the flattener's static segment reduction →
-XLA stage-2 (trust-ratio scaled apply, fused by XLA into one pass).
+The CUDA two-stage structure maps to two passes over a tensor: stage 1
+(m / v + step direction) yields the two norms of the trust ratio, stage 2
+applies it.  Two layouts of ONE mathematics (:meth:`FusedLAMB._direction`,
+:meth:`FusedLAMB._trust_ratio`): leaf by leaf in the leaves' own layouts
+(``impl="xla"``, a replicated update) and over flat buffers with the
+flattener's static segment reductions (``impl="fused"``: ``step_flat``, and
+``step_flat_shard`` where a replica holds a slice).
 """
 from __future__ import annotations
 
@@ -14,7 +18,7 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 
-from ._base import FusedOptimizer, tree_zeros_f32, resolve, _f32, global_l2norm
+from ._base import FusedOptimizer, resolve, _f32, global_l2norm
 from ..multi_tensor_apply import kernels
 from ..multi_tensor_apply.flattener import LANE
 
@@ -24,12 +28,15 @@ class FusedLAMBState(NamedTuple):
     m: Any
     v: Any
     master: Any = None   # fused impl: flat fp32 master params (authoritative)
+    #                      per-leaf: None — m and v are trees, the masters the
+    #                      caller's (amp's ``master_params``)
 
 
 class FusedLAMB(FusedOptimizer):
     #: per-tensor trust ratios + the global-grad-norm clip span shards:
     #: the sharded path needs the cross-shard override below
     elementwise_flat_update = False
+    leafwise_state_dtype = True
 
     def __init__(self, lr=1e-3, bias_correction=True, betas=(0.9, 0.999),
                  eps=1e-6, weight_decay=0.01, amsgrad=False,
@@ -58,8 +65,11 @@ class FusedLAMB(FusedOptimizer):
                                   jnp.zeros((fl.total,), self.state_dtype),
                                   jnp.zeros((fl.total,), self.state_dtype),
                                   fl.flatten(params))
-        return FusedLAMBState(jnp.zeros((), jnp.int32), tree_zeros_f32(params),
-                              tree_zeros_f32(params))
+        # per-leaf: moments shaped like the parameters, each in its leaf's
+        # own layout (two trees: m and v must not share buffers either)
+        zeros = lambda: jax.tree_util.tree_map(
+            lambda p: jnp.zeros(p.shape, self.state_dtype), params)
+        return FusedLAMBState(jnp.zeros((), jnp.int32), zeros(), zeros())
 
     def _clip_coeff(self, gnorm):
         """1/max(1, gnorm/max_grad_norm) — the global clip folded into stage 1
@@ -81,6 +91,35 @@ class FusedLAMB(FusedOptimizer):
             rc1 = rc2 = jnp.ones((), jnp.float32)
         return count, lr, rc1, rc2
 
+    def _direction(self, g, p, m, v, rc1, rc2):
+        """``(m, v, u)`` of the ``LAMBStage1Functor``: the moments and the
+        update direction from the unscaled + clipped float32 gradient ``g``,
+        the float32 weights ``p`` and the stored moments.  ONE function for
+        the per-leaf step and the flat chain (full or shard-length), so an
+        update-math fix can never miss a twin; moments may be stored narrow
+        (``state_dtype``): upcast here, cast back only at store."""
+        wd = jnp.asarray(self.weight_decay, jnp.float32)
+        b1, b2 = self.beta1, self.beta2
+        beta3 = 1.0 - b1 if self.grad_averaging else 1.0
+        if not self.adam_w_mode:
+            g = g + wd * p
+        m = b1 * _f32(m) + beta3 * g
+        v = b2 * _f32(v) + (1.0 - b2) * g * g
+        u = (m * rc1) / (jnp.sqrt(v * rc2) + self.eps)
+        if self.adam_w_mode:
+            u = u + wd * p
+        return m, v, u
+
+    def _trust_ratio(self, w_sumsq, u_sumsq):
+        """Per-tensor ``‖w‖ / ‖u‖`` (``LAMBStage2Functor``,
+        multi_tensor_lamb.cu:234); 1 where either norm is 0 and, unless
+        ``use_nvlamb``, where nothing decays."""
+        w_norm, u_norm = jnp.sqrt(w_sumsq), jnp.sqrt(u_sumsq)
+        ratio = jnp.where((w_norm > 0) & (u_norm > 0), w_norm / u_norm, 1.0)
+        if not self.use_nvlamb and self.weight_decay == 0.0:
+            ratio = jnp.ones_like(ratio)
+        return ratio
+
     def step(self, state, grads, params, *, scale=1.0, lr=None):
         if self.impl == "fused":
             fl = self.flattener_for(params)
@@ -88,35 +127,25 @@ class FusedLAMB(FusedOptimizer):
                                        lr=lr)
             return fl.unflatten(new_state.master), new_state
 
+        # leaf by leaf, each in the layout it has: ``scale`` and the clip
+        # are ONE scalar, and a leaf costs two passes — its two norms from
+        # one read of g, p, m, v, then the apply.  Under jit XLA fuses the
+        # neighbours into them: amp's unscale before (the gradient is read
+        # as the backward left it; no float32 copy of it is written), amp's
+        # skip select and model-precision copy after
         count, lr, rc1, rc2 = self._prep(state, lr)
         inv_scale = 1.0 / jnp.asarray(scale, jnp.float32)
-        wd = jnp.asarray(self.weight_decay, jnp.float32)
-        b1, b2, eps = self.beta1, self.beta2, self.eps
-        beta3 = 1.0 - b1 if self.grad_averaging else 1.0
-
         # global grad norm over *unscaled* grads (fused_lamb.py:123-135)
         gnorm = global_l2norm(grads) * inv_scale
-        clip = self._clip_coeff(gnorm)
-        adamw, use_nvlamb = self.adam_w_mode, self.use_nvlamb
+        coeff = inv_scale * self._clip_coeff(gnorm)
 
         def upd(g, p, m, v):
-            g = _f32(g) * inv_scale * clip
             p32 = _f32(p)
-            if not adamw:
-                g = g + wd * p32
-            m_new = b1 * m + beta3 * g
-            v_new = b2 * v + (1.0 - b2) * g * g
-            u = (m_new * rc1) / (jnp.sqrt(v_new * rc2) + eps)
-            if adamw:
-                u = u + wd * p32
-            # per-tensor trust ratio (LAMBStage2Functor,
-            # multi_tensor_lamb.cu:234)
-            w_norm = jnp.sqrt(jnp.sum(p32 * p32))
-            u_norm = jnp.sqrt(jnp.sum(u * u))
-            ratio = jnp.where((w_norm > 0) & (u_norm > 0), w_norm / u_norm, 1.0)
-            if not use_nvlamb and self.weight_decay == 0.0:
-                ratio = jnp.ones((), jnp.float32)
-            return (p32 - lr * ratio * u).astype(p.dtype), m_new, v_new
+            m_new, v_new, u = self._direction(_f32(g) * coeff, p32, m, v,
+                                              rc1, rc2)
+            ratio = self._trust_ratio(jnp.sum(p32 * p32), jnp.sum(u * u))
+            return ((p32 - lr * ratio * u).astype(p.dtype),
+                    self._store_moment(m_new), self._store_moment(v_new))
 
         out = jax.tree_util.tree_map(upd, grads, params, state.m, state.v)
         is_t = lambda x: isinstance(x, tuple)
@@ -171,26 +200,12 @@ class FusedLAMB(FusedOptimizer):
         model — the ``TreeFlattener``'s static row-range reductions or
         the ``ShardContext``'s psum'd partials.  ONE chain, so an
         update-math fix can never miss the sharded twin."""
-        wd = jnp.asarray(self.weight_decay, jnp.float32)
-        b1, b2, eps = self.beta1, self.beta2, self.eps
-        beta3 = 1.0 - b1 if self.grad_averaging else 1.0
         p = state.master
-        if not self.adam_w_mode:
-            g = g + wd * p
-        # moments may be stored narrow (state_dtype): upcast for the fp32
-        # math, cast back only at store
-        m = b1 * _f32(state.m) + beta3 * g
-        v = b2 * _f32(state.v) + (1.0 - b2) * g * g
-        u = (m * rc1) / (jnp.sqrt(v * rc2) + eps)
-        if self.adam_w_mode:
-            u = u + wd * p
+        m, v, u = self._direction(g, p, state.m, state.v, rc1, rc2)
 
         # stage 2: per-tensor trust ratios via the reducer
-        w_norm = jnp.sqrt(reducer.per_tensor_sumsq(p))
-        u_norm = jnp.sqrt(reducer.per_tensor_sumsq(u))
-        ratio = jnp.where((w_norm > 0) & (u_norm > 0), w_norm / u_norm, 1.0)
-        if not self.use_nvlamb and self.weight_decay == 0.0:
-            ratio = jnp.ones_like(ratio)
+        ratio = self._trust_ratio(reducer.per_tensor_sumsq(p),
+                                  reducer.per_tensor_sumsq(u))
         ratio_rows = reducer.broadcast_rows(ratio)            # (rows,)
         p_new = (p.reshape(-1, LANE)
                  - lr * ratio_rows[:, None] * u.reshape(-1, LANE))

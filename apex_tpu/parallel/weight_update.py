@@ -440,7 +440,10 @@ class ShardedUpdate:
             if p_scale != 1.0:
                 g_shard = g_shard * p_scale
 
-        # -- the 1/N-slice update over the flat master/moment buffers
+        # -- the 1/N-slice update over the flat master/moment buffers (a
+        # shard is a slice of ONE buffer: the flat path, whatever the leaves)
+        from ..telemetry import events as _tel_events
+        _tel_events.record_update_path("flat")
         ctx = ShardContext(self.axis_name, fl, n)
         new_state = self.optimizer.step_flat_shard(
             state, g_shard, shard=ctx, scale=scale, lr=lr)

@@ -58,7 +58,7 @@ from .ckpt import (CheckpointManager, DataStreamMismatchError,
                    ManifestCompatWarning, WorldSizeMismatchError,
                    META_DATA_KEY, META_LAYOUT_KEY, META_PLAN_KEY,
                    META_WORLD_KEY)
-from ..checkpoint import CheckpointError
+from ..checkpoint import CheckpointError, flat_state_hint
 
 
 class GuardAbort(RuntimeError):
@@ -488,7 +488,8 @@ class TrainGuard:
             raise CheckpointError(
                 f"checkpoint has {len(saved)} leaves but the live state "
                 f"has {len(leaves)} — the model/optimizer configuration "
-                "changed since the checkpoint was written")
+                "changed since the checkpoint was written"
+                + flat_state_hint(leaves, saved))
 
         from jax.sharding import NamedSharding
 

@@ -255,6 +255,27 @@ def record_gdn_rule(path: str) -> None:
     reg.event("gdn.rule", path=path)
 
 
+UPDATE_PATHS = ("leafwise", "flat")
+
+
+def record_update_path(path: str) -> None:
+    """Which form of the optimizer update a traced step took
+    (``amp.amp_step``, ``parallel.weight_update.ShardedUpdate.step``): one
+    call per traced update — trace time, like :func:`record_gdn_rule` —
+    with ``path`` ``"leafwise"`` (state and gradients are trees, each leaf
+    updated in its own layout: what a replicated update takes) or
+    ``"flat"`` (one buffer a field, what a sharded update slices).  Counter
+    ``optimizer.update_path.<path>`` and one ``optimizer.update`` event."""
+    if not active():
+        return
+    if path not in UPDATE_PATHS:
+        raise ValueError(f"path must be one of {UPDATE_PATHS}, "
+                         f"got {path!r}")
+    reg = _default
+    reg.counter(f"optimizer.update_path.{path}").add(1)
+    reg.event("optimizer.update", path=path)
+
+
 #: the newest steps' ``rows`` as :func:`record_expert_rows` was given them
 #: (numpy (expert layers, held) int arrays), oldest first
 EXPERT_ROWS_KEPT = 64

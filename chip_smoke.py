@@ -10,8 +10,10 @@ user would call, at the full width of the models the repo supports:
   serve       InferenceEngine + ContinuousBatcher at BERT-large width
   resnet50    examples/imagenet/main_amp.py, b128, amp O2 + FusedAdam
   bert_large  examples/bert/pretrain.py, 24L b8 x s512, flash + remat,
-              amp O5 + FusedLAMB(impl="fused"); the compiled step is
-              shown to contain the Pallas kernels
+              amp O5 + per-leaf FusedLAMB; the compiled step is shown to
+              contain the Pallas kernels; then the optimizer alone on that
+              tree, per-leaf beside the flat engine: the masters' largest
+              difference and, on the chip, both times
   lfm2        the same example with --lfm2 1 1 8: LFM2-24B-A2B's widths,
               one dense layer and one period, 8 of 64 experts, b8 x s4096
               (the benchmark cell's shapes); the step holds the kernels and
@@ -628,10 +630,64 @@ def _flash_bwd_present(names: set) -> bool:
             or {"apex_flash_bwd_dq", "apex_flash_bwd_dkv"} <= names)
 
 
+def _lamb_paths(ctx) -> dict:
+    """The optimizer leg: ``amp.amp_step`` under O5 on BERT-large's tree
+    (bfloat16 gradients), the per-leaf LAMB a replicated update runs beside
+    the flat engine a sharded one slices — three steps each from the same
+    state and gradients, the masters' largest difference relative to a
+    leaf's size, and on the chip the milliseconds of a call.  The only place
+    a fault of either path that exists only in the TPU's layouts would
+    show: the CPU tests hold the two to 1e-6."""
+    import jax
+    import numpy as np
+    from apex_tpu import amp
+    from apex_tpu.models import (TransformerConfig, bert_large_config,
+                                 transformer_init)
+    from apex_tpu.optimizers import FusedLAMB
+    cfg = bert_large_config() if ctx["full"] else TransformerConfig(
+        vocab_size=512, max_len=128, num_layers=2, d_model=64, num_heads=2,
+        d_ff=256)
+
+    def gradients(params):
+        leaves, treedef = jax.tree_util.tree_flatten(params)
+        keys = jax.random.split(jax.random.PRNGKey(1), len(leaves))
+        return jax.tree_util.tree_unflatten(treedef, [
+            (1e-2 * jax.random.normal(k, x.shape)).astype(x.dtype)
+            for k, x in zip(keys, leaves)])
+
+    facts, masters = {}, {}
+    for path, impl in (("leafwise", "xla"), ("flat", "fused")):
+        opt = FusedLAMB(lr=1e-3, weight_decay=0.01, max_grad_norm=1.0,
+                        impl=impl)
+        state = jax.jit(lambda key: amp.initialize(
+            transformer_init(key, cfg), opt, opt_level="O5", verbosity=0))(
+                jax.random.PRNGKey(0))
+        grads = jax.jit(gradients)(state.model_params)
+        update = jax.jit(amp.amp_step, donate_argnums=0)
+        for _ in range(3):
+            state = update(state, grads)
+        masters[path] = [np.asarray(x) for x in amp.master_params(state)]
+        if ctx["on_tpu"]:
+            def once():
+                nonlocal state
+                state = update(state, grads)
+                return state.scalers
+            facts[f"{path}_ms"] = median_ms(once)
+        del state, grads, update
+    worst = max(float(np.abs(a - b).max() / np.abs(a).max())
+                for a, b in zip(masters["leafwise"], masters["flat"]))
+    facts["masters_rel_diff"] = float(f"{worst:.3e}")
+    facts["parameters"] = int(sum(x.size for x in masters["flat"]))
+    if not facts["masters_rel_diff"] < 1e-4:
+        raise AssertionError(f"per-leaf and flat LAMB part ways: {facts}")
+    return facts
+
+
 def phase_bert_large(ctx) -> dict:
     """The required leg: BERT-large 24L b8 x s512, flash attention +
-    remat, amp O5, FusedLAMB on the flat engine with the global-norm clip
-    — and the compiled step shown to contain the Pallas kernels."""
+    remat, amp O5, FusedLAMB leaf by leaf with the global-norm clip — the
+    compiled step shown to contain the Pallas kernels and no flat engine —
+    then the optimizer alone, per-leaf beside flat (:func:`_lamb_paths`)."""
     import re
     steps = 9
     report = {}
@@ -641,7 +697,10 @@ def phase_bert_large(ctx) -> dict:
 
     traced = report["step"].trace(report["state"], report["batch"])
     names = pallas_kernel_names(traced)
-    required = {"apex_flash_fwd", "apex_l2norm"}
+    if "apex_l2norm" in names:
+        raise AssertionError("the replicated update took the flat engine: "
+                             "its l2norm kernel is in the traced step")
+    required = {"apex_flash_fwd"}
     if ctx["on_tpu"]:
         required.add("apex_xentropy_fwd")     # "auto" is XLA off the chip
     missing = sorted(required - names)
@@ -659,12 +718,16 @@ def phase_bert_large(ctx) -> dict:
                 f"lowered step has tpu_custom_calls {sorted(lowered)}, "
                 f"expected {sorted(required)} and a flash backward")
         facts["tpu_custom_calls_lowered"] = sorted(lowered)
+    del traced
+    report.clear()                  # the trainer's state, before two more
+    gc.collect()
+    facts["lamb"] = _lamb_paths(ctx)
     return facts
 
 
 def phase_lfm2(ctx) -> dict:
     """LFM2-24B-A2B's share of the benchmark cell (``--lfm2 1 1 8 --vocab
-    8192``, b8 x s4096, flash + remat, amp O5, FusedLAMB on the flat engine)
+    8192``, b8 x s4096, flash + remat, amp O5, per-leaf FusedLAMB)
     through ``parse_args`` -> ``run_standard``: trains, the step holds the
     Pallas kernels and the grouped products, and on one sequence the loss
     and its gradient agree with the XLA-attention twin.  The rehearsal
@@ -705,14 +768,15 @@ def phase_lfm2(ctx) -> dict:
         traced = step.trace(state, np_batch)
     names = pallas_kernel_names(traced)
     # ONE backward kernel at S 4096 (resident), not the dq / dkv pair
-    required = {"apex_flash_fwd", "apex_flash_bwd_fused", "apex_l2norm"}
+    required = {"apex_flash_fwd", "apex_flash_bwd_fused"}
     if ctx["on_tpu"]:
         required.add("apex_xentropy_fwd")
     if not required <= names or names & {"apex_flash_bwd_dq",
-                                         "apex_flash_bwd_dkv"}:
+                                         "apex_flash_bwd_dkv",
+                                         "apex_l2norm"}:
         raise AssertionError(f"traced step has Pallas kernels {sorted(names)}"
-                             f", expected {sorted(required)} and no split "
-                             "flash backward")
+                             f", expected {sorted(required)}, no split "
+                             "flash backward and no flat engine")
     grouped = len(re.findall(r"ragged_dot", str(traced.jaxpr)))
     if not grouped:
         raise AssertionError("the traced step holds no ragged_dot")

@@ -1,10 +1,11 @@
 """BERT-style masked-LM pretraining — the ``bert_large`` benchmark's
-workload (FusedLAMB + multi_tensor_l2norm grad-clip).
+workload (FusedLAMB with its global-norm gradient clip).
 
 The reference has no BERT example (its LAMB cites "BERT in 76 minutes");
 this harness makes it runnable end-to-end: transformer encoder + amp
-O5 (bf16 + fp32 masters on the flat engine) + FusedLAMB with global-norm
-clipping, on synthetic MLM batches.  Distributed options:
+O5 (bf16 + fp32 masters) + FusedLAMB with global-norm clipping, leaf by
+leaf where the update is replicated and on the flat engine where it is
+sharded (--zero), on synthetic MLM batches.  Distributed options:
 
   --distributed    shard the batch over all devices (DP: shard_map +
                    DistributedDataParallel gradient averaging)
@@ -254,12 +255,16 @@ def _device_batch(np_batch, sharding):
 
 
 def run_standard(args, cfg, mesh):
-    """amp O5 + FusedLAMB (flat fused engine), data-parallel over the
-    mesh's ``data`` axis: each device runs the step on its shard of the
-    batch inside ``shard_map`` and the gradients are averaged by
-    ``DistributedDataParallel``.  (Not GSPMD auto-partitioning: the
-    Pallas kernels — flash attention, xentropy, the l2norm of the clip —
-    have no partitioning rule, so on a TPU "Mosaic kernels cannot be
+    """amp O5 + FusedLAMB, data-parallel over the mesh's ``data`` axis: each
+    device runs the step on its shard of the batch inside ``shard_map`` and
+    the gradients are averaged by ``DistributedDataParallel``.  The update
+    is replicated — every device applies the whole of it — so it runs leaf
+    by leaf in the leaves' own layouts (``impl="xla"``: masters and moments
+    are trees shaped like the parameters); one flat buffer a field is what
+    a SHARDED update slices (``run_zero``, ``parallel.weight_update``), and
+    packing a tiled leaf into it is a relayout each way (PERF.md section 6,
+    PR 37).  (Not GSPMD auto-partitioning: the Pallas kernels — flash
+    attention, xentropy — have no partitioning rule, so on a TPU "Mosaic kernels cannot be
     automatically partitioned"; under ``shard_map`` each device simply
     runs them on its shard.)"""
     from jax import shard_map
@@ -271,7 +276,7 @@ def run_standard(args, cfg, mesh):
         Qwen3NextConfig: (qwen3_next_init, qwen3_next_loss),
     }.get(type(cfg), (transformer_init, transformer_loss))
     opt = FusedLAMB(lr=args.lr, weight_decay=0.01, max_grad_norm=1.0,
-                    impl="fused",
+                    impl="xla",
                     state_dtype=jnp.bfloat16 if args.state_dtype else None)
     # ONE program makes the float32 parameters and the amp state that owns
     # its copies of them, replicated over the mesh where it is written: no
@@ -288,12 +293,12 @@ def run_standard(args, cfg, mesh):
                 jax.random.PRNGKey(args.seed))
     sharding = NamedSharding(mesh, P("data"))
 
-    # donate the amp state: the flat fused engine writes fresh master/m/v
-    # buffers (no in-kernel aliasing, PERF_NOTES §2), so in-place HBM
-    # reuse must happen here at the jit boundary — at BERT-large scale
-    # the un-donated transient would be an extra ~4 GB of flat fp32
-    # state.  Safe: amp.initialize never aliases buffers between the
-    # model and master trees for this param family.
+    # donate the amp state: every leaf of master / m / v and of the model
+    # copy is written anew by its apply fusion, so in-place HBM reuse must
+    # happen here at the jit boundary — at BERT-large scale the un-donated
+    # transient would be an extra ~4 GB of fp32 state.  Safe: amp.initialize
+    # never aliases buffers between the model and master trees for this
+    # param family.
     ddp = DistributedDataParallel(axis_name="data")
 
     @functools.partial(jax.jit, donate_argnums=0)

@@ -34,16 +34,22 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
+from jax.sharding import PartitionSpec as P
 
+from apex_tpu import amp
 from apex_tpu.contrib.multihead_attn import flash as F
 from apex_tpu.contrib.multihead_attn.flash import flash_attention
 from apex_tpu.contrib.optimizers import (DistributedFusedAdam,
                                          DistributedFusedLAMB)
 from apex_tpu.contrib.xentropy import softmax_xentropy as sx
 from apex_tpu.mlp import MLP
-from apex_tpu.models import TransformerConfig, bert_large_config
+from apex_tpu.models import (Lfm2Config, NemotronHConfig, Qwen3NextConfig,
+                             TransformerConfig, bert_large_config,
+                             lfm2_cut_layer_types)
 from apex_tpu.optimizers import FusedAdam
 from apex_tpu.parallel import collectives, expert, overlap
+from apex_tpu.parallel.mesh import create_mesh, use_mesh
 from apex_tpu.parallel import plan as planmod
 from apex_tpu.parallel import weight_update as wu
 from apex_tpu.telemetry import MemorySink, Registry, events
@@ -333,6 +339,123 @@ def test_cell_choice(cell, chooser, monkeypatch, flash_events):
         backend = chooser[len("xent_on_"):]
         want = "pallas" if backend == "tpu" else "xla"
         assert _xent_auto_choice(monkeypatch, backend) == want
+
+
+# ---------------------------------------------------------------------------
+# the update's path: leaf by leaf where it is replicated, flat where sharded
+# ---------------------------------------------------------------------------
+
+#: the smallest model of each configuration's type, through the builder its
+#: cells' job calls: ``run_standard`` for the four ``*_pretrain`` jobs
+_TINY_MODELS = {
+    "bert_pretrain": lambda: TransformerConfig(
+        vocab_size=128, max_len=16, num_layers=2, d_model=32, num_heads=2,
+        d_ff=64),
+    "lfm2_pretrain": lambda: Lfm2Config(
+        vocab_size=128, hidden_size=32, intermediate_size=64,
+        moe_intermediate_size=16, num_experts=8, num_experts_per_tok=2,
+        num_dense_layers=1, layer_types=lfm2_cut_layer_types(1, 1),
+        num_attention_heads=4, num_key_value_heads=2, experts_held=(0, 4),
+        xent_impl="xla"),
+    "nemotron_h_pretrain": lambda: NemotronHConfig(
+        vocab_size=128, hidden_size=32, hybrid_override_pattern="ME*E",
+        mamba_num_heads=4, mamba_head_dim=8, n_groups=2, ssm_state_size=8,
+        conv_kernel=4, chunk_size=8, num_attention_heads=4,
+        num_key_value_heads=2, head_dim=8, n_routed_experts=8,
+        num_experts_per_tok=2, moe_latent_size=16, moe_intermediate_size=16,
+        moe_shared_expert_intermediate_size=32, mamba_heads_held=(0, 4),
+        attention_heads_held=(0, 4), experts_held=(0, 4), xent_impl="xla"),
+    "qwen3_next_pretrain": lambda: Qwen3NextConfig(
+        vocab_size=128, hidden_size=32, num_hidden_layers=4,
+        num_attention_heads=2, num_key_value_heads=1, head_dim=16,
+        linear_num_key_heads=2, linear_key_head_dim=8,
+        linear_num_value_heads=2, linear_value_head_dim=8, chunk_size=8,
+        num_experts=8, num_experts_per_tok=2, moe_intermediate_size=16,
+        shared_expert_intermediate_size=16, experts_held=(0, 4),
+        xent_impl="xla"),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _job_state(job):
+    """The amp state a cell's job steps, at the smallest size: what
+    ``examples/bert/pretrain.run_standard`` builds for the model's type,
+    and for ResNet what ``examples/imagenet/main_amp.py`` and its adapter
+    both build — per-leaf ``FusedAdam`` under the configured opt level."""
+    if job == "resnet_train":
+        return amp.initialize(
+            {"w": jnp.ones((4, 8)), "b": jnp.zeros((8,))}, FusedAdam(lr=0.1),
+            opt_level=_read("benchmarks", "configs",
+                            "resnet50.json")["amp_opt_level"], verbosity=0)
+    spec = importlib.util.spec_from_file_location(
+        "pretrain_for_choices", os.path.join(ROOT, "examples", "bert",
+                                             "pretrain.py"))
+    pretrain = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(pretrain)
+    mesh = create_mesh({"data": 1}, devices=jax.devices()[:1])
+    with use_mesh(mesh):
+        state, _ = pretrain.run_standard(pretrain.parse_args([]),
+                                         _TINY_MODELS[job](), mesh)
+    return state
+
+
+def _update_paths(reg, fn, *args):
+    """``{path: times taken}`` by the ``optimizer.update_path.*`` counters
+    of tracing ``fn`` — nothing runs; a fresh function each time, or jax
+    answers from its cache of traces."""
+    jax.eval_shape(lambda *a: fn(*a), *args)
+    prefix = "optimizer.update_path."
+    return {k[len(prefix):]: v for k, v in reg.read().items()
+            if k.startswith(prefix)}
+
+
+@pytest.mark.parametrize("cell", sorted(
+    w["name"] for w in _read("BENCHMARK.json")["workloads"]))
+def test_cell_update_path(cell, flash_events):
+    """Every cell's update is replicated, so every cell takes it leaf by
+    leaf: masters and moments are trees shaped like the parameters, no flat
+    buffer exists in the state, and the traced ``amp_step`` says so."""
+    config, = [w["config"] for w in _read("BENCHMARK.json")["workloads"]
+               if w["name"] == cell]
+    state = _job_state(_read("benchmarks", "configs",
+                             config + ".json")["job"])
+    assert getattr(state.opt_state, "master", None) is None
+    shapes = lambda t: [x.shape for x in jax.tree_util.tree_leaves(t)]
+    assert shapes(state.master_params) == shapes(state.model_params) \
+        == shapes(state.opt_state.m) == shapes(state.opt_state.v)
+    assert _update_paths(flash_events, amp.amp_step, state,
+                         state.model_params) == {"leafwise": 1.0}
+
+
+@pytest.mark.parametrize("sharded", [False, True],
+                         ids=["amp_flat_state", "ShardedUpdate"])
+def test_flat_states_take_the_flat_path(sharded, flash_events):
+    """What slices one buffer keeps it: an amp state whose masters live flat
+    in a ``impl="fused"`` optimizer's state, and ``ShardedUpdate``, whose
+    replica holds a slice of every flat field."""
+    params = {"w": jnp.ones((4, 8)), "b": jnp.zeros((8,))}
+    if not sharded:
+        state = amp.initialize(params, FusedAdam(lr=0.1, impl="fused"),
+                               opt_level="O5", verbosity=0)
+        assert state.master_params is None
+        step, args = amp.amp_step, (state, state.model_params)
+    else:
+        su = wu.ShardedUpdate(FusedAdam(lr=0.1, impl="fused"),
+                              axis_name="data")
+        mesh = create_mesh({"data": 2}, devices=jax.devices()[:2])
+        rep = jax.tree_util.tree_map(lambda _: P(), params)
+        sspec = su.state_pspecs(params, 2)
+        init = shard_map(su.init, mesh=mesh, in_specs=(rep,),
+                         out_specs=sspec)
+        step = shard_map(su.step, mesh=mesh, in_specs=(sspec, rep, rep),
+                         out_specs=(rep, sspec))
+        args = (jax.eval_shape(init, params), params, params)
+    assert _update_paths(flash_events, step, *args) == {"flat": 1.0}
+
+
+def test_an_unknown_update_path_is_refused(flash_events):
+    with pytest.raises(ValueError, match="leafwise"):
+        events.record_update_path("per_leaf")
 
 
 # ---------------------------------------------------------------------------

@@ -304,3 +304,47 @@ def test_mosaic_compiles_the_pair_at_the_published_shape(
     for kernel in ("apex_gdn_rule_fwd", "apex_gdn_rule_sweep",
                    "apex_gdn_rule_bwd"):
         assert f"{kernel}/pallas_call" in text, kernel
+
+
+# ---------------------------------------------------------------------------
+# the replicated LAMB update by the chip's own compiler (here because this is
+# the one file that may load the TPU's compiler: a second file can go to
+# another worker, whose fixture would then skip in silence)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("layers,most", [(24, 55.0), (4, 66.0)])
+def test_the_replicated_update_moves_a_leaf_twice_and_keeps_no_scratch(
+        layers, most, one_chip):
+    """``amp.amp_step`` for amp O5 + per-leaf ``FusedLAMB`` — what
+    ``run_standard`` builds — lowered for a described v5e on BERT-large's
+    tree (334 M parameters at 24 stacked layers, the benchmark's; 82 M at
+    four), bfloat16 gradients: the compiler's ``bytes accessed`` a parameter
+    and its scratch.  The fusions move 46 B: the finite check and the clip's
+    norm read g (2 + 2), a leaf's two norms come from ONE read of g, p, m, v
+    (14), the apply reads them again and writes p, m, v and the bfloat16
+    copy (14 + 14), with amp's skip select inside it.  The rest is the
+    compiler's own prefetch of an operand into fast memory, counted as a
+    read and a write: 3 B where a leaf is 24 layers, 17 B at four, where
+    every leaf fits.  The flat engine read 100 / 102 B here and kept 8.7 /
+    8.9 B a parameter of scratch (PERF.md section 6, PR 37): packing a tiled
+    leaf into a flat buffer is a relayout each way."""
+    from apex_tpu import amp
+    from apex_tpu.models import bert_large_config, transformer_init
+    from apex_tpu.optimizers import FusedLAMB
+    cfg = dataclasses.replace(bert_large_config(), num_layers=layers)
+    opt = FusedLAMB(lr=1e-3, weight_decay=0.01, max_grad_norm=1.0, impl="xla")
+    state = jax.eval_shape(
+        lambda key: amp.initialize(transformer_init(key, cfg), opt,
+                                   opt_level="O5", verbosity=0),
+        jax.random.PRNGKey(0))
+    on_chip = lambda tree: jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        tree)
+    state, grads = on_chip(state), on_chip(state.model_params)
+    n = sum(x.size for x in jax.tree_util.tree_leaves(grads))
+    compiled = jax.jit(amp.amp_step, donate_argnums=0).lower(
+        state, grads).compile()
+    accessed = compiled.cost_analysis()["bytes accessed"] / n
+    scratch = compiled.memory_analysis().temp_size_in_bytes / n
+    assert 44.0 <= accessed <= most, accessed
+    assert scratch < 1.0, scratch

@@ -545,8 +545,8 @@ def test_preset_is_the_published_configuration():
 
 
 def test_the_whole_step_trains_through_the_example():
-    """``parse_args`` -> ``run_standard`` under O5 with FusedLAMB on the flat
-    engine, the path the benchmark drives: finite, falling, no step skipped."""
+    """``parse_args`` -> ``run_standard`` under O5 with per-leaf FusedLAMB,
+    the path the benchmark drives: finite, falling, no step skipped."""
     pretrain = _load("examples/bert/pretrain.py", "pretrain_for_lfm2_step")
     args = pretrain.parse_args(["--lfm2", "1", "1", "4", "--vocab", "256",
                                 "--seq-len", "64", "--batch-size", "8",

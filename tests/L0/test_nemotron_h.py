@@ -507,8 +507,8 @@ def _tiny_step(argv=()):
 
 
 def test_the_whole_step_trains_through_the_example():
-    """``parse_args`` -> ``run_standard`` under O5 with FusedLAMB on the flat
-    engine, the path the benchmark drives: finite, falling, no step skipped."""
+    """``parse_args`` -> ``run_standard`` under O5 with per-leaf FusedLAMB,
+    the path the benchmark drives: finite, falling, no step skipped."""
     pretrain, args, cfg = _tiny_step(["--lr", "1e-2"])
     assert args.opt_level == "O5"
     mesh = create_mesh({"data": 1}, devices=jax.devices()[:1])

@@ -542,8 +542,8 @@ def test_init_is_the_public_implementations():
 
 
 def test_the_whole_step_trains_through_the_example():
-    """``parse_args`` -> ``run_standard`` under O5 with FusedLAMB on the flat
-    engine, the path the benchmark drives: finite, falling, no step skipped."""
+    """``parse_args`` -> ``run_standard`` under O5 with per-leaf FusedLAMB,
+    the path the benchmark drives: finite, falling, no step skipped."""
     pretrain = _load("examples/bert/pretrain.py", "pretrain_for_qwen3_step")
     args = pretrain.parse_args(["--qwen3-next", "4", "1", "--vocab", "256",
                                 "--seq-len", "48", "--batch-size", "4",
