@@ -25,6 +25,10 @@ from .nemotron_h import (NemotronHConfig, nemotron3_super_120b_a12b_config,
 from .qwen3_next import (Qwen3NextConfig, qwen3_next_80b_a3b_config,
                          qwen3_next_init, qwen3_next_share, qwen3_next_apply,
                          qwen3_next_loss, qwen3_next_routing)
+from .glm4_moe_lite import (Glm4MoeLiteConfig, glm47_flash_config,
+                            glm4_moe_lite_init, glm4_moe_lite_share,
+                            glm4_moe_lite_apply, glm4_moe_lite_loss,
+                            glm4_moe_lite_routing)
 
 __all__ = [
     "TransformerConfig", "transformer_init", "transformer_apply",
@@ -42,4 +46,7 @@ __all__ = [
     "Qwen3NextConfig", "qwen3_next_80b_a3b_config", "qwen3_next_init",
     "qwen3_next_share", "qwen3_next_apply", "qwen3_next_loss",
     "qwen3_next_routing",
+    "Glm4MoeLiteConfig", "glm47_flash_config", "glm4_moe_lite_init",
+    "glm4_moe_lite_share", "glm4_moe_lite_apply", "glm4_moe_lite_loss",
+    "glm4_moe_lite_routing",
 ]

@@ -61,6 +61,8 @@ SCOPES = (
     "apex.shared_expert",  # inside apex.moe: the expert every token passes
     "apex.gdn",            # models.qwen3_next: a whole Gated DeltaNet mixer
     "apex.gdn_rule",       # inside it: decays and the chunked delta rule
+    "apex.mla",            # models.glm4_moe_lite: a whole latent-attention mixer
+    "apex.mtp",            # models.glm4_moe_lite: the MTP join, block, head, loss
 )
 
 # jax strips debug info - where a named scope lives - before it hashes the

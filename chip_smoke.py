@@ -36,6 +36,15 @@ user would call, at the full width of the models the repo supports:
               mixer (flash at D 256) against float32 twins, the sparse FFN
               at one walk of its buffer and at a forced three, forward and
               every gradient
+  glm4_moe_lite
+              GLM-4.7-Flash at the published widths and the benchmark
+              cell's share (--glm4-moe-lite 8 4: the dense layer, four
+              sparse layers with 8 of 64 experts top-4, the MTP module):
+              the whole cut on one sequence of 4096 through flash against
+              the same model through XLA attention, loss and every
+              parameter leaf's gradient; one latent-attention mixer through
+              flash at S 4096 against its float32 twin, output and every
+              gradient
   multichip   (when jax finds >= 4 devices) the trainers --distributed /
               --zero / --sync-bn and one step of every plan family on a
               4-device mesh, each device holding its share
@@ -161,6 +170,14 @@ def rel_err(got, want) -> float:
         denom = float(jnp.max(jnp.abs(w))) or 1.0
         worst = max(worst, float(jnp.max(jnp.abs(g - w))) / denom)
     return worst
+
+
+def norm_rel_err(got, want) -> float:
+    """|got - want| over |want| (2-norms), in float32."""
+    import jax.numpy as jnp
+    got, want = (jnp.asarray(t, jnp.float32) for t in (got, want))
+    return float(jnp.linalg.norm(got - want)) / (
+        float(jnp.linalg.norm(want)) or 1.0)
 
 
 def median_ms(fn, *args, runs: int = 10) -> float:
@@ -1244,6 +1261,115 @@ def phase_qwen3_next(ctx) -> dict:
     return facts
 
 
+def phase_glm4_moe_lite(ctx) -> dict:
+    """The ``--glm4-moe-lite 8 4`` share at the published widths (the
+    rehearsal: width 64), bfloat16: the whole cut — MTP module and its loss
+    term included — on one sequence through flash (``--attn fast``) against
+    the same parameters through XLA's ``attention_core`` (``--attn
+    default``), the loss and every parameter leaf's gradient; then one
+    latent-attention mixer through flash against its float32 twin through
+    ``attention_core`` at the highest matmul precision, output and every
+    gradient.  A leaf's error is the norm of its difference over its norm;
+    the routed leaves (router, experts) are reported and not limited, beside
+    the assignments the two attentions route differently (a score a hair
+    from the fourth largest falls either way in bfloat16, and moves a
+    token's whole share of those leaves); the others are held to 0.15, which
+    a wrong leaf (an error near 1) cannot meet and bfloat16 on both sides
+    at the rehearsal's width 64 does (up to 0.11)."""
+    import dataclasses
+    import functools
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from apex_tpu.models import glm4_moe_lite, glm4_moe_lite_init
+    from benchmarks.job import global_norm
+    pretrain = load_example("examples/bert/pretrain.py")
+    cfg = pretrain.glm4_moe_lite_config(pretrain.parse_args(
+        ["--glm4-moe-lite", "8", "4", "--vocab", "19456", "--attn", "fast",
+         "--remat"]))
+    seq = 4096 if ctx["full"] else 40
+    if not ctx["full"]:
+        cfg = dataclasses.replace(
+            cfg, vocab_size=256, hidden_size=64, intermediate_size=96,
+            num_attention_heads=4, q_lora_rank=24, kv_lora_rank=16,
+            qk_nope_head_dim=12, qk_rope_head_dim=4, v_head_dim=16,
+            num_experts=32, moe_intermediate_size=24, experts_held=(0, 4))
+    half = functools.partial(jax.tree_util.tree_map,
+                             lambda x: x.astype(jnp.bfloat16))
+    params = jax.jit(lambda key: half(glm4_moe_lite_init(key, cfg)))(
+        jax.random.PRNGKey(7))
+    tokens, targets, weights = pretrain.synthetic_next_token(
+        np.random.RandomState(0), 1, seq, cfg.vocab_size)
+    one = {"tokens": jnp.asarray(tokens), "targets": jnp.asarray(targets),
+           "weights": jnp.asarray(weights)}
+
+    def loss_and_grads(attn):
+        c = dataclasses.replace(cfg, attn_impl=attn)
+        loss = functools.partial(glm4_moe_lite.glm4_moe_lite_loss, cfg=c)
+        ids = jax.jit(functools.partial(glm4_moe_lite.glm4_moe_lite_routing,
+                                        cfg=c))(params, one)["ids"]
+        return jax.jit(jax.value_and_grad(loss))(params, one) + (ids,)
+    fast, twin = loss_and_grads("fast"), loss_and_grads("default")
+    leaves = {jax.tree_util.keystr(path): float(f"{norm_rel_err(g, w):.3e}")
+              for (path, w), g in zip(
+                  jax.tree_util.tree_leaves_with_path(twin[1]),
+                  jax.tree_util.tree_leaves(fast[1]))}
+    routed = ("'router'", "'w13'", "'w2'")
+    limited = {n: e for n, e in leaves.items()
+               if not any(r in n for r in routed)}
+    loss_err = abs(float(fast[0]) - float(twin[0])) / abs(float(twin[0]))
+    norm_err = rel_err(global_norm(fast[1]), global_norm(twin[1]))
+    worst = max(limited, key=limited.get)
+    facts = {"seq": seq, "loss": float(fast[0]),
+             "xla_attention_twin_loss": float(twin[0]),
+             "loss_rel_err": float(f"{loss_err:.3e}"),
+             "grad_norm_rel_err": float(f"{norm_err:.3e}"),
+             "routed_differently": int(jnp.sum(jnp.sort(fast[2], -1)
+                                               != jnp.sort(twin[2], -1))),
+             "assignments": int(fast[2].size), "leaves": len(leaves),
+             "worst_unrouted_leaf": [worst, limited[worst]],
+             "leaf_rel_err": leaves}
+    del fast, twin
+    if not (loss_err < 2e-3 and norm_err < 2e-2
+            and limited[worst] < 0.15):
+        raise AssertionError(f"the cut through flash against XLA attention: "
+                             f"loss {loss_err:.3e}, gradient norm "
+                             f"{norm_err:.3e}, worst {worst} "
+                             f"{limited[worst]}")
+
+    # -- one latent-attention mixer against its float32 twin ------------------
+    names = ("q_a", "q_a_norm", "q_b", "kv_a", "kv_a_norm", "kv_b", "o")
+    lp = {n: params["layers"][1][n] for n in names}
+    keys = jax.random.split(jax.random.PRNGKey(8), 2)
+    u = jax.random.normal(keys[0], (1, seq, cfg.hidden_size))
+    probe = jax.random.normal(keys[1], u.shape)
+    seen = functools.partial(jax.tree_util.tree_map,
+                             lambda x: x.astype(jnp.float32))
+
+    def mixer(dtype, attn):
+        def loss(x, lp, probe):
+            y = glm4_moe_lite._mla_mixer(x, lp, dataclasses.replace(
+                cfg, dtype=dtype, attn_impl=attn))
+            return jnp.sum(y.astype(jnp.float32) * probe), y
+        # the probe is an argument: closed over it would be a constant
+        return jax.jit(jax.value_and_grad(jax.checkpoint(loss),
+                                          argnums=(0, 1), has_aux=True))
+    with jax.default_matmul_precision("highest"):
+        (_, want), want_grads = mixer(jnp.float32, "default")(
+            seen(half(u)), seen(lp), probe)
+    (_, got), grads = mixer(jnp.bfloat16, "fast")(half(u), lp, probe)
+    errors = {"out": float(f"{rel_err(got, want):.3e}"),
+              "d_u": float(f"{rel_err(grads[0], want_grads[0]):.3e}"),
+              **{"d_" + n: float(f"{rel_err(grads[1][n], want_grads[1][n]):.3e}")
+                 for n in names}}
+    facts["mla_mixer"] = {"seq": seq, "heads": cfg.num_attention_heads,
+                          "head_dim": cfg.qk_head_dim, "rel_err": errors}
+    if not max(errors.values()) < 3e-2:
+        raise AssertionError(f"the latent-attention mixer against its "
+                             f"float32 twin: {errors}")
+    return facts
+
+
 def _plan_families():
     from apex_tpu.parallel import plan as pm
     return [("dp2xtp2", pm.Plan(dp=2, tp=2)),
@@ -1339,6 +1465,7 @@ PHASES = {
     "lfm2": phase_lfm2,
     "nemotron_h": phase_nemotron_h,
     "qwen3_next": phase_qwen3_next,
+    "glm4_moe_lite": phase_glm4_moe_lite,
     "multichip": phase_multichip,
 }
 MULTICHIP_DEVICES = 4

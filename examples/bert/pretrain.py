@@ -43,7 +43,9 @@ from apex_tpu.models import (TransformerConfig, bert_large_config,
                              nemotron_h_cut_pattern, nemotron_h_init,
                              nemotron_h_loss, Qwen3NextConfig,
                              qwen3_next_80b_a3b_config, qwen3_next_init,
-                             qwen3_next_loss)
+                             qwen3_next_loss, Glm4MoeLiteConfig,
+                             glm47_flash_config, glm4_moe_lite_init,
+                             glm4_moe_lite_loss)
 from apex_tpu.optimizers import FusedLAMB
 from apex_tpu.parallel import create_mesh, use_mesh
 from apex_tpu.utils.logging import AverageMeter, Throughput
@@ -104,6 +106,18 @@ def parse_args(argv=None):
                         "--vocab rows of the 151936 of embedding and head; "
                         "mixers, router (all 512 outputs) and shared expert "
                         "whole (docs/qwen3_next.md)")
+    p.add_argument("--glm4-moe-lite", type=int, nargs=2, default=None,
+                   metavar=("EP", "LAYERS"),
+                   help="GLM-4.7-Flash at its published widths (causal LM on "
+                        "next-token batches, the multi-token-prediction "
+                        "module and its loss term included), cut to one "
+                        "chip's share of an EP-way expert-parallel group: "
+                        "the first 1/EP of the 64 routed experts of each "
+                        "sparse layer, the dense layer and the first LAYERS "
+                        "sparse layers of the 47, --vocab rows of the 154880 "
+                        "of embedding and head; latent-attention mixers, "
+                        "router (all 64 outputs) and shared expert whole "
+                        "(docs/glm4_moe_lite.md)")
     p.add_argument("--distributed", action="store_true")
     p.add_argument("--zero", action="store_true",
                    help="ZeRO sharded optimizer (DistributedFusedLAMB)")
@@ -274,6 +288,7 @@ def run_standard(args, cfg, mesh):
         Lfm2Config: (lfm2_init, lfm2_loss),
         NemotronHConfig: (nemotron_h_init, nemotron_h_loss),
         Qwen3NextConfig: (qwen3_next_init, qwen3_next_loss),
+        Glm4MoeLiteConfig: (glm4_moe_lite_init, glm4_moe_lite_loss),
     }.get(type(cfg), (transformer_init, transformer_loss))
     opt = FusedLAMB(lr=args.lr, weight_decay=0.01, max_grad_norm=1.0,
                     impl="xla",
@@ -469,6 +484,18 @@ def qwen3_next_config(args):
         dtype=jnp.bfloat16, remat=args.remat, attn_impl=args.attn)
 
 
+def glm4_moe_lite_config(args):
+    """``--glm4-moe-lite EP LAYERS``: the published widths, and one chip's
+    share of experts, depth and ``--vocab``."""
+    ep, layers = args.glm4_moe_lite
+    whole = glm47_flash_config()
+    return glm47_flash_config(
+        vocab_size=args.vocab,
+        num_hidden_layers=whole.first_k_dense_replace + layers,
+        experts_held=(0, whole.num_experts // ep), dtype=jnp.bfloat16,
+        remat=args.remat, attn_impl=args.attn)
+
+
 def main(argv=None, report=None):
     """Train; returns the last printed loss.  ``report``, a dict the
     caller owns, is filled (standard and ``--zero`` paths) with what a
@@ -492,6 +519,11 @@ def main(argv=None, report=None):
                             or args.data):
         raise SystemExit("--qwen3-next is a model preset of the standard "
                          "path on synthetic next-token batches")
+    if args.glm4_moe_lite and (args.bert_large or args.lfm2 or args.nemotron_h
+                               or args.qwen3_next or args.moe or args.zero
+                               or args.plan or args.data):
+        raise SystemExit("--glm4-moe-lite is a model preset of the standard "
+                         "path on synthetic next-token batches")
     if args.plan and (args.moe or args.zero or args.distributed
                       or args.auto_resume):
         raise SystemExit("--plan owns the parallelism decision — it does "
@@ -506,6 +538,8 @@ def main(argv=None, report=None):
         cfg = nemotron_h_config(args)
     elif args.qwen3_next:
         cfg = qwen3_next_config(args)
+    elif args.glm4_moe_lite:
+        cfg = glm4_moe_lite_config(args)
     elif args.moe:
         cfg = MoETransformerConfig(
             vocab_size=args.vocab, max_len=args.seq_len,
@@ -525,7 +559,8 @@ def main(argv=None, report=None):
     if args.batch_size % n_dev:
         raise ValueError(f"batch {args.batch_size} must divide {n_dev}")
     mesh = create_mesh({"data": n_dev}, devices=jax.devices()[:n_dev])
-    causal_lm = bool(args.lfm2 or args.nemotron_h or args.qwen3_next)
+    causal_lm = bool(args.lfm2 or args.nemotron_h or args.qwen3_next
+                     or args.glm4_moe_lite)
     print(f"=> {n_dev} device(s), {'ZeRO' if args.zero else 'standard'} "
           f"optimizer, layers="
           f"{cfg.num_hidden_layers if causal_lm else cfg.num_layers} d="

@@ -74,6 +74,14 @@ def test_rehearsal_runs_every_phase_on_cpu():
     assert qwen["rule"]["path"] == ["jnp"] and "kernel_ms" not in qwen["rule"]
     assert (qwen["expert_layer_one_walk"]["walks"],
             qwen["expert_layer_three_walks"]["walks"]) == (1, 3)
+    # the GLM-4.7-Flash leg held every parameter leaf of the cut, the MTP
+    # module's among them, through flash to XLA attention, and one latent
+    # mixer to its float32 twin
+    glm = report["phases"]["glm4_moe_lite"]
+    assert glm["leaves"] == len(glm["leaf_rel_err"]) == 93
+    assert any(name.startswith("['mtp'][0]") for name in glm["leaf_rel_err"])
+    assert glm["loss_rel_err"] < 2e-3 and glm["assignments"] == 5 * 40 * 4
+    assert max(glm["mla_mixer"]["rel_err"].values()) < 3e-2
     # every plan family took its step on the 4-device mesh
     legs = report["phases"]["multichip"]["legs"]
     assert sum(k.startswith("family_") for k in legs) == 7

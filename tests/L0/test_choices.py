@@ -44,9 +44,9 @@ from apex_tpu.contrib.optimizers import (DistributedFusedAdam,
                                          DistributedFusedLAMB)
 from apex_tpu.contrib.xentropy import softmax_xentropy as sx
 from apex_tpu.mlp import MLP
-from apex_tpu.models import (Lfm2Config, NemotronHConfig, Qwen3NextConfig,
-                             TransformerConfig, bert_large_config,
-                             lfm2_cut_layer_types)
+from apex_tpu.models import (Glm4MoeLiteConfig, Lfm2Config, NemotronHConfig,
+                             Qwen3NextConfig, TransformerConfig,
+                             bert_large_config, lfm2_cut_layer_types)
 from apex_tpu.optimizers import FusedAdam
 from apex_tpu.parallel import collectives, expert, overlap
 from apex_tpu.parallel.mesh import create_mesh, use_mesh
@@ -132,6 +132,10 @@ def _attention_cells():
         elif cfg["job"] == "qwen3_next_pretrain":
             heads = model["num_attention_heads"]
             width, causal = heads * model["head_dim"], True
+        elif cfg["job"] == "glm4_moe_lite_pretrain":
+            # latent attention: the QK head (nope + rope) is as wide as V's
+            heads = model["num_attention_heads"]
+            width, causal = heads * model["v_head_dim"], True
         else:
             continue                    # resnet50: no attention
         cells[w["name"]] = (traffic["batch"] // w["chips"] * heads,
@@ -156,6 +160,8 @@ CELL_TILES = {
     "bert_large.s128_b544": ((128, 128), ("whole_key", 128, 128, 1)),
     "qwen3_next_80b_a3b.ep16_s4096": (
         (512, 1024), ("resident", 512, 512, 8)),      # BH 128, D 256
+    "glm47_flash_30b_a3b.ep8_s4096": (
+        (512, 1024), ("resident", 512, 512, 8)),      # BH 80, D 256
 }
 
 
@@ -183,6 +189,7 @@ CELL_BUFFERS = {
     "lfm2_24b_a2b.ep8_s4096": (32768, 131072),
     "nemotron3_super_120b_a12b.tp8_ep64_s8192": (11264, 360448),
     "qwen3_next_80b_a3b.ep16_s4096": (40960, 327680),
+    "glm47_flash_30b_a3b.ep8_s4096": (16384, 65536),
 }
 
 
@@ -196,6 +203,8 @@ CELL_SUM_ROWS = {
     "nemotron3_super_120b_a12b.tp8_ep64_s8192": (True, 27648),
     # 10 · 40 960 + 32 768 > 327 680: ten gathers of T rows a sum
     "qwen3_next_80b_a3b.ep16_s4096": (False, 327680),
+    # LFM2's experts at half its tokens: 4 · 16 384 + 16 384 > 65 536
+    "glm47_flash_30b_a3b.ep8_s4096": (False, 65536),
 }
 
 
@@ -346,7 +355,7 @@ def test_cell_choice(cell, chooser, monkeypatch, flash_events):
 # ---------------------------------------------------------------------------
 
 #: the smallest model of each configuration's type, through the builder its
-#: cells' job calls: ``run_standard`` for the four ``*_pretrain`` jobs
+#: cells' job calls: ``run_standard`` for the five ``*_pretrain`` jobs
 _TINY_MODELS = {
     "bert_pretrain": lambda: TransformerConfig(
         vocab_size=128, max_len=16, num_layers=2, d_model=32, num_heads=2,
@@ -373,6 +382,12 @@ _TINY_MODELS = {
         num_experts=8, num_experts_per_tok=2, moe_intermediate_size=16,
         shared_expert_intermediate_size=16, experts_held=(0, 4),
         xent_impl="xla"),
+    "glm4_moe_lite_pretrain": lambda: Glm4MoeLiteConfig(
+        vocab_size=128, hidden_size=32, num_hidden_layers=2,
+        intermediate_size=64, num_attention_heads=2, q_lora_rank=16,
+        kv_lora_rank=8, qk_nope_head_dim=12, qk_rope_head_dim=4,
+        v_head_dim=16, num_experts=8, num_experts_per_tok=2,
+        moe_intermediate_size=16, experts_held=(0, 4), xent_impl="xla"),
 }
 
 

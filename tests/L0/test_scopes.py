@@ -20,12 +20,13 @@ import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
 from apex_tpu import amp, pyprof
-from apex_tpu.models import (Lfm2Config, NemotronHConfig, Qwen3NextConfig,
-                             TransformerConfig, lfm2_cut_layer_types,
-                             lfm2_init, lfm2_loss, nemotron_h_init,
-                             nemotron_h_loss, qwen3_next_init,
-                             qwen3_next_loss, transformer_init,
-                             transformer_loss)
+from apex_tpu.models import (Glm4MoeLiteConfig, Lfm2Config, NemotronHConfig,
+                             Qwen3NextConfig, TransformerConfig,
+                             glm4_moe_lite_init, glm4_moe_lite_loss,
+                             lfm2_cut_layer_types, lfm2_init, lfm2_loss,
+                             nemotron_h_init, nemotron_h_loss,
+                             qwen3_next_init, qwen3_next_loss,
+                             transformer_init, transformer_loss)
 from apex_tpu.optimizers import FusedLAMB
 from apex_tpu.parallel import DistributedDataParallel
 
@@ -61,6 +62,14 @@ QWEN3_NEXT = Qwen3NextConfig(
     num_experts=32, num_experts_per_tok=4, moe_intermediate_size=24,
     shared_expert_intermediate_size=24, experts_held=(0, 8),
     dtype=jnp.bfloat16, remat=True, attn_impl="fast")
+# ... and the fifth: GLM-4.7-Flash's dense layer, a sparse layer and its
+# MTP module, a share of the experts held
+GLM4_MOE_LITE = Glm4MoeLiteConfig(
+    vocab_size=256, hidden_size=64, num_hidden_layers=2,
+    intermediate_size=96, num_attention_heads=4, q_lora_rank=24,
+    kv_lora_rank=16, qk_nope_head_dim=12, qk_rope_head_dim=4, v_head_dim=16,
+    num_experts=32, num_experts_per_tok=4, moe_intermediate_size=24,
+    experts_held=(0, 8), dtype=jnp.bfloat16, remat=True, attn_impl="fast")
 #: the blocks only the LFM2 step enters (the Nemotron-H step enters the
 #: last three of them too)
 LFM2_ONLY = ("apex.conv", "apex.moe", "apex.router", "apex.experts")
@@ -70,6 +79,8 @@ NEMOTRON_H_ONLY = ("apex.ssm", "apex.ssm_scan", "apex.latent",
                    "apex.shared_expert")
 #: the blocks only the Qwen3-Next step enters
 QWEN3_NEXT_ONLY = ("apex.gdn", "apex.gdn_rule")
+#: the blocks only the GLM-4.7-Flash step enters
+GLM4_MOE_LITE_ONLY = ("apex.mla", "apex.mtp")
 # "%name = <type, maybe a tuple> opcode(operands), ..., metadata={op_name=..."
 _INSTRUCTION = re.compile(
     r' = .*? ([a-z][\w-]*)\(.*metadata=\{[^}]*op_name="([^"]*)"')
@@ -138,6 +149,14 @@ def qwen3_next_step_ops():
         *_state_and_batch(2, qwen3_next_init, QWEN3_NEXT))
 
 
+@pytest.fixture(scope="module")
+def glm4_moe_lite_step_ops():
+    return _op_names(
+        functools.partial(_step, loss_impl=glm4_moe_lite_loss,
+                          cfg=GLM4_MOE_LITE),
+        *_state_and_batch(2, glm4_moe_lite_init, GLM4_MOE_LITE))
+
+
 def test_scopes_are_a_fixed_vocabulary():
     assert len(set(pyprof.SCOPES)) == len(pyprof.SCOPES)
     for name in pyprof.SCOPES:
@@ -146,7 +165,8 @@ def test_scopes_are_a_fixed_vocabulary():
 
 @pytest.mark.parametrize("ops", ["step_ops", "lfm2_step_ops",
                                  "nemotron_h_step_ops",
-                                 "qwen3_next_step_ops"])
+                                 "qwen3_next_step_ops",
+                                 "glm4_moe_lite_step_ops"])
 def test_every_matmul_belongs_to_a_block(ops, request):
     matmuls = [path for opcode, path in request.getfixturevalue(ops)
                if opcode in ("dot", "convolution", "ragged-dot")]
@@ -158,9 +178,20 @@ def test_every_matmul_belongs_to_a_block(ops, request):
 @pytest.mark.parametrize("name", pyprof.SCOPES)
 def test_scope_occurs_in_the_step(name, step_ops, ddp_step_ops,
                                   lfm2_step_ops, nemotron_h_step_ops,
-                                  qwen3_next_step_ops):
+                                  qwen3_next_step_ops,
+                                  glm4_moe_lite_step_ops):
     one_chip = {path for _, path in step_ops if name in path}
-    if name in QWEN3_NEXT_ONLY:
+    if name in GLM4_MOE_LITE_ONLY:
+        # no other step has such a block; the GLM-4.7-Flash step enters it
+        # forward, backward and in remat's second forward
+        assert not one_chip
+        for ops in (lfm2_step_ops, nemotron_h_step_ops, qwen3_next_step_ops):
+            assert not [path for _, path in ops if name in path]
+        paths = [path for _, path in glm4_moe_lite_step_ops if name in path]
+        for mark in ("transpose(", "rematted_computation"):
+            assert any(mark in path for path in paths), (name, mark)
+        assert any("transpose(" not in path for path in paths), name
+    elif name in QWEN3_NEXT_ONLY:
         # no other step has such a block; the Qwen3-Next step enters it
         # forward, backward and in remat's second forward, the rule inside
         # the mixer
@@ -257,6 +288,30 @@ def test_qwen3_next_step_reuses_the_shared_blocks(name, qwen3_next_step_ops):
     if name in ("apex.router", "apex.experts", "apex.shared_expert"):
         assert all(path.index("apex.moe") < path.index(name)
                    for path in paths), name
+
+
+@pytest.mark.parametrize("name", ["apex.embed", "apex.mla", "apex.flash",
+                                  "apex.mlp", "apex.moe", "apex.router",
+                                  "apex.experts", "apex.shared_expert",
+                                  "apex.head", "apex.loss", "apex.mtp",
+                                  "apex.amp_step"])
+def test_glm4_moe_lite_step_nests_its_blocks(name, glm4_moe_lite_step_ops):
+    """Latent attention is ``apex.mla`` with ``apex.flash`` inside, the
+    dense FFN ``apex.mlp``, the sparse FFN ``apex.moe`` with router, experts
+    and the shared expert inside; the MTP module's block, head and loss nest
+    inside ``apex.mtp`` as they lie in the trunk."""
+    paths = [path for _, path in glm4_moe_lite_step_ops if name in path]
+    assert paths, name
+    inner = {"apex.flash": "apex.mla", "apex.router": "apex.moe",
+             "apex.experts": "apex.moe", "apex.shared_expert": "apex.moe"}
+    if name in inner:
+        assert all(path.index(inner[name]) < path.index(name)
+                   for path in paths), name
+    if name in ("apex.mla", "apex.moe", "apex.head", "apex.loss"):
+        # the trunk's, and the MTP module's inside its scope
+        assert any("apex.mtp" not in path for path in paths), name
+        assert any("apex.mtp" in path and path.index("apex.mtp")
+                   < path.index(name) for path in paths), name
 
 
 def test_update_blocks_nest_inside_amp_step(step_ops):
