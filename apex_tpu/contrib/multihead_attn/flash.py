@@ -49,6 +49,7 @@ backward : recompute-based (flash bwd).  One algorithm whose tile and
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -289,7 +290,20 @@ def vmem_estimate(bq, bk, D, esz, bias_per_q, bwd=False, sk=None) -> int:
     by its own report, and the kernel's whole need came to 16.2 MiB at
     512 x 512 with dropout, 25.0 at S 8192 x 256 x 512, where this model
     says 18.25 and 27.5; PERF.md section 6, PR 28), the bias block at
-    ``sk`` columns, the tile at (bq, bk).
+    ``sk`` columns, the tile at (bq, bk).  ``"packed"`` models the
+    projection layout's backward (:func:`_bwd_packed_kernel`), whose tile
+    holds a pair of heads' every query and key (``bq = bk = S``, ``D`` the
+    pair's 128 lanes): q, k, v, dO and O blocks, the bias block and the two
+    ``lse`` rows (sublane-padded to 8), each double-buffered; the three
+    (S, D) blocks the gradients leave from by DMA; one head's (S, S) tile
+    (Mosaic frees it before the next head's); both heads' f32 dq, dk and
+    dv; the ``lse`` and ``delta`` columns.  It leaves out the lane-masked
+    operands and the dropout hash, and is still above what Mosaic needs
+    for a described v5e at every shape it admits, with dropout and causal
+    masking: 7.38 MiB at S 512 in bf16 with dropout where it says 7.69
+    (PERF.md section 6, the projection layout;
+    ``tests/L0/test_gated_delta_rule.py`` compiles each admitted shape
+    within it).
 
     Every backward model counts what dominates a large tile: the (bq, bk)
     f32 intermediates of the recompute (``s``/``p``, ``dp``, ``ds``) and
@@ -309,6 +323,12 @@ def vmem_estimate(bq, bk, D, esz, bias_per_q, bwd=False, sk=None) -> int:
         bias = max(8, bq if bias_per_q else 1) * keys * 4   # sublane-padded
         scratch = (2 * keys + bq) * lanes * 4           # dk, dv, dq accumulators
         return 2 * (io + bias) + scratch + tile
+    if bwd == "packed":
+        io = 5 * bq * D * esz + 8 * bq * 4          # q, k, v, do, o; lse rows
+        bias = max(8, bq if bias_per_q else 1) * bk * 4
+        staged = 3 * bq * D * esz                   # dq, dk, dv out by DMA
+        grads = 6 * bq * D * 4                      # both heads' dq, dk, dv
+        return 2 * (io + bias) + staged + tile + grads + columns
     if bwd in ("dq", "dkv", "fused"):
         # streams common to every backward kernel: q, k, v, do, lse, delta
         io = (2 * bq * D + 2 * bk * D) * esz + columns
@@ -365,11 +385,48 @@ def _dropout_keep(seed, bh, row0, col0, shape, rate):
 # forward
 # ---------------------------------------------------------------------------
 
+def _head_lanes(j, hd, shape):
+    """The lanes of head ``j`` in a block that holds several heads of
+    ``hd`` side by side (the projection layout: two heads of 64 a 128-lane
+    block)."""
+    lanes = jax.lax.broadcasted_iota(jnp.int32, shape, len(shape) - 1)
+    return (lanes >= j * hd) & (lanes < (j + 1) * hd)
+
+
+def _only(mine, x):
+    """``x`` with every lane outside ``mine`` zeroed: a product over the
+    block's 128 lanes is then one head's product, and costs the MXU the
+    pass a 64-wide operand costs anyway."""
+    return jnp.where(mine, x, jnp.zeros_like(x))
+
+
+def _head_of(block, pack, j):
+    """The b·H + h index of head ``j`` of grid block ``block`` (= b·H/pack +
+    h // pack), which the dropout mask hashes: a mask is the same whichever
+    layout the head was read from."""
+    return block if pack == 1 else pack * block + j
+
+
+def _scaled(q, scale):
+    """q times the softmax scale, rounded to q's dtype: what the transposing
+    path hands the kernel pre-scaled (1/8 at hd 64 is exact)."""
+    if scale is None:
+        return q
+    return (q.astype(jnp.float32) * scale).astype(q.dtype)
+
+
 def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
                 m_ref, l_ref, acc_ref, *, bq, bk, causal, dropout_rate,
-                heads):
+                heads, pack=1, scale=None):
+    """One (q block, k block) step of the online softmax for ``pack`` heads
+    side by side in the block's lanes.  ``pack`` 1: the (B·H, S, D) layout,
+    ``lse`` a (bq, 1) column.  ``pack`` 2: the projection layout — two heads
+    of 64 a 128-lane block; each head's product takes the block with the
+    other head's lanes of q and v zeroed, ``m`` / ``l`` are a column a head
+    and ``lse`` leaves as ``pack`` rows with the queries on the lanes."""
     bh, qi, ki = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     nk = pl.num_programs(2)
+    hd = q_ref.shape[-1] // pack
 
     @pl.when(ki == 0)
     def _():
@@ -384,47 +441,78 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
 
     @pl.when(run)
     def _():
-        # matmuls take the native dtype (bf16 rides the MXU at full rate)
-        # and accumulate in f32 via preferred_element_type
-        s = jax.lax.dot_general(q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        s = s + bias_ref[0].astype(jnp.float32)               # (bq|1, bk)
-        if causal:
-            rows = qi * bq + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            cols = ki * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(cols <= rows, s, NEG_INF)
+        q = _scaled(q_ref[0], scale)
+        for j in range(pack):
+            mine = _head_lanes(j, hd, q.shape) if pack > 1 else None
+            # matmuls take the native dtype (bf16 rides the MXU at full
+            # rate) and accumulate in f32 via preferred_element_type
+            s = jax.lax.dot_general(
+                q if mine is None else _only(mine, q), k_ref[0],
+                (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+            s = s + bias_ref[0].astype(jnp.float32)           # (bq|1, bk)
+            if causal:
+                rows = qi * bq + jax.lax.broadcasted_iota(jnp.int32,
+                                                          s.shape, 0)
+                cols = ki * bk + jax.lax.broadcasted_iota(jnp.int32,
+                                                          s.shape, 1)
+                s = jnp.where(cols <= rows, s, NEG_INF)
 
-        m_old = m_ref[:, 0]
-        m_new = jnp.maximum(m_old, jnp.max(s, axis=1))
-        scale = jnp.exp(m_old - m_new)
-        p = jnp.exp(s - m_new[:, None])                       # (bq, bk)
-        l_ref[:, 0] = l_ref[:, 0] * scale + jnp.sum(p, axis=1)
-        m_ref[:, 0] = m_new
+            m_old = m_ref[:, j]
+            m_new = jnp.maximum(m_old, jnp.max(s, axis=1))
+            scale_j = jnp.exp(m_old - m_new)
+            p = jnp.exp(s - m_new[:, None])                   # (bq, bk)
+            l_ref[:, j] = l_ref[:, j] * scale_j + jnp.sum(p, axis=1)
+            m_ref[:, j] = m_new
 
-        if dropout_rate > 0.0:
-            keep = _dropout_keep(seed_ref[0], bh, qi * bq, ki * bk, p.shape,
-                                 dropout_rate)
-            p = p * keep / (1.0 - dropout_rate)
+            if dropout_rate > 0.0:
+                keep = _dropout_keep(seed_ref[0], _head_of(bh, pack, j),
+                                     qi * bq, ki * bk, p.shape, dropout_rate)
+                p = p * keep / (1.0 - dropout_rate)
 
-        v = v_ref[0]                                          # (bk, d)
-        acc_ref[:] = acc_ref[:] * scale[:, None] + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            v = v_ref[0]                                      # (bk, d)
+            if mine is None:
+                acc_ref[:] = acc_ref[:] * scale_j[:, None] + \
+                    jax.lax.dot_general(p.astype(v.dtype), v,
+                                        (((1,), (0,)), ((), ())),
+                                        preferred_element_type=jnp.float32)
+            else:       # the product is zero outside the head's lanes
+                acc_ref[:] = acc_ref[:] * jnp.where(
+                    mine[:1], scale_j[:, None], 1.0) + jax.lax.dot_general(
+                        p.astype(v.dtype),
+                        _only(_head_lanes(j, hd, v.shape), v),
+                        (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32)
 
     @pl.when(ki == nk - 1)
     def _():
-        l = l_ref[:, 0]
-        safe_l = jnp.where(l == 0.0, 1.0, l)
-        # a row whose max never rose above the mask floor saw only masked
-        # keys: emit zeros (constant NEG_INF bias cancels in the online
-        # softmax, so without this test pad content would leak through)
-        dead = m_ref[:, 0] <= NEG_INF / 2
-        o = acc_ref[:] / safe_l[:, None]
-        o_ref[0] = jnp.where(dead[:, None], 0.0, o).astype(o_ref.dtype)
-        # dead rows store +NEG_INF-magnitude lse so the backward's
-        # exp(s - lse) underflows to 0 (zero grads for dead rows)
-        lse_ref[0, :, 0] = jnp.where(dead, -NEG_INF,
-                                     m_ref[:, 0] + jnp.log(safe_l))
+        out = stats = None
+        for j in range(pack):
+            l = l_ref[:, j]
+            safe_l = jnp.where(l == 0.0, 1.0, l)
+            # a row whose max never rose above the mask floor saw only
+            # masked keys: emit zeros (constant NEG_INF bias cancels in the
+            # online softmax, so without this test pad content would leak
+            # through)
+            dead = m_ref[:, j] <= NEG_INF / 2
+            o = acc_ref[:] / safe_l[:, None]
+            o = jnp.where(dead[:, None], 0.0, o)
+            if pack == 1:
+                o_ref[0] = o.astype(o_ref.dtype)
+            # dead rows store +NEG_INF-magnitude lse so the backward's
+            # exp(s - lse) underflows to 0 (zero grads for dead rows)
+            lse = jnp.where(dead, -NEG_INF, m_ref[:, j] + jnp.log(safe_l))
+            if pack == 1:
+                lse_ref[0, :, 0] = lse
+            elif out is None:
+                out, stats = o, jnp.broadcast_to(lse[:, None], o.shape)
+            else:
+                out = jnp.where(_head_lanes(j, hd, o.shape), o, out)
+                stats = jnp.where(_head_lanes(j, 1, o.shape), lse[:, None],
+                                  stats)
+        if pack > 1:
+            o_ref[0] = out.astype(o_ref.dtype)
+            # head j's lse sits in lane j: one transpose makes them rows
+            lse_ref[0, 0] = jnp.transpose(stats)[:pack]
 
 
 def _bias_spec(bias, heads, bq, bk):
@@ -542,14 +630,41 @@ def _flash_fwd(q, k, v, bias, causal, dropout_rate, seed, heads,
 # ---------------------------------------------------------------------------
 
 def _recompute_p(q_ref, k_ref, bias_ref, lse_ref, qi, ki, bq, bk, causal):
-    s = jax.lax.dot_general(q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+    s = _recompute_s(q_ref[0], k_ref[0], bias_ref, qi, ki, bq, bk, causal)
+    return jnp.exp(s - lse_ref[0, :, 0][:, None])             # (bq, bk)
+
+
+def _recompute_s(q, k, bias_ref, qi, ki, bq, bk, causal):
+    """The scores of one (q tile, key block) of one head, bias and causal
+    mask applied."""
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
     s = s + bias_ref[0].astype(jnp.float32)
     if causal:
         rows = qi * bq + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         cols = ki * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(cols <= rows, s, NEG_INF)
-    return jnp.exp(s - lse_ref[0, :, 0][:, None])             # (bq, bk)
+    return s
+
+
+def _tile_grads(p, keep, q, k, v, do, delta):
+    """dv, dk and dq (float32) of one recomputed tile of one head: ``p`` the
+    softmax (bq, bk), ``keep`` the dropout mask over 1 - rate (or None),
+    ``q`` / ``do`` the tile's query rows, ``k`` / ``v`` its keys, ``delta``
+    the (bq, 1) rowsum(dO·O).  One recompute feeds all three."""
+    pd = p if keep is None else p * keep
+    dv = jax.lax.dot_general(pd.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    if keep is not None:
+        dp = dp * keep
+    ds = p * (dp - delta)                                     # (bq, bk)
+    dk = jax.lax.dot_general(ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    dq = jax.lax.dot_general(ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    return dv, dk, dq
 
 
 def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
@@ -665,30 +780,15 @@ def _bwd_fused_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref,
     def _():
         p = _recompute_p(q_ref, k_ref, bias_ref, lse_ref, qi, ki, bq, bk,
                          causal)                              # (bq, bk)
-        do = do_ref[0]                                        # (bq, d)
+        keep = None
         if dropout_rate > 0.0:
             keep = _dropout_keep(seed_ref[0], bh, qi * bq, ki * bk, p.shape,
                                  dropout_rate) / (1.0 - dropout_rate)
-            pd = p * keep
-        else:
-            pd = p
-        # dv += pd^T @ do
-        dv_acc[:] += jax.lax.dot_general(pd.astype(do.dtype), do,
-                                         (((0,), (0,)), ((), ())),
-                                         preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v_ref[0], (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        if dropout_rate > 0.0:
-            dp = dp * keep
-        ds = p * (dp - delta_ref[0, :, 0][:, None])           # (bq, bk)
-        q = q_ref[0]
-        dk_acc[:] += jax.lax.dot_general(ds.astype(q.dtype), q,
-                                         (((0,), (0,)), ((), ())),
-                                         preferred_element_type=jnp.float32)
-        k = k_ref[0]
-        dq_ref[dq_blk] = jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32).astype(dq_ref.dtype)
+        dv, dk, dq = _tile_grads(p, keep, q_ref[0], k_ref[0], v_ref[0],
+                                 do_ref[0], delta_ref[0, :, 0][:, None])
+        dv_acc[:] += dv
+        dk_acc[:] += dk
+        dq_ref[dq_blk] = dq.astype(dq_ref.dtype)
 
     if causal:
         @pl.when(jnp.logical_not(run))
@@ -1223,3 +1323,258 @@ def _vjp_bwd(causal, dropout_rate, heads, backward, res, do):
 
 
 flash_attention.defvjp(_vjp_fwd, _vjp_bwd)
+
+
+# ---------------------------------------------------------------------------
+# the projection's layout: self-attention read from and written to (B, S, ·)
+# ---------------------------------------------------------------------------
+#
+# A model computes q, k and v as ONE projection (B, S, 3·H·hd) and feeds its
+# output projection from (B, S, H·hd).  The (B·H, S, hd) entry above needs
+# both transposed around it: at hd 64 each such copy moves twice the bytes
+# the data holds (a 64-wide row pads to 128 lanes in HBM), and so does every
+# (B·H, S, 1) float32 ``lse`` / ``delta`` column.  Where the shape allows,
+# the kernels below index the heads along the projection's last axis
+# instead — one 128-lane block holds two heads of 64 (``pack`` 2 of
+# ``_fwd_kernel``) — write the context in place, keep ``lse`` as rows
+# (B, H/2, 2, S) with the queries on the lanes, and write the three
+# gradients straight into the (B, S, 3·H·hd) cotangent of the projection.
+
+_PACK = 2           # heads a 128-lane block
+
+
+def _packed_tile(S, heads, hd, esz, bias_per_q):
+    """The backward's tile ``(S, S)`` where the projection layout engages,
+    else None: heads of 64 in pairs, S whole 128-lane blocks up to the
+    whole-key tile's ``_WHOLE_KEY_MAX_BQ`` rows, the Pallas backward (the
+    ``"auto"`` chain of :func:`_resolve_backward`), nobody's block or
+    strategy pins (they name the (B·H, S, D) kernels), and a pair's whole
+    tile within the VMEM budget by the ``"packed"`` model of
+    :func:`vmem_estimate` (S 128 to 384 in either precision with either
+    bias, S 512 in bf16 with a bias over keys alone: the shapes Mosaic is
+    shown to take in ``tests/L0/test_gated_delta_rule.py``).  Every other
+    shape takes the transposing path around :func:`flash_attention`."""
+    if (hd * _PACK != 128 or heads % _PACK or S % 128
+            or S > _WHOLE_KEY_MAX_BQ
+            or _resolve_backward("auto") != "pallas"
+            or _chosen_blocks(None, None, "fused") != (None, None)
+            or _chosen_blocks(None, None, False) != (None, None)
+            or _forced_fuse(None) is False
+            or vmem_estimate(S, S, _PACK * hd, esz, bias_per_q, "packed")
+            > _vmem_budget()):
+        return None
+    fwd = _clamp_blocks(None, None, _PACK * hd, esz, bias_per_q, sq=S, sk=S)
+    return (S, S) if S % fwd[0] == 0 and S % fwd[1] == 0 else None
+
+
+def _flash_fwd_packed(qkv, bias, causal, dropout_rate, seed, heads):
+    """qkv (B, S, 3·H·64) as projected (q unscaled), bias (1|B, 1|S, S).
+    Returns the context (B, S, H·64) and lse (B, H/2, 2, S) f32 — head
+    2·g + j's row of queries at [b, g, j]."""
+    B, S, width = qkv.shape
+    W = width // 3
+    pairs, lanes = heads // _PACK, W // (heads // _PACK)
+    _check_bias_layout(qkv, bias, 1)
+    bq, bk = _clamp_blocks(None, None, lanes, qkv.dtype.itemsize,
+                           bias_per_q=bias.shape[1] != 1, sq=S, sk=S)
+    seed_arr = jnp.reshape(jnp.asarray(seed, jnp.int32), (1,))
+    vma = _out_vma(qkv, bias)
+    b_bcast, q_bcast = bias.shape[0] == 1, bias.shape[1] == 1
+
+    def part(t, rows):      # q (t 0), k (1) or v (2) of pair i % pairs
+        return pl.BlockSpec(
+            (1, rows, lanes),
+            lambda i, qi, ki: (i // pairs, qi if t == 0 else ki,
+                               t * pairs + i % pairs),
+            memory_space=pltpu.VMEM)
+
+    out, lse = pl.pallas_call(
+        functools.partial(_fwd_kernel, bq=bq, bk=bk, causal=causal,
+                          dropout_rate=dropout_rate, heads=heads,
+                          pack=_PACK, scale=1.0 / math.sqrt(W // heads)),
+        grid=(B * pairs, S // bq, S // bk),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),     # seed
+                  part(0, bq), part(1, bk), part(2, bk),
+                  pl.BlockSpec((1, 1 if q_bcast else bq, bk),
+                               lambda i, qi, ki: (
+                                   0 if b_bcast else i // pairs,
+                                   0 if q_bcast else qi, ki),
+                               memory_space=pltpu.VMEM)],
+        out_specs=[
+            pl.BlockSpec((1, bq, lanes),
+                         lambda i, qi, ki: (i // pairs, qi, i % pairs),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, 1, _PACK, bq),
+                         lambda i, qi, ki: (i // pairs, i % pairs, 0, qi),
+                         memory_space=pltpu.VMEM),
+        ],
+        out_shape=[_sds((B, S, W), qkv.dtype, vma),
+                   _sds((B, pairs, _PACK, S), jnp.float32, vma)],
+        scratch_shapes=[pltpu.VMEM((bq, _PACK), jnp.float32),
+                        pltpu.VMEM((bq, _PACK), jnp.float32),
+                        pltpu.VMEM((bq, lanes), jnp.float32)],
+        compiler_params=_compiler_params(
+            ("parallel", "parallel", "arbitrary")),
+        interpret=_interpret(),
+        name="apex_flash_fwd",
+    )(seed_arr, qkv, qkv, qkv, bias)
+    return out, lse
+
+
+def _bwd_packed_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref,
+                       o_ref, lse_ref, dqkv_ref, dq_buf, dk_buf, dv_buf,
+                       sems, *, pairs, causal, dropout_rate, scale):
+    """The three gradients of one pair of heads of one sequence, whole (the
+    tile holds every query and every key: ``_packed_tile``).  Each head's
+    tile is :func:`_bwd_fused_kernel`'s (:func:`_tile_grads`) on the block
+    with the other head's lanes of q, k and dO zeroed, so its dq, dk and dv
+    land in the head's own lanes and the pair's blocks are sums.  ``delta``
+    is rowsum(dO·O) over the head's lanes, a column here; ``lse`` arrives as
+    rows and one transpose makes them columns.  The blocks go by DMA to the
+    pair's three column blocks of the projection's cotangent (a BlockSpec
+    output is one block of one array); the step after waits for them before
+    it refills the buffers, so the copies overlap its work."""
+    i, n = pl.program_id(0), pl.num_programs(0)
+    b, g = i // pairs, i % pairs
+    q = _scaled(q_ref[0], scale)
+    k, v, do = k_ref[0], v_ref[0], do_ref[0]
+    seq, hd = q.shape[0], q.shape[1] // _PACK
+    lse = jnp.transpose(lse_ref[0, 0])                        # (S, pack)
+    grads = None                        # dv, dk, dq: each head in its lanes
+    for j in range(_PACK):
+        mine = _head_lanes(j, hd, q.shape)
+        q_j, k_j, do_j = _only(mine, q), _only(mine, k), _only(mine, do)
+        p = jnp.exp(_recompute_s(q_j, k, bias_ref, 0, 0, seq, seq, causal)
+                    - lse[:, j:j + 1])                        # (S, S)
+        delta = jnp.sum(do_j.astype(jnp.float32)
+                        * o_ref[0].astype(jnp.float32), axis=1, keepdims=True)
+        keep = None
+        if dropout_rate > 0.0:
+            keep = _dropout_keep(seed_ref[0], _head_of(i, _PACK, j), 0, 0,
+                                 p.shape, dropout_rate) / (1.0 - dropout_rate)
+        head = _tile_grads(p, keep, q_j, k_j, v, do_j, delta)
+        grads = head if grads is None else [a + h for a, h in zip(grads, head)]
+    dv, dk, dq = grads
+
+    def copies():
+        return [pltpu.make_async_copy(
+            buf, dqkv_ref.at[b, :, pl.ds(
+                pl.multiple_of((t * pairs + g) * buf.shape[-1], 128),
+                buf.shape[-1])], sems.at[t])
+            for t, buf in enumerate((dq_buf, dk_buf, dv_buf))]
+
+    @pl.when(i > 0)
+    def _():
+        for c in copies():
+            c.wait()
+
+    dq_buf[...] = (dq * scale).astype(dq_buf.dtype)
+    dk_buf[...] = dk.astype(dk_buf.dtype)
+    dv_buf[...] = dv.astype(dv_buf.dtype)
+    for c in copies():
+        c.start()
+
+    @pl.when(i == n - 1)
+    def _():
+        for c in copies():
+            c.wait()
+
+
+def _flash_bwd_packed(qkv, bias, causal, dropout_rate, seed, heads, out,
+                      lse, do):
+    """d(qkv) (B, S, 3·H·64) from the projection, the context ``out`` and its
+    cotangent ``do`` (B, S, H·64) and the packed forward's ``lse`` rows."""
+    B, S, width = qkv.shape
+    W = width // 3
+    pairs, lanes = heads // _PACK, W // (heads // _PACK)
+    _tel_events.record_flash_bwd("projection", S, S, 1)
+    seed_arr = jnp.reshape(jnp.asarray(seed, jnp.int32), (1,))
+    b_bcast, q_bcast = bias.shape[0] == 1, bias.shape[1] == 1
+
+    def block(t):           # column block t·pairs + pair of a (B, S, ·) array
+        return pl.BlockSpec((1, S, lanes),
+                            lambda i: (i // pairs, 0, t * pairs + i % pairs),
+                            memory_space=pltpu.VMEM)
+
+    return pl.pallas_call(
+        functools.partial(_bwd_packed_kernel, pairs=pairs, causal=causal,
+                          dropout_rate=dropout_rate,
+                          scale=1.0 / math.sqrt(W // heads)),
+        grid=(B * pairs,),
+        in_specs=[
+            pl.BlockSpec(memory_space=pltpu.SMEM),           # seed
+            block(0), block(1), block(2),                    # q, k, v
+            pl.BlockSpec((1, 1 if q_bcast else S, S),
+                         lambda i: (0 if b_bcast else i // pairs, 0, 0),
+                         memory_space=pltpu.VMEM),
+            block(0), block(0),                              # do, out
+            pl.BlockSpec((1, 1, _PACK, S),
+                         lambda i: (i // pairs, i % pairs, 0, 0),
+                         memory_space=pltpu.VMEM),
+        ],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        out_shape=_sds((B, S, width), qkv.dtype,
+                       _out_vma(qkv, bias, out, lse, do)),
+        scratch_shapes=[pltpu.VMEM((S, lanes), qkv.dtype)] * 3
+        + [pltpu.SemaphoreType.DMA((3,))],
+        # a step waits for the copies of the step before it: in order
+        compiler_params=_compiler_params(("arbitrary",)),
+        interpret=_interpret(),
+        name="apex_flash_bwd_fused",
+    )(seed_arr, qkv, qkv, qkv, bias, do, out, lse)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _packed_attention(qkv, bias, seed, causal, dropout_rate, heads):
+    with annotate("apex.flash"):
+        out, _ = _flash_fwd_packed(qkv, bias, causal, dropout_rate, seed,
+                                   heads)
+    return out
+
+
+def _packed_vjp_fwd(qkv, bias, seed, causal, dropout_rate, heads):
+    with annotate("apex.flash"):
+        out, lse = _flash_fwd_packed(qkv, bias, causal, dropout_rate, seed,
+                                     heads)
+    return out, (qkv, bias, seed, out, lse)
+
+
+def _packed_vjp_bwd(causal, dropout_rate, heads, res, do):
+    qkv, bias, seed, out, lse = res
+    with annotate("apex.flash"):
+        dqkv = _flash_bwd_packed(qkv, bias, causal, dropout_rate, seed,
+                                 heads, out, lse, do)
+    return dqkv, None, None
+
+
+_packed_attention.defvjp(_packed_vjp_fwd, _packed_vjp_bwd)
+
+
+def flash_attention_qkv(qkv, bias, seed=0, causal=False, dropout_rate=0.0,
+                        heads=1):
+    """Self-attention over a fused QKV projection: qkv (B, S, 3·H·hd) as the
+    projection wrote it, q NOT pre-scaled (1/sqrt(hd) is applied here); bias
+    (1|B, 1|S, S) additive f32; returns the context (B, S, H·hd), what the
+    output projection reads.  Semantics, dropout mask included, are
+    :func:`flash_attention`'s on the transposed heads.
+
+    The layout follows from the shape (:func:`_packed_tile`): heads of 64 in
+    pairs at a length whose whole tile fits VMEM read the projection in
+    place, two heads a 128-lane block; every other shape transposes to
+    (B·H, S, hd) around :func:`flash_attention`, whose backward is the
+    ``"auto"`` chain's."""
+    B, S, width = qkv.shape
+    hd = width // 3 // heads
+    if _packed_tile(S, heads, hd, qkv.dtype.itemsize,
+                    bias.shape[1] != 1) is not None:
+        return _packed_attention(qkv, bias, seed, causal, dropout_rate, heads)
+    q, k, v = (t.reshape(B, S, heads, hd).transpose(0, 2, 1, 3)
+               for t in jnp.split(qkv, 3, axis=-1))
+    scale = 1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32))
+    qf = (q.astype(jnp.float32) * scale).astype(qkv.dtype) \
+        .reshape(B * heads, S, hd)
+    ctx = flash_attention(qf, k.reshape(B * heads, S, hd),
+                          v.reshape(B * heads, S, hd), bias, seed, causal,
+                          dropout_rate, heads)
+    return ctx.reshape(B, heads, S, hd).transpose(0, 2, 1, 3) \
+        .reshape(B, S, heads * hd)

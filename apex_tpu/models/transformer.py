@@ -162,6 +162,24 @@ def _attention(x, wqkv, bqkv, wo, bo, cfg: TransformerConfig, mask,
     B, S, D = x.shape
     H, hd = cfg.num_heads, cfg.head_dim
     qkv = jnp.einsum("bsd,de->bse", x, wqkv.astype(x.dtype)) + bqkv.astype(x.dtype)
+    if attn_override is None and cfg.attn_impl == "fast":
+        # the kernel reads q, k and v from the projection and writes the
+        # context in the layout ``wo`` reads (flash_attention_qkv picks the
+        # layout from the shape)
+        from ..contrib.multihead_attn.flash import flash_attention_qkv
+        from ..contrib.multihead_attn.modules import _rng_seed_from
+        if mask is not None:   # (B, S) nonzero = PAD -> additive key bias
+            bias = jnp.where(mask[:, None, :] != 0, -1e9, 0.0) \
+                .astype(jnp.float32)
+        else:
+            bias = jnp.zeros((1, 1, S), jnp.float32)
+        rate = cfg.dropout if dropout_rng is not None else 0.0
+        ctx = flash_attention_qkv(qkv, bias,
+                                  seed=_rng_seed_from(dropout_rng),
+                                  causal=cfg.causal, dropout_rate=rate,
+                                  heads=H)
+        return jnp.einsum("bsd,de->bse", ctx, wo.astype(x.dtype)) \
+            + bo.astype(x.dtype)
     q, k, v = jnp.split(qkv, 3, axis=-1)
     q = q.reshape(B, S, H, hd).transpose(0, 2, 1, 3)
     k = k.reshape(B, S, H, hd).transpose(0, 2, 1, 3)
@@ -173,26 +191,6 @@ def _attention(x, wqkv, bqkv, wo, bo, cfg: TransformerConfig, mask,
                 "(the sequence-parallel collectives carry no mask plumbing)")
         ctx = attn_override(q, k, v, causal=cfg.causal)
         ctx = ctx.astype(x.dtype).transpose(0, 2, 1, 3).reshape(B, S, D)
-        return jnp.einsum("bsd,de->bse", ctx, wo.astype(x.dtype)) \
-            + bo.astype(x.dtype)
-    if cfg.attn_impl == "fast":
-        from ..contrib.multihead_attn.flash import flash_attention
-        from ..contrib.multihead_attn.modules import _rng_seed_from
-        scale = 1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32))
-        qf = (q.astype(jnp.float32) * scale).astype(x.dtype) \
-            .reshape(B * H, S, hd)
-        kf = k.reshape(B * H, S, hd)
-        vf = v.reshape(B * H, S, hd)
-        if mask is not None:   # (B, S) nonzero = PAD -> additive key bias
-            bias = jnp.where(mask[:, None, :] != 0, -1e9, 0.0) \
-                .astype(jnp.float32)
-        else:
-            bias = jnp.zeros((1, 1, S), jnp.float32)
-        rate = cfg.dropout if dropout_rng is not None else 0.0
-        ctx = flash_attention(qf, kf, vf, bias,
-                              seed=_rng_seed_from(dropout_rng),
-                              causal=cfg.causal, dropout_rate=rate, heads=H)
-        ctx = ctx.reshape(B, H, S, hd).transpose(0, 2, 1, 3).reshape(B, S, D)
         return jnp.einsum("bsd,de->bse", ctx, wo.astype(x.dtype)) \
             + bo.astype(x.dtype)
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) / jnp.sqrt(

@@ -174,7 +174,8 @@ def record_collective(axis_name: str, nbytes: int, n_leaves: int,
               wire_bytes=wire, **extra)
 
 
-FLASH_BWD_PATHS = ("whole_key", "resident", "partials", "split", "xla")
+FLASH_BWD_PATHS = ("whole_key", "projection", "resident", "partials",
+                   "split", "xla")
 
 
 def record_flash_bwd(path: str, bq: Optional[int] = None,

@@ -381,6 +381,44 @@ def _kernel_checks(full: bool):
         checks.append((f"flash_bwd_{impl}_dropout[S{S}c]", 5e-2,
                        lambda i=impl: flash_bwd(S, BH, True, i, 0.1)))
 
+    # -- flash in the projection's layout (BERT's): qkv (B, S, 3·H·64) in,
+    #    the context (B, S, H·64) out, two heads a 128-lane block; only the
+    #    chip sees a lane or row the kernels leave unwritten --
+    def flash_qkv(S, B, H, causal, rate, padded):
+        qkv = rnd(5, (B, S, 3 * H * 64), bf16, 0.5)
+        do = rnd(6, (B, S, H * 64), bf16)
+        bias = jnp.zeros((1, 1, S), f32)
+        if padded:      # the model's key padding, one sequence wholly dead
+            pad = jnp.arange(S)[None, :] >= S - (S // 8) * jnp.arange(B)[:, None]
+            bias = jnp.where(pad[:, None, :], -1e9, 0.0).astype(f32) \
+                .at[1].set(F.NEG_INF)
+        if F._packed_tile(S, H, 64, 2, False) is None:
+            raise AssertionError(f"S {S}, {H} heads: not the projection layout")
+
+        def twin(x):    # the heads transposed around plain-XLA attention
+            q, k, v = (t.reshape(B, S, H, 64).transpose(0, 2, 1, 3)
+                       .reshape(B * H, S, 64) for t in jnp.split(x, 3, -1))
+            q = (q.astype(f32) / 8.0).astype(bf16)
+            o = F._xla_reference(q, k, v, bias, causal, rate, 7, H)
+            return o.reshape(B, H, S, 64).transpose(0, 2, 1, 3) \
+                .reshape(B, S, H * 64)
+
+        def both(fn):
+            def run(x):
+                o, vjp = jax.vjp(fn, x)
+                return o, vjp(do)[0]
+            return jax.jit(run)(qkv)
+        return rel_err(both(lambda x: F.flash_attention_qkv(
+            x, bias, 7, causal, rate, H)), both(twin))
+
+    B, H = (8, 16) if full else (2, 2)
+    for tag, causal, rate, padded in (("", False, 0.0, False),
+                                      ("_padded", False, 0.0, True),
+                                      ("_dropout", True, 0.1, False)):
+        checks.append((f"flash_qkv{tag}[B{B}xS{S}xH{H}]", 5e-2,
+                       lambda c=causal, r=rate, p=padded: flash_qkv(
+                           S, B, H, c, r, p)))
+
     # -- xentropy, forward and backward (ragged last vocabulary block) ----
     N, V = (4096, 30592) if full else (64, 1000)
 

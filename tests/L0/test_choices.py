@@ -350,6 +350,38 @@ def test_cell_choice(cell, chooser, monkeypatch, flash_events):
         assert _xent_auto_choice(monkeypatch, backend) == want
 
 
+#: the cells whose model reads q, k and v from the projection
+#: (``transformer._attention`` -> ``flash_attention_qkv``): BERT's 16 heads
+#: of 64.  The hybrids call ``flash_attention`` on (B·H, S, D) themselves.
+BERT_HEADS = 16
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_TILES))
+def test_cell_layout(cell, flash_events):
+    """Every BERT cell's shape takes the projection layout — the packed
+    forward on a (B·H/2, 1, 1) grid, the packed backward on (B·H/2,), its
+    path recorded as ``projection`` at the whole-key tile — and no
+    hybrid cell's shape would, so their (B·H, S, D) entry is untouched."""
+    BH, S, D, dtype, causal = CELLS[cell]
+    esz = jnp.dtype(dtype).itemsize
+    if not cell.startswith("bert_large."):
+        assert F._packed_tile(S, 2, D, esz, False) is None
+        return
+    B = BH // BERT_HEADS
+    qkv = jax.ShapeDtypeStruct((B, S, 3 * BERT_HEADS * D), jnp.dtype(dtype))
+    bias = jnp.zeros((1, 1, S), jnp.float32)
+    jaxpr = jax.make_jaxpr(jax.grad(lambda x: F.flash_attention_qkv(
+        x, bias, 0, causal, 0.0, BERT_HEADS).astype(jnp.float32).sum()))(qkv)
+    grids = {e.params["name"]: tuple(e.params["grid_mapping"].grid)
+             for e in _walk_eqns(jaxpr.jaxpr)
+             if e.primitive.name == "pallas_call"}
+    assert grids == {"apex_flash_fwd": (BH // 2, 1, 1),
+                     "apex_flash_bwd_fused": (BH // 2,)}
+    ev = [r["fields"] for r in flash_events.flush()
+          if r.get("name") == "flash.bwd"]
+    assert ev == [{"path": "projection", "bq": S, "bk": S, "nk": 1}]
+
+
 # ---------------------------------------------------------------------------
 # the update's path: leaf by leaf where it is replicated, flat where sharded
 # ---------------------------------------------------------------------------

@@ -307,6 +307,91 @@ def test_mosaic_compiles_the_pair_at_the_published_shape(
 
 
 # ---------------------------------------------------------------------------
+# flash attention in the projection's layout at the BERT cells' shapes (here
+# because this is the one file that may load the TPU's compiler)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("batch,seq", [(112, 512), (544, 128)])
+def test_mosaic_compiles_the_projection_flash_at_the_bert_cells(
+        batch, seq, one_chip, monkeypatch):
+    """``flash_attention_qkv`` and its gradient at ``bert_large.s512`` (b112 x
+    S 512) and ``s128_b544`` (b544 x S 128), 16 heads of 64: both packed
+    kernels compile under Mosaic for a described v5e — two heads a block
+    doubles every stream of a step, which the packed VMEM model counts and
+    keeps within the budget — and the compiled program holds no
+    (B, S, H, 64) transpose: nothing around the kernels but the seed."""
+    from apex_tpu.contrib.multihead_attn import flash as F
+    monkeypatch.setattr(F, "_interpret", lambda: False)
+    assert F._packed_tile(seq, 16, 64, 2, False) == (seq, seq)
+    assert F.vmem_estimate(seq, seq, 128, 2, False, "packed") \
+        <= F._vmem_budget()
+    qkv = jax.ShapeDtypeStruct((batch, seq, 3 * 1024), jnp.bfloat16,
+                               sharding=one_chip)
+    bias = jnp.zeros((1, 1, seq), jnp.float32)
+    with jax.default_matmul_precision("default"):
+        text = jax.jit(jax.value_and_grad(
+            lambda x: jnp.sum(F.flash_attention_qkv(
+                x, bias, 0, False, 0.0, 16).astype(jnp.float32)))).lower(
+                    qkv).compile().as_text()
+    for kernel in ("apex_flash_fwd", "apex_flash_bwd_fused"):
+        assert f"{kernel}/pallas_call" in text, kernel
+    assert not re.search(r"= \S+\[\d+,\d+,16,64\]", text)
+    assert f"f32[{batch},8,2,{seq}]" in text          # lse: rows, not columns
+
+
+#: every (S, dtype, bias over queries too) at which a pair of heads of 64
+#: reads the projection in place: the packed backward's VMEM model within
+#: the budget
+PACKED_SHAPES = [(s, dt, per_q) for s in (128, 256, 384)
+                 for dt in (jnp.bfloat16, jnp.float32)
+                 for per_q in (False, True)] + [(512, jnp.bfloat16, False)]
+
+
+def test_the_projection_layout_admits_only_the_compiled_shapes():
+    """The layout engages at the shapes below and at no other length, width
+    or bias up to S 1152 — each of them is compiled next."""
+    from apex_tpu.contrib.multihead_attn import flash as F
+    admitted = [(s, dt, per_q) for s in range(128, 1153, 128)
+                for dt in (jnp.bfloat16, jnp.float32)
+                for per_q in (False, True)
+                if F._packed_tile(s, 2, 64, jnp.dtype(dt).itemsize,
+                                  per_q) is not None]
+    assert admitted == PACKED_SHAPES
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("seq,dtype,per_q", PACKED_SHAPES)
+def test_mosaic_fits_the_projection_flash_in_its_vmem_model(
+        seq, dtype, per_q, causal, one_chip, monkeypatch):
+    """Each admitted shape, with dropout (its hash's tiles are the most the
+    kernel holds): the packed forward and backward compile for a described
+    v5e, the backward told it may use no more VMEM than the ``"packed"``
+    model of ``vmem_estimate`` says it needs — the model is an upper
+    bound, and what admits a shape is what Mosaic takes."""
+    from apex_tpu.contrib.multihead_attn import flash as F
+    monkeypatch.setattr(F, "_interpret", lambda: False)
+    esz = jnp.dtype(dtype).itemsize
+    limit = F.vmem_estimate(seq, seq, 128, esz, per_q, "packed")
+    real = F._compiler_params
+
+    def capped(semantics, vmem_limit_bytes=None):
+        if tuple(semantics) == ("arbitrary",):      # the packed backward
+            vmem_limit_bytes = limit
+        return real(semantics, vmem_limit_bytes)
+    monkeypatch.setattr(F, "_compiler_params", capped)
+    qkv = jax.ShapeDtypeStruct((1, seq, 3 * 128), dtype, sharding=one_chip)
+    bias = jnp.zeros((1, seq if per_q else 1, seq), jnp.float32)
+    with jax.default_matmul_precision("default"):
+        text = jax.jit(jax.value_and_grad(
+            lambda x: jnp.sum(F.flash_attention_qkv(
+                x, bias, 0, causal, 0.1, 2).astype(jnp.float32)))).lower(
+                    qkv).compile().as_text()
+    for kernel in ("apex_flash_fwd", "apex_flash_bwd_fused"):
+        assert f"{kernel}/pallas_call" in text, kernel
+    assert f"f32[1,1,2,{seq}]" in text                 # the packed lse
+
+
+# ---------------------------------------------------------------------------
 # the replicated LAMB update by the chip's own compiler (here because this is
 # the one file that may load the TPU's compiler: a second file can go to
 # another worker, whose fixture would then skip in silence)

@@ -1137,3 +1137,188 @@ def test_flash_bwd_resident_skips_what_lies_above_the_diagonal(small_vmem):
                                       err_msg=name)
     assert not np.any(np.asarray(clean[1])[:, sq:])
     assert not np.any(np.asarray(clean[2])[:, sq:])
+
+
+# ---------------------------------------------------------------------------
+# the projection's layout: q, k, v read from (B, S, 3·H·hd), the context
+# written as (B, S, H·hd), two heads of 64 a 128-lane block
+# ---------------------------------------------------------------------------
+
+def _transposed_entry(qkv, bias, seed, causal, rate, heads):
+    """What the model did before the projection layout: the heads
+    transposed to (B·H, S, hd) around ``flash_attention``, q pre-scaled."""
+    B, S, width = qkv.shape
+    hd = width // 3 // heads
+    q, k, v = (t.reshape(B, S, heads, hd).transpose(0, 2, 1, 3)
+               .reshape(B * heads, S, hd) for t in jnp.split(qkv, 3, -1))
+    scale = 1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32))
+    q = (q.astype(jnp.float32) * scale).astype(qkv.dtype)
+    ctx = flash_attention(q, k, v, bias, seed, causal, rate, heads)
+    return ctx.reshape(B, heads, S, hd).transpose(0, 2, 1, 3) \
+        .reshape(B, S, heads * hd)
+
+
+def _qkv_inputs(B, S, heads, hd, dtype, seed=40):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 2)
+    qkv = 0.5 * jax.random.normal(ks[0], (B, S, 3 * heads * hd), jnp.float32)
+    do = jax.random.normal(ks[1], (B, S, heads * hd), jnp.float32)
+    return qkv.astype(dtype), do.astype(dtype)
+
+
+def _both_entries(qkv, do, bias, causal, rate, heads):
+    """(context, d(qkv)) of ``flash_attention_qkv`` and of the transposed
+    entry, under one jit with a traced seed, as in training."""
+    from apex_tpu.contrib.multihead_attn import flash as F
+
+    @jax.jit
+    def run(seed):
+        got = []
+        for fn in (F.flash_attention_qkv, _transposed_entry):
+            o, vjp = jax.vjp(lambda x: fn(x, bias, seed, causal, rate, heads),
+                             qkv)
+            got.append((o, vjp(do)[0]))
+        return got
+
+    return run(jnp.int32(7))
+
+
+#: case -> (B, S, heads, causal, dropout rate, bias, dtype)
+_QKV_CASES = {
+    "plain": (2, 128, 2, False, 0.0, "none", jnp.float32),
+    "causal": (2, 128, 4, True, 0.0, "none", jnp.float32),
+    "padding": (2, 128, 2, False, 0.0, "padding", jnp.float32),
+    "dead_row": (2, 128, 2, False, 0.0, "dead", jnp.float32),
+    "dropout": (2, 128, 2, True, 0.1, "none", jnp.float32),
+    "bf16_s256": (1, 256, 2, False, 0.0, "padding", jnp.bfloat16),
+}
+
+
+def _qkv_bias(kind, B, S):
+    if kind == "none":
+        return jnp.zeros((1, 1, S), jnp.float32)
+    if kind == "padding":               # the model's key-padding bias
+        pad = jnp.arange(S)[None, :] >= S - 16 * (1 + jnp.arange(B))[:, None]
+        return jnp.where(pad[:, None, :], -1e9, 0.0).astype(jnp.float32)
+    # every key of the second sequence masked out: all its rows are dead
+    return jnp.zeros((B, 1, S), jnp.float32).at[1].set(-1e30)
+
+
+@pytest.mark.parametrize("case", sorted(_QKV_CASES))
+def test_flash_qkv_matches_the_transposed_entry(flash_env, flash_counts,
+                                                case):
+    """Heads of 64 in pairs at a length whose whole-key tile holds a head:
+    ``flash_attention_qkv`` reads the projection in place (the backward
+    records path ``projection``), and its context, ``lse`` and d(qkv) are
+    those of ``flash_attention`` on the transposed heads — the same dropout
+    mask, dead rows zero, within the whole-key tolerances."""
+    from apex_tpu.contrib.multihead_attn import flash as F
+    B, S, heads, causal, rate, kind, dtype = _QKV_CASES[case]
+    qkv, do = _qkv_inputs(B, S, heads, 64, dtype)
+    bias = _qkv_bias(kind, B, S)
+    assert F._packed_tile(S, heads, 64, qkv.dtype.itemsize,
+                          False) == (S, S)
+    (o, g), (o_t, g_t) = _both_entries(qkv, do, bias, causal, rate, heads)
+    counts = flash_counts.read()
+    assert counts["flash.bwd_calls.projection"] == 1
+    assert counts["flash.bwd_calls.whole_key"] == 1      # the transposed one
+    bf16 = dtype == jnp.bfloat16
+    for name, a, b in (("out", o, o_t), ("dqkv", g, g_t)):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        np.testing.assert_allclose(
+            np.asarray(a, np.float32), np.asarray(b, np.float32),
+            atol=3e-2 if bf16 else 2e-5, rtol=2e-2 if bf16 else 1e-5,
+            err_msg=name)
+    # lse: (B, H/2, 2, S) rows against the (B·H, S, 1) columns
+    q_t = (qkv[..., :heads * 64].reshape(B, S, heads, 64).transpose(0, 2, 1, 3)
+           .reshape(B * heads, S, 64).astype(jnp.float32) / 8.0).astype(dtype)
+    kv_t = [t.reshape(B, S, heads, 64).transpose(0, 2, 1, 3)
+            .reshape(B * heads, S, 64)
+            for t in jnp.split(qkv[..., heads * 64:], 2, -1)]
+    _, lse_t = F._flash_fwd(q_t, *kv_t, bias, causal, rate, 7, heads)
+    _, lse = F._flash_fwd_packed(qkv, bias, causal, rate, 7, heads)
+    assert lse.shape == (B, heads // 2, 2, S) and lse.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(lse).reshape(B * heads, S),
+                               np.asarray(lse_t)[..., 0], rtol=1e-5,
+                               atol=1e-4)
+    if kind == "dead":
+        assert not np.any(np.asarray(o)[1]) and not np.any(np.asarray(g)[1])
+
+
+@pytest.mark.parametrize("case", ["odd_heads", "hd128", "no_whole_key"])
+def test_flash_qkv_falls_back_to_the_transposed_entry(flash_env, flash_counts,
+                                                      case):
+    """Every other shape takes the transposing path, with its numbers:
+    three heads, a head of 128, and a length whose pair of heads' whole
+    tile no longer fits the VMEM budget (here 2 MiB at S 256) go through the (B·H, S, hd) entry — bit for bit — and the
+    backward records ``whole_key``, never ``projection``."""
+    from apex_tpu.contrib.multihead_attn import flash as F
+    heads, hd, S = {"odd_heads": (3, 64, 128), "hd128": (2, 128, 128),
+                    "no_whole_key": (2, 64, 256)}[case]
+    if case == "no_whole_key":
+        flash_env.setenv("APEX_TPU_FLASH_VMEM_MB", "2")
+    qkv, do = _qkv_inputs(2, S, heads, hd, jnp.float32)
+    bias = _qkv_bias("padding", 2, S)
+    assert F._packed_tile(S, heads, hd, 4, False) is None
+    (o, g), (o_t, g_t) = _both_entries(qkv, do, bias, False, 0.0, heads)
+    counts = flash_counts.read()
+    assert counts["flash.bwd_calls.whole_key"] == 2
+    assert "flash.bwd_calls.projection" not in counts
+    np.testing.assert_array_equal(np.asarray(o), np.asarray(o_t))
+    np.testing.assert_array_equal(np.asarray(g), np.asarray(g_t))
+
+
+@pytest.mark.parametrize("pin", ["backward_xla", "bwd_block", "fwd_block",
+                                 "split"])
+def test_flash_qkv_pins_keep_the_transposed_entry(flash_env, pin):
+    """A choice somebody made names the (B·H, S, hd) kernels — the XLA
+    backward, a backward or forward block pin, the split strategy — so the
+    shape that would read the projection transposes instead."""
+    from apex_tpu.contrib.multihead_attn import flash as F
+    shape = (128, 2, 64, 2, False)
+    assert F._packed_tile(*shape) == (128, 128)
+    flash_env.setenv(*{"backward_xla": ("APEX_TPU_FLASH_BWD_IMPL", "xla"),
+                       "bwd_block": ("APEX_TPU_FLASH_BWD_BLOCK_Q", "128"),
+                       "fwd_block": ("APEX_TPU_FLASH_BLOCK_K", "128"),
+                       "split": ("APEX_TPU_FLASH_BWD_FUSE", "0")}[pin])
+    assert F._packed_tile(*shape) is None
+
+
+@pytest.mark.parametrize("masked", [False, True])
+def test_transformer_fast_reads_the_projection_and_matches_default(masked):
+    """``attn_impl="fast"`` at heads of 64 (d 128, two heads, S 128): the
+    layer's attention takes the projection layout — no transpose of an
+    activation in its traced gradient, one packed kernel each way — and
+    its logits and gradients are the jnp oracle's."""
+    import dataclasses as dc
+    from apex_tpu.models import (TransformerConfig, transformer_apply,
+                                 transformer_init, transformer_loss)
+    cfg = TransformerConfig(vocab_size=64, max_len=128, num_layers=1,
+                            d_model=128, num_heads=2, d_ff=128)
+    fast = dc.replace(cfg, attn_impl="fast")
+    params = transformer_init(jax.random.PRNGKey(0), cfg)
+    toks = (jnp.arange(256).reshape(2, 128) * 7 % 64).astype(jnp.int32)
+    mask = (jnp.zeros((2, 128), jnp.int32).at[1, 100:].set(1)
+            if masked else None)
+    batch = {"tokens": toks, "targets": toks, "mask": mask}
+    np.testing.assert_allclose(
+        np.asarray(transformer_apply(params, toks, fast, mask=mask)),
+        np.asarray(transformer_apply(params, toks, cfg, mask=mask)),
+        atol=2e-4, rtol=2e-4)
+    g_def = jax.grad(lambda p: transformer_loss(p, batch, cfg))(params)
+    g_fast = jax.grad(lambda p: transformer_loss(p, batch, fast))(params)
+    for a, b in zip(jax.tree_util.tree_leaves(g_def),
+                    jax.tree_util.tree_leaves(g_fast)):
+        np.testing.assert_allclose(np.asarray(b), np.asarray(a),
+                                   atol=5e-4, rtol=5e-3)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda p: transformer_loss(p, batch, fast)))(params)
+    kernels = {e.params["name"]: tuple(e.params["grid_mapping"].grid)
+               for e in _walk_eqns(jaxpr.jaxpr)
+               if e.primitive.name == "pallas_call"
+               and e.params["name"].startswith("apex_flash")}
+    assert kernels == {"apex_flash_fwd": (2, 1, 1),
+                       "apex_flash_bwd_fused": (2,)}
+    moved = [e.outvars[0].aval.shape for e in _walk_eqns(jaxpr.jaxpr)
+             if e.primitive.name == "transpose"
+             and len(e.outvars[0].aval.shape) >= 3]
+    assert moved == [], moved
