@@ -54,6 +54,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -417,13 +418,16 @@ def _scaled(q, scale):
 
 def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
                 m_ref, l_ref, acc_ref, *, bq, bk, causal, dropout_rate,
-                heads, pack=1, scale=None):
+                heads, pack=1, scale=None, window=None, keys=None):
     """One (q block, k block) step of the online softmax for ``pack`` heads
     side by side in the block's lanes.  ``pack`` 1: the (B·H, S, D) layout,
     ``lse`` a (bq, 1) column.  ``pack`` 2: the projection layout — two heads
     of 64 a 128-lane block; each head's product takes the block with the
     other head's lanes of q and v zeroed, ``m`` / ``l`` are a column a head
-    and ``lse`` leaves as ``pack`` rows with the queries on the lanes."""
+    and ``lse`` leaves as ``pack`` rows with the queries on the lanes.
+    ``window``: the band's grid (:func:`_band_fwd`) — step ``ki`` is key
+    block ``first + ki`` of the ``keys`` blocks, and none past the band's
+    last computes."""
     bh, qi, ki = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     nk = pl.num_programs(2)
     hd = q_ref.shape[-1] // pack
@@ -434,8 +438,12 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    run = True
-    if causal:
+    run, kb = True, ki
+    if window is not None:
+        first, last = _band_keys(qi, bq, bk, window, keys)
+        kb = first + ki
+        run = kb <= last
+    elif causal:
         # whole block above the diagonal: nothing to do
         run = (ki * bk) <= (qi * bq + bq - 1)
 
@@ -451,11 +459,7 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
                 (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
             s = s + bias_ref[0].astype(jnp.float32)           # (bq|1, bk)
             if causal:
-                rows = qi * bq + jax.lax.broadcasted_iota(jnp.int32,
-                                                          s.shape, 0)
-                cols = ki * bk + jax.lax.broadcasted_iota(jnp.int32,
-                                                          s.shape, 1)
-                s = jnp.where(cols <= rows, s, NEG_INF)
+                s = _causal_mask(s, qi, bq, kb, bk, window)
 
             m_old = m_ref[:, j]
             m_new = jnp.maximum(m_old, jnp.max(s, axis=1))
@@ -466,7 +470,7 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
 
             if dropout_rate > 0.0:
                 keep = _dropout_keep(seed_ref[0], _head_of(bh, pack, j),
-                                     qi * bq, ki * bk, p.shape, dropout_rate)
+                                     qi * bq, kb * bk, p.shape, dropout_rate)
                 p = p * keep / (1.0 - dropout_rate)
 
             v = v_ref[0]                                      # (bk, d)
@@ -629,21 +633,34 @@ def _flash_fwd(q, k, v, bias, causal, dropout_rate, seed, heads,
 # backward (recompute): dq kernel (grid over q), dkv kernel (grid over k)
 # ---------------------------------------------------------------------------
 
-def _recompute_p(q_ref, k_ref, bias_ref, lse_ref, qi, ki, bq, bk, causal):
-    s = _recompute_s(q_ref[0], k_ref[0], bias_ref, qi, ki, bq, bk, causal)
+def _causal_mask(s, qi, bq, ki, bk, window=None):
+    """The (bq, bk) tile ``s`` of q block ``qi`` and key block ``ki`` with
+    NEG_INF wherever the key lies after the query or, given a ``window``,
+    ``window`` or more keys before it: query i sees keys j with i - window <
+    j <= i."""
+    rows = qi * bq + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    cols = ki * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    seen = cols <= rows
+    if window is not None:
+        seen = seen & (cols > rows - window)
+    return jnp.where(seen, s, NEG_INF)
+
+
+def _recompute_p(q_ref, k_ref, bias_ref, lse_ref, qi, ki, bq, bk, causal,
+                 window=None):
+    s = _recompute_s(q_ref[0], k_ref[0], bias_ref, qi, ki, bq, bk, causal,
+                     window)
     return jnp.exp(s - lse_ref[0, :, 0][:, None])             # (bq, bk)
 
 
-def _recompute_s(q, k, bias_ref, qi, ki, bq, bk, causal):
+def _recompute_s(q, k, bias_ref, qi, ki, bq, bk, causal, window=None):
     """The scores of one (q tile, key block) of one head, bias and causal
-    mask applied."""
+    (or band) mask applied."""
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
     s = s + bias_ref[0].astype(jnp.float32)
     if causal:
-        rows = qi * bq + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        cols = ki * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(cols <= rows, s, NEG_INF)
+        s = _causal_mask(s, qi, bq, ki, bk, window)
     return s
 
 
@@ -669,7 +686,9 @@ def _tile_grads(p, keep, q, k, v, do, delta):
 
 def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
                    delta_ref, dq_ref, dq_acc, *, bq, bk, causal, dropout_rate,
-                   heads):
+                   heads, window=None, keys=None):
+    """dq of one q block over the key blocks the grid walks: all of them,
+    or with a ``window`` the band's (as in :func:`_fwd_kernel`)."""
     bh, qi, ki = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     nk = pl.num_programs(2)
 
@@ -677,19 +696,23 @@ def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
     def _():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    run = True
-    if causal:
+    run, kb = True, ki
+    if window is not None:
+        first, last = _band_keys(qi, bq, bk, window, keys)
+        kb = first + ki
+        run = kb <= last
+    elif causal:
         run = (ki * bk) <= (qi * bq + bq - 1)
 
     @pl.when(run)
     def _():
-        p = _recompute_p(q_ref, k_ref, bias_ref, lse_ref, qi, ki, bq, bk,
-                         causal)
+        p = _recompute_p(q_ref, k_ref, bias_ref, lse_ref, qi, kb, bq, bk,
+                         causal, window)
         dp = jax.lax.dot_general(do_ref[0], v_ref[0],
                                  (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         if dropout_rate > 0.0:
-            keep = _dropout_keep(seed_ref[0], bh, qi * bq, ki * bk, p.shape,
+            keep = _dropout_keep(seed_ref[0], bh, qi * bq, kb * bk, p.shape,
                                  dropout_rate)
             dp = dp * keep / (1.0 - dropout_rate)
         ds = p * (dp - delta_ref[0, :, 0][:, None])           # (bq, bk)
@@ -705,7 +728,10 @@ def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
 
 def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
                     delta_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, bq, bk,
-                    causal, dropout_rate, heads):
+                    causal, dropout_rate, heads, window=None, queries=None):
+    """dk and dv of one key block over the q blocks the grid walks: all of
+    them, or with a ``window`` the band's — step ``qi`` is q block ``first
+    + qi`` of the ``queries`` blocks (:func:`_band_queries`)."""
     bh, ki, qi = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     nq = pl.num_programs(2)
 
@@ -714,17 +740,21 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    run = True
-    if causal:
+    run, qb = True, qi
+    if window is not None:
+        first, last = _band_queries(ki, bq, bk, window, queries)
+        qb = first + qi
+        run = qb <= last
+    elif causal:
         run = (ki * bk) <= (qi * bq + bq - 1)
 
     @pl.when(run)
     def _():
-        p = _recompute_p(q_ref, k_ref, bias_ref, lse_ref, qi, ki, bq, bk,
-                         causal)                              # (bq, bk)
+        p = _recompute_p(q_ref, k_ref, bias_ref, lse_ref, qb, ki, bq, bk,
+                         causal, window)                      # (bq, bk)
         do = do_ref[0]                                        # (bq, d)
         if dropout_rate > 0.0:
-            keep = _dropout_keep(seed_ref[0], bh, qi * bq, ki * bk, p.shape,
+            keep = _dropout_keep(seed_ref[0], bh, qb * bq, ki * bk, p.shape,
                                  dropout_rate) / (1.0 - dropout_rate)
             pd = p * keep
         else:
@@ -1213,16 +1243,193 @@ def _bias_spec_swapped(bias, heads, bq, bk):
 
 
 # ---------------------------------------------------------------------------
+# the band: causal attention within a sliding window
+# ---------------------------------------------------------------------------
+#
+# With ``window`` W query i sees keys j with i - W < j <= i: W keys, counting
+# itself.  A causal grid would visit every key block up to the diagonal; the
+# band's grids visit only the blocks that meet the band — the key blocks of
+# a q block (forward, dq), the q blocks of a key block (dk, dv) — by an index
+# map that offsets the block by the row of blocks the step belongs to, so a
+# tile outside the band is neither fetched nor computed.  A step past the
+# band's last block (the rows of blocks near the start meet fewer) repeats
+# its index, which copies nothing, and computes nothing.
+
+_BAND_BLOCK = 512
+#: (window, head width, dtype) the band kernels compiled and ran at on a
+#: v5e, at ``_BAND_BLOCK`` square tiles: every other window takes the XLA
+#: band path (:func:`_band_admitted`)
+_BAND_SHAPES = ((512, 128, "bfloat16"),)
+
+
+def _band_keys(qi, bq, bk, window, keys, lib=jnp):
+    """(first, last) key block that q block ``qi``'s band meets, of
+    ``keys``; ``lib`` numpy for python ints."""
+    first = lib.maximum(qi * bq - window + 1, 0) // bk
+    return first, lib.minimum((qi * bq + bq - 1) // bk, keys - 1)
+
+
+def _band_queries(ki, bq, bk, window, queries, lib=jnp):
+    """(first, last) q block whose band meets key block ``ki``, of
+    ``queries``."""
+    last = (ki * bk + bk - 1 + window - 1) // bq
+    return (ki * bk) // bq, lib.minimum(last, queries - 1)
+
+
+def _band_axis(span, rows):
+    """``(steps, block)`` of a band grid's walking axis over ``rows`` rows
+    of blocks, ``span(row, lib)`` = (first, last) block of a row: the most
+    blocks a row covers, and the block step j of row i reads — clamped to
+    the row's last, so a step past it fetches nothing new."""
+    steps = max(last - first + 1 for first, last in (
+        span(i, np) for i in range(rows)))
+
+    def block(i, j):
+        first, last = span(i, jnp)
+        return jnp.minimum(first + j, last)
+    return int(steps), block
+
+
+def _band_admitted(q, k, bias, window, dropout_rate):
+    """Whether the band kernels take this call: a shape of
+    ``_BAND_SHAPES``, self-attention over whole ``_BAND_BLOCK`` blocks, a
+    bias over keys alone and no dropout."""
+    S = q.shape[1]
+    return ((window, q.shape[-1], q.dtype.name) in _BAND_SHAPES
+            and k.shape[1] == S and S % _BAND_BLOCK == 0
+            and bias.shape[1] == 1 and dropout_rate == 0.0)
+
+
+def _band_specs(bias, heads, bq, bk, D, rows_of, cols_of):
+    """in_specs (seed, q, k, v, bias, do, lse, delta) on a band grid whose
+    step ``(bh, i, j)`` reads q block ``rows_of(i, j)`` and key block
+    ``cols_of(i, j)``; the bias is over keys, (1|B, 1, Sk)."""
+    b_bcast = bias.shape[0] == 1
+
+    def at_rows(width):
+        return pl.BlockSpec((1, bq, width),
+                            lambda bh, i, j: (bh, rows_of(i, j), 0),
+                            memory_space=pltpu.VMEM)
+
+    def at_cols(width):
+        return pl.BlockSpec((1, bk, width),
+                            lambda bh, i, j: (bh, cols_of(i, j), 0),
+                            memory_space=pltpu.VMEM)
+
+    def bias_map(bh, i, j):
+        return (0 if b_bcast else bh // heads, 0, cols_of(i, j))
+
+    return [pl.BlockSpec(memory_space=pltpu.SMEM),
+            at_rows(D), at_cols(D), at_cols(D),
+            pl.BlockSpec((1, 1, bk), bias_map, memory_space=pltpu.VMEM),
+            at_rows(D), at_rows(1), at_rows(1)]
+
+
+def _band_fwd(q, k, v, bias, window, heads, bq=_BAND_BLOCK, bk=_BAND_BLOCK):
+    """The band's forward: q (BH, S, D) pre-scaled, k / v (BH, S, D), bias
+    (1|B, 1, S).  Grid (BH, nq, steps): step j of q block i reads key block
+    ``first(i) + j``, clamped to the band's last.  Returns out (BH, S, D)
+    and lse (BH, S, 1) f32."""
+    _check_bias_layout(q, bias, heads)
+    q, k, v, bias, _, orig, _ = _pad_inputs(q, k, v, bias, bq=bq, bk=bk)
+    BH, Sq, D = q.shape
+    bq, bk = min(bq, Sq), min(bk, k.shape[1])
+    nq, nk = Sq // bq, k.shape[1] // bk
+    steps, key_block = _band_axis(
+        lambda i, lib: _band_keys(i, bq, bk, window, nk, lib), nq)
+    seed_arr = jnp.zeros((1,), jnp.int32)
+    vma = _out_vma(q, k, v, bias)
+    specs = _band_specs(bias, heads, bq, bk, D, lambda i, j: i, key_block)
+    out, lse = pl.pallas_call(
+        functools.partial(_fwd_kernel, bq=bq, bk=bk, causal=True,
+                          dropout_rate=0.0, heads=heads, window=window,
+                          keys=nk),
+        grid=(BH, nq, steps),
+        in_specs=specs[:5],
+        out_specs=[specs[1], specs[6]],
+        out_shape=[_sds((BH, Sq, D), q.dtype, vma),
+                   _sds((BH, Sq, 1), jnp.float32, vma)],
+        scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),
+                        pltpu.VMEM((bq, 1), jnp.float32),
+                        pltpu.VMEM((bq, D), jnp.float32)],
+        compiler_params=_compiler_params(
+            ("parallel", "parallel", "arbitrary")),
+        interpret=_interpret(),
+        name="apex_flash_window_fwd",
+    )(seed_arr, q, k, v, bias)
+    return out[:, :orig], lse[:, :orig]
+
+
+def _band_bwd(q, k, v, bias, window, heads, out, lse, do, bq=_BAND_BLOCK,
+              bk=_BAND_BLOCK):
+    """(dq, dk, dv) of the band: the split pair of the recompute backward
+    on band grids — dq over the key blocks of each q block's band
+    (``apex_flash_window_bwd_dq``), dk / dv over the q blocks of each key
+    block's (``apex_flash_window_bwd_dkv``)."""
+    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
+                    axis=-1, keepdims=True)                   # (BH, Sq, 1)
+    q, k, v, bias, do, orig, _ = _pad_inputs(q, k, v, bias, do, bq=bq, bk=bk)
+    BH, Sq, D = q.shape
+    bq, bk = min(bq, Sq), min(bk, k.shape[1])
+    nq, nk = Sq // bq, k.shape[1] // bk
+    lse, delta = _pad_lse_delta(lse, delta, Sq)
+    seed_arr = jnp.zeros((1,), jnp.int32)
+    vma = _out_vma(q, k, v, bias, do, lse, delta)
+    _tel_events.record_flash_bwd("window", bq, bk, nk)
+    key_steps, key_block = _band_axis(
+        lambda i, lib: _band_keys(i, bq, bk, window, nk, lib), nq)
+    q_steps, q_block = _band_axis(
+        lambda i, lib: _band_queries(i, bq, bk, window, nq, lib), nk)
+
+    specs = _band_specs(bias, heads, bq, bk, D, lambda i, j: i, key_block)
+    dq = pl.pallas_call(
+        functools.partial(_bwd_dq_kernel, bq=bq, bk=bk, causal=True,
+                          dropout_rate=0.0, heads=heads, window=window,
+                          keys=nk),
+        grid=(BH, nq, key_steps),
+        in_specs=specs,
+        out_specs=specs[1],
+        out_shape=_sds((BH, Sq, D), q.dtype, vma),
+        scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
+        compiler_params=_compiler_params(
+            ("parallel", "parallel", "arbitrary")),
+        interpret=_interpret(),
+        name="apex_flash_window_bwd_dq",
+    )(seed_arr, q, k, v, bias, do, lse, delta)
+
+    specs = _band_specs(bias, heads, bq, bk, D, q_block, lambda i, j: i)
+    dk, dv = pl.pallas_call(
+        functools.partial(_bwd_dkv_kernel, bq=bq, bk=bk, causal=True,
+                          dropout_rate=0.0, heads=heads, window=window,
+                          queries=nq),
+        grid=(BH, nk, q_steps),
+        in_specs=specs,
+        out_specs=[specs[2], specs[3]],
+        out_shape=[_sds((BH, k.shape[1], D), k.dtype, vma),
+                   _sds((BH, k.shape[1], D), v.dtype, vma)],
+        scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
+                        pltpu.VMEM((bk, D), jnp.float32)],
+        compiler_params=_compiler_params(
+            ("parallel", "parallel", "arbitrary")),
+        interpret=_interpret(),
+        name="apex_flash_window_bwd_dkv",
+    )(seed_arr, q, k, v, bias, do, lse, delta)
+    return dq[:, :orig], dk[:, :orig], dv[:, :orig]
+
+
+# ---------------------------------------------------------------------------
 # XLA backward: the 11 ms fwd+bwd pair as a drop-in gradient path
 # ---------------------------------------------------------------------------
 
-def _xla_reference(q, k, v, bias, causal, dropout_rate, seed, heads):
+def _xla_reference(q, k, v, bias, causal, dropout_rate, seed, heads,
+                   window=None):
     """Plain-XLA mirror of the kernel semantics on (BH, S, D) layouts:
     softmax over keys THEN dropout on the probabilities (denominator sees
     no dropout), the SAME counter-based keep mask (``_dropout_keep`` is
     plain jnp, so the mask is bit-identical to the kernels'), NEG_INF dead
     rows emitting zeros.  Exists so ``backward="xla"`` can take
-    ``jax.vjp`` of it — gradients consistent with the Pallas forward."""
+    ``jax.vjp`` of it — gradients consistent with the Pallas forward — and
+    is the XLA band path: with a ``window`` the causal mask is the band's."""
     BH, Sq, D = q.shape
     Sk = k.shape[1]
     s = jnp.einsum("bqd,bkd->bqk", q.astype(jnp.float32),
@@ -1234,7 +1441,10 @@ def _xla_reference(q, k, v, bias, causal, dropout_rate, seed, heads):
     if causal:
         rows = jax.lax.broadcasted_iota(jnp.int32, (Sq, Sk), 0)
         cols = jax.lax.broadcasted_iota(jnp.int32, (Sq, Sk), 1)
-        s = jnp.where((cols <= rows)[None], s, NEG_INF)
+        seen = cols <= rows
+        if window is not None:
+            seen = seen & (cols > rows - window)
+        s = jnp.where(seen[None], s, NEG_INF)
     m = jnp.max(s, axis=-1)
     dead = m <= NEG_INF / 2
     p = jnp.exp(s - m[..., None])
@@ -1250,14 +1460,16 @@ def _xla_reference(q, k, v, bias, causal, dropout_rate, seed, heads):
     return jnp.where(dead[..., None], 0.0, o).astype(q.dtype)
 
 
-def _xla_bwd(q, k, v, bias, causal, dropout_rate, seed, heads, out, lse, do):
+def _xla_bwd(q, k, v, bias, causal, dropout_rate, seed, heads, out, lse, do,
+             window=None):
     """(dq, dk, dv) via autodiff of :func:`_xla_reference`
-    (``backward="xla"``).  The saved out/lse residuals are unused; XLA
-    refuses nothing at these shapes and fuses its own recompute."""
+    (``backward="xla"``, and the XLA band path).  The saved out/lse
+    residuals are unused; XLA refuses nothing at these shapes and fuses its
+    own recompute."""
     del out, lse
     _, vjp = jax.vjp(
         lambda q_, k_, v_: _xla_reference(q_, k_, v_, bias, causal,
-                                          dropout_rate, seed, heads),
+                                          dropout_rate, seed, heads, window),
         q, k, v)
     return vjp(do)
 
@@ -1266,9 +1478,9 @@ def _xla_bwd(q, k, v, bias, causal, dropout_rate, seed, heads, out, lse, do):
 # public entry: custom_vjp over (q, k, v, bias)
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
 def flash_attention(q, k, v, bias, seed=0, causal=False, dropout_rate=0.0,
-                    heads=1, backward="auto"):
+                    heads=1, backward="auto", window=None):
     """Fused attention.  q (BH, Sq, D) pre-scaled; k/v (BH, Sk, D);
     bias (1|B, 1|Sq, Sk) additive f32 (use 0s for none); seed may be a traced
     int32 (fold your step rng into it).  Returns (BH, Sq, D).
@@ -1278,6 +1490,12 @@ def flash_attention(q, k, v, bias, seed=0, causal=False, dropout_rate=0.0,
     with the identical dropout mask), or ``"auto"``
     (:func:`_resolve_backward`: env > amp-config > pallas).
 
+    ``window`` (with ``causal``; None: every earlier key) is a sliding
+    window: query i sees keys j with i - window < j <= i, ``window`` keys
+    counting itself.  The band kernels (``apex_flash_window_*``) visit only
+    the tiles that meet the band, at the shapes they were measured at
+    (:func:`_band_admitted`); every other shape takes the XLA band path.
+
     ``bias`` is NOT differentiated on this path (cotangent is zero): it
     models masks — data, not parameters — exactly like the reference's CUDA
     kernels, whose masks have no gradient.  Use ``impl='default'`` /
@@ -1285,8 +1503,23 @@ def flash_attention(q, k, v, bias, seed=0, causal=False, dropout_rate=0.0,
     """
     _check_backward(backward)
     with annotate("apex.flash"):
-        out, _ = _flash_fwd(q, k, v, bias, causal, dropout_rate, seed, heads)
+        out, _ = _attention_fwd(q, k, v, bias, causal, dropout_rate, seed,
+                                heads, window)
     return out
+
+
+def _attention_fwd(q, k, v, bias, causal, dropout_rate, seed, heads, window):
+    """``(out, lse)`` of :func:`flash_attention`'s forward; ``lse`` is None
+    where the XLA band path ran."""
+    if window is None:
+        return _flash_fwd(q, k, v, bias, causal, dropout_rate, seed, heads)
+    if not causal or window < 1:
+        raise ValueError(f"a window of {window} keys wants causal=True and "
+                         "a window of at least one key")
+    if _band_admitted(q, k, bias, window, dropout_rate):
+        return _band_fwd(q, k, v, bias, window, heads)
+    return _xla_reference(q, k, v, bias, True, dropout_rate, seed, heads,
+                          window), None
 
 
 def _check_backward(backward):
@@ -1300,22 +1533,26 @@ def _check_backward(backward):
                          f"got {backward!r}")
 
 
-def _vjp_fwd(q, k, v, bias, seed, causal, dropout_rate, heads, backward):
+def _vjp_fwd(q, k, v, bias, seed, causal, dropout_rate, heads, backward,
+             window):
     _check_backward(backward)
     with annotate("apex.flash"):
-        out, lse = _flash_fwd(q, k, v, bias, causal, dropout_rate, seed,
-                              heads)
+        out, lse = _attention_fwd(q, k, v, bias, causal, dropout_rate, seed,
+                                  heads, window)
     return out, (q, k, v, bias, seed, out, lse)
 
 
-def _vjp_bwd(causal, dropout_rate, heads, backward, res, do):
+def _vjp_bwd(causal, dropout_rate, heads, backward, window, res, do):
     q, k, v, bias, seed, out, lse = res
     impl = _resolve_backward(backward)
     with annotate("apex.flash"):
-        if impl == "xla":
+        if impl == "xla" or (window is not None and lse is None):
             _tel_events.record_flash_bwd("xla")
             dq, dk, dv = _xla_bwd(q, k, v, bias, causal, dropout_rate, seed,
-                                  heads, out, lse, do)
+                                  heads, out, lse, do, window)
+        elif window is not None:
+            dq, dk, dv = _band_bwd(q, k, v, bias, window, heads, out, lse,
+                                   do)
         else:
             dq, dk, dv = _flash_bwd(q, k, v, bias, causal, dropout_rate,
                                     seed, heads, out, lse, do)

@@ -37,18 +37,25 @@ def build_bias(mask, mask_additive, *, batch, sq, sk, use_time_mask):
 
 
 def attention_core(q, k, v, bias, *, causal=False, dropout_rate=0.0,
-                   dropout_rng=None, heads=1):
+                   dropout_rng=None, heads=1, window=None):
     """q (B, H, Sq, D) pre-scaled, k/v (B, H, Sk, D), bias (1|B, 1|Sq, Sk).
-    Returns (B, H, Sq, D).  Reference math path (softmax → dropout → PV)."""
+    Returns (B, H, Sq, D).  Reference math path (softmax → dropout → PV).
+    ``window`` (causal only): query i sees keys j with i - window < j <= i,
+    the flash kernel's band."""
     B, H, Sq, D = q.shape
     Sk = k.shape[2]
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
                    k.astype(jnp.float32))
     s = s + bias[:, None, :, :]
+    if window is not None and not causal:
+        raise ValueError("a window is a causal band: pass causal=True")
     if causal:
         rows = jax.lax.broadcasted_iota(jnp.int32, (Sq, Sk), 0)
         cols = jax.lax.broadcasted_iota(jnp.int32, (Sq, Sk), 1)
-        s = jnp.where((cols <= rows)[None, None], s, -jnp.inf)
+        seen = cols <= rows
+        if window is not None:
+            seen = seen & (cols > rows - window)
+        s = jnp.where(seen[None, None], s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
     if dropout_rate > 0.0 and dropout_rng is not None:
         keep = jax.random.bernoulli(dropout_rng, 1.0 - dropout_rate, p.shape)

@@ -29,6 +29,8 @@ from .glm4_moe_lite import (Glm4MoeLiteConfig, glm47_flash_config,
                             glm4_moe_lite_init, glm4_moe_lite_share,
                             glm4_moe_lite_apply, glm4_moe_lite_loss,
                             glm4_moe_lite_routing)
+from .laguna import (LagunaConfig, laguna_s_2_1_config, laguna_init,
+                     laguna_share, laguna_apply, laguna_loss, laguna_routing)
 
 __all__ = [
     "TransformerConfig", "transformer_init", "transformer_apply",
@@ -49,4 +51,6 @@ __all__ = [
     "Glm4MoeLiteConfig", "glm47_flash_config", "glm4_moe_lite_init",
     "glm4_moe_lite_share", "glm4_moe_lite_apply", "glm4_moe_lite_loss",
     "glm4_moe_lite_routing",
+    "LagunaConfig", "laguna_s_2_1_config", "laguna_init", "laguna_share",
+    "laguna_apply", "laguna_loss", "laguna_routing",
 ]

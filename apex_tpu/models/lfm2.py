@@ -181,21 +181,22 @@ def _attention_mixer(u, lp, cfg):
     return causal_attention(q, k, v, cfg.attn_impl) @ lp["wo"].astype(dt)
 
 
-def causal_attention(q, k, v, impl: str):
+def causal_attention(q, k, v, impl: str, window=None):
     """Causal softmax attention of ``q`` (pre-scaled), ``k``, ``v`` (B, H, S,
     hd), every key/value head already repeated for its query heads: the flash
-    kernel under ``impl="fast"``, else the XLA core.  Returns (B, S, H·hd)."""
+    kernel under ``impl="fast"``, else the XLA core; ``window`` limits a
+    query to that many keys, itself the last.  Returns (B, S, H·hd)."""
     bsz, heads, seq, hd = q.shape
     no_bias = jnp.zeros((1, 1, seq), jnp.float32)
     if impl == "fast":
         from ..contrib.multihead_attn.flash import flash_attention
         ctx = flash_attention(
             q.reshape(bsz * heads, seq, hd), k.reshape(bsz * heads, seq, hd),
-            v.reshape(bsz * heads, seq, hd), no_bias, causal=True, heads=heads
-        ).reshape(bsz, heads, seq, hd)
+            v.reshape(bsz * heads, seq, hd), no_bias, causal=True, heads=heads,
+            window=window).reshape(bsz, heads, seq, hd)
     else:
         from ..contrib.multihead_attn.functional import attention_core
-        ctx = attention_core(q, k, v, no_bias, causal=True)
+        ctx = attention_core(q, k, v, no_bias, causal=True, window=window)
     return ctx.astype(q.dtype).transpose(0, 2, 1, 3).reshape(
         bsz, seq, heads * hd)
 

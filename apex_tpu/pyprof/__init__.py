@@ -63,6 +63,7 @@ SCOPES = (
     "apex.gdn_rule",       # inside it: decays and the chunked delta rule
     "apex.mla",            # models.glm4_moe_lite: a whole latent-attention mixer
     "apex.mtp",            # models.glm4_moe_lite: the MTP join, block, head, loss
+    "apex.swa",            # models.laguna: a whole sliding-window attention mixer
 )
 
 # jax strips debug info - where a named scope lives - before it hashes the

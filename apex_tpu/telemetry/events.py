@@ -175,7 +175,7 @@ def record_collective(axis_name: str, nbytes: int, n_leaves: int,
 
 
 FLASH_BWD_PATHS = ("whole_key", "projection", "resident", "partials",
-                   "split", "xla")
+                   "split", "window", "xla")
 
 
 def record_flash_bwd(path: str, bq: Optional[int] = None,

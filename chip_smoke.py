@@ -419,6 +419,34 @@ def _kernel_checks(full: bool):
                        lambda c=causal, r=rate, p=padded: flash_qkv(
                            S, B, H, c, r, p)))
 
+    # -- flash's band (a sliding window: the Laguna cell's window layers),
+    #    forward and both gradients through the public entry against the
+    #    XLA band, at the one shape the band kernels admit: S 8192 x D 128
+    #    at W 512 in bfloat16 (BH small: the twin's scores are BH x 256
+    #    MiB).  The rehearsal leaves it out: interpret mode cannot reach
+    #    that shape, and tests/L0/test_laguna.py holds the kernels to the
+    #    XLA band at small ones --
+    def flash_band(S, BH, W):
+        q, k, v, do = (rnd(10 + i, (BH, S, 128), bf16, 0.5)
+                       for i in range(4))
+        bias = jnp.zeros((1, 1, S), f32)
+        if not F._band_admitted(q, k, bias, W, 0.0):
+            raise AssertionError(f"S {S}, W {W}: not the band kernels")
+
+        def both(fn):
+            def run(a, b, c):
+                out, vjp = jax.vjp(fn, a, b, c)
+                return out, vjp(do)
+            return jax.jit(run)(q, k, v)
+        return rel_err(
+            both(lambda *t: F.flash_attention(*t, bias, 0, True, 0.0, BH,
+                                              "auto", W)),
+            both(lambda *t: F._xla_reference(*t, bias, True, 0.0, 0, BH, W)))
+
+    if full:
+        checks.append(("flash_band[S8192xW512]", 5e-2,
+                       lambda: flash_band(8192, 4, 512)))
+
     # -- xentropy, forward and backward (ragged last vocabulary block) ----
     N, V = (4096, 30592) if full else (64, 1000)
 

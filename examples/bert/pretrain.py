@@ -45,7 +45,8 @@ from apex_tpu.models import (TransformerConfig, bert_large_config,
                              qwen3_next_80b_a3b_config, qwen3_next_init,
                              qwen3_next_loss, Glm4MoeLiteConfig,
                              glm47_flash_config, glm4_moe_lite_init,
-                             glm4_moe_lite_loss)
+                             glm4_moe_lite_loss, LagunaConfig,
+                             laguna_s_2_1_config, laguna_init, laguna_loss)
 from apex_tpu.optimizers import FusedLAMB
 from apex_tpu.parallel import create_mesh, use_mesh
 from apex_tpu.utils.logging import AverageMeter, Throughput
@@ -118,6 +119,16 @@ def parse_args(argv=None):
                         "of embedding and head; latent-attention mixers, "
                         "router (all 64 outputs) and shared expert whole "
                         "(docs/glm4_moe_lite.md)")
+    p.add_argument("--laguna", type=int, default=None, metavar="EP",
+                   help="Laguna-S-2.1 at its published widths (causal LM on "
+                        "next-token batches), cut to one chip's share of an "
+                        "EP-way expert-parallel group: the first 1/EP of the "
+                        "256 routed experts of each sparse layer, the dense "
+                        "layer 0 and one whole period after it (three "
+                        "sliding-window layers, one full YaRN layer) of the "
+                        "48, --vocab rows of the 100352 of embedding and "
+                        "head; attention, gates, router (all 256 outputs) "
+                        "and shared expert whole (docs/laguna.md)")
     p.add_argument("--distributed", action="store_true")
     p.add_argument("--zero", action="store_true",
                    help="ZeRO sharded optimizer (DistributedFusedLAMB)")
@@ -289,6 +300,7 @@ def run_standard(args, cfg, mesh):
         NemotronHConfig: (nemotron_h_init, nemotron_h_loss),
         Qwen3NextConfig: (qwen3_next_init, qwen3_next_loss),
         Glm4MoeLiteConfig: (glm4_moe_lite_init, glm4_moe_lite_loss),
+        LagunaConfig: (laguna_init, laguna_loss),
     }.get(type(cfg), (transformer_init, transformer_loss))
     opt = FusedLAMB(lr=args.lr, weight_decay=0.01, max_grad_norm=1.0,
                     impl="xla",
@@ -496,6 +508,21 @@ def glm4_moe_lite_config(args):
         remat=args.remat, attn_impl=args.attn)
 
 
+#: the leading dense layer and one whole period of (full, window, window,
+#: window) after it: the least a cut keeps (layers 0-4)
+LAGUNA_CUT_LAYERS = 5
+
+
+def laguna_config(args):
+    """``--laguna EP``: the published widths, and one chip's share of
+    experts, depth and ``--vocab``."""
+    whole = laguna_s_2_1_config()
+    return laguna_s_2_1_config(
+        LAGUNA_CUT_LAYERS, vocab_size=args.vocab,
+        experts_held=(0, whole.num_experts // args.laguna),
+        dtype=jnp.bfloat16, remat=args.remat, attn_impl=args.attn)
+
+
 def main(argv=None, report=None):
     """Train; returns the last printed loss.  ``report``, a dict the
     caller owns, is filled (standard and ``--zero`` paths) with what a
@@ -524,6 +551,11 @@ def main(argv=None, report=None):
                                or args.plan or args.data):
         raise SystemExit("--glm4-moe-lite is a model preset of the standard "
                          "path on synthetic next-token batches")
+    if args.laguna and (args.bert_large or args.lfm2 or args.nemotron_h
+                        or args.qwen3_next or args.glm4_moe_lite or args.moe
+                        or args.zero or args.plan or args.data):
+        raise SystemExit("--laguna is a model preset of the standard path on "
+                         "synthetic next-token batches")
     if args.plan and (args.moe or args.zero or args.distributed
                       or args.auto_resume):
         raise SystemExit("--plan owns the parallelism decision — it does "
@@ -540,6 +572,8 @@ def main(argv=None, report=None):
         cfg = qwen3_next_config(args)
     elif args.glm4_moe_lite:
         cfg = glm4_moe_lite_config(args)
+    elif args.laguna:
+        cfg = laguna_config(args)
     elif args.moe:
         cfg = MoETransformerConfig(
             vocab_size=args.vocab, max_len=args.seq_len,
@@ -560,7 +594,7 @@ def main(argv=None, report=None):
         raise ValueError(f"batch {args.batch_size} must divide {n_dev}")
     mesh = create_mesh({"data": n_dev}, devices=jax.devices()[:n_dev])
     causal_lm = bool(args.lfm2 or args.nemotron_h or args.qwen3_next
-                     or args.glm4_moe_lite)
+                     or args.glm4_moe_lite or args.laguna)
     print(f"=> {n_dev} device(s), {'ZeRO' if args.zero else 'standard'} "
           f"optimizer, layers="
           f"{cfg.num_hidden_layers if causal_lm else cfg.num_layers} d="

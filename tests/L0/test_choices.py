@@ -44,9 +44,10 @@ from apex_tpu.contrib.optimizers import (DistributedFusedAdam,
                                          DistributedFusedLAMB)
 from apex_tpu.contrib.xentropy import softmax_xentropy as sx
 from apex_tpu.mlp import MLP
-from apex_tpu.models import (Glm4MoeLiteConfig, Lfm2Config, NemotronHConfig,
-                             Qwen3NextConfig, TransformerConfig,
-                             bert_large_config, lfm2_cut_layer_types)
+from apex_tpu.models import (Glm4MoeLiteConfig, LagunaConfig, Lfm2Config,
+                             NemotronHConfig, Qwen3NextConfig,
+                             TransformerConfig, bert_large_config,
+                             laguna_s_2_1_config, lfm2_cut_layer_types)
 from apex_tpu.optimizers import FusedAdam
 from apex_tpu.parallel import collectives, expert, overlap
 from apex_tpu.parallel.mesh import create_mesh, use_mesh
@@ -136,6 +137,12 @@ def _attention_cells():
             # latent attention: the QK head (nope + rope) is as wide as V's
             heads = model["num_attention_heads"]
             width, causal = heads * model["v_head_dim"], True
+        elif cfg["job"] == "laguna_pretrain":
+            # the full layers' causal calls (the window layers' band:
+            # ``test_window_choice``)
+            heads = model["num_attention_heads_per_layer"][
+                model["layer_types"].index("full_attention")]
+            width, causal = heads * model["head_dim"], True
         else:
             continue                    # resnet50: no attention
         cells[w["name"]] = (traffic["batch"] // w["chips"] * heads,
@@ -162,6 +169,8 @@ CELL_TILES = {
         (512, 1024), ("resident", 512, 512, 8)),      # BH 128, D 256
     "glm47_flash_30b_a3b.ep8_s4096": (
         (512, 1024), ("resident", 512, 512, 8)),      # BH 80, D 256
+    "laguna_s_2_1.ep32_s8192": (
+        (512, 1024), ("resident", 512, 512, 16)),     # BH 48, D 128
 }
 
 
@@ -190,6 +199,7 @@ CELL_BUFFERS = {
     "nemotron3_super_120b_a12b.tp8_ep64_s8192": (11264, 360448),
     "qwen3_next_80b_a3b.ep16_s4096": (40960, 327680),
     "glm47_flash_30b_a3b.ep8_s4096": (16384, 65536),
+    "laguna_s_2_1.ep32_s8192": (5120, 81920),
 }
 
 
@@ -205,6 +215,8 @@ CELL_SUM_ROWS = {
     "qwen3_next_80b_a3b.ep16_s4096": (False, 327680),
     # LFM2's experts at half its tokens: 4 · 16 384 + 16 384 > 65 536
     "glm47_flash_30b_a3b.ep8_s4096": (False, 65536),
+    # 5120 + 8192 rows in row space against ten gathers of 8192
+    "laguna_s_2_1.ep32_s8192": (True, 13312),
 }
 
 
@@ -350,6 +362,84 @@ def test_cell_choice(cell, chooser, monkeypatch, flash_events):
         assert _xent_auto_choice(monkeypatch, backend) == want
 
 
+#: (BH, S, D, causal) -> {kernel: grid} of ``flash_attention`` and its
+#: gradient with no window: BERT's S 512 on the (B·H, S, D) entry, the
+#: hybrids' S 4096 at D 64 (LFM2) and S 8192 at D 128 (Nemotron-H, and the
+#: Laguna cell's full layers at BH 48) — what they lowered to before the
+#: band existed
+NO_WINDOW = {
+    (1792, 512, 64, False): {"apex_flash_fwd": (1792, 1, 1),
+                             "apex_flash_bwd_fused": (1792, 1, 1)},
+    (256, 4096, 64, True): {"apex_flash_fwd": (256, 8, 4),
+                            "apex_flash_bwd_fused": (256, 8)},
+    (8, 8192, 128, True): {"apex_flash_fwd": (8, 16, 8),
+                           "apex_flash_bwd_fused": (8, 16)},
+    (48, 8192, 128, True): {"apex_flash_fwd": (48, 16, 8),
+                            "apex_flash_bwd_fused": (48, 16)},
+}
+
+
+def _grad_kernels(BH, S, D, causal, dtype=jnp.bfloat16, **kw):
+    x = jax.ShapeDtypeStruct((BH, S, D), dtype)
+    bias = kw.pop("bias", jnp.zeros((1, 1, S), jnp.float32))
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda q, k, v: flash_attention(q, k, v, bias, 0, causal, heads=BH,
+                                        **kw).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2)))(x, x, x)
+    return {e.params["name"]: tuple(e.params["grid_mapping"].grid)
+            for e in _walk_eqns(jaxpr.jaxpr)
+            if e.primitive.name == "pallas_call"}
+
+
+@pytest.mark.parametrize("shape", sorted(NO_WINDOW))
+def test_no_window_lowers_to_the_causal_kernels_as_before(shape,
+                                                          flash_events):
+    """``window=None`` (the default, or passed) leaves every grid, tile and
+    name as it was: the same kernels, the same grids, the same backward
+    path."""
+    assert _grad_kernels(*shape) == _grad_kernels(*shape, window=None) \
+        == NO_WINDOW[shape]
+    paths = {r["fields"]["path"] for r in flash_events.flush()
+             if r.get("name") == "flash.bwd"}
+    assert paths == {"whole_key" if shape[1] == 512 else "resident"}
+
+
+def test_window_choice(flash_events):
+    """The Laguna cell's window layers: BH 72, S 8192, D 128, bfloat16, W
+    512 — the band's grids, two key blocks a q block and two q blocks a key
+    block, on 512 x 512 tiles, path ``window``; no causal kernel."""
+    assert _grad_kernels(72, 8192, 128, True, window=512) == {
+        "apex_flash_window_fwd": (72, 16, 2),
+        "apex_flash_window_bwd_dq": (72, 16, 2),
+        "apex_flash_window_bwd_dkv": (72, 16, 2)}
+    ev = [r["fields"] for r in flash_events.flush()
+          if r.get("name") == "flash.bwd"]
+    assert ev == [{"path": "window", "bq": 512, "bk": 512, "nk": 16}]
+
+
+@pytest.mark.parametrize("what,kw", [
+    ("window 256", dict(window=256)),
+    ("window 1024", dict(window=1024)),
+    ("D 64", dict(D=64)),
+    ("float32", dict(dtype=jnp.float32)),
+    ("S 8320", dict(S=8320)),
+    ("dropout", dict(dropout_rate=0.1)),
+    ("a bias a query", dict(bias=jnp.zeros((1, 8192, 8192), jnp.float32))),
+])
+def test_the_band_kernels_refuse_what_they_were_not_measured_at(
+        what, kw, flash_events):
+    """Only the shape the band kernels compiled and ran at on the chip
+    (``flash._BAND_SHAPES``: W 512, D 128, bfloat16, whole 512-blocks, a
+    bias over keys, no dropout) takes them; any other window takes the XLA
+    band path — no Pallas kernel at all, backward ``xla``."""
+    shape = dict(BH=8, S=8192, D=128, causal=True, window=512)
+    shape.update(kw)
+    assert _grad_kernels(**shape) == {}, what
+    ev = [r["fields"] for r in flash_events.flush()
+          if r.get("name") == "flash.bwd"]
+    assert ev == [{"path": "xla"}], what
+
+
 #: the cells whose model reads q, k and v from the projection
 #: (``transformer._attention`` -> ``flash_attention_qkv``): BERT's 16 heads
 #: of 64.  The hybrids call ``flash_attention`` on (B·H, S, D) themselves.
@@ -420,6 +510,14 @@ _TINY_MODELS = {
         kv_lora_rank=8, qk_nope_head_dim=12, qk_rope_head_dim=4,
         v_head_dim=16, num_experts=8, num_experts_per_tok=2,
         moe_intermediate_size=16, experts_held=(0, 4), xent_impl="xla"),
+    "laguna_pretrain": lambda: LagunaConfig(
+        vocab_size=128, hidden_size=32, intermediate_size=64,
+        layer_types=laguna_s_2_1_config(5).layer_types,
+        mlp_layer_types=("dense",) + ("sparse",) * 4,
+        num_attention_heads_per_layer=(2, 4, 4, 4, 2), num_key_value_heads=2,
+        head_dim=16, sliding_window=8, num_experts=8, num_experts_per_tok=2,
+        moe_intermediate_size=16, shared_expert_intermediate_size=16,
+        experts_held=(0, 4), xent_impl="xla"),
 }
 
 

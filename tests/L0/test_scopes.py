@@ -20,10 +20,12 @@ import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
 from apex_tpu import amp, pyprof
-from apex_tpu.models import (Glm4MoeLiteConfig, Lfm2Config, NemotronHConfig,
-                             Qwen3NextConfig, TransformerConfig,
-                             glm4_moe_lite_init, glm4_moe_lite_loss,
-                             lfm2_cut_layer_types, lfm2_init, lfm2_loss,
+from apex_tpu.models import (Glm4MoeLiteConfig, LagunaConfig, Lfm2Config,
+                             NemotronHConfig, Qwen3NextConfig,
+                             TransformerConfig, glm4_moe_lite_init,
+                             glm4_moe_lite_loss, laguna_init, laguna_loss,
+                             laguna_s_2_1_config, lfm2_cut_layer_types,
+                             lfm2_init, lfm2_loss,
                              nemotron_h_init, nemotron_h_loss,
                              qwen3_next_init, qwen3_next_loss,
                              transformer_init, transformer_loss)
@@ -70,6 +72,16 @@ GLM4_MOE_LITE = Glm4MoeLiteConfig(
     kv_lora_rank=16, qk_nope_head_dim=12, qk_rope_head_dim=4, v_head_dim=16,
     num_experts=32, num_experts_per_tok=4, moe_intermediate_size=24,
     experts_held=(0, 8), dtype=jnp.bfloat16, remat=True, attn_impl="fast")
+# ... and the sixth: Laguna-S-2.1's dense full layer and a sparse
+# sliding-window layer, a share of the experts held
+LAGUNA = LagunaConfig(
+    vocab_size=256, hidden_size=64, intermediate_size=96,
+    layer_types=laguna_s_2_1_config(2).layer_types,
+    mlp_layer_types=("dense", "sparse"),
+    num_attention_heads_per_layer=(4, 6), num_key_value_heads=2,
+    head_dim=16, sliding_window=32, num_experts=32, num_experts_per_tok=4,
+    moe_intermediate_size=24, shared_expert_intermediate_size=24,
+    experts_held=(0, 8), dtype=jnp.bfloat16, remat=True, attn_impl="fast")
 #: the blocks only the LFM2 step enters (the Nemotron-H step enters the
 #: last three of them too)
 LFM2_ONLY = ("apex.conv", "apex.moe", "apex.router", "apex.experts")
@@ -81,6 +93,8 @@ NEMOTRON_H_ONLY = ("apex.ssm", "apex.ssm_scan", "apex.latent",
 QWEN3_NEXT_ONLY = ("apex.gdn", "apex.gdn_rule")
 #: the blocks only the GLM-4.7-Flash step enters
 GLM4_MOE_LITE_ONLY = ("apex.mla", "apex.mtp")
+#: the blocks only the Laguna-S-2.1 step enters
+LAGUNA_ONLY = ("apex.swa",)
 # "%name = <type, maybe a tuple> opcode(operands), ..., metadata={op_name=..."
 _INSTRUCTION = re.compile(
     r' = .*? ([a-z][\w-]*)\(.*metadata=\{[^}]*op_name="([^"]*)"')
@@ -157,6 +171,13 @@ def glm4_moe_lite_step_ops():
         *_state_and_batch(2, glm4_moe_lite_init, GLM4_MOE_LITE))
 
 
+@pytest.fixture(scope="module")
+def laguna_step_ops():
+    return _op_names(
+        functools.partial(_step, loss_impl=laguna_loss, cfg=LAGUNA),
+        *_state_and_batch(2, laguna_init, LAGUNA))
+
+
 def test_scopes_are_a_fixed_vocabulary():
     assert len(set(pyprof.SCOPES)) == len(pyprof.SCOPES)
     for name in pyprof.SCOPES:
@@ -166,7 +187,8 @@ def test_scopes_are_a_fixed_vocabulary():
 @pytest.mark.parametrize("ops", ["step_ops", "lfm2_step_ops",
                                  "nemotron_h_step_ops",
                                  "qwen3_next_step_ops",
-                                 "glm4_moe_lite_step_ops"])
+                                 "glm4_moe_lite_step_ops",
+                                 "laguna_step_ops"])
 def test_every_matmul_belongs_to_a_block(ops, request):
     matmuls = [path for opcode, path in request.getfixturevalue(ops)
                if opcode in ("dot", "convolution", "ragged-dot")]
@@ -179,9 +201,20 @@ def test_every_matmul_belongs_to_a_block(ops, request):
 def test_scope_occurs_in_the_step(name, step_ops, ddp_step_ops,
                                   lfm2_step_ops, nemotron_h_step_ops,
                                   qwen3_next_step_ops,
-                                  glm4_moe_lite_step_ops):
+                                  glm4_moe_lite_step_ops, laguna_step_ops):
     one_chip = {path for _, path in step_ops if name in path}
-    if name in GLM4_MOE_LITE_ONLY:
+    if name in LAGUNA_ONLY:
+        # no other step has such a block; the Laguna step enters it forward,
+        # backward and in remat's second forward
+        assert not one_chip
+        for ops in (lfm2_step_ops, nemotron_h_step_ops, qwen3_next_step_ops,
+                    glm4_moe_lite_step_ops):
+            assert not [path for _, path in ops if name in path]
+        paths = [path for _, path in laguna_step_ops if name in path]
+        for mark in ("transpose(", "rematted_computation"):
+            assert any(mark in path for path in paths), (name, mark)
+        assert any("transpose(" not in path for path in paths), name
+    elif name in GLM4_MOE_LITE_ONLY:
         # no other step has such a block; the GLM-4.7-Flash step enters it
         # forward, backward and in remat's second forward
         assert not one_chip
@@ -312,6 +345,29 @@ def test_glm4_moe_lite_step_nests_its_blocks(name, glm4_moe_lite_step_ops):
         assert any("apex.mtp" not in path for path in paths), name
         assert any("apex.mtp" in path and path.index("apex.mtp")
                    < path.index(name) for path in paths), name
+
+
+@pytest.mark.parametrize("name", ["apex.embed", "apex.attn", "apex.swa",
+                                  "apex.flash", "apex.mlp", "apex.moe",
+                                  "apex.router", "apex.experts",
+                                  "apex.shared_expert", "apex.head",
+                                  "apex.loss", "apex.amp_step"])
+def test_laguna_step_nests_its_blocks(name, laguna_step_ops):
+    """A window layer's attention is ``apex.swa`` and a full layer's
+    ``apex.attn``, each with ``apex.flash`` inside; the dense FFN is
+    ``apex.mlp``, the sparse FFN ``apex.moe`` with router, experts and the
+    gated shared expert inside."""
+    paths = [path for _, path in laguna_step_ops if name in path]
+    assert paths, name
+    if name == "apex.flash":
+        assert all(("apex.swa" in path and path.index("apex.swa")
+                    < path.index(name)) or path.index("apex.attn")
+                   < path.index(name) for path in paths), name
+        assert any("apex.swa" in path for path in paths)
+        assert any("apex.swa" not in path for path in paths)
+    if name in ("apex.router", "apex.experts", "apex.shared_expert"):
+        assert all(path.index("apex.moe") < path.index(name)
+                   for path in paths), name
 
 
 def test_update_blocks_nest_inside_amp_step(step_ops):
